@@ -81,7 +81,11 @@ def main():
         compression=compression,
         op=hvd.Adasum if args.adasum else hvd.Average,
     )
-    opt_state = tx.init(params)
+    # Broadcast both: state left on one device is another signature than
+    # the mesh-resident state a step returns, and the step would compile a
+    # second time inside the first timed iteration.
+    params = hvd.broadcast_parameters(params, root_rank=0)
+    opt_state = hvd.broadcast_optimizer_state(tx.init(params), root_rank=0)
     step = hvd.make_train_step(loss_fn, tx)
 
     if hvd.rank() == 0:

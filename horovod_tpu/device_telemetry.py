@@ -114,21 +114,6 @@ def lookup_peak_flops(device_kind: str) -> float | None:
     return None
 
 
-def normalize_cost_analysis(cost: Any) -> dict:
-    """``Compiled.cost_analysis()`` returns a dict on new jax and a
-    one-element list of dicts on older releases (None when the backend
-    has no cost model); flatten to one plain dict."""
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        out: dict = {}
-        for entry in cost:
-            if isinstance(entry, dict):
-                out.update(entry)
-        return out
-    return dict(cost)
-
-
 class DeviceTelemetry:
     """Per-engine device observability plane.
 
@@ -243,7 +228,7 @@ class DeviceTelemetry:
         try:
             compiled = jitfn.lower(*avals).compile()
             entry["compile_s"] = time.perf_counter() - t0
-            cost = normalize_cost_analysis(compiled.cost_analysis())
+            cost = compiled.cost_analysis() or {}   # None: no cost model
             entry["flops"] = float(cost.get("flops", 0.0) or 0.0)
             entry["bytes_accessed"] = float(
                 cost.get("bytes accessed", 0.0) or 0.0)

@@ -48,8 +48,6 @@ ENV_KNOBS = (
      "host:port of the rank-0 coordinator for multi-process init."),
     ("HOROVOD_TPU_ELASTIC_RETRIES", "3",
      "Elastic-training restarts allowed before giving up."),
-    ("HOROVOD_TPU_FORCE_PLATFORM", "",
-     "Force a jax platform (cpu/tpu) instead of auto-detection."),
     ("HOROVOD_TPU_HIERARCHY_LOCAL_SIZE", "0",
      "Inner mesh extent for hierarchical dispatch (0 = local devices)."),
     ("HOROVOD_TPU_LOCAL_RANK", "",

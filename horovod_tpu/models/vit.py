@@ -6,10 +6,9 @@ breadth built from this repo's own attention stack).
 TPU-first choices:
 * Patchify as a single strided conv ([P,P] kernel, stride P) — one big
   MXU contraction, no gather/reshape shuffle.
-* Attention through :func:`horovod_tpu.parallel.flash_attention` on TPU
-  (the pallas kernel benched 1.16–2.4× over dense on-chip, see
-  docs/artifacts/) with a dense fallback for CPU simulation and tiny
-  sequence lengths — resolved by ``attn_impl``.
+* Attention through :func:`horovod_tpu.parallel.flash_attention` (the
+  pallas kernel) for long sequences, dense for tiny ones — chosen by
+  ``attn_impl``.
 * bfloat16 compute / float32 params via ``dtype=jnp.bfloat16`` (MXU
   native), pre-LN blocks (stable without warmup tricks), learned
   position embeddings, mean-pool head (no CLS token: a masked-token

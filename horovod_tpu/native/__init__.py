@@ -14,6 +14,7 @@ the Python side dispatches one compiled XLA collective per returned batch.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import struct
 import subprocess
@@ -25,6 +26,8 @@ from horovod_tpu.native import _build_flags
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _REPO = os.path.dirname(os.path.dirname(_HERE))
 _SO_PATH = os.path.join(_HERE, "libhvdtpu.so")
+# Digest of the sources the library beside it was built from.
+_DIGEST_PATH = _SO_PATH + ".sha256"
 
 
 def _find_src_dir() -> str:
@@ -68,19 +71,34 @@ def _sources() -> list[str]:
     return srcs + [h for h in headers if os.path.exists(h)]
 
 
+def _source_digest() -> str:
+    """sha256 over the compile line and every source and header."""
+    h = hashlib.sha256(" ".join(
+        _build_flags.compile_cmd("libhvdtpu.so", "src")).encode())
+    for path in _sources():
+        h.update(os.path.basename(path).encode())
+        with open(path, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
 def _so_stale() -> bool:
     if not os.path.exists(_SO_PATH):
         return True
     if _SRC_DIR != os.path.join(_REPO, "native", "src"):
-        # Installed layout: pip extracts files with arbitrary mtimes, so a
-        # wheel's prebuilt .so must be trusted as-is, never "refreshed" —
-        # a rebuild there would discard the prebuild (or fail on read-only
-        # site-packages / missing g++).  Staleness only means anything in
-        # the repo layout, where sources are actually edited.
+        # Installed layout: a wheel's prebuilt .so is trusted as-is, never
+        # "refreshed" — a rebuild there would discard the prebuild (or fail
+        # on read-only site-packages / missing g++).  Staleness only means
+        # anything in the repo layout, where sources are actually edited.
         return False
-    so_mtime = os.path.getmtime(_SO_PATH)
-    return any(os.path.getmtime(s) > so_mtime for s in _sources()
-               if os.path.exists(s))
+    # By content, not mtime: a copied or checked-out tree keeps no mtimes,
+    # and must never load a library built from other sources.
+    try:
+        with open(_DIGEST_PATH) as f:
+            built_from = f.read().strip()
+    except FileNotFoundError:
+        return True
+    return built_from != _source_digest()
 
 
 def _build_so() -> None:
@@ -95,6 +113,7 @@ def _build_so() -> None:
     # ranks can never dlopen a partially-written .so.
     tmp = f"{_SO_PATH}.tmp.{os.getpid()}"
     cmd = _build_flags.compile_cmd(tmp, _SRC_DIR)
+    digest = _source_digest()      # of what g++ is about to read
     # hvdlint: disable=HVD008 -- one-shot cold-start g++ build, intentionally serialized under _build_lock before any engine thread exists
     proc = subprocess.run(cmd, capture_output=True, text=True)
     if proc.returncode != 0:
@@ -102,6 +121,9 @@ def _build_so() -> None:
             "building libhvdtpu.so failed:\n" + proc.stderr[-2000:]
         )
     os.replace(tmp, _SO_PATH)
+    with open(tmp, "w") as f:
+        f.write(digest + "\n")
+    os.replace(tmp, _DIGEST_PATH)
 
 
 def load_library() -> ctypes.CDLL:

@@ -34,7 +34,6 @@ from horovod_tpu.basics import AXIS_NAME
 from horovod_tpu.ops import collective_ops
 from horovod_tpu.ops.collective_ops import Average, Sum, _ReduceOp
 from horovod_tpu.ops.compression import Compression, TopKCompressor
-from horovod_tpu.utils.compat import shard_map as _shard_map
 
 
 def allreduce_gradients(
@@ -225,8 +224,9 @@ def make_train_step(
     ``optimizer`` is typically ``DistributedOptimizer(...)``.  The returned
     function takes ``(params, opt_state, batch)`` where ``batch`` leaves are
     rank-major (dim 0 == world size × local batch) and params/opt_state are
-    replicated; it returns updated replicated params, opt_state, and the
-    globally-averaged loss.
+    replicated (they are put on the mesh on the way in if they are not, so
+    a bare ``optimizer.init(params)`` will do); it returns updated
+    replicated params, opt_state, and the globally-averaged loss.
 
     This is the whole L5→L2 stack of the reference collapsed into one
     compiled program: examples/tensorflow_mnist.py:85's
@@ -244,7 +244,7 @@ def make_train_step(
         mean_loss = collective_ops.allreduce(loss, op=Average, axis_name=axis_name)
         return TrainStepResult(params, opt_state, mean_loss)
 
-    smapped = _shard_map(
+    smapped = jax.shard_map(
         step,
         mesh=mesh,
         in_specs=(P(), P(), P(axis_name)),
@@ -252,21 +252,43 @@ def make_train_step(
         check_vma=False,
     )
     jitted = jax.jit(smapped, donate_argnums=(0, 1) if donate else ())
-    if jax.default_backend() != "cpu":
-        return jitted
+    replicated = jax.sharding.NamedSharding(mesh, P())
+    # CPU-simulation only: XLA's in-process CPU collectives deadlock (40 s
+    # rendezvous abort) when many launches of a collective module are in
+    # flight at once — the N virtual devices share one thread pool, so deep
+    # async dispatch can starve a device thread out of an active rendezvous.
+    # Blocking per step caps the in-flight depth at 1; on TPU the async
+    # pipeline is left untouched.
+    throttle = jax.default_backend() == "cpu"
 
-    def throttled(params, opt_state, batch):
-        # CPU-simulation only: XLA's in-process CPU collectives deadlock
-        # (40 s rendezvous abort) when many launches of a collective module
-        # are in flight at once — the N virtual devices share one thread
-        # pool, so deep async dispatch can starve a device thread out of an
-        # active rendezvous.  Blocking per step caps the in-flight depth at
-        # 1; on TPU the async pipeline is left untouched.
+    def train_step(params, opt_state, batch):
+        # The step returns params and state replicated over the mesh.  State
+        # that arrives anywhere else (a bare ``tx.init(params)`` sits on one
+        # device) is a different signature from what every later call gets
+        # back, and jit would compile the whole step a second time — so it
+        # is put on the mesh here, which costs nothing once it is there.
+        params, opt_state = _on_mesh((params, opt_state), replicated)
         out = jitted(params, opt_state, batch)
-        jax.block_until_ready(out.loss)
+        if throttle:
+            jax.block_until_ready(out.loss)
         return out
 
-    return throttled
+    def lower(params, opt_state, batch):
+        params, opt_state = _on_mesh((params, opt_state), replicated)
+        return jitted.lower(params, opt_state, batch)
+
+    train_step.lower = lower
+    train_step._cache_size = jitted._cache_size
+    return train_step
+
+
+def _on_mesh(tree: Any, sharding: jax.sharding.Sharding) -> Any:
+    """``tree`` with every leaf on ``sharding``; the tree itself when it
+    already is (the steady state: one comparison per leaf)."""
+    if all(getattr(leaf, "sharding", None) == sharding
+           for leaf in jax.tree.leaves(tree)):
+        return tree
+    return jax.device_put(tree, sharding)
 
 
 # ---------------------------------------------------------------------------

@@ -40,7 +40,6 @@ from jax.sharding import PartitionSpec as P
 
 from horovod_tpu import basics
 from horovod_tpu.basics import AXIS_NAME
-from horovod_tpu.utils.compat import shard_map as _shard_map
 
 
 class ZeroStepResult(NamedTuple):
@@ -114,7 +113,7 @@ def make_zero_train_step(
             return optimizer.init(my_slice(flat))
 
         init_jitted = jax.jit(
-            _shard_map(
+            jax.shard_map(
                 init_inner, mesh=mesh, in_specs=P(), out_specs=opt_specs,
                 check_vma=False,
             )
@@ -143,7 +142,7 @@ def make_zero_train_step(
             )
 
         step_jitted = jax.jit(
-            _shard_map(
+            jax.shard_map(
                 step_inner, mesh=mesh,
                 in_specs=(P(), opt_specs, P(axis_name)),
                 out_specs=ZeroStepResult(P(), opt_specs, P()),

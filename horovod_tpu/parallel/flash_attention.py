@@ -16,12 +16,15 @@ training hot path stays at MXU-kernel speed end to end.  Set
 through :func:`horovod_tpu.parallel.attention.blockwise_attention` (the
 cross-check oracle the tests compare against).
 
-On non-TPU backends the kernels run in interpreter mode so the whole test
-matrix exercises the same code path on the CPU mesh.
+The kernels are always compiled by Mosaic.  Mosaic has no CPU target, so
+the CPU test suite opts into the Pallas interpreter explicitly with
+:func:`interpret_mode` (tests/conftest.py); nothing in the package does, so
+an interpreted kernel cannot reach a chip quietly.
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 
@@ -34,6 +37,22 @@ from horovod_tpu.parallel.attention import blockwise_attention
 
 NEG_INF = -1e30
 
+_interpret = False
+
+
+@contextlib.contextmanager
+def interpret_mode(on: bool = True):
+    """Trace :func:`flash_attention` calls made inside this context for the
+    Pallas interpreter instead of Mosaic — for tests on the CPU mesh only.
+    The choice is read when ``flash_attention`` is traced and is baked into
+    the forward and both backward kernels of that call."""
+    global _interpret
+    prev, _interpret = _interpret, on
+    try:
+        yield
+    finally:
+        _interpret = prev
+
 
 def _out_vma(*arrays):
     """Varying-mesh-axes set for kernel outputs: the union of the inputs'.
@@ -43,20 +62,14 @@ def _out_vma(*arrays):
     inputs" lets the flash kernels run without ``check_vma=False``.
     Outside shard_map every input vma is empty → ``None`` (a plain aval).
     """
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:             # older jax: no vma system at all
-        return None
     vma: frozenset = frozenset()
     for a in arrays:
-        vma = vma | getattr(typeof(a), "vma", frozenset())
+        vma = vma | jax.typeof(a).vma
     return vma or None
 
 
 def _sds(shape, dtype, vma):
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except TypeError:          # older jax: no vma parameter
-        return jax.ShapeDtypeStruct(shape, dtype)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
@@ -369,9 +382,9 @@ def _flash_backward(q, k, v, o, lse, g, *, n_heads, n_kv_heads, causal,
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, n_heads, n_kv_heads, causal, block_q, block_k, bwd_impl):
-    interpret = jax.default_backend() != "tpu"
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, n_heads, n_kv_heads, causal, block_q, block_k, bwd_impl,
+           interpret):
     out, _ = _flash_forward(q, k, v, n_heads=n_heads, n_kv_heads=n_kv_heads,
                             causal=causal, block_q=block_q, block_k=block_k,
                             interpret=interpret)
@@ -379,8 +392,7 @@ def _flash(q, k, v, n_heads, n_kv_heads, causal, block_q, block_k, bwd_impl):
 
 
 def _flash_fwd(q, k, v, n_heads, n_kv_heads, causal, block_q, block_k,
-               bwd_impl):
-    interpret = jax.default_backend() != "tpu"
+               bwd_impl, interpret):
     out, lse = _flash_forward(q, k, v, n_heads=n_heads, n_kv_heads=n_kv_heads,
                               causal=causal, block_q=block_q, block_k=block_k,
                               interpret=interpret)
@@ -388,7 +400,7 @@ def _flash_fwd(q, k, v, n_heads, n_kv_heads, causal, block_q, block_k,
 
 
 def _flash_bwd(n_heads, n_kv_heads, causal, block_q, block_k, bwd_impl,
-               res, g):
+               interpret, res, g):
     q, k, v, o, lse = res
     if bwd_impl == "blockwise":
         # Cross-check oracle: recompute gradients through the XLA blockwise
@@ -406,7 +418,6 @@ def _flash_bwd(n_heads, n_kv_heads, causal, block_q, block_k, bwd_impl,
 
         _, vjp = jax.vjp(ref, q, k, v)
         return vjp(g)
-    interpret = jax.default_backend() != "tpu"
     return _flash_backward(
         q, k, v, o, lse, g, n_heads=n_heads, n_kv_heads=n_kv_heads,
         causal=causal, block_q=block_q, block_k=block_k, interpret=interpret,
@@ -456,5 +467,6 @@ def flash_attention(
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, l, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * kvh, l, d)
     vt = v.transpose(0, 2, 1, 3).reshape(b * kvh, l, d)
-    out = _flash(qt, kt, vt, h, kvh, causal, block_q, block_k, bwd_impl)
+    out = _flash(qt, kt, vt, h, kvh, causal, block_q, block_k, bwd_impl,
+                 _interpret)
     return out.reshape(b, h, l, d).transpose(0, 2, 1, 3)

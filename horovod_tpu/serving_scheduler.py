@@ -2213,11 +2213,12 @@ def measure_spec_throughput(
 
     Both engines serve the same queue; each is warmed by a full untimed
     pass (compiles every program — the spec engine's always-wide
-    ``spec_tick`` included), then timed on a second pass.  Outputs are
-    asserted token-identical between the two engines — the greedy
-    bit-identity guarantee of :func:`llama.spec_verify_paged
-    <horovod_tpu.models.llama.spec_verify_paged>` — so the ratio prices
-    pure scheduling, never output drift.  Returns
+    ``spec_tick`` included), then timed on a second pass.  Both emit the
+    same number of tokens, so the ratio prices scheduling;
+    ``serve_spec_diverged_requests`` counts the requests whose tokens
+    differ between the two engines (0 in float32, by the greedy
+    bit-identity of :func:`llama.spec_verify_paged
+    <horovod_tpu.models.llama.spec_verify_paged>`).  Returns
     ``serve_spec_tokens_per_sec`` (spec on),
     ``serve_spec_plain_tokens_per_sec``, ``serve_spec_vs_plain_ratio``,
     ``serve_spec_accepted_per_round`` (mean accepted drafts per
@@ -2258,9 +2259,16 @@ def measure_spec_throughput(
                 (eng.spec_counters["accepted"] - acc0) / rr if rr
                 else 0.0)
             rounds = eng.spec_counters["rounds"] - rounds0
-    assert [list(a) for a in outputs[True]] == \
-        [list(b) for b in outputs[False]], "speculation parity broken"
+    # Greedy verification makes the two engines token-identical wherever
+    # they compute the same floats (float32: tests/test_spec_sched.py pins
+    # it).  In bf16 the 1-wide tick and the (draft_k + 1)-wide verify round
+    # differently and a near-tied argmax can go either way (on the chip, 10
+    # of 32 random-weight streams, PERF.md PR 21), so this is a count for
+    # the caller to judge, not an assertion.
+    diverged = sum(list(a) != list(b)
+                   for a, b in zip(outputs[True], outputs[False]))
     return {
+        "serve_spec_diverged_requests": diverged,
         "serve_spec_tokens_per_sec": n_tokens / timings[True],
         "serve_spec_plain_tokens_per_sec": n_tokens / timings[False],
         "serve_spec_vs_plain_ratio": timings[False] / timings[True],

@@ -19,7 +19,6 @@ import optax
 
 from horovod_tpu import basics
 from horovod_tpu.callbacks import Callback
-from horovod_tpu.utils.compat import shard_map as _shard_map
 from horovod_tpu.optim.distributed_optimizer import make_train_step
 
 
@@ -55,7 +54,7 @@ def make_eval_step(
         }
 
     jitted = jax.jit(
-        _shard_map(
+        jax.shard_map(
             step, mesh=mesh, in_specs=(P(), P(axis_name)), out_specs=P(),
             check_vma=False,
         )

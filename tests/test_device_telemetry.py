@@ -39,7 +39,7 @@ from horovod_tpu import metrics as metrics_mod
 from horovod_tpu.alerts import ALERT_RULES, AlertManager, rule_names
 from horovod_tpu.device_telemetry import (
     DeviceTelemetry, PROGRAMS, build_report, lookup_peak_flops,
-    maybe_telemetry, normalize_cost_analysis, report_from_events)
+    maybe_telemetry, report_from_events)
 from horovod_tpu.metrics import MetricsRegistry
 from horovod_tpu.models import llama
 from horovod_tpu.monitor import MonitorServer
@@ -106,13 +106,15 @@ def test_peak_table_lookup_and_override(monkeypatch):
     assert t.peak_source is None and not t.peak_flops_known
 
 
-def test_normalize_cost_analysis_shapes():
-    # old jax: list of dicts; new jax: one dict; no cost model: None
-    assert normalize_cost_analysis(None) == {}
-    assert normalize_cost_analysis({"flops": 3.0}) == {"flops": 3.0}
-    out = normalize_cost_analysis([{"flops": 3.0},
-                                   {"bytes accessed": 8.0}])
-    assert out == {"flops": 3.0, "bytes accessed": 8.0}
+def test_cost_analysis_is_one_dict_on_the_pinned_jax():
+    # The capture reads compiled.cost_analysis() as one dict (or None when
+    # the backend has no cost model); older jax returned a list of them.
+    import jax
+    import jax.numpy as jnp
+
+    cost = jax.jit(lambda x: x @ x).lower(jnp.ones((8, 8))).compile() \
+        .cost_analysis()
+    assert isinstance(cost, dict) and cost["flops"] > 0
 
 
 def test_poll_and_window_knobs(monkeypatch):

@@ -241,6 +241,37 @@ def test_launcher_escalates_to_kill_for_sigterm_trappers(tmp_path):
     assert "worker(s) [1] failed" in r.stderr
 
 
+def test_launcher_refuses_workers_that_would_share_tpu_chips(
+        tmp_path, monkeypatch, capsys):
+    """A chip belongs to one process at a time.  On a host with TPU chips,
+    --nproc > 1 without --cpu would start workers that each open every
+    chip; the launcher says so and exits at once instead of hanging.  Chips
+    are told from other devices by PCI vendor and device id."""
+    from horovod_tpu import launch
+
+    cards = [("0x1ae0", "0x0063")] * 4      # four v5e chips
+    cards += [("0x1ae0", "0x0042"),         # the same vendor's NIC
+              ("0x8086", "0x0063")]         # another vendor, same device id
+    for i, (vendor, device) in enumerate(cards):
+        d = tmp_path / f"0000:00:{i:02x}.0"
+        d.mkdir()
+        (d / "vendor").write_text(vendor + "\n")
+        (d / "device").write_text(device + "\n")
+    assert launch._local_tpu_chips(str(tmp_path)) == 4
+    assert launch._local_tpu_chips(str(tmp_path / "absent")) == 0
+
+    monkeypatch.setattr(launch, "_local_tpu_chips", lambda: 4)
+    for nproc in ("2", "4"):
+        with pytest.raises(SystemExit) as exc:
+            launch.main(["--nproc", nproc, "--", "true"])
+        assert exc.value.code == 2
+        err = capsys.readouterr().err
+        assert "a chip belongs to one process" in err and "--cpu" in err
+    # --cpu and --nproc 1 are untouched by any of this.
+    assert launch.main(["--nproc", "2", "--cpu", "--", "true"]) == 0
+    assert launch.main(["--nproc", "1", "--", "true"]) == 0
+
+
 def test_launcher_rejects_nproc_zero():
     r = subprocess.run(
         [sys.executable, "-m", "horovod_tpu.launch", "--nproc", "0",

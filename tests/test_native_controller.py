@@ -73,6 +73,22 @@ def drain(ctrl, n_names):
     return out
 
 
+def test_staleness_is_by_source_digest_not_mtime(tmp_path, monkeypatch):
+    """A copied tree keeps no mtimes: a library is trusted only when the
+    digest recorded beside it is that of the sources present — not because
+    it is newer than them."""
+    assert not native._so_stale()             # built and recorded above
+    recorded = tmp_path / "libhvdtpu.so.sha256"
+    monkeypatch.setattr(native, "_DIGEST_PATH", str(recorded))
+    assert native._so_stale()                 # a library with no record
+    recorded.write_text(native._source_digest() + "\n")
+    assert not native._so_stale()             # ... whatever the mtimes say
+    os.utime(native._SO_PATH, (0, 0))
+    assert not native._so_stale()
+    recorded.write_text("0" * 64 + "\n")      # built from other sources
+    assert native._so_stale()
+
+
 def test_agreement_and_fusion_across_ranks():
     """Ranks submit in different orders; all must agree on one fused order
     (the core coordinator property, reference operations.cc:1795-2007)."""

@@ -173,18 +173,49 @@ def test_broadcast_object_single_host():
     assert hvd.broadcast_object({"resume_epoch": 7}) == {"resume_epoch": 7}
 
 
-def test_train_step_cpu_backend_throttles_dispatch_depth():
-    """Pin the CPU-simulation deadlock defense: on the cpu backend
-    make_train_step must return the blocking wrapper (XLA's in-process CPU
+def test_train_step_cpu_backend_throttles_dispatch_depth(monkeypatch):
+    """Pin the CPU-simulation deadlock defense: on the cpu backend the step
+    make_train_step returns must wait for each launch (XLA's in-process CPU
     collectives abort their rendezvous when many launches are in flight;
-    see distributed_optimizer.py).  On TPU the raw jitted step is returned —
-    this test documents the contract so a refactor cannot silently drop the
-    throttle and resurface the 40s rendezvous hang."""
+    see distributed_optimizer.py).  On TPU it does not wait — this test
+    documents the contract so a refactor cannot silently drop the throttle
+    and resurface the 40s rendezvous hang."""
     assert jax.default_backend() == "cpu"  # the whole suite runs CPU-sim
+    x, y, _ = _linreg_data()
+    params = {"w": jnp.zeros(4), "b": jnp.zeros(())}
     tx = hvd.DistributedOptimizer(optax.sgd(0.1))
     step = hvd.make_train_step(_loss_fn, tx, donate=False)
-    assert step.__name__ == "throttled"
-    assert not hasattr(step, "lower")  # plain function, not jax.jit wrapper
+    waits = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(
+        jax, "block_until_ready", lambda v: waits.append(v) or real(v))
+    out = step(params, tx.init(params), (x, y))
+    assert len(waits) == 1 and waits[0] is out.loss
+
+
+def test_train_step_compiles_once_whatever_the_state_sits_on():
+    """README's recipe hands make_train_step a bare ``tx.init(params)``: on
+    one device, while the step returns its state on the mesh.  The step puts
+    what it is given on the mesh itself, so the second call is the same
+    signature as the first and nothing compiles twice — and the placed tree
+    is handed on untouched from then on."""
+    from horovod_tpu.optim.distributed_optimizer import _on_mesh
+
+    x, y, _ = _linreg_data()
+    for broadcast in (False, True):
+        params = {"w": jnp.zeros(4), "b": jnp.zeros(())}
+        tx = hvd.DistributedOptimizer(optax.sgd(0.1, momentum=0.9))
+        if broadcast:
+            params = hvd.broadcast_parameters(params, root_rank=0)
+        opt_state = tx.init(params)
+        step = hvd.make_train_step(_loss_fn, tx)
+        lowered = step.lower(params, opt_state, (x, y))
+        assert "all-reduce" in lowered.compile().as_text()
+        for _ in range(3):
+            params, opt_state, _ = step(params, opt_state, (x, y))
+        assert step._cache_size() == 1
+        state = (params, opt_state)
+        assert _on_mesh(state, hvd.replicated_sharding()) is state
 
 
 def test_backward_passes_per_step_accumulates():

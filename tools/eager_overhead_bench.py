@@ -44,12 +44,11 @@ WARMUP_ROUNDS = 2
 
 
 def _force_cpu() -> None:
+    """Before jax is imported.  Only the worker modes come here: the
+    orchestrating parent never touches jax."""
     os.environ["JAX_PLATFORMS"] = "cpu"
     if REPO not in sys.path:
         sys.path.insert(0, REPO)
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
 
 def _measure(tag: str) -> dict:

@@ -22,9 +22,7 @@ ONE exit code — the shape a CI job wants:
 
 Each flag takes the OLD and NEW saved report JSONs its tool's own
 ``--json`` (or ``--compare`` contract) produces; omitted gates are
-skipped.  Exit 1 when ANY supplied gate regressed.  ``bench.py``'s
-preflight routes its simfleet compare through here, so the bench
-round and a standalone CI job share one verdict path.
+skipped.  Exit 1 when ANY supplied gate regressed.
 """
 
 from __future__ import annotations
