@@ -195,8 +195,8 @@ METRIC_HELP: dict[str, str] = {
     "serve.phase.draft_s": "Tick phase: prompt-lookup draft proposal (spec engines)",
     "serve.phase.decode_dispatch_s": "Tick phase: host time dispatching the decode tick",
     "serve.phase.device_sync_s": "Tick phase: blocking token readback (device wait)",
-    "serve.phase.device_sync_compute_est_s": "Device-sync sub-phase: cost-model-predicted device compute share",
-    "serve.phase.device_sync_host_stall_s": "Device-sync sub-phase: readback wait beyond predicted device time",
+    "serve.phase.device_sync_compute_est_s": "Device-sync sub-phase: cost-model-predicted device compute share (estimate, host clock, not on the device trace)",
+    "serve.phase.device_sync_host_stall_s": "Device-sync sub-phase: readback wait beyond predicted device time (estimate, host clock, not on the device trace)",
     "serve.phase.verify_s": "Tick phase: acceptance + token emission (spec engines)",
     "serve.phase.sample_postprocess_s": "Tick phase: per-slot token handling and retirement",
     "serve.phase.bookkeeping_s": "Tick phase: counters, gauges, sentry, watchdog",

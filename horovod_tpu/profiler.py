@@ -1,55 +1,68 @@
-"""Per-tick phase profiler for the serving engine.
+"""Phase spans and the per-tick phase profiler of the serving engine.
 
 Continuous-batching schedulers hide host-side stalls inside "decode
 time": admission bookkeeping, chunked-prefill dispatch, the blocking
 token readback, and per-request postprocessing all happen between two
 device ticks, and a whole-step latency histogram cannot say which one
 got slower.  vLLM and SGLang both ship per-phase step timing for
-exactly this reason; :class:`TickProfiler` is that layer here, stdlib
-only, threaded through :meth:`ServeEngine.step
-<horovod_tpu.serving_scheduler.ServeEngine.step>`.
+exactly this reason.  Here it comes in two levels, driven by the one
+set of call sites in :meth:`ServeEngine.step
+<horovod_tpu.serving_scheduler.ServeEngine.step>`; the engine always
+holds one of the two (``profile`` / ``HVD_TPU_PROFILE`` decides which):
 
-Design rules (the acceptance criteria of the profiler):
+* :class:`PhaseSpans` — the default.  Every phase is a
+  ``jax.profiler.TraceAnnotation`` (through
+  :func:`horovod_tpu.timeline.trace_annotation`): ``serve.step`` around
+  the tick, ``serve.step.<phase>`` tiling it, ``serve.step.<sub-phase>``
+  nested inside their parent.  They land on the host plane of jax's
+  profiler trace, the clock the device's ``XLA Ops`` are on, so an idle
+  gap of the device can be pinned on the phase the host was in.  While
+  no trace is being taken an annotation costs under a microsecond and
+  records nothing: the xplane is the only span record.
+* :class:`TickProfiler` — the same spans plus host clocks
+  (``time.perf_counter``): per-phase histograms in the engine's
+  :class:`~horovod_tpu.metrics.MetricsRegistry` (``serve.phase.*_s``),
+  one ``serve.profile_tick`` structured event per tick when the registry
+  has a JSONL sink (replayed by ``tools/profile_report.py``), and
+  ``report()`` over a rolling window of the last
+  ``HVD_TPU_PROFILE_WINDOW`` ticks — the payload of
+  ``metrics_snapshot()["profile"]`` and the monitor's ``/profile``
+  endpoint.
 
-* **Free when disabled.**  The engine holds ``prof = None`` and every
-  call site is a single ``is not None`` test — no wrapper objects, no
-  no-op method dispatch on the hot path.
-* **No new jit signatures when enabled.**  The profiler only reads
-  ``time.perf_counter()`` and feeds host-side instruments; it never
-  touches a traced value, so ``compile_cache_sizes()`` is unchanged
-  (pinned by ``tests/test_profiler.py``).
-* **Phases tile the tick.**  ``mark(phase)`` charges the time since the
-  previous boundary, so the top-level :data:`PHASES` sum to the
-  measured step wall time by construction (the final ``mark`` →
-  ``return`` gap is a few statements of python).  :data:`SUB_PHASES`
-  are attributed *inside* their parent via explicit ``add()`` intervals
-  and are excluded from the coverage arithmetic.
+Design rules (pinned by ``tests/test_profiler.py``):
 
-Each tick lands in three sinks: per-phase histograms in the engine's
-:class:`~horovod_tpu.metrics.MetricsRegistry` (``serve.phase.*_s``),
-closed async spans named ``phase/<name>`` on the timeline (id = step,
-aggregated by ``tools/timeline_summary.py``), and one
-``serve.profile_tick`` structured event when the registry has a JSONL
-sink (replayed by ``tools/profile_report.py``).  ``report()`` summarizes
-a rolling window of the last ``HVD_TPU_PROFILE_WINDOW`` ticks — the
-payload of ``metrics_snapshot()["profile"]`` and the monitor's
-``/profile`` endpoint.
+* **One vocabulary.**  :data:`PHASES`, :data:`SPEC_PHASES` and
+  :data:`SUB_PHASES` name the phases in ``/profile``, in the
+  ``serve.phase.*_s`` histograms and (behind ``serve.step.``) in the
+  trace, letter for letter.
+* **Host code only.**  Neither level touches a traced value or sits
+  inside a jitted function, so ``compile_cache_sizes()`` is unchanged.
+* **Phases tile the tick.**  ``begin(step)`` opens the tick in its
+  first phase and ``mark(phase)`` is the boundary at which ``phase``
+  starts and the phase before it ends, so the top-level phases sum to
+  the tick's wall time by construction (in the trace: up to the few
+  statements between ``step()``'s entry and ``begin``).
+  :data:`SUB_PHASES` are intervals *inside* their parent (``sub()``,
+  and for the cost-model pair ``add()``) and are excluded from the
+  coverage arithmetic.
 """
 
 from __future__ import annotations
 
 import collections
+import contextlib
 import os
 import threading
 import time
-from typing import Any
 
 from horovod_tpu import metrics as metrics_mod
+from horovod_tpu.timeline import trace_annotation
 
 #: Top-level phases in ``step()`` order.  They TILE the tick — each is
 #: measured boundary-to-boundary, so their sum equals the tick wall time.
-#: Every engine produces exactly these; schema consumers (replay,
-#: timeline aggregation, the bench arm) may rely on their presence.
+#: Every engine produces exactly these (the three of the decode tick
+#: only on steps in which a row decodes); schema consumers (replay, the
+#: bench arm) may rely on their presence in ``report()``.
 PHASES = ("expire", "admit", "decode_dispatch", "device_sync",
           "sample_postprocess", "bookkeeping")
 
@@ -61,17 +74,22 @@ PHASES = ("expire", "admit", "decode_dispatch", "device_sync",
 SPEC_PHASES = ("draft", "verify")
 
 #: Nested sub-phases (explicit intervals inside a parent phase).  They
-#: overlap their parent, so coverage math skips them.  The
-#: ``device_sync`` pair is the device-telemetry split of the readback
-#: wait: cost-model-predicted device compute vs host stall (only
-#: emitted when the engine runs with ``device_telemetry``).
+#: overlap their parent, so coverage math skips them.  The ``admit``
+#: pair are spans (``sub()``); the ``device_sync`` pair is the
+#: device-telemetry split of the readback wait: cost-model-predicted
+#: device compute vs host stall (only emitted when the engine runs with
+#: ``device_telemetry``) — an estimate, so a host-clock ``add()`` of
+#: :class:`TickProfiler` alone and never an interval on the trace.
 SUB_PHASES = ("admit.cache_acquire", "admit.prefill_dispatch",
               "device_sync.compute_est", "device_sync.host_stall")
 
-_DEFAULT_WINDOW = 256
+#: The span around one ``step()``; its phases are ``serve.step.<phase>``,
+#: the names built once so that the hot path concatenates nothing.
+STEP_SPAN = "serve.step"
+_SPAN_NAMES = {p: f"{STEP_SPAN}.{p}"
+               for p in PHASES + SPEC_PHASES + SUB_PHASES}
 
-#: The timeline track profiler spans live on.
-TRACK = "serving.profiler"
+_DEFAULT_WINDOW = 256
 
 
 def _env_window() -> int:
@@ -82,25 +100,73 @@ def _env_window() -> int:
         return _DEFAULT_WINDOW
 
 
-class TickProfiler:
-    """Mark-based per-tick phase timer.
+class PhaseSpans:
+    """The phases of one ``step()`` as spans on the profiler trace.
 
     The engine thread drives ``begin(step)`` → ``mark(phase)`` /
-    ``add(sub_phase, t0, t1)`` → ``end()`` once per ``step()``; the
-    monitor thread calls ``report()`` on scrape.  Only the rolling
+    ``with sub(sub_phase)`` → ``end()`` once per ``step()``, ``end()`` in
+    a ``finally`` so that an exception out of the step leaves no span
+    open.  All state is engine-thread private (one ``step()`` at a
+    time); nothing is kept once a span has closed."""
+
+    def __init__(self) -> None:
+        self._step_span = None
+        self._phase_span = None
+
+    def begin(self, step: int) -> None:
+        """Open the tick in its first phase."""
+        self._step_span = trace_annotation(STEP_SPAN)
+        self._step_span.__enter__()
+        self._open(PHASES[0])
+
+    def mark(self, phase: str) -> None:
+        """The boundary at which ``phase`` starts: the phase open until
+        here ends."""
+        self._phase_span.__exit__(None, None, None)
+        self._open(phase)
+
+    def sub(self, phase: str):
+        """Context manager around a nested sub-phase; the parent phase
+        stays open and still covers it."""
+        return trace_annotation(_SPAN_NAMES[phase])
+
+    def add(self, phase: str, t0: float, t1: float) -> None:
+        """A cost-model interval has no place on the trace: only
+        :class:`TickProfiler` keeps it."""
+
+    def end(self) -> None:
+        """Close the open phase and the tick."""
+        self._phase_span.__exit__(None, None, None)
+        self._step_span.__exit__(None, None, None)
+        self._phase_span = self._step_span = None
+
+    def report(self) -> dict | None:
+        """Spans alone keep no numbers: ``None``."""
+        return None
+
+    def _open(self, phase: str) -> None:
+        self._phase_span = trace_annotation(_SPAN_NAMES[phase])
+        self._phase_span.__enter__()
+
+
+class TickProfiler(PhaseSpans):
+    """:class:`PhaseSpans` plus host clocks: what each phase cost, per
+    tick and over a rolling window.
+
+    The monitor thread calls ``report()`` on scrape.  Only the rolling
     window crosses threads — the per-tick scratch state is engine-thread
     private by construction (one ``step()`` at a time)."""
 
     _GUARDED_BY_LOCK = ("_ring", "_n_ticks")
 
     def __init__(self, metrics: "metrics_mod.MetricsRegistry",
-                 timeline: Any = None, window: int | None = None):
+                 window: int | None = None):
+        super().__init__()
         window = _env_window() if window is None else window
         if window < 1:
             raise ValueError(f"profile window must be >= 1, got {window}")
         self.window = window
         self.metrics = metrics
-        self.timeline = timeline
         self._lock = threading.Lock()
         self._ring: collections.deque[dict] = collections.deque(
             maxlen=window)
@@ -109,6 +175,7 @@ class TickProfiler:
         self._cur: dict[str, float] = {}
         self._t0 = 0.0
         self._t_last = 0.0
+        self._phase = PHASES[0]
         self._step = -1
         # Pre-bound histograms, registered by LITERAL name (the HVD005
         # contract) so the snapshot is schema-stable from tick 0 and the
@@ -143,32 +210,36 @@ class TickProfiler:
         """Open a tick: resets the scratch dict and both clocks."""
         self._step = step
         self._cur = {}
+        self._phase = PHASES[0]
         self._t0 = self._t_last = time.perf_counter()
+        super().begin(step)
 
     def mark(self, phase: str) -> None:
-        """Close the current tiling boundary: charges ``phase`` with the
-        time since the previous ``mark``/``begin``."""
-        now = time.perf_counter()
-        t0, self._t_last = self._t_last, now
-        self._cur[phase] = self._cur.get(phase, 0.0) + (now - t0)
-        if self.timeline is not None:
-            self.timeline.async_span(TRACK, "phase/" + phase,
-                                     self._step, t0, now)
+        """The boundary at which ``phase`` starts: the phase open until
+        here is charged with the time since the previous boundary."""
+        super().mark(phase)
+        self._charge()
+        self._phase = phase
+
+    @contextlib.contextmanager
+    def sub(self, phase: str):
+        t0 = time.perf_counter()
+        with super().sub(phase):
+            yield
+        self.add(phase, t0, time.perf_counter())
 
     def add(self, phase: str, t0: float, t1: float) -> None:
         """Attribute an explicit ``[t0, t1]`` ``perf_counter`` interval
         to a nested sub-phase WITHOUT moving the tiling boundary (the
         parent phase still covers it)."""
         self._cur[phase] = self._cur.get(phase, 0.0) + (t1 - t0)
-        if self.timeline is not None:
-            self.timeline.async_span(TRACK, "phase/" + phase,
-                                     self._step, t0, t1)
 
     def end(self) -> None:
-        """Close the tick: the trailing time becomes ``bookkeeping``,
-        every phase feeds its histogram, the tick joins the rolling
-        window, and one ``serve.profile_tick`` event is emitted."""
-        self.mark("bookkeeping")
+        """Close the tick: the open phase is charged, every phase feeds
+        its histogram, the tick joins the rolling window, and one
+        ``serve.profile_tick`` event is emitted."""
+        super().end()
+        self._charge()
         cur = self._cur
         cur["tick"] = self._t_last - self._t0
         for phase, dt in cur.items():
@@ -181,6 +252,11 @@ class TickProfiler:
         self.metrics.event(
             "serve.profile_tick", step=self._step, tick_s=cur["tick"],
             phases={k: v for k, v in cur.items() if k != "tick"})
+
+    def _charge(self) -> None:
+        now = time.perf_counter()
+        t0, self._t_last = self._t_last, now
+        self._cur[self._phase] = self._cur.get(self._phase, 0.0) + (now - t0)
 
     # -- reporting (any thread) --------------------------------------------
 

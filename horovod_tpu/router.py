@@ -94,6 +94,7 @@ from horovod_tpu.monitor import env_float
 from horovod_tpu.prefix_cache import chunk_path_digests
 from horovod_tpu.serving import (FAILED, OK, REJECTED, Request,
                                  RequestResult)
+from horovod_tpu.timeline import trace_annotation
 
 # ---------------------------------------------------------------------------
 # Shadow prefix index: what the router believes each replica has cached.
@@ -450,32 +451,38 @@ class LocalReplica(ReplicaHandle):
         }
 
     def _pump(self) -> None:
+        # Each section of the loop body is a span on jax's profiler
+        # trace (free while none is taken); with the engine's own
+        # serve.step between them the thread has no stretch without a
+        # name, so a device idle gap can be pinned on the pump.
         eng = self.engine
         while True:
-            with self._lock:
-                if self._stop:
-                    return
-                batch, self._inbox = self._inbox, []
-            for k, (req, cb) in enumerate(batch):
-                try:
-                    rid = eng.submit(req)
-                except (TypeError, ValueError) as e:
-                    # Engine-side validation, including TypeError from
-                    # lifecycle-field arithmetic on a malformed request:
-                    # surface as a terminal REJECTED rather than killing
-                    # a well-behaved fleet over one bad request.
-                    cb(RequestResult([], REJECTED, e))
-                    continue
-                except BaseException:
-                    for _req3, cb3 in batch[k:]:
-                        cb3(None)
-                    self._die()
-                    return
-                if rid in eng.results:      # rejected-on-submit
-                    cb(eng.results[rid])
-                else:
-                    with self._lock:
-                        self._cbs[rid] = cb
+            with trace_annotation("replica.pump.submit"):
+                with self._lock:
+                    if self._stop:
+                        return
+                    batch, self._inbox = self._inbox, []
+                for k, (req, cb) in enumerate(batch):
+                    try:
+                        rid = eng.submit(req)
+                    except (TypeError, ValueError) as e:
+                        # Engine-side validation, including TypeError
+                        # from lifecycle-field arithmetic on a malformed
+                        # request: surface as a terminal REJECTED rather
+                        # than killing a well-behaved fleet over one bad
+                        # request.
+                        cb(RequestResult([], REJECTED, e))
+                        continue
+                    except BaseException:
+                        for _req3, cb3 in batch[k:]:
+                            cb3(None)
+                        self._die()
+                        return
+                    if rid in eng.results:      # rejected-on-submit
+                        cb(eng.results[rid])
+                    else:
+                        with self._lock:
+                            self._cbs[rid] = cb
             stepped = False
             finished: dict[int, RequestResult] = {}
             try:
@@ -486,20 +493,22 @@ class LocalReplica(ReplicaHandle):
             except BaseException:
                 self._die()
                 return
-            for rid, res in finished.items():
-                with self._lock:
-                    cb2 = self._cbs.pop(rid, None)
-                if cb2 is not None:
-                    cb2(res)
+            with trace_annotation("replica.pump.callbacks"):
+                for rid, res in finished.items():
+                    with self._lock:
+                        cb2 = self._cbs.pop(rid, None)
+                    if cb2 is not None:
+                        cb2(res)
             try:
-                with self._lock:
+                with trace_annotation("replica.pump.view"), self._lock:
                     self._refresh_view_locked()
             except BaseException:
                 self._die()
                 return
             if not stepped:
-                self._wake.wait(0.005)
-                self._wake.clear()
+                with trace_annotation("replica.pump.wait"):
+                    self._wake.wait(0.005)
+                    self._wake.clear()
 
     def _die(self) -> None:
         """Mark dead, then hand every in-flight request back to the
@@ -1318,7 +1327,8 @@ class RouterServer:
         whose terminal result is journaled answers from the journal
         without touching a replica; a key still in flight shares the
         original's outcome instead of running twice."""
-        return self._route(req, idempotency_key).rid
+        with trace_annotation("router.route"):
+            return self._route(req, idempotency_key).rid
 
     def _route(self, req: Request,
                idempotency_key: str | None = None) -> _Ticket:
@@ -1363,7 +1373,8 @@ class RouterServer:
                     return ticket
             if ticket.result is None:
                 t0 = self.clock()
-                shed = self._admission_locked()
+                with trace_annotation("router.admission"):
+                    shed = self._admission_locked()
                 ticket.admission_s = self.clock() - t0
                 if shed is not None:
                     self._shed_locked(ticket, shed)
@@ -1373,7 +1384,8 @@ class RouterServer:
                     if idempotency_key is not None:
                         self._journal_inflight[idempotency_key] = rid
                 t0 = self.clock()
-                handle, info = self._place_locked(ticket)
+                with trace_annotation("router.place"):
+                    handle, info = self._place_locked(ticket)
                 ticket.route_decision_s = self.clock() - t0
         if ticket.result is not None:       # journal dedup hit
             ticket.done.set()
@@ -1411,7 +1423,8 @@ class RouterServer:
             ticket.attempt_parent = ticket.tctx.span_id
             ticket.attempt_t0 = ticket.submit_ts
             req.trace_ctx = ticket.attempt_ctx
-        handle.submit(req, lambda res, t=ticket: self._on_done(t, res))
+        with trace_annotation("router.submit"):
+            handle.submit(req, lambda res, t=ticket: self._on_done(t, res))
         return ticket
 
     def result(self, rid: int,
@@ -1543,7 +1556,8 @@ class RouterServer:
         JSON reply.  Shed requests answer 429 (back off and retry is
         the right client response to load shedding); every other
         terminal status is a 200 whose ``status`` field speaks."""
-        ticket = self._route(req, idempotency_key)
+        with trace_annotation("router.route"):
+            ticket = self._route(req, idempotency_key)
         ticket.done.wait()
         with self._lock:
             # Claim the ticket with the reply: the HTTP reply is its

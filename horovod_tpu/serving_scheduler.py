@@ -436,14 +436,15 @@ class ServeEngine:
         self.tracer = tracing_mod.Tracer(self.metrics)
         self._trace_fraction = tracing_mod.env_sample_fraction()
         self._trace_seed = tracing_mod.env_trace_seed()
-        # Per-tick phase profiler: None = env-driven (HVD_TPU_PROFILE=1).
-        # Off means prof is None and every call site is one `is not
-        # None` test — the hot path pays nothing.
+        # The phases of step(): None = env-driven (HVD_TPU_PROFILE=1).
+        # Off, they are spans on jax's profiler trace and nothing else
+        # (PhaseSpans: free while no trace is taken); on, TickProfiler
+        # adds host clocks, histograms and report().
         if profile is None:
             profile = os.environ.get("HVD_TPU_PROFILE", "") == "1"
         self.prof = (profiler_mod.TickProfiler(
-            self.metrics, timeline=timeline, window=profile_window)
-            if profile else None)
+            self.metrics, window=profile_window)
+            if profile else profiler_mod.PhaseSpans())
         # Device telemetry plane (horovod_tpu.device_telemetry): XLA
         # cost model + compile ledger + HBM polling + the device_sync
         # compute/stall split.  None = env-driven
@@ -741,8 +742,9 @@ class ServeEngine:
             # router needs to know about THIS replica's cached prefixes
             # (rides /snapshot via the monitor for free).
             snap["prefix"] = self.prefix.key_digest()
-        if self.prof is not None:
-            snap["profile"] = self.prof.report()
+        profile = self.prof.report()
+        if profile is not None:
+            snap["profile"] = profile
         if self.device is not None:
             snap["device"] = self.device.report()
         if self.sampler is not None:
@@ -871,8 +873,8 @@ class ServeEngine:
             f" tp_size={self.tp_size}"
             f" shard_total="
             f"{self._shard_block_bytes * self.pcache.k.shape[1]}")
-        if self.prof is not None:
-            rep = self.prof.report()
+        rep = self.prof.report()
+        if rep is not None:
             lines.append(
                 "  profile (mean ms over last "
                 f"{rep['n']} ticks): " + " ".join(
@@ -1149,13 +1151,9 @@ class ServeEngine:
             if self.prefix is not None:
                 try:
                     self.faults.check("serve.cache", key=e.rid)
-                    t_cq = (0.0 if self.prof is None
-                            else time.perf_counter())
-                    hit = self.prefix.acquire(
-                        list(e.req.prompt) + list(e.prior))
-                    if self.prof is not None:
-                        self.prof.add("admit.cache_acquire", t_cq,
-                                      time.perf_counter())
+                    with self.prof.sub("admit.cache_acquire"):
+                        hit = self.prefix.acquire(
+                            list(e.req.prompt) + list(e.prior))
                 except Exception as exc:
                     # quarantine: nothing was referenced, the index and
                     # every shared block are intact — only this request
@@ -1538,14 +1536,21 @@ class ServeEngine:
         slot, then one decode tick over the pool.  Returns
         ``{request_id: RequestResult}`` for every request that reached a
         terminal state during the step."""
+        # The phases are mark-based: begin() opens the tick in `expire`
+        # and each mark() is the boundary at which the named phase
+        # starts, so they tile the tick — as spans on the profiler
+        # trace and, with profiling on, as host-clock shares.
+        prof = self.prof
+        prof.begin(self.step_index)
+        try:
+            return self._step(prof)
+        finally:
+            prof.end()      # also closes what an exception left open
+
+    def _step(self, prof: profiler_mod.PhaseSpans
+              ) -> dict[int, RequestResult]:
         self._finished = {}
         progress = 0
-        # Phase profiling is mark-based: each boundary charges the time
-        # since the previous one, so the phases tile the tick.  prof is
-        # None when disabled — the only cost then is these None tests.
-        prof = self.prof
-        if prof is not None:
-            prof.begin(self.step_index)
         # deadlines first: an expired request must not admit or tick
         now = None
         if (any(e.deadline is not None for e in self._queue)
@@ -1571,8 +1576,9 @@ class ServeEngine:
                 e.wait_steps -= 1
                 progress += 1
             i += 1
-        if prof is not None:
-            prof.mark("expire")       # deadlines + queue bookkeeping
+        # admit covers _admit_ready + preemption + the prefill windows;
+        # the cache lookups and the window dispatch are nested spans
+        prof.mark("admit")
         admitted, starved_need = self._admit_ready()
         progress += admitted
         if starved_need is None:
@@ -1587,62 +1593,57 @@ class ServeEngine:
                     self._starve_steps = 0
                     more, _ = self._admit_ready()  # head admits this step
                     progress += more
-        t_pf = 0.0 if prof is None else time.perf_counter()
-        for slot, s in enumerate(self._slots):
-            if s.state != PREFILL:
-                continue
-            if s.wait_steps > 0:          # prefill-retry backoff
-                s.wait_steps -= 1
+        with prof.sub("admit.prefill_dispatch"):
+            for slot, s in enumerate(self._slots):
+                if s.state != PREFILL:
+                    continue
+                if s.wait_steps > 0:          # prefill-retry backoff
+                    s.wait_steps -= 1
+                    progress += 1
+                    continue
+                w = s.w_done
+                final = w == s.n_win - 1
+                toks = s.padded[:, w * self.chunk:(w + 1) * self.chunk]
+                # windows cover prompt[base:] — a prefix-cache hit rewound
+                # nothing: the row's length started at base, so positions
+                # [0, base) are the shared blocks' KV, never rewritten
+                new_len = (s.true_len if final
+                           else s.base + (w + 1) * self.chunk)
+                sel = (s.true_len - 1 - s.base - w * self.chunk
+                       if final else 0)
+                tr = self.traces.get(s.request_id)
+                traced = tr is not None and tr.trace_id is not None
+                t_chunk = time.monotonic() if traced else 0.0
+                try:
+                    self.faults.check("serve.prefill", key=s.request_id)
+                    self.pcache, self.last_logits = self._chunk(
+                        self.params, self.pcache, self.last_logits,
+                        jnp.asarray(toks), jnp.asarray(slot, jnp.int32),
+                        jnp.asarray(new_len, jnp.int32),
+                        jnp.asarray(sel, jnp.int32))
+                except Exception as exc:
+                    self._slot_fault(slot, exc)
+                    progress += 1
+                    continue
+                if self.device is not None:
+                    # chunk args materialized per call: the token window
+                    # plus three int32 scalars (slot / new_len / sel).
+                    self.device.dispatch("chunk",
+                                         h2d_bytes=toks.nbytes + 12)
+                s.w_done += 1
                 progress += 1
-                continue
-            w = s.w_done
-            final = w == s.n_win - 1
-            toks = s.padded[:, w * self.chunk:(w + 1) * self.chunk]
-            # windows cover prompt[base:] — a prefix-cache hit rewound
-            # nothing: the row's length started at base, so positions
-            # [0, base) are the shared blocks' KV, never rewritten
-            new_len = (s.true_len if final
-                       else s.base + (w + 1) * self.chunk)
-            sel = (s.true_len - 1 - s.base - w * self.chunk
-                   if final else 0)
-            tr = self.traces.get(s.request_id)
-            traced = tr is not None and tr.trace_id is not None
-            t_chunk = time.monotonic() if traced else 0.0
-            try:
-                self.faults.check("serve.prefill", key=s.request_id)
-                self.pcache, self.last_logits = self._chunk(
-                    self.params, self.pcache, self.last_logits,
-                    jnp.asarray(toks), jnp.asarray(slot, jnp.int32),
-                    jnp.asarray(new_len, jnp.int32),
-                    jnp.asarray(sel, jnp.int32))
-            except Exception as exc:
-                self._slot_fault(slot, exc)
-                progress += 1
-                continue
-            if self.device is not None:
-                # chunk args materialized per call: the token window
-                # plus three int32 scalars (slot / new_len / sel).
-                self.device.dispatch("chunk",
-                                     h2d_bytes=toks.nbytes + 12)
-            s.w_done += 1
-            progress += 1
-            if tr is not None:
-                if traced:
-                    self._emit_chunk_span(tr, t_chunk, time.monotonic())
-                tr.prefill_chunks += 1
-            if final:
-                s.state = DECODE      # joins this step's tick
-        if prof is not None:
-            # admit covers _admit_ready + preemption + the prefill
-            # windows; the dispatch portion is also attributed to the
-            # nested admit.prefill_dispatch sub-phase.
-            prof.add("admit.prefill_dispatch", t_pf, time.perf_counter())
-            prof.mark("admit")
+                if tr is not None:
+                    if traced:
+                        self._emit_chunk_span(tr, t_chunk, time.monotonic())
+                    tr.prefill_chunks += 1
+                if final:
+                    s.state = DECODE      # joins this step's tick
         decoding = [i for i, s in enumerate(self._slots)
                     if s.state == DECODE]
         spec = self.spec and bool(decoding)
         drafts_host: np.ndarray | None = None
         if spec:
+            prof.mark("draft")
             # draft phase: each decoding row proposes up to draft_k
             # continuation tokens from its own history; -1 pads can
             # never be accepted (argmax preds are >= 0).  Drafting is
@@ -1663,9 +1664,8 @@ class ServeEngine:
                 if prop:
                     drafts_host[slot, :len(prop)] = prop
                     self._bump_spec("proposed", len(prop))
-            if prof is not None:
-                prof.mark("draft")
         if decoding:
+            prof.mark("decode_dispatch")
             try:
                 active = np.zeros((self.n_slots,), np.int32)
                 active[decoding] = 1
@@ -1685,11 +1685,10 @@ class ServeEngine:
                         "spec_tick" if spec else "tick",
                         h2d_bytes=active.nbytes + (
                             drafts_host.nbytes if spec else 0))
-                if prof is not None:
-                    prof.mark("decode_dispatch")
                 # np.asarray on the device token array is the readback
                 # boundary: everything the tick queued must complete
                 # first, so this wait is the device-time share.
+                prof.mark("device_sync")
                 t_sync0 = time.perf_counter()
                 tok_host = np.asarray(tok)
                 if spec:
@@ -1707,13 +1706,13 @@ class ServeEngine:
                     est, stall = self.device.on_sync(
                         "spec_tick" if spec else "tick",
                         t_sync0, t_sync1, d2h_bytes=d2h)
-                    if prof is not None:
-                        prof.add("device_sync.compute_est",
-                                 t_sync0, t_sync0 + est)
-                        prof.add("device_sync.host_stall",
-                                 t_sync0 + est, t_sync1)
-                if prof is not None:
-                    prof.mark("device_sync")
+                    prof.add("device_sync.compute_est",
+                             t_sync0, t_sync0 + est)
+                    prof.add("device_sync.host_stall",
+                             t_sync0 + est, t_sync1)
+                # spec engines account their acceptance/emission loop
+                # as `verify`; plain engines keep the classic name
+                prof.mark("verify" if spec else "sample_postprocess")
             except Exception as exc:
                 # a whole-tick failure cannot be attributed to one row;
                 # quarantine every decoding row (transients replay)
@@ -1762,10 +1761,7 @@ class ServeEngine:
                         if s.budget <= 0 or t == s.eos:
                             self._terminate(slot, OK)
                             break
-        if prof is not None:
-            # spec engines account their acceptance/emission loop as
-            # `verify`; plain engines keep the classic name
-            prof.mark("verify" if spec else "sample_postprocess")
+        prof.mark("bookkeeping")
         if self.timeline is not None:
             self.timeline.counter(
                 "serving.scheduler", "SCHED",
@@ -1865,8 +1861,6 @@ class ServeEngine:
             self.device.on_step(self.step_index)
         self._last_step_ts = time.monotonic()
         self.step_index += 1
-        if prof is not None:
-            prof.end()                # closes the bookkeeping phase
         return self._finished
 
     def run(self, requests: list[Request]) -> list[RequestResult]:
@@ -1987,7 +1981,9 @@ def measure_throughput(
     scraper = threading.Thread(target=_scrape_loop, daemon=True)
     scraper.start()
     preg = metrics_mod.MetricsRegistry(event_log=None)
-    prof = profiler_mod.TickProfiler(preg, timeline=eng.timeline)
+    prof = profiler_mod.TickProfiler(preg)
+    # every other leg runs with the spans alone (profiling off)
+    spans = eng.prof = profiler_mod.PhaseSpans()
     hreg = metrics_mod.MetricsRegistry(event_log=None)
     # 20 Hz sampling is 20x the shipping default — the health arm
     # prices a deliberately aggressive cadence.
@@ -2022,7 +2018,7 @@ def measure_throughput(
             eng.metrics = preg
             eng.prof = prof
             t_serve_prof = min(t_serve_prof, _timed_pass())
-            eng.prof = None
+            eng.prof = spans
             # health leg: time-series sampler + alert evaluation ON in
             # the step loop (acceptance: within 2 % of the monitor
             # baseline).
@@ -2050,7 +2046,7 @@ def measure_throughput(
             dev_pass_flops = dtel.total_flops - dev_flops0
             eng.device = None
     finally:
-        eng.prof = None
+        eng.prof = spans
         eng.sampler = None
         eng.alerts = None
         eng.device = None

@@ -89,7 +89,7 @@ def clone_engine(eng: Any) -> Any:
         monitor=False,
         slo_window=eng.slo._traces.maxlen,
         slo_e2e_s=eng.slo.slo_e2e_s,
-        profile=eng.prof is not None,
+        profile=eng.prof.report() is not None,
         spec=eng.spec,
         draft_k=eng.draft_k,
         policy=eng.policy,

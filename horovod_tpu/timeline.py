@@ -175,28 +175,6 @@ class Timeline:
                  "pid": self._pid(tensor_name), "tid": 0}
             )
 
-    def async_span(self, tensor_name: str, activity: str, aid: int,
-                   t0: float, t1: float) -> None:
-        """Closed async span with explicit ``perf_counter`` endpoints:
-        both the 'b' and 'e' events in one lock pass, back-dated to the
-        caller's own timestamps rather than emission time.  The serving
-        profiler uses this so a whole tick's phase spans can be written
-        after the fact without skewing their measured boundaries."""
-        with self._lock:
-            if self._closed:
-                return
-            pid = self._pid(tensor_name)
-            self._emit(
-                {"name": activity, "ph": "b", "cat": activity,
-                 "id": aid, "ts": (t0 - self._start) * 1e6,
-                 "pid": pid, "tid": 0}
-            )
-            self._emit(
-                {"name": activity, "ph": "e", "cat": activity,
-                 "id": aid, "ts": (t1 - self._start) * 1e6,
-                 "pid": pid, "tid": 0}
-            )
-
     def close(self) -> None:
         with self._lock:
             if self._closed:
@@ -211,10 +189,16 @@ class Timeline:
 
 
 def trace_annotation(name: str):
-    """Bridge an engine phase into the JAX/XLA profiler (TensorBoard trace).
+    """A host span on the JAX/XLA profiler's trace, the clock the device's
+    operations are on (``jax.profiler.TraceAnnotation``; costs under a
+    microsecond and records nothing while no trace is being taken).
 
     The reference points users at chrome://tracing only; on TPU the XLA
-    profiler is the richer source, so engine phases are mirrored there.
+    profiler is the richer source.  Callers, all host code and all with
+    constant names: the phases of ``ServeEngine.step``
+    (:class:`horovod_tpu.profiler.PhaseSpans`, ``serve.step[.<phase>]``),
+    the sections of ``LocalReplica``'s pump loop (``replica.pump.*``) and
+    ``RouterServer.route`` (``router.*``).
     """
     return jax.profiler.TraceAnnotation(name)
 

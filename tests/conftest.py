@@ -13,6 +13,7 @@ not spend it on unit tests.
 """
 
 import faulthandler
+import glob
 import os
 
 # Belt and braces with pytest's faulthandler plugin (whose
@@ -76,3 +77,55 @@ def _ensure_world(_hvd_world):
             hvd.shutdown()
         hvd.init()
     yield
+
+
+class HostTrace:
+    """What jax's profiler recorded of the host inside a ``with`` block
+    (python tracer off, as the benchmark takes its traces): ``lines`` has
+    one list per host thread of its ``TraceAnnotation`` spans as
+    ``(name, start_ns, end_ns)``, sorted by start."""
+
+    def __init__(self, path: str):
+        self.path = path
+        self.lines: list[list[tuple]] = []
+
+    def __enter__(self) -> "HostTrace":
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(self.path, profiler_options=opts)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        jax.profiler.stop_trace()
+        pb, = glob.glob(os.path.join(self.path, "**", "*.xplane.pb"),
+                        recursive=True)
+        data = jax.profiler.ProfileData.from_file(pb)
+        host, = [p for p in data.planes if p.name == "/host:CPU"]
+        for line in host.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for e in line.events if not e.name.startswith("$")]
+            if evs:         # an enclosing span sorts before what it holds
+                self.lines.append(sorted(evs, key=lambda e: (e[1], -e[2])))
+
+    def line_with(self, name: str) -> list[tuple]:
+        """The one thread that carries spans called ``name``."""
+        line, = [ln for ln in self.lines if any(e[0] == name for e in ln)]
+        return line
+
+    @staticmethod
+    def children(line: list[tuple], parent: tuple) -> list[tuple]:
+        """The spans of ``line`` directly inside ``parent``."""
+        kids: list[tuple] = []
+        for e in line:
+            if (e is not parent and parent[1] <= e[1] and e[2] <= parent[2]
+                    and (not kids or e[1] >= kids[-1][2])):
+                kids.append(e)
+        return kids
+
+
+@pytest.fixture
+def host_trace(tmp_path):
+    """``with host_trace() as tr:`` takes a profiler trace of the block;
+    one at a time in a process."""
+    n = iter(range(1000))
+    return lambda: HostTrace(str(tmp_path / f"xplane{next(n)}"))

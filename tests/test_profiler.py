@@ -20,6 +20,11 @@ The acceptance criteria, pinned:
    dtype/shape arithmetic.
 5. *Serving surface*: ``/profile`` over a real socket, snapshot and
    state-dump embedding, event-log replay via tools/profile_report.
+6. *The phases are spans on the profiler trace*, profiling on or off:
+   under ``jax.profiler``'s trace every ``step()`` is one ``serve.step``
+   tiled by ``serve.step.<phase>``, the sub-phases nest inside
+   ``admit``, an exception out of the step leaves nothing open, and
+   taking the trace changes neither tokens nor compile counts.
 """
 
 from __future__ import annotations
@@ -38,7 +43,8 @@ from horovod_tpu import profiler as profiler_mod
 from horovod_tpu.metrics import MetricsRegistry
 from horovod_tpu.models import llama
 from horovod_tpu.monitor import MonitorServer
-from horovod_tpu.profiler import PHASES, SUB_PHASES, TickProfiler
+from horovod_tpu.profiler import (PHASES, SUB_PHASES, PhaseSpans,
+                                  TickProfiler)
 from horovod_tpu.serving import OK, Request
 from horovod_tpu.serving_scheduler import ServeEngine
 
@@ -76,12 +82,12 @@ def test_profiler_marks_tile_the_tick():
     reg = MetricsRegistry(event_log=None)
     prof = TickProfiler(reg, window=8)
     for step in range(3):
-        prof.begin(step)
-        prof.mark("expire")
-        t0 = time.perf_counter()
+        prof.begin(step)                 # opens "expire"
         prof.mark("admit")
-        prof.add("admit.cache_acquire", t0, time.perf_counter())
-        prof.end()                       # closes "bookkeeping"
+        with prof.sub("admit.cache_acquire"):
+            pass
+        prof.mark("bookkeeping")
+        prof.end()
     rep = prof.report()
     assert rep["n"] == rep["ticks"] == 3 and rep["window"] == 8
     # tiling: per tick, the sum of top-level phases IS the tick time
@@ -157,12 +163,14 @@ def test_profile_on_off_parity_and_phase_sum(world):
 def test_profile_env_knob(world, monkeypatch):
     monkeypatch.setenv("HVD_TPU_PROFILE", "1")
     eng = _engine(world)
-    assert eng.prof is not None
+    assert type(eng.prof) is TickProfiler and eng.prof.report()["n"] == 0
     monkeypatch.delenv("HVD_TPU_PROFILE")
-    assert _engine(world).prof is None
+    # off: the spans alone, which keep no numbers
+    off = _engine(world)
+    assert type(off.prof) is PhaseSpans and off.prof.report() is None
     # explicit argument beats the env
     monkeypatch.setenv("HVD_TPU_PROFILE", "1")
-    assert _engine(world, profile=False).prof is None
+    assert type(_engine(world, profile=False).prof) is PhaseSpans
 
 
 # ---------------------------------------------------------------------------
@@ -349,32 +357,6 @@ def test_event_log_replay_matches_live_report(world, tmp_path):
                    for r in compare_reports(tiny_old, tiny_new))
 
 
-def test_timeline_phase_spans_aggregate(world, tmp_path):
-    from horovod_tpu import timeline as timeline_mod
-    from tools.timeline_summary import load_events, summarize
-    path = str(tmp_path / "trace.json")
-    tl = timeline_mod.Timeline(path)
-    eng = _engine(world, timeline=tl, profile=True)
-    eng.run(_reqs(3))
-    tl.close()
-    s = summarize(load_events(path))
-    # phase/* spans moved into their own section, stripped of the prefix
-    assert set(PHASES) <= set(s["profile"])
-    assert not any(n.startswith("phase/") for n in s["spans"])
-    top_pct = sum(sp["pct"] for p, sp in s["profile"].items()
-                  if "." not in p)
-    assert top_pct == pytest.approx(100.0, rel=1e-6)
-    # spans carry real durations and close (no dangling ids)
-    for p in PHASES:
-        assert s["profile"][p]["open"] == 0
-    # unconditional boundaries emit one span per tick; the decode pair
-    # only on steps that actually ticked the device
-    for p in ("expire", "admit", "sample_postprocess", "bookkeeping"):
-        assert s["profile"][p]["count"] == eng.step_index
-    for p in ("decode_dispatch", "device_sync"):
-        assert 1 <= s["profile"][p]["count"] <= eng.step_index
-
-
 def test_profiler_overhead_and_registry_cache(world):
     # The rendered-exposition cache: unchanged registry -> the SAME
     # string object (no re-render); any instrument write invalidates;
@@ -392,3 +374,110 @@ def test_profiler_overhead_and_registry_cache(world):
     assert reg.to_prometheus() is b
     assert reg.snapshot()["counters"]["monitor.scrapes"] == 1
     mon._httpd.server_close()
+
+
+# ---------------------------------------------------------------------------
+# Acceptance 6: the phases as spans on jax's profiler trace.
+# ---------------------------------------------------------------------------
+
+BOTH = pytest.mark.parametrize("profile", [False, True],
+                               ids=["spans", "profiler"])
+
+
+def _step_spans(tr):
+    """The traced thread's line and its ``serve.step`` spans."""
+    line = tr.line_with("serve.step")
+    return line, [e for e in line if e[0] == "serve.step"]
+
+
+@BOTH
+def test_trace_phases_tile_every_step(world, host_trace, profile):
+    eng = _engine(world, profile=profile, prefix_cache=True)
+    eng.run(_reqs(2))                    # compiles outside the trace
+    with host_trace() as tr:
+        out = eng.run(_reqs(5))
+    assert all(r.status == OK for r in out)
+    line, steps = _step_spans(tr)
+    assert len(steps) >= 5
+    assert not any(e[0] == "serve.step"
+                   for s in steps for e in tr.children(line, s))
+    full = 0
+    for s in steps:
+        kids = tr.children(line, s)
+        names = [k[0] for k in kids]
+        # the decode tick's three phases only where a row decoded
+        want = PHASES if len(kids) > 3 else (PHASES[:2] + PHASES[-1:])
+        assert names == ["serve.step." + p for p in want]
+        full += len(kids) > 3
+        # boundary to boundary: what the phases leave uncovered is the
+        # few statements around begin(), mark() and end()
+        covered = sum(k[2] - k[1] for k in kids)
+        assert (s[2] - s[1]) - covered < max(0.05 * (s[2] - s[1]), 1e5)
+        assert all(a[2] <= b[1] for a, b in zip(kids, kids[1:]))
+    assert full >= 5
+
+
+@BOTH
+def test_trace_sub_phases_nest_in_admit(world, host_trace, profile):
+    eng = _engine(world, profile=profile, prefix_cache=True)
+    eng.run(_reqs(2))
+    with host_trace() as tr:
+        eng.run(_reqs(4))
+    line, steps = _step_spans(tr)
+    subs = {"serve.step.admit.cache_acquire": 0,
+            "serve.step.admit.prefill_dispatch": 0}
+    for e in line:
+        if e[0] in subs:
+            subs[e[0]] += 1
+            admit, = [a for a in line if a[0] == "serve.step.admit"
+                      and a[1] <= e[1] and e[2] <= a[2]]
+            assert e in tr.children(line, admit)
+    # one window dispatch span a step; one lookup per admitted request
+    assert subs["serve.step.admit.prefill_dispatch"] == len(steps)
+    assert subs["serve.step.admit.cache_acquire"] >= 4
+    # the cost-model pair is an estimate: never a span
+    assert not any("compute_est" in e[0] or "host_stall" in e[0]
+                   for e in line)
+
+
+def test_trace_changes_neither_tokens_nor_compiles(world, host_trace):
+    reqs = _reqs(5)
+    plain = _engine(world, prefix_cache=True)
+    want = [list(r) for r in plain.run(reqs)]
+    sizes = plain.compile_cache_sizes()
+    assert sizes == {"tick": 1, "chunk": 1, "set_row": 1}
+    for profile in (False, True):
+        eng = _engine(world, profile=profile, prefix_cache=True)
+        with host_trace() as tr:
+            got = eng.run(reqs)
+        assert [list(r) for r in got] == want
+        assert eng.compile_cache_sizes() == sizes
+        assert eng.metrics.counter("serve.retrace").value == 0
+        assert tr.line_with("serve.step")
+
+
+@BOTH
+def test_exception_out_of_step_leaves_no_span_open(world, host_trace,
+                                                   monkeypatch, profile):
+    monkeypatch.setenv("HVD_TPU_RETRACE_FATAL", "1")
+    eng = _engine(world, profile=profile)
+    eng.run(_reqs(2))
+    eng.pcache = eng._set_row(           # unpinned: the sentry will raise
+        eng.pcache, 1, jnp.asarray(eng._trash_row),
+        jnp.asarray(0, jnp.int32))
+    eng.submit(_reqs(1)[0])
+    with host_trace() as tr:
+        with pytest.raises(RuntimeError, match="retrace sentry"):
+            eng.step()
+        assert eng.prof._phase_span is None and eng.prof._step_span is None
+        while eng.pending():
+            eng.step()
+    line, steps = _step_spans(tr)
+    assert len(steps) >= 2
+    # the step that raised closed in the phase it was in, and the next
+    # step's span starts after it: not nested in it
+    raised = tr.children(line, steps[0])
+    assert raised[-1][0] == "serve.step.bookkeeping"
+    assert raised[-1][2] <= steps[0][2] <= steps[1][1]
+    if profile:                          # the aborted tick still counted
+        assert eng.prof.report()["ticks"] == eng.step_index + 1

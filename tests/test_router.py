@@ -581,6 +581,70 @@ def test_http_front_door(world):
         router.stop()
 
 
+# -- the pump and route() as spans on jax's profiler trace -------------------
+
+PUMP_SPANS = {"replica.pump.submit", "replica.pump.callbacks",
+              "replica.pump.view", "replica.pump.wait", "serve.step"}
+
+
+@pytest.mark.parametrize("profile", [False, True],
+                         ids=["spans", "profiler"])
+def test_trace_pump_thread_has_no_unnamed_stretch(world, host_trace,
+                                                  profile):
+    cfg, params = world
+    eng, = _engines(params, cfg, 1, profile=profile)
+    eng.run([Request(prompt=[5, 17, 42], max_new_tokens=2)])   # compile
+    router = RouterServer([eng], policy="round_robin")
+    try:
+        with host_trace() as tr:
+            time.sleep(0.02)             # a few rounds of the idle pump
+            rids = [router.route(Request(prompt=[3, 5, 7 + i],
+                                         max_new_tokens=6))
+                    for i in range(4)]
+            out = [router.result(r, timeout=60) for r in rids]
+    finally:
+        router.stop()
+    assert all(r.status == OK for r in out)
+    line = tr.line_with("serve.step")
+    top = tr.children(line, ("", line[0][1], max(e[2] for e in line)))
+    # while requests are pending the pump's line is its four sections
+    # and the engine's step, end to end: no stretch of it without a name
+    first = next(i for i, e in enumerate(top) if e[0] == "serve.step")
+    last = max(i for i, e in enumerate(top) if e[0] == "serve.step")
+    busy = top[first - 1:last + 3]       # submit ... callbacks, view
+    assert {e[0] for e in busy} <= PUMP_SPANS
+    assert {"replica.pump.submit", "replica.pump.callbacks",
+            "replica.pump.view", "serve.step"} <= {e[0] for e in busy}
+    assert [e[0] for e in busy[-2:]] == ["replica.pump.callbacks",
+                                        "replica.pump.view"]
+    assert max(b[1] - a[2] for a, b in zip(busy, busy[1:])) < 1e6   # 1 ms
+    # the idle pump waits under a name too
+    assert any(e[0] == "replica.pump.wait" for e in top)
+
+
+@pytest.mark.parametrize("door", ["route", "handle_generate"])
+def test_trace_route_encloses_admission_and_place(world, host_trace, door):
+    cfg, params = world
+    router = RouterServer(_engines(params, cfg, 1), policy="round_robin")
+    req = Request(prompt=[3, 5, 7], max_new_tokens=2)
+    try:
+        with host_trace() as tr:
+            if door == "route":
+                res = router.result(router.route(req), timeout=60)
+                assert res.status == OK
+            else:
+                code, body = router.handle_generate(req)
+                assert code == 200 and body["status"] == OK
+    finally:
+        router.stop()
+    line = tr.line_with("router.route")
+    route, = [e for e in line if e[0] == "router.route"]
+    assert [e[0] for e in tr.children(line, route)] == [
+        "router.admission", "router.place", "router.submit"]
+    # the caller's thread, not the pump's
+    assert line is not tr.line_with("serve.step")
+
+
 def test_multiprocess_router_real_sockets(world):
     """Real OS processes, real sockets: stdlib-only clients hammer one
     router concurrently and read byte-identical token payloads

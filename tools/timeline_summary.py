@@ -24,11 +24,9 @@ series — the serving scheduler's SCHED/LIFECYCLE/PREFIX tracks, plus
 SPEC (speculative-decode rounds/proposed/accepted, spec engines only):
 final values plus the delta and sample count across the trace — and
 per-request async spans (the engine's ``REQ`` ``b``/``e`` pairs, one id
-per request).  The serving profiler's ``phase/<name>`` spans (one id per
-tick, ``HVD_TPU_PROFILE=1``) get their own per-phase table with each
-top-level phase's share of the tiled tick time (``draft``/``verify``
-appear there on spec engines).  ``--json`` dumps the
-whole summary dict as JSON for scripting.
+per request).  The phases of ``ServeEngine.step`` are not here: they are
+spans of jax's profiler trace (``serve.step.<phase>``), on the device's
+clock.  ``--json`` dumps the whole summary dict as JSON for scripting.
 """
 
 from __future__ import annotations
@@ -239,24 +237,12 @@ def summarize(events: list[dict]) -> dict:
         }
         for name, ds in span_durs.items()
     }
-    # TickProfiler spans ("phase/<name>", id = step) get their own
-    # section: stripped of the prefix, with each top-level phase's share
-    # of the tiled tick time (dotted names are nested sub-phases —
-    # contained in their parent, so excluded from the 100 % base).
-    profile = {name[len("phase/"):]: spans.pop(name)
-               for name in [n for n in spans if n.startswith("phase/")]}
-    tiled_us = sum(sp["total_us"] for p, sp in profile.items()
-                   if "." not in p)
-    for p, sp in profile.items():
-        sp["pct"] = (100.0 * sp["total_us"] / tiled_us
-                     if tiled_us else 0.0)
     return {
         "tensors": per_tensor,
         "phase_totals": dict(phase_totals),
         "ticks": dict(ticks),
         "counters": counters,
         "spans": spans,
-        "profile": profile,
         "unbalanced": unbalanced,
     }
 
@@ -316,29 +302,6 @@ def main(argv=None) -> int:
             print(f"  {name:24s} n={sp['count']:5d} open={sp['open']:3d} "
                   f"mean {sp['mean_us'] / 1e3:8.2f}ms "
                   f"max {sp['max_us'] / 1e3:8.2f}ms")
-    if s["profile"]:
-        print("\nprofiler phases (ms):")
-        # Top-level phases by descending total, each followed by its
-        # own nested sub-phases (admit.* under admit, device_sync.*
-        # under device_sync) — indentation reads as containment, and
-        # the dotted rows stay outside the 100 % tiling base.
-        prof = s["profile"]
-        order = []
-        for name in sorted((p for p in prof if "." not in p),
-                           key=lambda p: -prof[p]["total_us"]):
-            order.append(name)
-            order.extend(sorted(
-                (p for p in prof if p.startswith(name + ".")),
-                key=lambda p: -prof[p]["total_us"]))
-        order += [p for p in prof if p not in order]
-        for name in order:
-            sp = prof[name]
-            label = ("  " + name) if "." in name else name
-            print(f"  {label:24s} n={sp['count']:5d} "
-                  f"total {sp['total_us'] / 1e3:10.2f} "
-                  f"mean {sp['mean_us'] / 1e3:8.3f} "
-                  f"max {sp['max_us'] / 1e3:8.3f}  {sp['pct']:5.1f}%")
-
     rows = sorted(
         s["tensors"].items(),
         key=lambda kv: -sum(kv[1]["phases"].values()),
