@@ -351,20 +351,43 @@ def _serve_and_probe(eng, reqs, probes, http: int) -> tuple[list, dict]:
         logits.append((list(req.prompt) + list(res),
                        np.asarray(eng.last_logits[slot], np.float32)))
     times["warm_request_ms"] = (time.perf_counter() - t0) / len(probes) * 1e3
-    # Counts only grow, so once at the end covers run(), HTTP and the probes:
-    # admission, recycling and prefix hits never retraced a pinned program.
+    times["scratch"] = _pool_held_once(eng)
+    # Counts only grow, so once at the end covers run(), HTTP, the probes and
+    # the AOT compiles: admission, recycling and prefix hits never retraced a
+    # pinned program, and lowering one mints no entry.
     sizes = eng.compile_cache_sizes()
     assert sizes == {"tick": 1, "chunk": 1, "set_row": 1}, sizes
     return logits, times
 
 
+def _pool_held_once(eng) -> dict:
+    """AOT-compile the tick and the chunk at the engine's own signature and
+    require each program's scratch (per device) to stay under half of the KV
+    pool's share of a device: the pool rides the layer scan as a carry that
+    aliases the donated input, and a second copy of it would be the whole
+    pool again."""
+    pool = (eng.pcache.k.nbytes + eng.pcache.v.nbytes) // eng.tp_size
+    out = {"pool_bytes": pool}
+    progs = eng.pinned_programs()
+    for name in ("tick", "chunk"):
+        fn, *avals = progs[name]
+        temp = fn.lower(*avals).compile().memory_analysis().temp_size_in_bytes
+        out[f"{name}_temp_bytes"] = temp
+        assert temp < pool / 2, (name, temp, pool)
+    _say(f"  scratch at tp={eng.tp_size}: {out}")
+    return out
+
+
 def phase_server(*, cfg=None, n_slots: int = 8, max_len: int = 2048,
                  chunk: int = 256, max_new: int = 24, http: int = 4,
-                 tp_size: int | None = None) -> dict:
+                 tp_size: int | None = None,
+                 n_blocks: int | None = None) -> dict:
     """``ServeEngine`` with the prefix cache on: every request ``OK`` with the
-    asked number of tokens, one compiled signature per pinned program, and
-    logits within :func:`logit_tolerance` of ``llama.forward`` on the same
-    tokens.  With four devices or more, the same through ``tp_size=4``."""
+    asked number of tokens, one compiled signature per pinned program, the
+    KV pool held once by the tick and the chunk, and logits within
+    :func:`logit_tolerance` of ``llama.forward`` on the same tokens.  With
+    four devices or more, the same through ``tp_size=4``.  ``n_blocks`` is
+    for toy sizes, where full backing is smaller than a layer's weights."""
     if cfg is None:
         cfg = llama.llama_tiny(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
                                attn_impl="dense", **LLAMA_SERVE)
@@ -386,7 +409,7 @@ def phase_server(*, cfg=None, n_slots: int = 8, max_len: int = 2048,
     def engine(tp: int) -> ServeEngine:
         return ServeEngine(
             params, cfg, n_slots=n_slots, max_len=max_len, chunk=chunk,
-            prefix_cache=True, tp_size=tp,
+            prefix_cache=True, tp_size=tp, n_blocks=n_blocks,
             metrics=metrics_mod.MetricsRegistry(event_log=None))
 
     out: dict = {}

@@ -596,6 +596,9 @@ def decode_chunk(
 # Admission allocates just the blocks a request needs; retirement returns
 # them — all on the host, with device programs keeping ONE compiled
 # signature (the tables are data, not shapes, so admission never retraces).
+# Those programs donate the pool and ``_paged_attend`` carries it through
+# the layer scan, so it is updated in place: a step moves the positions it
+# writes and the view attention reads, never a block it does not touch.
 #
 # Block 0 is the TRASH block: it is never allocated, and unallocated table
 # entries point at it.  Free/idle rows that tick along with the batch (the
@@ -784,7 +787,15 @@ def _paged_attend(params, tokens, cfg: LlamaConfig, kv_k, kv_v,
     so for identical cache VALUES the masked softmax/matvec sequence is
     the same XLA computation as the dense path — bit-identical logits
     (gathered garbage beyond a row's frontier is masked to an exact-zero
-    softmax term, just like dense pad slots)."""
+    softmax term, just like dense pad slots).
+
+    The pool is written IN PLACE: ``kv_k`` / ``kv_v`` ride the layer scan
+    as CARRIES (flattened to ``[L * n_blocks * bs, KVH, Dh]``, a bitcast),
+    never as scanned inputs or stacked outputs, and layer ``i`` scatters
+    to and gathers from its own stripe at ``i * n_blocks * bs``.  With the
+    caller's pool donated the carry aliases it, so a program's only pool
+    traffic is the B x T scatter and the gather attention needs — no
+    layer slice is copied and the pool is held once."""
     b, t = tokens.shape
     nl, n_blocks, bs, kvh, dh = kv_k.shape
     m = gflat.shape[1]
@@ -795,19 +806,21 @@ def _paged_attend(params, tokens, cfg: LlamaConfig, kv_k, kv_v,
     scale = 1.0 / (cfg.head_dim ** 0.5)
     valid = jnp.arange(m)[None, None, :] <= qpos[:, :, None]
     valid = valid[:, None, None, :, :]                    # [B,1,1,T,M]
+    stripe = n_blocks * bs                  # one layer's flat positions
 
-    def layer(x, inputs):
-        lp, kc, vc = inputs                 # kc/vc [n_blocks, bs, KVH, Dh]
+    def layer(carry, lp):
+        x, kf, vf, i = carry                # kf/vf [L * stripe, KVH, Dh]
         h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
         q = (h @ lp["wq"].astype(dt)).reshape(b, t, cfg.n_heads, cfg.head_dim)
         k = (h @ lp["wk"].astype(dt)).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
         v = (h @ lp["wv"].astype(dt)).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-        kf = kc.reshape(n_blocks * bs, kvh, dh).at[wflat].set(k)
-        vf = vc.reshape(n_blocks * bs, kvh, dh).at[wflat].set(v)
-        kd = kf[gflat]                                    # [B, M, KVH, Dh]
-        vd = vf[gflat]
+        off = i * stripe
+        kf = kf.at[wflat + off].set(k)
+        vf = vf.at[wflat + off].set(v)
+        kd = kf[gflat + off]                              # [B, M, KVH, Dh]
+        vd = vf[gflat + off]
         qg = q.reshape(b, t, cfg.n_kv_heads, n_rep, cfg.head_dim)
         s = jnp.einsum(
             "bqkrd,bmkd->bkrqm", qg.astype(jnp.float32),
@@ -821,13 +834,16 @@ def _paged_attend(params, tokens, cfg: LlamaConfig, kv_k, kv_v,
         gate = jax.nn.silu(h @ lp["w_gate"].astype(dt))
         up = h @ lp["w_up"].astype(dt)
         x = x + (gate * up) @ lp["w_down"].astype(dt)
-        return x, (kf.reshape(n_blocks, bs, kvh, dh),
-                   vf.reshape(n_blocks, bs, kvh, dh))
+        return (x, kf, vf, i + 1), None
 
-    x, (ks, vs) = lax.scan(layer, x, (params["layers"], kv_k, kv_v))
+    (x, kf, vf, _), _ = lax.scan(
+        layer,
+        (x, kv_k.reshape(nl * stripe, kvh, dh),
+         kv_v.reshape(nl * stripe, kvh, dh), jnp.int32(0)),
+        params["layers"])
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
-    return logits, ks, vs
+    return logits, kf.reshape(kv_k.shape), vf.reshape(kv_v.shape)
 
 
 def decode_chunk_paged(

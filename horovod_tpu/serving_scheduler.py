@@ -606,7 +606,10 @@ class ServeEngine:
             # the length advance so idle/prefilling rows hold position
             # (their garbage write lands in their own blocks or trash —
             # invariant 1).  Donation matters: decode cost IS cache
-            # traffic, an undonated pool would copy every block per tick.
+            # traffic.  The donated pool aliases the carry of
+            # _paged_attend's layer scan, so the tick scatters one
+            # position per row in place; undonated (or scanned in and
+            # stacked out) every tick would copy every block.
             tok = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
             logits, pcache = llama.decode_chunk_paged(
                 params, tok[:, None], cfg, pcache, advance=active)
@@ -681,16 +684,15 @@ class ServeEngine:
             sizes["spec_tick"] = self._spec_tick._cache_size()
         return sizes
 
-    def _device_capture_programs(
-            self, dev: "device_telemetry_mod.DeviceTelemetry") -> None:
-        """AOT-capture the XLA cost model of every pinned program into
-        ``dev`` (FLOPs / bytes-accessed / compile wall time per
-        dispatch) and hand it the exact model-side device bytes for HBM
-        reconciliation.  Built from ``ShapeDtypeStruct`` avals of the
-        live arrays, so each capture lowers the very signature serving
-        will call — and ``jitfn.lower()`` never touches the jit call
-        cache, so ``compile_cache_sizes()`` is identical telemetry-on
-        vs off (pinned by tests/test_device_telemetry.py)."""
+    def pinned_programs(self) -> dict[str, tuple]:
+        """Every pinned program as ``name -> (jitted fn, *avals)``, the
+        ``ShapeDtypeStruct`` avals built from the live arrays — so
+        ``fn.lower(*avals)`` lowers the very signature serving calls, and
+        never touches the jit call cache (``compile_cache_sizes()`` is the
+        same before and after).  ``.compile().memory_analysis()`` of a
+        lowered ``tick`` / ``chunk`` is where the in-place pool shows: their
+        scratch holds no second pool (tests/test_paged_inplace.py,
+        chip_smoke.py)."""
         aval = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)  # noqa: E731
         p_av = jax.tree.map(aval, self.params)
         c_av = jax.tree.map(aval, self.pcache)
@@ -699,15 +701,29 @@ class ServeEngine:
         active_av = jax.ShapeDtypeStruct((self.n_slots,), jnp.int32)
         toks_av = jax.ShapeDtypeStruct((1, self.chunk), jnp.int32)
         row_av = jax.ShapeDtypeStruct((self.blocks_per_slot,), jnp.int32)
-        dev.capture("tick", self._tick, p_av, c_av, ll_av, active_av)
-        dev.capture("chunk", self._chunk, p_av, c_av, ll_av, toks_av,
-                    i32, i32, i32)
-        dev.capture("set_row", self._set_row, c_av, i32, row_av, i32)
+        progs = {
+            "tick": (self._tick, p_av, c_av, ll_av, active_av),
+            "chunk": (self._chunk, p_av, c_av, ll_av, toks_av,
+                      i32, i32, i32),
+            "set_row": (self._set_row, c_av, i32, row_av, i32),
+        }
         if self._spec_tick is not None:
             drafts_av = jax.ShapeDtypeStruct(
                 (self.n_slots, self.draft_k), jnp.int32)
-            dev.capture("spec_tick", self._spec_tick, p_av, c_av,
-                        ll_av, drafts_av, active_av)
+            progs["spec_tick"] = (self._spec_tick, p_av, c_av, ll_av,
+                                  drafts_av, active_av)
+        return progs
+
+    def _device_capture_programs(
+            self, dev: "device_telemetry_mod.DeviceTelemetry") -> None:
+        """AOT-capture the XLA cost model of every pinned program into
+        ``dev`` (FLOPs / bytes-accessed / compile wall time per
+        dispatch) and hand it the exact model-side device bytes for HBM
+        reconciliation.  ``jitfn.lower()`` never touches the jit call
+        cache, so ``compile_cache_sizes()`` is identical telemetry-on
+        vs off (pinned by tests/test_device_telemetry.py)."""
+        for name, (jitfn, *avals) in self.pinned_programs().items():
+            dev.capture(name, jitfn, *avals)
         param_bytes = sum(
             int(np.prod(x.shape)) * x.dtype.itemsize
             for x in jax.tree.leaves(self.params))

@@ -51,10 +51,17 @@ def test_kernel_toy_interpreted(smoke):
 
 def test_server_toy_with_tp(smoke):
     cfg = llama.llama_tiny(dtype=jnp.float32, n_kv_heads=4)
+    # 513 blocks where 17 would back both slots: at toy widths the pool has
+    # to outweigh a layer's weights for "scratch under half a pool" to bite
     r = smoke.phase_server(cfg=cfg, n_slots=2, max_len=32, chunk=4,
-                           max_new=6, http=2, tp_size=4)
+                           max_new=6, http=2, tp_size=4, n_blocks=513)
     assert r["tp1_logit_err"] <= r["logit_tol"]
     assert r["tp4_logit_err"] <= r["logit_tol"]
+    for tp in (1, 4):
+        scratch = r[f"tp{tp}"]["scratch"]
+        assert scratch["pool_bytes"] * tp == 2 * 2 * 513 * 4 * 64 * 4
+        for prog in ("tick", "chunk"):
+            assert 0 < scratch[f"{prog}_temp_bytes"] < scratch["pool_bytes"] / 2
 
 
 def test_eager_toy(smoke):
