@@ -1,0 +1,4 @@
+"""``hbm_peak_gb``: the peak on the chip, read when the window closes and before
+the reference runs: the weights (8.17 GB), the three pools, the programs' scratch."""
+
+from benchmark.lib import hbm_peak_gb as read  # noqa: F401

@@ -332,7 +332,7 @@ def run_campaign(params: dict, cfg: Any, *, seed: int = 0,
             eng = getattr(r, "engine", None)
             if eng is None:
                 continue
-            total = int(eng.pcache.k.shape[1]) - 1
+            total = eng.pool.n_blocks - 1
             free = eng.free_block_count() + eng.cached_block_count()
             leaked_blocks += total - free
             if eng.prefix is not None:
@@ -674,7 +674,7 @@ def run_autoscale_campaign(params: dict, cfg: Any, *,
             eng = getattr(r, "engine", None)
             if eng is None:
                 continue
-            total = int(eng.pcache.k.shape[1]) - 1
+            total = eng.pool.n_blocks - 1
             free = eng.free_block_count() + eng.cached_block_count()
             leaked_blocks += total - free
             if eng.prefix is not None:

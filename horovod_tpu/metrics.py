@@ -209,7 +209,7 @@ METRIC_HELP: dict[str, str] = {
     "kv.referenced_bytes": "KV pool bytes mapped by live rows",
     "kv.cached_blocks": "Zero-ref KV pool blocks parked in the prefix cache",
     "kv.cached_bytes": "Zero-ref KV pool bytes parked in the prefix cache",
-    "kv.block_bytes": "Device bytes one KV block holds (k+v, all layers)",
+    "kv.block_bytes": "Device bytes one KV block holds (every pool, all layers)",
     "kv.total_bytes": "Device bytes of the whole paged KV pool (incl. trash)",
     # kv.shard_* / tp.* — per-chip view of the same pool under
     # tensor-parallel serving (logical bytes / tp.size: the pool is
@@ -221,6 +221,19 @@ METRIC_HELP: dict[str, str] = {
     "kv.shard_referenced_bytes": "Per-chip KV pool bytes mapped by live rows",
     "kv.shard_cached_bytes": "Per-chip KV pool bytes parked in the prefix cache",
     "tp.size": "Tensor-parallel degree of the serving engine (chips per replica)",
+    # models/latent_moe.py — the three pools behind one block table, and
+    # the device-side counters of routing and of sparse selection (read
+    # back with the tick's tokens)
+    "kv.latent_block_bytes": "Device bytes of one block of the full layers' latent pool",
+    "kv.index_block_bytes": "Device bytes of one block of the indexer's key pool",
+    "kv.window_block_bytes": "Device bytes of one block of the window layers' latent pool",
+    "kv.window_bytes_beyond_window": "Window-pool bytes live rows hold for positions older than the window (what a window-sized pool would free)",
+    "moe.choices_total": "Token-choices routed (tokens x top_k x expert layers)",
+    "moe.choices_held": "Token-choices that fell on an expert this chip holds",
+    "moe.held_load": "Token-choices per held expert since start (moe.held_load.<expert>)",
+    "moe.experts_touched": "Held experts (summed over layers) the last decode tick computed",
+    "dsa.keys_visible": "Cached keys the sparse indexer scored (summed over queries and full layers)",
+    "dsa.keys_selected": "Keys the indexer's exact top-k kept for attention",
     # mem.* — host-side observability footprint (approximate)
     "mem.registry_bytes": "Approximate host bytes held by the metrics registry",
     "mem.trace_ring_bytes": "Approximate host bytes of live traces + the SLO ring",

@@ -657,6 +657,30 @@ def init_paged_cache(
     )
 
 
+def paged_pool_bytes(pcache: PagedKVCache) -> dict:
+    """Device bytes one block holds in each pool (all layers): part of the
+    paged model interface (:mod:`horovod_tpu.models.paged`)."""
+    return {name: int(np.prod(a.shape) // a.shape[1]) * a.dtype.itemsize
+            for name, a in (("k", pcache.k), ("v", pcache.v))}
+
+
+def paged_counters(pcache: PagedKVCache) -> None:
+    """This model keeps no counters on the device."""
+    return None
+
+
+def publish_paged_metrics(metrics, cfg, pcache, stats_host=None,
+                          row_blocks=()) -> None:
+    """This model has no gauges of its own beside the engine's ``kv.*``."""
+
+
+def tp_split_dims(cfg: LlamaConfig) -> tuple:
+    """``(name, size)`` of every axis tensor-parallel serving splits."""
+    return (("n_heads", cfg.n_heads), ("n_kv_heads", cfg.n_kv_heads),
+            ("dim", cfg.dim), ("ffn_dim", cfg.ffn_dim),
+            ("vocab_size", cfg.vocab_size))
+
+
 class BlockPool:
     """Host-side reference-counted allocator over the paged pool's
     physical blocks — the free-list's successor once blocks can be
@@ -881,6 +905,7 @@ def decode_chunk_paged(
 def spec_verify_paged(
     params: dict, cfg: LlamaConfig, pcache: PagedKVCache,
     last_logits: jax.Array, drafts: jax.Array, active: jax.Array,
+    *, decode: Callable | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array, PagedKVCache]:
     """One batched self-speculation verify round over the paged pool:
     every row argmaxes its last logits into ``tok`` and decodes the
@@ -906,13 +931,17 @@ def spec_verify_paged(
     next_logits, pcache)``: the unconditional token [B], accepted draft
     counts [B], the logits following each row's last accepted token
     [B, V] (seeding the next round), and the advanced cache.
+
+    The round is generic over the wide tick: ``decode`` (default
+    :func:`decode_chunk_paged`) is any model's function of that signature
+    whose cache has a per-row ``length`` that alone rolls back.
     """
+    decode = decode_chunk_paged if decode is None else decode
     b, k = drafts.shape
     tok = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)       # [B]
     chunk = jnp.concatenate([tok[:, None], drafts], axis=1)   # [B, K+1]
     hold = jnp.zeros((b,), jnp.int32)
-    logits, pcache = decode_chunk_paged(
-        params, chunk, cfg, pcache, advance=hold)
+    logits, pcache = decode(params, chunk, cfg, pcache, advance=hold)
     preds = jnp.argmax(logits, axis=-1).astype(jnp.int32)     # [B, K+1]
     match = (drafts == preds[:, :k]).astype(jnp.int32)
     accept = jnp.sum(jnp.cumprod(match, axis=1), axis=1)           # [B]

@@ -1,4 +1,9 @@
-"""Mixture-of-Experts layer with expert parallelism.
+"""Mixture-of-Experts layer with expert parallelism — the TRAINING-side
+layer: routing is over a fixed expert capacity and tokens past it are
+dropped (``capacity_factor``), which a training step tolerates and a served
+model cannot (its logits would not agree with a reference).  The served,
+dropless expert layer that is told which experts it holds is
+:func:`horovod_tpu.models.latent_moe.held_experts`.
 
 No reference equivalent (the reference is a data-parallel-only framework,
 SURVEY.md §2.3); this supplies the EP axis of the framework's parallelism

@@ -409,6 +409,8 @@ class LocalReplica(ReplicaHandle):
                             "free_kv_frac": 1.0,
                             "tp_size": getattr(engine, "tp_size", 1),
                             "prefix": None}
+        self._prefix_key: tuple | None = None   # the pump thread's own
+        self._prefix_digest: dict | None = None
         self._thread = threading.Thread(
             target=self._pump, name=f"hvd-replica-{name}", daemon=True)
         self._thread.start()
@@ -437,7 +439,7 @@ class LocalReplica(ReplicaHandle):
 
     def _refresh_view_locked(self) -> None:
         eng = self.engine
-        total = max(eng.pcache.k.shape[1] - 1, 1)
+        total = max(eng.pool.n_blocks - 1, 1)
         free = eng.free_block_count() + eng.cached_block_count()
         self._view = {
             "healthy": not self._dead,
@@ -446,9 +448,23 @@ class LocalReplica(ReplicaHandle):
             "goodput": eng.slo.goodput(),
             "free_kv_frac": free / total,
             "tp_size": getattr(eng, "tp_size", 1),
-            "prefix": (eng.prefix.key_digest()
-                       if eng.prefix is not None else None),
+            "prefix": self._prefix_digest_locked(),
         }
+
+    def _prefix_digest_locked(self) -> "dict | None":
+        """The engine's ``key_digest()``, walked again only when its
+        index changed.  The walk hashes up to 256 chunks of
+        ``block_size`` tokens (26 ms at 512-token blocks), and this
+        runs every turn of the pump: behind a step that only ticks it
+        was most of the step."""
+        prefix = self.engine.prefix
+        if prefix is None:
+            return None
+        key = (prefix.stats["inserted_blocks"],
+               prefix.stats["evicted_blocks"])
+        if key != self._prefix_key:
+            self._prefix_key, self._prefix_digest = key, prefix.key_digest()
+        return self._prefix_digest
 
     def _pump(self) -> None:
         # Each section of the loop body is a span on jax's profiler

@@ -1,5 +1,10 @@
 """Continuous-batching decode engine: slot recycling over a paged KV pool.
 
+The engine names no model: it serves any config whose module implements
+the paged model interface (:mod:`horovod_tpu.models.paged`) — a
+``LlamaConfig`` (described below) or a ``LatentMoEConfig`` (three latent
+pools behind the same block table, ``docs/inference.md``).
+
 :class:`~horovod_tpu.serving.ContinuousBatcher` admits into a fixed slot
 pool but each admission runs its whole prefill at once and the pool's
 dense cache reserves max_len per slot.  :class:`ServeEngine` is the next
@@ -145,6 +150,8 @@ from horovod_tpu import timeseries as timeseries_mod
 from horovod_tpu import tracing as tracing_mod
 from horovod_tpu.metrics import Trace
 from horovod_tpu.models import llama
+from horovod_tpu.models.llama import BlockPool
+from horovod_tpu.models.paged import paged_model
 from horovod_tpu.parallel.mesh import tensor_parallel_mesh
 from horovod_tpu.prefix_cache import RadixPrefixCache
 from horovod_tpu.serving import (
@@ -308,7 +315,7 @@ class ServeEngine:
     single-device one.
     """
 
-    def __init__(self, params: dict, cfg: llama.LlamaConfig, *,
+    def __init__(self, params: dict, cfg: Any, *,
                  n_slots: int, max_len: int, chunk: int,
                  block_size: int | None = None,
                  n_blocks: int | None = None,
@@ -359,12 +366,12 @@ class ServeEngine:
         tp_size = int(tp_size)
         if tp_size < 1:
             raise ValueError(f"tp_size must be >= 1, got {tp_size}")
+        # The model behind the engine: a module of the paged model
+        # interface (horovod_tpu.models.paged), chosen by the config's
+        # type.  Everything below reaches the model through it.
+        self.model = model = paged_model(cfg)
         if tp_size > 1:
-            for dim_name, dim in (("n_heads", cfg.n_heads),
-                                  ("n_kv_heads", cfg.n_kv_heads),
-                                  ("dim", cfg.dim),
-                                  ("ffn_dim", cfg.ffn_dim),
-                                  ("vocab_size", cfg.vocab_size)):
+            for dim_name, dim in model.tp_split_dims(cfg):
                 if dim % tp_size:
                     raise ValueError(
                         f"tp_size={tp_size} does not divide "
@@ -373,12 +380,12 @@ class ServeEngine:
         self.tp_size = tp_size
         if tp_size > 1:
             self.mesh = tensor_parallel_mesh(tp_size)
-            pspecs = llama.param_partition_specs(cfg, tp_axis="tp")
-            cspecs = llama.paged_cache_partition_specs(tp_axis="tp")
+            pspecs = model.param_partition_specs(cfg, tp_axis="tp")
+            cspecs = model.paged_cache_partition_specs(tp_axis="tp")
             self._param_sh = jax.tree.map(
                 lambda s: NamedSharding(self.mesh, s), pspecs,
                 is_leaf=lambda x: isinstance(x, PartitionSpec))
-            self._cache_sh = llama.PagedKVCache(
+            self._cache_sh = type(cspecs)(
                 *(NamedSharding(self.mesh, s) for s in cspecs))
             self._repl_sh = NamedSharding(self.mesh, PartitionSpec())
             # Pre-commit the persistent state to its exact target
@@ -513,28 +520,31 @@ class ServeEngine:
             raise ValueError(
                 f"monitor must be None / False / port int / "
                 f"MonitorServer, got {monitor!r}")
-        self.pcache = llama.init_paged_cache(
+        self.pcache = model.init_paged_cache(
             cfg, n_slots, max_len, block_size=block_size,
             n_blocks=n_blocks)
         if self.tp_size > 1:
-            self.pcache = llama.PagedKVCache(*(
+            self.pcache = type(self.pcache)(*(
                 jax.device_put(x, s)
                 for x, s in zip(self.pcache, self._cache_sh)))
         self.blocks_per_slot = self.pcache.block_table.shape[1]
-        total = self.pcache.k.shape[1]
+        # per pool (k and v; latent, index and window; ...) the device
+        # bytes one block holds over all its layers: a block id means the
+        # same block in every pool, so the pool's extent is any one's
+        self._pool_block_bytes = model.paged_pool_bytes(self.pcache)
+        total = next(a.shape[1] for a in self.pcache if a.ndim > 2)
         # block 0 is trash — never allocated; the pool's free list pops
         # low ids first, matching the classic free-list order
-        self.pool = llama.BlockPool(total)
+        self.pool = BlockPool(total)
         # legacy alias: the SAME list object the pool allocates from
         # (white-box tests drain it to force block starvation)
         self._free_blocks = self.pool._free
         # KV memory accounting: one physical block holds block_size
-        # positions of K and V across every layer, so its device
-        # footprint follows directly from the cache dtype and shape
-        # ([n_layers, n_blocks, block_size, n_kv_heads, head_dim]).
-        kb = self.pcache.k
-        self._block_bytes = (2 * kb.dtype.itemsize * kb.shape[0]
-                             * kb.shape[2] * kb.shape[3] * kb.shape[4])
+        # positions of every pool across every layer; the model says
+        # what that is in bytes, pool by pool, and kv.block_bytes is
+        # their sum.
+        self._block_bytes = sum(self._pool_block_bytes.values())
+        model.publish_paged_metrics(self.metrics, cfg, self.pcache)
         self.metrics.gauge("kv.block_bytes").set(self._block_bytes)
         self.metrics.gauge("kv.total_bytes").set(
             self._block_bytes * total)
@@ -611,7 +621,7 @@ class ServeEngine:
             # position per row in place; undonated (or scanned in and
             # stacked out) every tick would copy every block.
             tok = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
-            logits, pcache = llama.decode_chunk_paged(
+            logits, pcache = model.decode_chunk_paged(
                 params, tok[:, None], cfg, pcache, advance=active)
             return tok, logits[:, 0], pcache
 
@@ -621,7 +631,7 @@ class ServeEngine:
             # continue the row from its current length; `sel` picks the
             # window position whose logits seed decoding (only the final
             # window's pick survives — later windows overwrite).
-            logits, pcache = llama.decode_chunk_paged_row(
+            logits, pcache = model.decode_chunk_paged_row(
                 params, toks, cfg, pcache, slot, new_length=new_len)
             last_logits = last_logits.at[slot].set(logits[0, sel])
             return pcache, last_logits
@@ -648,7 +658,7 @@ class ServeEngine:
                 # back tokens AND accepted counts in one sync.  Replaces
                 # _tick entirely on a spec engine — still one signature
                 # per program for the life of the server.
-                return llama.spec_verify_paged(
+                return model.spec_verify_paged(
                     params, cfg, pcache, last_logits, drafts, active)
 
             self._spec_tick = _spec_tick
@@ -729,7 +739,7 @@ class ServeEngine:
             for x in jax.tree.leaves(self.params))
         dev.set_model_bytes(
             param_bytes=param_bytes,
-            kv_total_bytes=self._block_bytes * self.pcache.k.shape[1])
+            kv_total_bytes=self._block_bytes * self.pool.n_blocks)
 
     def free_block_count(self) -> int:
         return len(self._free_blocks)
@@ -788,7 +798,12 @@ class ServeEngine:
         sbb = self._shard_block_bytes
         kv = {
             "block_bytes": bb,
-            "total_bytes": bb * self.pcache.k.shape[1],
+            "total_bytes": bb * self.pool.n_blocks,
+            # pool by pool (k and v, or latent / index / window): what
+            # one block holds and what the whole pool does
+            "pools": {name: {"block_bytes": b,
+                             "total_bytes": b * self.pool.n_blocks}
+                      for name, b in self._pool_block_bytes.items()},
             "free_blocks": free, "free_bytes": free * bb,
             "referenced_blocks": referenced,
             "referenced_bytes": referenced * bb,
@@ -798,7 +813,7 @@ class ServeEngine:
             # block, each holding its own head slice)
             "tp_size": self.tp_size,
             "shard_block_bytes": sbb,
-            "shard_total_bytes": sbb * self.pcache.k.shape[1],
+            "shard_total_bytes": sbb * self.pool.n_blocks,
             "shard_free_bytes": free * sbb,
             "shard_referenced_bytes": referenced * sbb,
             "shard_cached_bytes": cached * sbb,
@@ -862,7 +877,7 @@ class ServeEngine:
             f"{time.monotonic() - self._t0:.3f} "
             f"queue_depth={len(self._queue)} "
             f"free_blocks={len(self._free_blocks)}/"
-            f"{self.pcache.k.shape[1] - 1} starve_steps="
+            f"{self.pool.n_blocks - 1} starve_steps="
             f"{self._starve_steps} counters={self.counters}",
             f"  slots: free={states[FREE]} prefill={states[PREFILL]} "
             f"decode={states[DECODE]}; submitted={self._next_id} "
@@ -885,10 +900,11 @@ class ServeEngine:
             f"  kv bytes: block={bb} free={self.pool.free_count() * bb}"
             f" referenced={self.pool.ref_count() * bb}"
             f" cached={self.pool.cached_count() * bb}"
-            f" total={bb * self.pcache.k.shape[1]}"
+            f" total={bb * self.pool.n_blocks}"
+            f" pools={self._pool_block_bytes}"
             f" tp_size={self.tp_size}"
             f" shard_total="
-            f"{self._shard_block_bytes * self.pcache.k.shape[1]}")
+            f"{self._shard_block_bytes * self.pool.n_blocks}")
         rep = self.prof.report()
         if rep is not None:
             lines.append(
@@ -972,10 +988,10 @@ class ServeEngine:
                 f"prompt {L} padded to {n_win * self.chunk} prefill "
                 f"windows exceeds max_len {self.max_len}")
         need = self._need_blocks(req)
-        if need > self.pcache.k.shape[1] - 1:
+        if need > self.pool.n_blocks - 1:
             raise ValueError(
                 f"request needs {need} cache blocks but the pool only "
-                f"has {self.pcache.k.shape[1] - 1} allocatable")
+                f"has {self.pool.n_blocks - 1} allocatable")
         rid = self._next_id
         self._next_id += 1
         now = time.monotonic()
@@ -1537,7 +1553,7 @@ class ServeEngine:
                     f"references but no live row maps it")
         if self.prefix is not None:
             self.prefix.check_consistency()
-        total = self.pcache.k.shape[1] - 1
+        total = self.pool.n_blocks - 1
         accounted = (len(free) + self.pool.cached_count()
                      + len(self.pool._ref))
         if accounted != total:
@@ -1658,6 +1674,7 @@ class ServeEngine:
                     if s.state == DECODE]
         spec = self.spec and bool(decoding)
         drafts_host: np.ndarray | None = None
+        stats_host: np.ndarray | None = None
         if spec:
             prof.mark("draft")
             # draft phase: each decoding row proposes up to draft_k
@@ -1696,6 +1713,12 @@ class ServeEngine:
                     tok, self.last_logits, self.pcache = self._tick(
                         self.params, self.pcache, self.last_logits,
                         jnp.asarray(active))
+                # the model's device-side counters (None for a model
+                # that keeps none) start their way to the host behind
+                # the tokens: read below, after the sync that is there
+                stats = self.model.paged_counters(self.pcache)
+                if stats is not None:
+                    stats.copy_to_host_async()
                 if self.device is not None:
                     self.device.dispatch(
                         "spec_tick" if spec else "tick",
@@ -1709,6 +1732,7 @@ class ServeEngine:
                 tok_host = np.asarray(tok)
                 if spec:
                     accept_host = np.asarray(accept)
+                stats_host = None if stats is None else np.asarray(stats)
                 if self.device is not None:
                     # split the measured readback wait into the cost
                     # model's predicted device-compute share vs host
@@ -1778,6 +1802,10 @@ class ServeEngine:
                             self._terminate(slot, OK)
                             break
         prof.mark("bookkeeping")
+        if decoding and stats_host is not None:
+            self.model.publish_paged_metrics(
+                self.metrics, self.cfg, self.pcache, stats_host,
+                tuple(s.n_blocks for s in self._slots if s.state != FREE))
         if self.timeline is not None:
             self.timeline.counter(
                 "serving.scheduler", "SCHED",

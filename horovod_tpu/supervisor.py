@@ -77,7 +77,7 @@ def clone_engine(eng: Any) -> Any:
         block_size=eng.block_size,
         # The paged cache's axis-1 extent IS n_blocks (trash block
         # included), so the clone's KV geometry matches bit-for-bit.
-        n_blocks=int(eng.pcache.k.shape[1]),
+        n_blocks=eng.pool.n_blocks,
         tp_size=eng.tp_size,
         timeline=eng.timeline,
         preempt_after=eng.preempt_after,

@@ -1,0 +1,398 @@
+"""models/latent_moe.py against the benchmark's plain reference
+(benchmark/reference/dots3.py, the one copy), at the tiny preset on the CPU
+with seeded weights: the whole forward and chunked prefill followed by decoding
+through the three pools, the discrete choices (the indexer's selected sets and
+the router's experts), bfloat16, the tie between the chip's share and the uncut
+model, the engine and the router over the model interface, and the counters."""
+
+import functools
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark import lib  # noqa: E402
+from horovod_tpu import metrics as metrics_mod  # noqa: E402
+from horovod_tpu.models import latent_moe as lm  # noqa: E402
+from horovod_tpu.models import llama  # noqa: E402
+from horovod_tpu.router import LocalReplica, RouterServer  # noqa: E402
+from horovod_tpu.serving import Request  # noqa: E402
+from horovod_tpu.serving_scheduler import ServeEngine  # noqa: E402
+
+ref = lib.load_module("reference", "dots3")
+fam = lib.load_module("families", "dots3_serve")
+SEED = 5
+
+#: The tiny preset in the configuration file's keys: all three kinds of layer,
+#: 16 experts of which 8 are held, top-6 selection and a window of 5, both
+#: smaller than the test lengths.
+TINY = dict(
+    name="tiny", reference="dots3", hidden_size=32, num_hidden_layers=5,
+    layer_types=["full_attention", "full_attention", "sliding_attention",
+                 "sliding_attention", "sliding_attention"],
+    first_k_dense_replace=1, intermediate_size=64, num_attention_heads=4,
+    q_lora_rank=16, kv_lora_rank=8, qk_nope_head_dim=8, qk_rope_head_dim=4,
+    v_head_dim=8, rope_theta=1e4, index_n_heads=2, index_head_dim=8,
+    index_topk=6, swa_num_attention_heads=2, swa_q_lora_rank=16,
+    swa_kv_lora_rank=16, swa_qk_nope_head_dim=12, swa_qk_rope_head_dim=4,
+    swa_v_head_dim=8, swa_rope_theta=1e3, sliding_window_size=5,
+    n_routed_experts=8, n_routed_experts_published=16, held_experts_first=0,
+    moe_intermediate_size=16, num_experts_per_tok=4, n_shared_experts=1,
+    routed_scaling_factor=1.0, vocab_size=64, vocab_first_row=0,
+    rms_norm_eps=1e-5, apply_mla_qkv_lora_rescale=True,
+    torch_dtype="float32")
+
+
+def tiny(**changes):
+    """``(configuration dict, LatentMoEConfig, parameters)``, the parameters
+    the reference's own for the seed."""
+    cfg = dict(TINY, **changes)
+    return cfg, fam.model_config(cfg, 64), fam.make_params(cfg, SEED)
+
+
+def tokens(n, vocab=64, seed=0):
+    return np.random.default_rng(seed).integers(1, vocab, n).tolist()
+
+
+def reference_logits(cfg, seq):
+    n = len(seq)
+    return np.asarray(ref.logits_at(cfg, SEED, [seq], [list(range(n))],
+                                    "float32", pad_to=n, q_block=n,
+                                    head_block=2)[0])
+
+
+def reference_choices(cfg, seq):
+    """Per layer what the reference's discrete parts chose over ``seq``."""
+    top = ref.top_weights(cfg, ref.seed_arg(SEED))
+    x = top["embed"][jnp.asarray(seq)].astype(jnp.float32)
+    out = []
+    for i in range(cfg["num_hidden_layers"]):
+        w = ref.layer_weights(cfg, ref.seed_arg(SEED), i)
+        x, aux = ref.layer(cfg, ref.layer_kind(cfg, i), x, w,
+                           q_block=len(seq), head_block=2, aux=True)
+        out.append(aux)
+    return out
+
+
+def test_preset_matches_the_tiny_configuration():
+    assert fam.model_config(TINY, 64) == lm.latent_moe_tiny()
+
+
+def test_forward_equals_the_reference_with_the_same_choices(monkeypatch):
+    cfg, mc, params = tiny()
+    seq = tokens(24)                    # beyond top-6 and the window of 5
+    selected, routed = [], []
+    select, route = lm._index_select, lm.route
+
+    def spy_select(*a, **k):
+        idx, real = select(*a, **k)
+        selected.append(np.where(np.asarray(real), np.asarray(idx), -1)[0])
+        return idx, real
+
+    def spy_route(*a, **k):
+        experts, weights = route(*a, **k)
+        routed.append(np.asarray(experts))
+        return experts, weights
+
+    monkeypatch.setattr(lm, "_index_select", spy_select)
+    monkeypatch.setattr(lm, "route", spy_route)
+    with jax.disable_jit():
+        got = lm.forward(params, jnp.asarray([seq], jnp.int32), mc)[0]
+    np.testing.assert_allclose(np.asarray(got), reference_logits(cfg, seq),
+                               atol=1e-4, rtol=0)
+    want = reference_choices(cfg, seq)
+    full = [a["selected"] for a in want if a["selected"] is not None]
+    assert len(selected) == len(full) == 2
+    for mine, theirs in zip(selected, full):        # the same sets of keys
+        for t in range(len(seq)):
+            assert set(mine[t]) - {-1} == set(np.asarray(theirs[t])) - {-1}
+    moe = [np.asarray(a["experts"]) for a in want if a["experts"] is not None]
+    assert len(routed) == len(moe) == 4
+    for mine, theirs in zip(routed, moe):
+        assert (np.sort(mine, -1) == np.sort(theirs, -1)).all()
+
+
+def _serve_by_hand(mc, params, seq, n_prompt, chunk, max_len=48):
+    """Chunked prefill of ``seq[:n_prompt]`` into slot 1 of a two-slot cache,
+    then the rest a token a tick: the logits at every position."""
+    pc = lm.init_paged_cache(mc, 2, max_len, block_size=chunk)
+    per = pc.block_table.shape[1]
+    pc = pc._replace(block_table=pc.block_table.at[1].set(
+        1 + jnp.arange(per, dtype=jnp.int32)))
+    row = jax.jit(functools.partial(lm.decode_chunk_paged_row, cfg=mc))
+    tick = jax.jit(functools.partial(lm.decode_chunk_paged, cfg=mc))
+    logits = []
+    for start in range(0, n_prompt, chunk):
+        piece = seq[start:min(start + chunk, n_prompt)]
+        toks = jnp.asarray([piece + [0] * (chunk - len(piece))], jnp.int32)
+        out, pc = row(params, toks, pcache=pc, slot=1,
+                      new_length=start + len(piece))
+        logits.append(np.asarray(out[0, :len(piece)]))
+    active = jnp.asarray([0, 1], jnp.int32)
+    for tok in seq[n_prompt:]:
+        out, pc = tick(params, jnp.asarray([[0], [tok]], jnp.int32),
+                       pcache=pc, advance=active)
+        logits.append(np.asarray(out[1]))
+    return np.concatenate(logits), pc
+
+
+def test_chunked_prefill_then_decode_through_the_pools_equals_the_reference():
+    cfg, mc, params = tiny()
+    seq = tokens(31, seed=1)
+    got, pc = _serve_by_hand(mc, params, seq, n_prompt=19, chunk=8)
+    np.testing.assert_allclose(got, reference_logits(cfg, seq), atol=1e-4,
+                               rtol=0)
+    assert int(pc.length[1]) == len(seq) and int(pc.length[0]) == 0
+    # the idle row counted for nothing: every counted token was slot 1's
+    c = lm.read_counters(np.asarray(pc.stats))
+    assert c["choices_total"] == len(seq) * mc.top_k * 4
+
+
+def test_long_computations_taken_in_steps_equal_the_reference(monkeypatch):
+    """At real sizes the indexer scores a few blocks of keys at a time and no
+    further than the rows reach, the top-k sorts the shortest width that
+    holds the visible keys, the selected latents are gathered a block of
+    queries at a time, and an expert's tile has 128 rows: here the same code
+    with steps small enough for the tiny preset to take several."""
+    monkeypatch.setattr(lm, "INDEX_STEP_KEYS", 8)
+    monkeypatch.setattr(lm, "QUERY_BLOCK", 4)
+    monkeypatch.setattr(lm, "TILE_ROWS", (2, 4))
+    cfg, mc, params = tiny()
+    seq = tokens(70, seed=7)
+    got, _ = _serve_by_hand(mc, params, seq, n_prompt=61, chunk=8,
+                            max_len=96)
+    np.testing.assert_allclose(got, reference_logits(cfg, seq), atol=1e-4,
+                               rtol=0)
+
+
+def test_bfloat16_stays_near_float32_but_for_flipped_choices():
+    """The program's own precision.  bfloat16 rounds every activation to 8
+    bits, which moves a logit of this size by a few hundredths; where a
+    near-tie of the router or of the indexer falls the other way, one expert
+    (a quarter of a layer here) or one key (a sixth of a selection) changes
+    and the position's logits move by tenths.  So the typical position is held
+    close and the share of far ones is bounded, not the worst one."""
+    cfg, mc, params = tiny()
+    seq = tokens(40, seed=2)
+    want = reference_logits(cfg, seq)
+    mc16 = fam.model_config(dict(cfg, torch_dtype="bfloat16"), 64)
+    p16 = jax.tree.map(lambda x: x.astype(jnp.bfloat16)
+                       if x.dtype == jnp.float32 and x.ndim > 1 else x, params)
+    got = np.asarray(lm.forward(p16, jnp.asarray([seq], jnp.int32), mc16)[0])
+    err = np.max(np.abs(got - want), axis=-1)          # per position
+    assert np.median(err) < 0.08, np.median(err)
+    assert np.mean(err < 0.3) >= 0.75, np.sort(err)[::-1][:8]
+    assert np.isfinite(got).all()
+
+
+def test_the_eight_shares_add_up_to_the_uncut_layer_and_head():
+    """What ties the chip's share to the model: the routed parts of all 8
+    shares (2 experts each of 16), with the shared expert counted once, are the
+    uncut reference's expert layer; the 8 slices of the vocabulary give the
+    uncut head's logits side by side."""
+    uncut = dict(TINY, n_routed_experts=16, n_routed_experts_published=16)
+    w_all = ref.layer_weights(uncut, ref.seed_arg(SEED), 1)
+    h = jax.random.normal(jax.random.key(0), (11, 32), jnp.float32)
+    whole, experts, _ = ref.moe(ref._dims(uncut), h, w_all, "float32")
+    total = ref._swiglu(h, w_all["s_gate"], w_all["s_up"], w_all["s_down"],
+                        "float32")
+    loads = []
+    for share in range(8):
+        cfg = dict(TINY, n_routed_experts=2, held_experts_first=2 * share)
+        mc = fam.model_config(cfg, 64)
+        lp = ref.layer_weights(cfg, ref.seed_arg(SEED), 1)
+        np.testing.assert_array_equal(
+            np.asarray(lp["e_gate"]),
+            np.asarray(w_all["e_gate"][2 * share:2 * share + 2]))
+        part, load = lm.held_experts(mc, lp, h, jnp.ones((11,), bool))
+        total = total + part
+        loads += [int(x) for x in load]
+    np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
+                               atol=1e-5, rtol=0)
+    assert loads == [int((np.asarray(experts) == e).sum()) for e in range(16)]
+    assert sum(loads) == 11 * 4          # every choice computed somewhere
+
+    seq = tokens(9, vocab=8, seed=3)     # ids every slice holds
+    whole_vocab = reference_logits(dict(TINY, vocab_size=64), seq)
+    parts = []
+    for share in range(8):
+        cfg, mc, params = tiny(vocab_size=8, vocab_first_row=8 * share)
+        full = ref.top_weights(dict(TINY, vocab_size=64), ref.seed_arg(SEED))
+        # the same inputs everywhere: the tokens' rows of the whole embedding
+        params = dict(params, embed=full["embed"][:8])
+        parts.append(np.asarray(lm.forward(
+            params, jnp.asarray([seq], jnp.int32), mc)[0]))
+    np.testing.assert_allclose(np.concatenate(parts, -1), whole_vocab,
+                               atol=1e-4, rtol=0)
+
+
+def _engine(mc, params, **kw):
+    kw.setdefault("n_slots", 2)
+    kw.setdefault("max_len", 48)
+    kw.setdefault("chunk", 8)
+    return ServeEngine(params, mc, monitor=False, sampler=False,
+                       metrics=metrics_mod.MetricsRegistry(event_log=None),
+                       **kw)
+
+
+@pytest.fixture(scope="module")
+def served():
+    cfg, mc, params = tiny()
+    prompts = [tokens(19, seed=4), tokens(7, seed=5), tokens(26, seed=6)]
+    want = [lm.generate(params, mc, p, 9, pad_to=48) for p in prompts]
+    return mc, params, prompts, want
+
+
+def test_engine_run_equals_cache_free_generate(served):
+    mc, params, prompts, want = served
+    eng = _engine(mc, params)
+    out = eng.run([Request(prompt=p, max_new_tokens=9) for p in prompts])
+    assert [r.status for r in out] == ["OK"] * 3
+    assert [list(r) for r in out] == want
+    assert eng.compile_cache_sizes() == {"tick": 1, "chunk": 1, "set_row": 1}
+
+
+def test_prefix_cache_hit_serves_the_same_tokens(served):
+    mc, params, prompts, want = served
+    eng = _engine(mc, params, prefix_cache=True)
+    first = eng.run([Request(prompt=prompts[2], max_new_tokens=9)])
+    again = eng.run([Request(prompt=prompts[2], max_new_tokens=9)])
+    assert list(first[0]) == list(again[0]) == want[2]
+    # the second run mapped the first's blocks in all three pools at once
+    assert eng.prefix_counters["hits"] >= 1
+    assert eng.prefix_counters["tokens_skipped"] >= 16
+
+
+def test_preemption_and_replay_serve_the_same_tokens(served):
+    mc, params, prompts, want = served
+    # 7 blocks cannot hold both long requests: the second starves, the first
+    # is preempted, requeued and replayed from its prompt plus what it emitted
+    eng = _engine(mc, params, n_blocks=7, preempt_after=2)
+    out = eng.run([Request(prompt=prompts[0], max_new_tokens=9),
+                   Request(prompt=prompts[2], max_new_tokens=9)])
+    assert [list(r) for r in out] == [want[0], want[2]]
+    assert eng.counters["preemptions"] >= 1
+
+
+def test_speculative_round_serves_the_same_tokens(served):
+    mc, params, prompts, want = served
+    eng = _engine(mc, params, spec=True, draft_k=3)
+    out = eng.run([Request(prompt=p, max_new_tokens=9) for p in prompts])
+    assert [list(r) for r in out] == want
+    assert eng.spec_counters["rounds"] > 0
+    assert eng.compile_cache_sizes()["tick"] == 0       # the wide tick only
+
+
+def test_router_over_a_local_replica_serves_the_same_tokens(served):
+    mc, params, prompts, want = served
+    router = RouterServer([LocalReplica(_engine(mc, params), "r0")])
+    try:
+        rids = [router.route(Request(prompt=p, max_new_tokens=9))
+                for p in prompts]
+        got = [router.result(rid, timeout=120) for rid in rids]
+    finally:
+        router.stop(drain_s=0.0)
+    assert [r.status for r in got] == ["OK"] * 3
+    assert [list(r) for r in got] == want
+
+
+def test_cancel_frees_every_block(served):
+    mc, params, prompts, _ = served
+    eng = _engine(mc, params)
+    rid = eng.submit(Request(prompt=prompts[2], max_new_tokens=9))
+    eng.step()
+    assert eng.cancel(rid)
+    while eng.pending():
+        eng.step()
+    assert eng.results[rid].status == "CANCELLED"
+    assert eng.free_block_count() == eng.pool.n_blocks - 1
+
+
+def test_tensor_parallel_serving_is_refused_clearly(served):
+    mc, params, _, _ = served
+    with pytest.raises(NotImplementedError, match="tensor-parallel"):
+        _engine(mc, params, tp_size=2)
+
+
+def test_counters_equal_what_the_reference_counts(served):
+    """One request, no prefix cache: the engine decodes the prompt and each
+    token it emits, so the counters are the reference's choices over prompt
+    plus output."""
+    mc, params, prompts, want = served
+    cfg = dict(TINY)
+    eng = _engine(mc, params)
+    out = eng.run([Request(prompt=prompts[0], max_new_tokens=9)])
+    seq = prompts[0] + list(out[0])
+    choices = reference_choices(cfg, seq)
+    experts = np.stack([np.asarray(a["experts"]) for a in choices
+                        if a["experts"] is not None])           # [4, T, k]
+    snap = eng.metrics_snapshot()
+    c, g = snap["counters"], snap["gauges"]
+    assert c["moe.choices_total"] == experts.size
+    assert c["moe.choices_held"] == int((experts < 8).sum())
+    for e in range(8):
+        assert g[f"moe.held_load.{e}"] == int((experts == e).sum())
+    n = len(seq)
+    assert c["dsa.keys_visible"] == 2 * sum(t + 1 for t in range(n))
+    assert c["dsa.keys_selected"] == 2 * sum(min(t + 1, 6) for t in range(n))
+    selected = [np.asarray(a["selected"]) for a in choices
+                if a["selected"] is not None]
+    assert c["dsa.keys_selected"] == sum(int((s >= 0).sum()) for s in selected)
+    assert 0 < g["moe.experts_touched"] <= 4 * 8
+    # per pool, and their sum
+    pools = eng.memory_report()["kv"]["pools"]
+    assert set(pools) == {"latent", "index", "window"}
+    assert g["kv.block_bytes"] == sum(p["block_bytes"] for p in pools.values())
+    assert g["kv.latent_block_bytes"] == pools["latent"]["block_bytes"]
+    assert g["kv.window_block_bytes"] == pools["window"]["block_bytes"]
+    assert "pools=" in eng.state_dump()
+
+
+def test_counters_carry_past_a_word():
+    """A running sum is two int32 words; the carry is exact."""
+    stats = jnp.zeros((2, lm.LOAD0 + 2), jnp.int32)
+    add = jnp.zeros((lm.LOAD0 + 2,), jnp.int32).at[lm.KEYS_VISIBLE].set(
+        2**30 + 12345)
+    for _ in range(5):
+        stats = lm._add_stats(stats, add, None)
+    assert lm.read_counters(np.asarray(stats))["keys_visible"] \
+        == 5 * (2**30 + 12345)
+
+
+def test_a_llama_engine_lowers_to_the_same_programs_as_before_the_interface():
+    """The engine reaches ``models.llama`` through the model interface; for a
+    ``LlamaConfig`` its tick and chunk are, letter for letter, the programs
+    that named ``llama`` directly."""
+    cfg = llama.llama_tiny()
+    params = llama.init_params(cfg, jax.random.key(0))
+    eng = ServeEngine(params, cfg, n_slots=2, max_len=32, chunk=8,
+                      monitor=False, sampler=False,
+                      metrics=metrics_mod.MetricsRegistry(event_log=None))
+    assert eng.model is llama
+
+    @functools.partial(jax.jit, donate_argnums=(1, 2))
+    def _tick(params, pcache, last_logits, active):
+        tok = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
+        logits, pcache = llama.decode_chunk_paged(
+            params, tok[:, None], cfg, pcache, advance=active)
+        return tok, logits[:, 0], pcache
+
+    @functools.partial(jax.jit, donate_argnums=(1, 2))
+    def _chunk(params, pcache, last_logits, toks, slot, new_len, sel):
+        logits, pcache = llama.decode_chunk_paged_row(
+            params, toks, cfg, pcache, slot, new_length=new_len)
+        last_logits = last_logits.at[slot].set(logits[0, sel])
+        return pcache, last_logits
+
+    progs = eng.pinned_programs()
+    for name, before in (("tick", _tick), ("chunk", _chunk)):
+        fn, *avals = progs[name]
+        assert fn.lower(*avals).as_text() == before.lower(*avals).as_text()
+    assert set(eng.memory_report()["kv"]["pools"]) == {"k", "v"}

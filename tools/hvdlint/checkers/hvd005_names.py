@@ -114,7 +114,9 @@ class CounterNameChecker(Checker):
             # Per-endpoint scrape instruments: emitted as
             # monitor.scrape_s.<endpoint> f-strings, documented under
             # the family base name.
-            | {"monitor.scrape_s", "monitor.scrape_errors"})
+            | {"monitor.scrape_s", "monitor.scrape_errors",
+               # one gauge per held expert: moe.held_load.<expert>
+               "moe.held_load"})
         for name in sorted(set(metric_sites) - help_names):
             rel, line = metric_sites[name]
             yield Finding(
