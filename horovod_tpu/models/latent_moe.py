@@ -348,12 +348,14 @@ def read_counters(stats_host: np.ndarray) -> dict:
 def publish_paged_metrics(metrics, cfg: LatentMoEConfig,
                           pcache: LatentPagedCache,
                           stats_host: np.ndarray | None = None,
-                          row_blocks: tuple = ()) -> None:
+                          row_blocks: tuple = (),
+                          programs: tuple = ()) -> None:
     """The model's own gauges and counters in the engine's registry.  Without
-    ``stats_host`` (at construction) the per-pool sizes; with it (after a
-    tick's readback) the counters, and from ``row_blocks`` (blocks mapped by
-    each live row) what a window-sized pool would free."""
-    if stats_host is None:          # once, at construction
+    ``stats_host`` and ``programs`` (at construction) the per-pool sizes; with
+    ``stats_host`` (after a tick's readback) the counters, and from
+    ``row_blocks`` (blocks mapped by each live row) what a window-sized pool
+    would free."""
+    if stats_host is None and not programs:     # once, at construction
         per_block = paged_pool_bytes(pcache)
         metrics.gauge("kv.latent_block_bytes").set(per_block["latent"])
         metrics.gauge("kv.index_block_bytes").set(per_block["index"])
@@ -362,6 +364,7 @@ def publish_paged_metrics(metrics, cfg: LatentMoEConfig,
         for name in ("moe.choices_total", "moe.choices_held",
                      "dsa.keys_visible", "dsa.keys_selected"):
             metrics.counter(name)
+    if stats_host is None:          # nothing was read back: no tick ran
         return
     keep = -(-(cfg.window - 1) // pcache.block_size) + 1
     metrics.gauge("kv.window_bytes_beyond_window").set(

@@ -598,7 +598,10 @@ def decode_chunk(
 # signature (the tables are data, not shapes, so admission never retraces).
 # Those programs donate the pool and ``_paged_attend`` carries it through
 # the layer scan, so it is updated in place: a step moves the positions it
-# writes and the view attention reads, never a block it does not touch.
+# writes and the blocks attention reads, never a block it does not touch.
+# Attention's work follows the longest live row: it walks the block tables
+# tile by tile with a running softmax, up to a bound read from the rows'
+# lengths, and neither gathers nor scores the depth a table could hold.
 #
 # Block 0 is the TRASH block: it is never allocated, and unallocated table
 # entries point at it.  Free/idle rows that tick along with the batch (the
@@ -624,7 +627,9 @@ class PagedKVCache(NamedTuple):
 
     @property
     def logical_len(self) -> int:
-        """Dense attention width each row's table spans (== max_len)."""
+        """Positions each row's table can map (== max_len): the bound on a
+        row's length, not a width attention pays (``_paged_attend`` reads
+        the blocks up to the longest live row)."""
         return self.block_table.shape[1] * self.k.shape[2]
 
 
@@ -670,8 +675,18 @@ def paged_counters(pcache: PagedKVCache) -> None:
 
 
 def publish_paged_metrics(metrics, cfg, pcache, stats_host=None,
-                          row_blocks=()) -> None:
-    """This model has no gauges of its own beside the engine's ``kv.*``."""
+                          row_blocks=(), programs=()) -> None:
+    """Beside the engine's ``kv.*`` this model has two counters: the share
+    of the block tables its attention walks, ``attn.blocks_visited`` over
+    ``attn.blocks_in_table``.  ``programs`` holds ``(rows, tokens a row,
+    longest row's length)`` of each program a step dispatched, which the
+    host knows from its slots; nothing is read back."""
+    bs, per = pcache.block_size, pcache.block_table.shape[1]
+    metrics.counter("attn.blocks_visited").inc(sum(
+        b * paged_blocks_walked(longest, t, bs, per)
+        for b, t, longest in programs))
+    metrics.counter("attn.blocks_in_table").inc(
+        per * sum(b for b, _, _ in programs))
 
 
 def tp_split_dims(cfg: LlamaConfig) -> tuple:
@@ -802,35 +817,67 @@ class BlockPool:
         ]
 
 
+# Key positions one step of ``_paged_attend``'s loop scores per row, in
+# whole pool blocks.  Measured on one v5e at Mistral-7B widths, blocks of
+# 256: tiles of 512-1,024 keys are fastest for the one-token tick and the
+# 256-token chunk alike (PERF.md, PR 28), so the tile follows the block
+# size alone.
+_KEY_TILE = 512
+
+
+def _tile_blocks(block_size: int, per: int) -> int:
+    """Pool blocks of each row that one step of the key loop reads."""
+    return max(1, min(per, _KEY_TILE // block_size))
+
+
+def paged_blocks_walked(longest: int, t: int, block_size: int,
+                        per: int) -> int:
+    """Blocks of each row's table that a program over ``t`` tokens a row
+    reads when its longest row holds ``longest`` positions: whole key tiles
+    up to the last query position, no further than the table.  The host's
+    form of the trip count :func:`_paged_attend` reads from ``qpos``."""
+    g = _tile_blocks(block_size, per)
+    return min(((longest + t - 1) // (g * block_size) + 1) * g, per)
+
+
 def _paged_attend(params, tokens, cfg: LlamaConfig, kv_k, kv_v,
-                  qpos, wflat, gflat):
+                  qpos, wflat, table):
     """Shared body of the paged decode paths: scatter the chunk's K/V at
-    flat physical positions ``wflat`` [B, T], gather each row's dense
-    [M] view via ``gflat`` [B, M], and run :func:`decode_chunk`'s exact
-    mask/einsum math on it.  The gather width M equals the logical depth,
-    so for identical cache VALUES the masked softmax/matvec sequence is
-    the same XLA computation as the dense path — bit-identical logits
-    (gathered garbage beyond a row's frontier is masked to an exact-zero
-    softmax term, just like dense pad slots).
+    flat physical positions ``wflat`` [B, T], then attend block-wise
+    through ``table`` [B, blocks_per_row] (the rows' block tables) with a
+    running softmax: a loop over key tiles of whole pool blocks that ends
+    at the longest row's last query position, a traced bound read from
+    ``qpos``.  Nothing as deep as the table is gathered or scored; a tile
+    past a shorter row's frontier (its table points at trash there) is
+    masked to an exact-zero softmax term, as dense pad slots are.  The
+    numbers are :func:`decode_chunk`'s up to the order of summation:
+    products of K and V as stored, accumulated in float32; maximum, sum
+    and output accumulator float32; one division after the loop.
 
     The pool is written IN PLACE: ``kv_k`` / ``kv_v`` ride the layer scan
     as CARRIES (flattened to ``[L * n_blocks * bs, KVH, Dh]``, a bitcast),
     never as scanned inputs or stacked outputs, and layer ``i`` scatters
-    to and gathers from its own stripe at ``i * n_blocks * bs``.  With the
+    to and reads blocks from its own stripe at ``i * n_blocks``.  With the
     caller's pool donated the carry aliases it, so a program's only pool
-    traffic is the B x T scatter and the gather attention needs — no
+    traffic is the B x T scatter and the live blocks attention reads — no
     layer slice is copied and the pool is held once."""
     b, t = tokens.shape
     nl, n_blocks, bs, kvh, dh = kv_k.shape
-    m = gflat.shape[1]
+    per = table.shape[1]
+    m = per * bs                            # the table's logical depth
     dt = cfg.dtype
     x = params["embed"][tokens].astype(dt)                # [B, T, D]
     cos, sin = rope_tables(cfg, qpos)
     n_rep = cfg.n_heads // cfg.n_kv_heads
     scale = 1.0 / (cfg.head_dim ** 0.5)
-    valid = jnp.arange(m)[None, None, :] <= qpos[:, :, None]
-    valid = valid[:, None, None, :, :]                    # [B,1,1,T,M]
     stripe = n_blocks * bs                  # one layer's flat positions
+    g = _tile_blocks(bs, per)               # blocks a key tile spans
+    w = g * bs
+    n_tiles = -(-per // g)
+    table = jnp.pad(table, ((0, 0), (0, n_tiles * g - per)))  # with trash
+    # tiles up to the longest row's last query position: data, not shape
+    n_live = jnp.minimum(jnp.max(qpos) // w + 1, n_tiles)
+    stat = (b, kvh, n_rep, t)
 
     def layer(carry, lp):
         x, kf, vf, i = carry                # kf/vf [L * stripe, KVH, Dh]
@@ -843,16 +890,37 @@ def _paged_attend(params, tokens, cfg: LlamaConfig, kv_k, kv_v,
         off = i * stripe
         kf = kf.at[wflat + off].set(k)
         vf = vf.at[wflat + off].set(v)
-        kd = kf[gflat + off]                              # [B, M, KVH, Dh]
-        vd = vf[gflat + off]
+        kb = kf.reshape(nl * n_blocks, bs, kvh, dh)       # a bitcast
+        vb = vf.reshape(nl * n_blocks, bs, kvh, dh)
         qg = q.reshape(b, t, cfg.n_kv_heads, n_rep, cfg.head_dim)
-        s = jnp.einsum(
-            "bqkrd,bmkd->bkrqm", qg.astype(jnp.float32),
-            kd.astype(jnp.float32)
-        ) * scale                                         # [B,KVH,R,T,M]
-        s = jnp.where(valid, s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bkrqm,bmkd->bqkrd", p, vd.astype(jnp.float32))
+
+        def tile(j, acc):
+            mx, den, o = acc
+            blk = lax.dynamic_slice_in_dim(table, j * g, g, axis=1)
+            blk = blk + i * n_blocks                      # [B, G]
+            kt = kb[blk].reshape(b, w, kvh, dh)
+            vt = vb[blk].reshape(b, w, kvh, dh)
+            s = jnp.einsum("bqkrd,bmkd->bkrqm", qg, kt,
+                           preferred_element_type=jnp.float32) * scale
+            kpos = j * w + jnp.arange(w)
+            seen = (kpos <= qpos[:, :, None]) & (kpos < m)    # [B, T, W]
+            s = jnp.where(seen[:, None, None], s, NEG_INF_LOGIT)
+            mx_new = jnp.maximum(mx, jnp.max(s, axis=-1))
+            p = jnp.exp(s - mx_new[..., None])            # [B,KVH,R,T,W]
+            fade = jnp.exp(mx - mx_new)
+            den = fade * den + jnp.sum(p, axis=-1)
+            o = fade[..., None] * o + jnp.einsum(
+                "bkrqm,bmkd->bkrqd", p, vt.astype(jnp.float32))
+            return mx_new, den, o
+
+        # every query sees key 0, so the first tile makes `mx` a real
+        # maximum and a masked term is exp(-1e30 - mx) == 0 from there on
+        _, den, o = lax.fori_loop(
+            0, n_live, tile,
+            (jnp.full(stat, NEG_INF_LOGIT, jnp.float32),
+             jnp.zeros(stat, jnp.float32),
+             jnp.zeros(stat + (dh,), jnp.float32)))
+        o = jnp.moveaxis(o / den[..., None], 3, 1)        # [B,T,KVH,R,Dh]
         x = x + o.astype(dt).reshape(b, t, cfg.dim) @ lp["wo"].astype(dt)
         h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
         gate = jax.nn.silu(h @ lp["w_gate"].astype(dt))
@@ -893,10 +961,9 @@ def decode_chunk_paged(
     wblk = jnp.take_along_axis(
         pcache.block_table, jnp.clip(qpos // bs, 0, per - 1), axis=1)
     wflat = wblk * bs + qpos % bs                         # [B, T]
-    gflat = (pcache.block_table[:, :, None] * bs
-             + jnp.arange(bs)[None, None, :]).reshape(b, per * bs)
     logits, ks, vs = _paged_attend(
-        params, tokens, cfg, pcache.k, pcache.v, qpos, wflat, gflat)
+        params, tokens, cfg, pcache.k, pcache.v, qpos, wflat,
+        pcache.block_table)
     adv = (jnp.asarray(t, jnp.int32) if advance is None
            else jnp.asarray(advance, jnp.int32))
     return logits, pcache._replace(k=ks, v=vs, length=pos + adv)
@@ -974,10 +1041,9 @@ def decode_chunk_paged_row(
     qpos = (pos + jnp.arange(t))[None, :]                 # [1, T]
     wblk = row_table[jnp.clip(qpos // bs, 0, per - 1)]
     wflat = wblk * bs + qpos % bs
-    gflat = (row_table[None, :, None] * bs
-             + jnp.arange(bs)[None, None, :]).reshape(1, per * bs)
     logits, ks, vs = _paged_attend(
-        params, tokens, cfg, pcache.k, pcache.v, qpos, wflat, gflat)
+        params, tokens, cfg, pcache.k, pcache.v, qpos, wflat,
+        row_table[None])
     length = pcache.length.at[slot].set(
         jnp.asarray(new_length, jnp.int32))
     return logits, pcache._replace(k=ks, v=vs, length=length)

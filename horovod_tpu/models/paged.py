@@ -21,8 +21,10 @@ The engine names no model.  A model is a module with these functions, which
   tensor-parallel layout (a model may raise ``NotImplementedError``);
 * ``paged_counters(pcache)`` — a small device array of counters the engine
   reads back beside the tick's tokens, or ``None``;
-* ``publish_paged_metrics(metrics, cfg, pcache, stats_host, row_blocks)`` —
-  the model's own gauges and counters into the engine's registry.
+* ``publish_paged_metrics(metrics, cfg, pcache, stats_host, row_blocks,
+  programs)`` — the model's own gauges and counters into the engine's
+  registry: at construction, and after every step that dispatched a program
+  (``programs``: rows, tokens a row and the longest row's length of each).
 """
 
 from __future__ import annotations

@@ -956,6 +956,15 @@ class ServeEngine:
         return -(-(len(req.prompt) + req.max_new_tokens)
                  // self.block_size)
 
+    def _row_length(self, s: _Slot) -> int:
+        """The host's mirror of a row's device ``length``: what the slot's
+        own bookkeeping says its row holds, with no read-back."""
+        if s.state == PREFILL:
+            return s.base + s.w_done * self.chunk
+        if s.state == DECODE:
+            return s.true_len + len(s.out)
+        return 0
+
     def submit(self, req: Request) -> int:
         """Queue a request; returns its id (key into ``results``).
         Validation happens here so a rejected request never holds a
@@ -1625,6 +1634,9 @@ class ServeEngine:
                     self._starve_steps = 0
                     more, _ = self._admit_ready()  # head admits this step
                     progress += more
+        # (rows, tokens a row, longest row) of every program this step
+        # dispatches: what the model's own counters are reckoned from
+        programs: list[tuple[int, int, int]] = []
         with prof.sub("admit.prefill_dispatch"):
             for slot, s in enumerate(self._slots):
                 if s.state != PREFILL:
@@ -1648,6 +1660,7 @@ class ServeEngine:
                 t_chunk = time.monotonic() if traced else 0.0
                 try:
                     self.faults.check("serve.prefill", key=s.request_id)
+                    programs.append((1, self.chunk, self._row_length(s)))
                     self.pcache, self.last_logits = self._chunk(
                         self.params, self.pcache, self.last_logits,
                         jnp.asarray(toks), jnp.asarray(slot, jnp.int32),
@@ -1703,6 +1716,9 @@ class ServeEngine:
                 active = np.zeros((self.n_slots,), np.int32)
                 active[decoding] = 1
                 accept_host = None
+                programs.append((
+                    self.n_slots, self.draft_k + 1 if spec else 1,
+                    max(self._row_length(s) for s in self._slots)))
                 if spec:
                     tok, accept, self.last_logits, self.pcache = \
                         self._spec_tick(
@@ -1802,10 +1818,11 @@ class ServeEngine:
                             self._terminate(slot, OK)
                             break
         prof.mark("bookkeeping")
-        if decoding and stats_host is not None:
+        if programs:
             self.model.publish_paged_metrics(
                 self.metrics, self.cfg, self.pcache, stats_host,
-                tuple(s.n_blocks for s in self._slots if s.state != FREE))
+                tuple(s.n_blocks for s in self._slots if s.state != FREE),
+                programs)
         if self.timeline is not None:
             self.timeline.counter(
                 "serving.scheduler", "SCHED",
