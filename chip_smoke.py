@@ -188,7 +188,7 @@ def phase_dp_equivalence() -> dict:
 
 # ── phase 2: the kernel ────────────────────────────────────────────────────
 
-#: bench.py's Llama train shape (189 M parameters).
+#: A Llama train shape of 189 M parameters.
 LLAMA_TRAIN = dict(vocab_size=32768, dim=1024, n_layers=8, n_heads=16,
                    n_kv_heads=4, ffn_dim=4096, max_seq_len=2048)
 
@@ -273,7 +273,7 @@ def phase_kernel(*, head_dims=(64, 128), batch: int = 2,
 
 # ── phase 3: the server ────────────────────────────────────────────────────
 
-#: The repo's 1.11 B Llama shape (tools/tpu_sustained_run.py "1b").
+#: A Llama shape of 1.11 B parameters.
 LLAMA_SERVE = dict(vocab_size=32768, dim=2048, n_layers=16, n_heads=16,
                    n_kv_heads=4, ffn_dim=8192, max_seq_len=2048)
 

@@ -43,7 +43,7 @@ def _build(model_name: str, on_tpu: bool, image_size: int):
         from horovod_tpu.models.vit import ViT_B16
 
         # Dense attention: 224px/patch16 = 196 tokens, far below the
-        # flash kernel's ~2k-token crossover (see bench.py _bench_vit).
+        # flash kernel's ~2k-token crossover.
         model = ViT_B16(dtype=jnp.bfloat16 if on_tpu else jnp.float32)
         x = jnp.ones((1, image_size, image_size, 3), jnp.float32)
         classes = 1000

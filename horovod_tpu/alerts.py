@@ -42,8 +42,8 @@ degrades freshness, not correctness.
 campaigns evaluate production-shaped rules against compressed
 wall-clock storms without a parallel rule table.
 
-:class:`CapacityAdvisor` folds the live series with the last
-``serve_load_report.json`` knee (PR 11) into ``scale_up(n)`` /
+:class:`CapacityAdvisor` folds the live series with the knee of a
+load report (``loadgen.measure_saturation``, PR 11) into ``scale_up(n)`` /
 ``scale_down(n)`` / ``hold`` recommendation records with the evidence
 attached — the exact input the PR-13 autoscaler will wire to the
 PR-10 supervisor actuators.
@@ -371,10 +371,11 @@ class CapacityAdvisor:
     Evidence carries every input the decision read, so the PR-13
     autoscaler (and a human reading ``state_dump()``) can audit it.
 
-    The knee comes from the last ``serve_load_report.json`` the bench
-    wrote (PR 11) — per-replica sustainable goodput RPS.  Without a
-    report the advisor still works from goodput, queue growth, and
-    free-KV slope; it just can't size ``n`` from demand.
+    The knee comes from the ``load_report`` it was given (a
+    ``loadgen.measure_saturation`` report, as a dict or a path) —
+    per-replica sustainable goodput RPS.  Without a report the advisor
+    still works from goodput, queue growth, and free-KV slope; it just
+    can't size ``n`` from demand.
     """
 
     def __init__(self, sampler: timeseries_mod.MetricsSampler, *,
@@ -402,18 +403,13 @@ class CapacityAdvisor:
         self._delta_g = self.registry.gauge("advisor.target_delta")
 
     def load_knee(self) -> dict | None:
-        """The knee row from the configured load report: explicit dict,
-        a path, or the bench's default drop location."""
+        """The knee row from the configured load report (an explicit
+        dict or a path); ``None`` where none was configured."""
         src = self._load_report
-        if isinstance(src, dict):
+        if src is None or isinstance(src, dict):
             return src
-        path = src
-        if path is None:
-            path = os.path.join(
-                os.environ.get("HVD_TPU_BENCH_CACHE") or ".",
-                "serve_load_report.json")
         try:
-            with open(path) as f:
+            with open(src) as f:
                 r = json.load(f)
         except (OSError, ValueError):
             return None

@@ -534,111 +534,6 @@ class FleetAutoscaler:
         }
 
 
-def measure_autoscale_goodput(
-        params: Any = None, cfg: Any = None, *,
-        engines: "Sequence[Any] | None" = None,
-        rate: float = 32.0, duration_s: float = 0.5,
-        seed: int = 0, grow_n: int = 1,
-        n_slots: int = 4, chunk: int = 16,
-        max_len: "int | None" = None,
-        timeout_s: float = 60.0) -> dict:
-    """The ``serve_autoscale_*`` bench arm: goodput retention across a
-    Bursty traffic step.
-
-    Serves one seeded open-loop :class:`~horovod_tpu.loadgen.Bursty`
-    schedule against a single-replica fleet (the pre-step baseline),
-    actuates a scripted ``scale_up(grow_n)`` through the live
-    :class:`FleetAutoscaler` — supervisor factory seam, epoch bump and
-    all — then serves the *same* schedule again on the grown fleet.
-    ``retention = goodput_post / goodput_pre`` is the headline: how
-    much of the burst's SLO-good work the grow won back.  The arm ends
-    with a scripted scale-down so the zero-drop cordon → drain →
-    retire path runs under the bench too; ``serve_autoscale_scale_ok``
-    gates on the full round trip (grew, served on the new replica,
-    retired back to one, epoch advanced twice).
-
-    Pass ``engines`` to reuse an existing fleet seed-replica list
-    (tests), or ``params``/``cfg`` to build one."""
-    from horovod_tpu import faults as faults_mod
-    from horovod_tpu.loadgen import (DEFAULT_TENANTS, Bursty,
-                                     RequestMix, build_schedule,
-                                     run_open_loop, summarize_rung)
-    from horovod_tpu.metrics import MetricsRegistry
-    from horovod_tpu.router import RouterServer
-    from horovod_tpu.serving import Request
-    from horovod_tpu.supervisor import ReplicaSupervisor
-
-    mix = RequestMix(DEFAULT_TENANTS, seed)
-    reg = MetricsRegistry()
-    fr = faults_mod.FaultRegistry()
-    if engines is None:
-        from horovod_tpu.serving_scheduler import ServeEngine
-        if max_len is None:
-            need = (max(t.prefix_len + t.prompt_len[1]
-                        + t.new_tokens[1] for t in mix.tenants) + chunk)
-            max_len = -(-need // chunk) * chunk      # block-aligned
-        engines = [ServeEngine(params, cfg, n_slots=n_slots,
-                               max_len=max_len, chunk=chunk,
-                               prefix_cache=True, metrics=reg,
-                               faults=fr)]
-    for eng in engines:
-        eng.run([Request(prompt=[1] * (eng.chunk + 1),
-                         max_new_tokens=2)])
-    router = RouterServer(engines, registry=reg, faults=fr)
-    sup = ReplicaSupervisor(router, backoff_s=0.01, warm_prefixes=4)
-    asc = FleetAutoscaler(router, supervisor=sup, enabled=True,
-                          cooldown_s=0.0, stable_s=0.0,
-                          min_replicas=1,
-                          max_replicas=len(engines) + grow_n,
-                          step=grow_n, drain_s=0.0, faults=fr)
-    base_size = len(engines)
-    sched = build_schedule(Bursty(rate, seed), mix, duration_s, seed)
-    try:
-        pre = summarize_rung(
-            run_open_loop(router, sched, timeout_s=timeout_s),
-            offered_rps=rate, duration_s=duration_s)
-        grow = asc.actuate({"action": "scale_up", "n": grow_n,
-                            "reason": "bench traffic step"})
-        post = summarize_rung(
-            run_open_loop(router, sched, timeout_s=timeout_s),
-            offered_rps=rate, duration_s=duration_s)
-        shrink = asc.actuate({"action": "scale_down", "n": grow_n,
-                              "reason": "bench step over"})
-        deadline = time.monotonic() + timeout_s
-        while asc.draining() and time.monotonic() < deadline:
-            router.poll_now()
-            time.sleep(0.005)
-        router.reap_tickets(0)
-        leaked = router.memory_report()["tickets"]
-        with router._lock:
-            final_size = len(router.replicas)
-        epoch = asc.epoch.generation
-    finally:
-        router.stop()
-    grown = list(grow.get("replicas", []))
-    scale_ok = (grow["action"] == "scale_up"
-                and shrink["action"] == "scale_down"
-                and final_size == base_size
-                and epoch >= 2 and leaked == 0)
-    retention = (post["goodput"] / pre["goodput"]
-                 if pre["goodput"] > 0 else float(post["goodput"] > 0))
-    return {
-        "serve_autoscale_seed": seed,
-        "serve_autoscale_rate_rps": rate,
-        "serve_autoscale_duration_s": duration_s,
-        "serve_autoscale_requests": pre["n"] + post["n"],
-        "serve_autoscale_goodput_pre": pre["goodput"],
-        "serve_autoscale_goodput_post": post["goodput"],
-        "serve_autoscale_goodput_retention": retention,
-        "serve_autoscale_p99_ttft_pre_ms": pre["p99_ttft_s"] * 1e3,
-        "serve_autoscale_p99_ttft_post_ms": post["p99_ttft_s"] * 1e3,
-        "serve_autoscale_grown_replicas": grown,
-        "serve_autoscale_final_replicas": final_size,
-        "serve_autoscale_epoch": epoch,
-        "serve_autoscale_scale_ok": scale_ok,
-    }
-
-
 def maybe_autoscaler(router: Any) -> "FleetAutoscaler | None":
     """A :class:`FleetAutoscaler` per the env contract: needs
     ``HVD_TPU_AUTOSCALE`` truthy AND a capacity advisor on the router

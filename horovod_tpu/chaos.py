@@ -51,9 +51,7 @@ the journal without touching a replica), ``grew_and_served``, and
 wall-clock budget runs out (the long-haul mode); :func:`compare_campaigns`
 is the JSON regression gate (the ``profile_report.py --compare``
 contract: exit nonzero when recovery got worse).  The CLI lives in
-``tools/chaos_run.py``; the bench arm
-(:func:`measure_chaos_goodput`) reports goodput retention under a
-canned storm versus the fault-free fleet.
+``tools/chaos_run.py``.
 """
 
 from __future__ import annotations
@@ -157,9 +155,8 @@ class ChaosSchedule:
 def _workload(n_groups: int, waves: int, *, prefix_len: int = 16,
               suffix_len: int = 4, max_new_tokens: int = 6,
               ) -> list[Request]:
-    """The router bench's prompt-family shape, chaos-sized: shared
-    per-group prefixes keep the shadow index (and therefore warm
-    respawn) meaningful."""
+    """Prompt families, chaos-sized: shared per-group prefixes keep
+    the shadow index (and therefore warm respawn) meaningful."""
     out = []
     for w in range(waves):
         for g in range(n_groups):
@@ -228,10 +225,9 @@ def run_campaign(params: dict, cfg: Any, *, seed: int = 0,
         seed, replica_names=names, n_faults=n_faults, n_kills=n_kills)
 
     # Fault-free reference: one solo engine (routing never changes
-    # tokens — the router bench asserts that — so a single engine's
-    # greedy output IS the fleet's fault-free output).  Covers the
-    # recovery waves too — same prompt generator, so OK bits must
-    # match there as well.
+    # tokens, so a single engine's greedy output IS the fleet's
+    # fault-free output).  Covers the recovery waves too — same prompt
+    # generator, so OK bits must match there as well.
     ref_engine = ServeEngine(params, cfg, n_slots=n_slots,
                              max_len=max_len, chunk=chunk,
                              prefix_cache=True, monitor=False,
@@ -474,25 +470,6 @@ def compare_campaigns(old: dict, new: dict, *,
                     f"({old[key]:.3f} -> {new[key]:.3f}, "
                     f"threshold {threshold})")
     return (not problems), problems
-
-
-def measure_chaos_goodput(params: dict, cfg: Any, *, seed: int = 0,
-                          **campaign_kw: Any) -> dict:
-    """The ``serve_chaos_*`` bench arm: one seeded storm campaign,
-    reporting what fraction of the workload still terminated ``OK``
-    (the fault-free fleet completes everything, so OK fraction IS
-    goodput retention) plus the storm's shape for context."""
-    report = run_campaign(params, cfg, seed=seed, **campaign_kw)
-    return {
-        "serve_chaos_seed": seed,
-        "serve_chaos_requests": report["n_requests"],
-        "serve_chaos_faults_fired": report["faults_fired"],
-        "serve_chaos_kills_fired": report["kills_fired"],
-        "serve_chaos_respawns": report["respawns"],
-        "serve_chaos_ok_fraction": report["ok_fraction"],
-        "serve_chaos_goodput_retention": report["ok_fraction"],
-        "serve_chaos_oracles_ok": report["ok"],
-    }
 
 
 def run_autoscale_campaign(params: dict, cfg: Any, *,

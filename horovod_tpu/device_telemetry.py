@@ -205,7 +205,7 @@ class DeviceTelemetry:
                 "h2d_bytes": 0, "d2h_bytes": 0, "sync_s": 0.0,
                 "compute_est_s": 0.0, "host_stall_s": 0.0}
 
-    # -- cost model + compile ledger (engine init / bench attach) ----------
+    # -- cost model + compile ledger (engine init) ------------------------
 
     def set_model_bytes(self, *, param_bytes: int,
                         kv_total_bytes: int) -> None:

@@ -78,8 +78,6 @@ ENV_KNOBS = (
      "Seconds of sustained shrink advice before a scale-down starts."),
     ("HVD_TPU_AUTOSCALE_STEP", "1",
      "Replicas added or retired per autoscaler action at most."),
-    ("HVD_TPU_BENCH_CACHE", "",
-     "Directory for cached benchmark baselines (default: repo-local)."),
     ("HVD_TPU_DEVICE_POLL_S", "1.0",
      "Seconds between device memory_stats() polls (HBM gauges)."),
     ("HVD_TPU_DEVICE_TELEMETRY", "0",

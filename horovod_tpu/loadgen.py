@@ -710,8 +710,8 @@ def measure_saturation(
     ``schedule_digest`` in the report is the witness.  Pass ``engines``
     to sweep an existing fleet (tests), or ``params``/``cfg`` to build
     one.  ``http=True`` drives the started HTTP front door instead of
-    in-process ``route()``.  Flat ``serve_load_*`` keys are the bench
-    arm's contract; the full ``rungs`` list is what
+    in-process ``route()``.  The flat ``serve_load_*`` keys summarise
+    the sweep; the full ``rungs`` list is what
     ``tools/load_report.py`` renders and gates on."""
     from horovod_tpu import faults as faults_mod
     from horovod_tpu.metrics import MetricsRegistry

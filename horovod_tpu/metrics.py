@@ -44,8 +44,8 @@ Everything here is standard library only and imports nothing else from
 The module-level :data:`DEFAULT` registry is the shared venue: the
 eager collectives engine and a default-constructed ``ServeEngine``
 both feed it, so one scrape sees training and serving side by side.
-:data:`NULL` is the no-op twin for measuring instrumentation overhead
-(``bench.py`` records the on-vs-off delta in its serve arm extras).
+:data:`NULL` is the no-op twin: every instrument discards what it is
+given (instrumentation off, with no if-guard at any site).
 """
 
 from __future__ import annotations
@@ -919,8 +919,8 @@ class _NullHistogram(Histogram):
 
 class NullRegistry(MetricsRegistry):
     """A registry whose instruments discard everything — attach it to
-    measure the cost of instrumentation itself (the bench's metrics-off
-    arm), or to silence a hot path without if-guards at every site."""
+    measure the cost of instrumentation itself (the same run with the
+    instruments off), or to silence a hot path without if-guards."""
 
     def __init__(self):
         super().__init__(event_log=None)

@@ -61,8 +61,8 @@ from horovod_tpu.timeline import trace_annotation
 #: Top-level phases in ``step()`` order.  They TILE the tick — each is
 #: measured boundary-to-boundary, so their sum equals the tick wall time.
 #: Every engine produces exactly these (the three of the decode tick
-#: only on steps in which a row decodes); schema consumers (replay, the
-#: bench arm) may rely on their presence in ``report()``.
+#: only on steps in which a row decodes); schema consumers (replay)
+#: may rely on their presence in ``report()``.
 PHASES = ("expire", "admit", "decode_dispatch", "device_sync",
           "sample_postprocess", "bookkeeping")
 

@@ -991,8 +991,8 @@ class RouterServer:
     bare engines.  The HTTP server binds at construction (``port=0``
     picks an ephemeral port — read ``.port``) and serves after
     :meth:`start`; the programmatic surface (:meth:`route` /
-    :meth:`result`) works without ever starting HTTP, which is how the
-    bench arm and most tests drive it.
+    :meth:`result`) works without ever starting HTTP, which is how
+    most tests drive it.
 
     Thread model: handler threads call :meth:`route`/:meth:`result`,
     replica pump/POST threads call the completion callbacks, one
@@ -2009,7 +2009,7 @@ class RouterServer:
 
     def poll_now(self) -> None:
         """One synchronous poll pass (the poller thread's body; tests
-        and the bench call it directly for deterministic views).
+        call it directly for deterministic views).
 
         Death is debounced for revivable replicas: an HTTP replica
         needs ``probe_fails`` CONSECUTIVE failed probes before it
@@ -2273,101 +2273,3 @@ def maybe_start_router(replicas: Sequence[Any],
         warnings.warn(f"router port {port} unavailable ({e}); "
                       "router disabled", RuntimeWarning, stacklevel=2)
         return None
-
-
-# ---------------------------------------------------------------------------
-# Bench arm: affinity routing vs round robin over an in-process fleet.
-# ---------------------------------------------------------------------------
-
-
-def measure_router_fleet(
-    params: dict, cfg: Any, *,
-    n_replicas: int = 3, n_groups: int = 4, waves: int = 6,
-    prefix_blocks: int = 4, suffix_len: int = 4,
-    max_new_tokens: int = 8, n_slots: int = 4,
-    chunk: int = 16, max_len: int | None = None,
-    policies: Sequence[str] = ("round_robin", "prefix_affinity"),
-) -> dict:
-    """Fleet prefix hit rate and throughput, affinity vs round robin
-    (the ``serve_router_*`` bench metrics).
-
-    The workload is ``n_groups`` families sharing a
-    ``prefix_blocks * chunk``-token prefix, submitted in ``waves``
-    rounds of one request per family (each wave waits for the previous
-    — the steady drip of a production prompt family, and it makes hit
-    accounting deterministic).  Keep ``n_groups`` non-multiple of
-    ``n_replicas``: with ``G == R`` round robin aligns each family to
-    one replica by accident and the contrast vanishes.  Each policy serves the identical
-    workload on a fresh ``n_replicas``-engine fleet whose programs are
-    pre-compiled by an untimed disjoint warmup, so the timed passes
-    compare *routing* — affinity concentrates each family on one
-    replica (first wave misses, the rest hit); round robin smears it
-    across the fleet (one cold miss per replica per family).  Outputs
-    are asserted token-identical across policies (routing must never
-    change tokens).  Returns per-policy
-    ``serve_router_hit_rate_<policy>`` /
-    ``serve_router_tokens_per_sec_<policy>`` plus the affinity-minus-
-    round-robin ``serve_router_hit_rate_gain`` and workload shape."""
-    from horovod_tpu.serving_scheduler import ServeEngine
-
-    prefix_len = prefix_blocks * chunk
-    if max_len is None:
-        need = prefix_len + suffix_len + max_new_tokens + chunk
-        max_len = -(-need // chunk) * chunk     # block-aligned
-    workload: list[Request] = []
-    for w in range(waves):
-        for g in range(n_groups):
-            prefix = [(11 + 13 * g + i) % 89 + 2
-                      for i in range(prefix_len)]
-            suffix = [(29 + 7 * g + 3 * w + i) % 89 + 2
-                      for i in range(suffix_len)]
-            workload.append(Request(prompt=prefix + suffix,
-                                    max_new_tokens=max_new_tokens))
-
-    out: dict[str, Any] = {
-        "serve_router_replicas": n_replicas,
-        "serve_router_groups": n_groups,
-        "serve_router_waves": waves,
-        "n_requests": len(workload),
-        "chunk": chunk,
-        "n_slots": n_slots,
-    }
-    outputs: dict[str, list[list[int]]] = {}
-    for policy in policies:
-        engines = [ServeEngine(params, cfg, n_slots=n_slots,
-                               max_len=max_len, chunk=chunk,
-                               prefix_cache=True)
-                   for _ in range(n_replicas)]
-        # Untimed warmup: compile every program with a token family the
-        # workload never shares a first chunk with, so the timed hit
-        # counters start from a cold radix for the measured prompts.
-        for eng in engines:
-            warm = eng.run([Request(prompt=[1] * (chunk + 1),
-                                    max_new_tokens=2)])
-            assert all(r.ok for r in warm)
-        router = RouterServer(engines, policy=policy)
-        try:
-            hits0 = sum(e.prefix_counters["hits"] for e in engines)
-            toks: list[list[int]] = []
-            t0 = time.perf_counter()
-            for w in range(waves):
-                wave = workload[w * n_groups:(w + 1) * n_groups]
-                rids = [router.route(r) for r in wave]
-                toks.extend(list(router.result(rid)) for rid in rids)
-            dt = time.perf_counter() - t0
-            hits = sum(e.prefix_counters["hits"] for e in engines) - hits0
-            n_tokens = sum(len(t) for t in toks)
-            outputs[policy] = toks
-            out[f"serve_router_hit_rate_{policy}"] = hits / len(workload)
-            out[f"serve_router_tokens_per_sec_{policy}"] = n_tokens / dt
-        finally:
-            router.stop()
-    first = next(iter(outputs))
-    for policy, toks in outputs.items():
-        assert toks == outputs[first], \
-            f"routing changed tokens: {first} vs {policy}"
-    if "round_robin" in outputs and "prefix_affinity" in outputs:
-        out["serve_router_hit_rate_gain"] = (
-            out["serve_router_hit_rate_prefix_affinity"]
-            - out["serve_router_hit_rate_round_robin"])
-    return out

@@ -128,7 +128,6 @@ import dataclasses
 import json
 import os
 import sys
-import threading
 import time
 from functools import partial
 from typing import Any
@@ -149,7 +148,6 @@ from horovod_tpu import scheduling as scheduling_mod
 from horovod_tpu import timeseries as timeseries_mod
 from horovod_tpu import tracing as tracing_mod
 from horovod_tpu.metrics import Trace
-from horovod_tpu.models import llama
 from horovod_tpu.models.llama import BlockPool
 from horovod_tpu.models.paged import paged_model
 from horovod_tpu.parallel.mesh import tensor_parallel_mesh
@@ -1932,485 +1930,3 @@ class ServeEngine:
         while self.pending():
             self.step()
         return [self.results[i] for i in ids]
-
-
-# ---------------------------------------------------------------------------
-# Throughput measurement (the serve_tokens_per_sec bench metric).
-# ---------------------------------------------------------------------------
-
-
-def measure_throughput(
-    params: dict, cfg: llama.LlamaConfig, requests: list[Request], *,
-    n_slots: int, max_len: int, chunk: int,
-    block_size: int | None = None, n_blocks: int | None = None,
-    preempt_after: int | None = None,
-) -> dict:
-    """Continuous-batching vs fixed-batch throughput on one workload.
-
-    The engine serves the queue with slot recycling; the static baseline
-    is plain :func:`llama.generate` over fixed batches of ``n_slots`` in
-    submission order — every batch decodes until its LONGEST budget is
-    spent and prompts pad to the global maximum (the costs continuous
-    batching exists to remove).  Both paths are warmed (compiled) before
-    timing; only true emitted tokens count, for both.  Returns
-    ``serve_tokens_per_sec``, ``static_tokens_per_sec``,
-    ``serve_vs_static_ratio``, ``preemptions`` (timed pass only; nonzero
-    only with ``preempt_after`` on an overcommitted ``n_blocks`` pool),
-    latency percentiles from the metrics-on pass
-    (``serve_ttft_p50_ms`` .. ``serve_e2e_p99_ms``),
-    ``serve_metrics_overhead_pct`` (instrumented vs null-registry pass —
-    the acceptance bound for the observability layer is < 2 %),
-    ``monitor_overhead_pct`` (exporter on and scraped at ~100 Hz),
-    ``serve_profiler_overhead_pct`` (phase profiler on — bound < 3 %)
-    ``serve_health_overhead_pct`` (time-series sampler + alert
-    evaluation in the step loop at 20 Hz — acceptance keeps it within
-    2 % of the monitor baseline) and ``serve_trace_overhead_pct``
-    (causal span plane at 100 % head sampling vs the None-check
-    disabled plane — prices the worst case; disabled is near-free by
-    construction) and ``device_telemetry_overhead_pct`` (device
-    telemetry plane ON: cost-model dispatch stamping, sync split, and
-    per-step gauge refresh — bound < 5 %; its leg also yields
-    ``serve_mfu`` — honest ``None`` when no peak is known, i.e. every
-    CPU rehearsal — ``serve_model_flops_per_token``,
-    ``serve_device_flops_per_s`` and ``serve_overlap_headroom_pct``) —
-    all min-of-2 passes against an adjacent min-of-2 metrics-on base,
-    so inter-pass drift doesn't masquerade as overhead — with
-    ``serve_phase_pct`` / ``serve_phase_mean_ms`` per-phase breakdowns,
-    ``serve_goodput``
-    (windowed SLO goodput after the timed passes) and workload shape
-    fields.
-    """
-    if not requests:
-        raise ValueError("empty workload")
-
-    eng = ServeEngine(params, cfg, n_slots=n_slots, max_len=max_len,
-                      chunk=chunk, block_size=block_size,
-                      n_blocks=n_blocks, preempt_after=preempt_after,
-                      metrics=metrics_mod.NULL)
-    warm = eng.run(requests)                 # compiles every program
-    assert all(r.ok for r in warm), [r.status for r in warm]
-    n_tokens = sum(len(t) for t in warm)
-    # timed pass reuses the SAME engine (its jit programs are
-    # per-instance): after run() every slot is free, so the pool is in
-    # its admission-ready state again.  Metrics ON is the shipping
-    # configuration, so it is the primary number; a second pass with
-    # the null registry prices the instrumentation itself.
-    reg = metrics_mod.MetricsRegistry(event_log=None)
-    eng.metrics = reg
-    preempt0 = eng.counters["preemptions"]
-
-    def _timed_pass() -> float:
-        t0 = time.perf_counter()
-        out = eng.run(requests)
-        jax.block_until_ready(eng.pcache.k)
-        dt = time.perf_counter() - t0
-        assert [len(t) for t in out] == [len(t) for t in warm]
-        return dt
-
-    t_serve = _timed_pass()
-    preemptions = eng.counters["preemptions"] - preempt0
-    eng.metrics = metrics_mod.NULL
-    t_serve_off = _timed_pass()
-    hist = {name: reg.histogram(name)
-            for name in ("serve.ttft_s", "serve.tpot_s",
-                         "serve.queue_wait_s", "serve.e2e_s")}
-
-    # Overhead arms.  A single pass A/B'd against a single earlier pass
-    # is noise-dominated at small shapes (allocator/scheduler drift
-    # between passes exceeds the effect being priced), so each arm runs
-    # INTERLEAVED with a fresh metrics-on base — base, arm, base, arm —
-    # and both sides take their min (the standard drift-robust
-    # estimator); the overheads are deltas between those mins.
-    mon_reg = metrics_mod.MetricsRegistry(event_log=None)
-    mon = monitor_mod.MonitorServer(mon_reg, eng, port=0).start()
-    scraping_on = threading.Event()
-    stop_scraping = threading.Event()
-
-    def _scrape_loop() -> None:
-        import urllib.request
-        url = f"http://{mon.host}:{mon.port}/metrics"
-        while not stop_scraping.is_set():
-            if scraping_on.is_set():
-                try:
-                    urllib.request.urlopen(url, timeout=1).read()
-                except OSError:
-                    pass
-                stop_scraping.wait(0.01)
-            else:
-                stop_scraping.wait(0.001)
-
-    scraper = threading.Thread(target=_scrape_loop, daemon=True)
-    scraper.start()
-    preg = metrics_mod.MetricsRegistry(event_log=None)
-    prof = profiler_mod.TickProfiler(preg)
-    # every other leg runs with the spans alone (profiling off)
-    spans = eng.prof = profiler_mod.PhaseSpans()
-    hreg = metrics_mod.MetricsRegistry(event_log=None)
-    # 20 Hz sampling is 20x the shipping default — the health arm
-    # prices a deliberately aggressive cadence.
-    hsampler = timeseries_mod.MetricsSampler(hreg, sample_s=0.05)
-    halerts = alerts_mod.AlertManager(hsampler, registry=hreg)
-    treg = metrics_mod.MetricsRegistry(event_log=None)
-    ttracer = tracing_mod.Tracer(treg)
-    dreg = metrics_mod.MetricsRegistry(event_log=None)
-    dtel = device_telemetry_mod.DeviceTelemetry(dreg, n_devices=eng.tp_size)
-    # Cost-model capture (AOT compiles) happens OUTSIDE the timed
-    # passes — it is a construction-time cost in the shipping config
-    # too, not a per-tick one.
-    eng._device_capture_programs(dtel)
-    orig_tracer, orig_fraction = eng.tracer, eng._trace_fraction
-    t_base = t_serve_mon = t_serve_prof = float("inf")
-    t_serve_health = t_serve_trace = t_serve_dev = float("inf")
-    try:
-        for _ in range(2):
-            # base leg: metrics on, no exporter scrape, no profiler
-            eng.metrics = metrics_mod.MetricsRegistry(event_log=None)
-            t_base = min(t_base, _timed_pass())
-            # monitor leg: exporter ON and actively scraped — a sidecar
-            # polling /metrics while the engine serves prices the
-            # monitor itself (lock contention + render cost).
-            eng.metrics = mon_reg
-            scraping_on.set()
-            t_serve_mon = min(t_serve_mon, _timed_pass())
-            scraping_on.clear()
-            # profiler leg: per-tick phase timing ON (acceptance bound
-            # < 3 %); its report also says where tick time goes (the
-            # BENCH_r06+ breakdown).
-            eng.metrics = preg
-            eng.prof = prof
-            t_serve_prof = min(t_serve_prof, _timed_pass())
-            eng.prof = spans
-            # health leg: time-series sampler + alert evaluation ON in
-            # the step loop (acceptance: within 2 % of the monitor
-            # baseline).
-            eng.metrics = hreg
-            eng.sampler = hsampler
-            eng.alerts = halerts
-            t_serve_health = min(t_serve_health, _timed_pass())
-            eng.sampler = None
-            eng.alerts = None
-            # trace leg: causal span plane ON at 100 % head sampling —
-            # every request opens, closes, and tiles its span set.
-            # This prices the worst case; the disabled plane is one
-            # None-check per request by construction.
-            eng.metrics = treg
-            eng.tracer = ttracer
-            eng._trace_fraction = 1.0
-            t_serve_trace = min(t_serve_trace, _timed_pass())
-            eng._trace_fraction = orig_fraction
-            # device leg: cost-model dispatch stamping + sync split +
-            # per-step gauge refresh ON (acceptance bound < 5 %).
-            eng.metrics = dreg
-            eng.device = dtel
-            dev_flops0 = dtel.total_flops
-            t_serve_dev = min(t_serve_dev, _timed_pass())
-            dev_pass_flops = dtel.total_flops - dev_flops0
-            eng.device = None
-    finally:
-        eng.prof = spans
-        eng.sampler = None
-        eng.alerts = None
-        eng.device = None
-        eng.tracer = orig_tracer
-        eng._trace_fraction = orig_fraction
-        stop_scraping.set()
-        scraper.join(timeout=5)
-        mon.stop()
-    prof_report = prof.report()
-    dev_report = dtel.report()
-
-    # static baseline: batches of n_slots, one compiled generate per
-    # distinct batch budget (compiles excluded by per-batch warmup)
-    pad_w = max(len(r.prompt) for r in requests)
-    batches = []
-    for i in range(0, len(requests), n_slots):
-        group = requests[i:i + n_slots]
-        while len(group) < n_slots:          # pad rows don't count below
-            group.append(group[0])
-        toks = np.zeros((n_slots, pad_w), np.int32)
-        lens = np.zeros((n_slots,), np.int32)
-        for j, r in enumerate(group):
-            toks[j, :len(r.prompt)] = r.prompt
-            lens[j] = len(r.prompt)
-        mn = max(r.max_new_tokens for r in group)
-        batches.append((jnp.asarray(toks), jnp.asarray(lens), mn))
-    gen_cache: dict[int, Any] = {}
-    for _, _, mn in batches:
-        if mn not in gen_cache:
-            # hvdlint: disable=HVD001 -- bench baseline, one program per token budget
-            gen_cache[mn] = jax.jit(partial(
-                llama.generate, cfg=cfg, max_new_tokens=mn,
-                max_len=max_len))
-    for toks, lens, mn in batches:           # warm every batch shape
-        jax.block_until_ready(
-            gen_cache[mn](params, toks, prompt_lengths=lens))
-    t0 = time.perf_counter()
-    outs = [gen_cache[mn](params, toks, prompt_lengths=lens)
-            for toks, lens, mn in batches]
-    jax.block_until_ready(outs)
-    t_static = time.perf_counter() - t0
-
-    return {
-        "serve_tokens_per_sec": n_tokens / t_serve,
-        "static_tokens_per_sec": n_tokens / t_static,
-        "serve_vs_static_ratio": t_static / t_serve,
-        "preemptions": preemptions,
-        "serve_ttft_p50_ms": hist["serve.ttft_s"].percentile(0.5) * 1e3,
-        "serve_ttft_p99_ms": hist["serve.ttft_s"].percentile(0.99) * 1e3,
-        "serve_tpot_p50_ms": hist["serve.tpot_s"].percentile(0.5) * 1e3,
-        "serve_queue_wait_p99_ms":
-            hist["serve.queue_wait_s"].percentile(0.99) * 1e3,
-        "serve_e2e_p99_ms": hist["serve.e2e_s"].percentile(0.99) * 1e3,
-        "serve_metrics_overhead_pct":
-            (t_serve - t_serve_off) / t_serve_off * 100.0,
-        "monitor_overhead_pct":
-            (t_serve_mon - t_base) / t_base * 100.0,
-        "serve_profiler_overhead_pct":
-            (t_serve_prof - t_base) / t_base * 100.0,
-        "serve_health_overhead_pct":
-            (t_serve_health - t_base) / t_base * 100.0,
-        "serve_trace_overhead_pct":
-            (t_serve_trace - t_base) / t_base * 100.0,
-        "device_telemetry_overhead_pct":
-            (t_serve_dev - t_base) / t_base * 100.0,
-        # honest MFU: None on platforms with no known peak (every CPU
-        # rehearsal) — consumers must not coerce it to 0.
-        "serve_mfu": dev_report["win"]["mfu"],
-        "serve_model_flops_per_token": dev_pass_flops / n_tokens,
-        "serve_device_flops_per_s": dev_report["win"]["flops_per_s"],
-        "serve_overlap_headroom_pct":
-            dev_report["win"]["overlap_headroom_pct"],
-        "device_peak_flops_known": dev_report["peak_flops_known"],
-        "serve_phase_pct": {
-            p: prof_report["phases"][p]["pct_of_tick"]
-            for p in profiler_mod.PHASES},
-        "serve_phase_mean_ms": {
-            p: prof_report["phases"][p]["mean_s"] * 1e3
-            for p in profiler_mod.PHASES},
-        "serve_goodput": eng.slo.goodput(),
-        "tokens": n_tokens,
-        "n_requests": len(requests),
-        "n_slots": n_slots,
-        "max_len": max_len,
-        "chunk": chunk,
-    }
-
-
-def measure_prefix_throughput(
-    params: dict, cfg: llama.LlamaConfig, requests: list[Request], *,
-    n_slots: int, max_len: int, chunk: int,
-    block_size: int | None = None, n_blocks: int | None = None,
-) -> dict:
-    """Prefix-cache-on vs cache-off throughput on one workload (the
-    ``serve_prefix_*`` bench metrics).
-
-    Both engines serve the same queue; the cache-on engine is warmed by
-    a full untimed pass (compiles every program AND populates the radix
-    index — the steady state of a server that has seen its system
-    prompt before), mirrored by an untimed cache-off warmup, so the
-    timed passes compare prefill-skipping against recompute on equal
-    footing.  Outputs are asserted token-identical between the two
-    engines (the parity guarantee).  Returns
-    ``serve_prefix_tokens_per_sec`` (cache on),
-    ``serve_prefix_off_tokens_per_sec``, ``serve_prefix_speedup``,
-    ``serve_prefix_hit_rate`` (admissions with >= 1 reused block over
-    all admissions, timed pass), ``serve_prefix_tokens_skipped`` and
-    workload shape fields.
-    """
-    if not requests:
-        raise ValueError("empty workload")
-    kw = dict(n_slots=n_slots, max_len=max_len, chunk=chunk,
-              block_size=block_size, n_blocks=n_blocks)
-    timings: dict[bool, float] = {}
-    outputs: dict[bool, list[RequestResult]] = {}
-    hit_rate = 0.0
-    tokens_skipped = 0
-    n_tokens = 0
-    for cache_on in (False, True):
-        eng = ServeEngine(params, cfg, prefix_cache=cache_on, **kw)
-        warm = eng.run(requests)
-        assert all(r.ok for r in warm), [r.status for r in warm]
-        n_tokens = sum(len(t) for t in warm)
-        hits0 = eng.prefix_counters["hits"]
-        skip0 = eng.prefix_counters["tokens_skipped"]
-        t0 = time.perf_counter()
-        out = eng.run(requests)
-        jax.block_until_ready(eng.pcache.k)
-        timings[cache_on] = time.perf_counter() - t0
-        outputs[cache_on] = out
-        if cache_on:
-            hit_rate = ((eng.prefix_counters["hits"] - hits0)
-                        / len(requests))
-            tokens_skipped = (eng.prefix_counters["tokens_skipped"]
-                              - skip0)
-    assert [list(a) for a in outputs[True]] == \
-        [list(b) for b in outputs[False]], "prefix-cache parity broken"
-    return {
-        "serve_prefix_tokens_per_sec": n_tokens / timings[True],
-        "serve_prefix_off_tokens_per_sec": n_tokens / timings[False],
-        "serve_prefix_speedup": timings[False] / timings[True],
-        "serve_prefix_hit_rate": hit_rate,
-        "serve_prefix_tokens_skipped": tokens_skipped,
-        "tokens": n_tokens,
-        "n_requests": len(requests),
-        "n_slots": n_slots,
-        "max_len": max_len,
-        "chunk": chunk,
-    }
-
-
-def measure_spec_throughput(
-    params: dict, cfg: llama.LlamaConfig, requests: list[Request], *,
-    n_slots: int, max_len: int, chunk: int,
-    block_size: int | None = None, n_blocks: int | None = None,
-    draft_k: int = 4,
-) -> dict:
-    """Speculation-on vs plain-decode throughput on one workload (the
-    ``serve_spec_*`` bench metrics).
-
-    Both engines serve the same queue; each is warmed by a full untimed
-    pass (compiles every program — the spec engine's always-wide
-    ``spec_tick`` included), then timed on a second pass.  Both emit the
-    same number of tokens, so the ratio prices scheduling;
-    ``serve_spec_diverged_requests`` counts the requests whose tokens
-    differ between the two engines (0 in float32, by the greedy
-    bit-identity of :func:`llama.spec_verify_paged
-    <horovod_tpu.models.llama.spec_verify_paged>`).  Returns
-    ``serve_spec_tokens_per_sec`` (spec on),
-    ``serve_spec_plain_tokens_per_sec``, ``serve_spec_vs_plain_ratio``,
-    ``serve_spec_accepted_per_round`` (mean accepted drafts per
-    decoding row per verify round, timed pass),
-    ``serve_spec_rounds`` (timed-pass verify ticks), ``draft_k`` and
-    workload shape fields.  The ratio beats 1 exactly when acceptance
-    buys more rounds than the wider tick costs — lookup-friendly
-    (repetitive) workloads win, lookup-hostile (random) ones price the
-    overhead floor.
-    """
-    if not requests:
-        raise ValueError("empty workload")
-    kw = dict(n_slots=n_slots, max_len=max_len, chunk=chunk,
-              block_size=block_size, n_blocks=n_blocks,
-              metrics=metrics_mod.NULL)
-    timings: dict[bool, float] = {}
-    outputs: dict[bool, list[RequestResult]] = {}
-    n_tokens = 0
-    accepted_per_round = 0.0
-    rounds = 0
-    for spec_on in (False, True):
-        eng = ServeEngine(params, cfg, spec=spec_on, draft_k=draft_k,
-                          **kw)
-        warm = eng.run(requests)
-        assert all(r.ok for r in warm), [r.status for r in warm]
-        n_tokens = sum(len(t) for t in warm)
-        acc0 = eng.spec_counters["accepted"]
-        rr0 = eng.spec_counters["row_rounds"]
-        rounds0 = eng.spec_counters["rounds"]
-        t0 = time.perf_counter()
-        out = eng.run(requests)
-        jax.block_until_ready(eng.pcache.k)
-        timings[spec_on] = time.perf_counter() - t0
-        outputs[spec_on] = out
-        if spec_on:
-            rr = eng.spec_counters["row_rounds"] - rr0
-            accepted_per_round = (
-                (eng.spec_counters["accepted"] - acc0) / rr if rr
-                else 0.0)
-            rounds = eng.spec_counters["rounds"] - rounds0
-    # Greedy verification makes the two engines token-identical wherever
-    # they compute the same floats (float32: tests/test_spec_sched.py pins
-    # it).  In bf16 the 1-wide tick and the (draft_k + 1)-wide verify round
-    # differently and a near-tied argmax can go either way (on the chip, 10
-    # of 32 random-weight streams, PERF.md PR 21), so this is a count for
-    # the caller to judge, not an assertion.
-    diverged = sum(list(a) != list(b)
-                   for a, b in zip(outputs[True], outputs[False]))
-    return {
-        "serve_spec_diverged_requests": diverged,
-        "serve_spec_tokens_per_sec": n_tokens / timings[True],
-        "serve_spec_plain_tokens_per_sec": n_tokens / timings[False],
-        "serve_spec_vs_plain_ratio": timings[False] / timings[True],
-        "serve_spec_accepted_per_round": accepted_per_round,
-        "serve_spec_rounds": rounds,
-        "draft_k": draft_k,
-        "tokens": n_tokens,
-        "n_requests": len(requests),
-        "n_slots": n_slots,
-        "max_len": max_len,
-        "chunk": chunk,
-    }
-
-
-def measure_tp_throughput(
-    params: dict, cfg: llama.LlamaConfig, requests: list[Request], *,
-    n_slots: int, max_len: int, chunk: int,
-    block_size: int | None = None, n_blocks: int | None = None,
-    tp_sizes: tuple[int, ...] = (1, 2, 4),
-    prefix_cache: bool = False,
-    spec: bool | None = None,
-) -> dict:
-    """Tensor-parallel throughput sweep on one workload (the
-    ``serve_tp_*`` bench metrics).
-
-    One engine per ``tp_size``, each warmed by a full untimed pass
-    (compiles every sharded program) and timed on a second pass over
-    the same queue.  Outputs are asserted token-identical across every
-    tp size (the sharded-parity guarantee), so the ratios price pure
-    mesh mechanics.  Returns per-tp ``serve_tp{N}_tokens_per_sec`` and
-    ``serve_tp{N}_scaling_eff`` — tokens/s relative to tp=1 divided by
-    N, the per-chip scaling efficiency (1.0 = linear; on a faked-CPU
-    rehearsal this prices collective overhead only, real ICI numbers
-    come from a TPU window) — plus ``serve_tp_sizes`` actually run and
-    workload shape fields.  tp entries whose size exceeds the device
-    count (or does not divide the head/ffn/vocab axes) are skipped and
-    listed under ``serve_tp_skipped``.
-    """
-    if not requests:
-        raise ValueError("empty workload")
-    kw = dict(n_slots=n_slots, max_len=max_len, chunk=chunk,
-              block_size=block_size, n_blocks=n_blocks,
-              prefix_cache=prefix_cache, spec=spec,
-              metrics=metrics_mod.NULL)
-    timings: dict[int, float] = {}
-    outputs: dict[int, list[RequestResult]] = {}
-    skipped: list[int] = []
-    n_tokens = 0
-    for tp in tp_sizes:
-        if tp > jax.device_count() or any(
-                d % tp for d in (cfg.n_heads, cfg.n_kv_heads, cfg.dim,
-                                 cfg.ffn_dim, cfg.vocab_size)):
-            skipped.append(tp)
-            continue
-        eng = ServeEngine(params, cfg, tp_size=tp, **kw)
-        warm = eng.run(requests)
-        assert all(r.ok for r in warm), [r.status for r in warm]
-        n_tokens = sum(len(t) for t in warm)
-        t0 = time.perf_counter()
-        out = eng.run(requests)
-        jax.block_until_ready(eng.pcache.k)
-        timings[tp] = time.perf_counter() - t0
-        outputs[tp] = out
-    ran = sorted(timings)
-    if not ran:
-        raise ValueError(
-            f"no tp size in {tp_sizes} fits {jax.device_count()} "
-            f"devices and the model's sharded axes")
-    base = ran[0]
-    for tp in ran[1:]:
-        assert [list(a) for a in outputs[tp]] == \
-            [list(b) for b in outputs[base]], \
-            f"tensor-parallel parity broken at tp={tp}"
-    result: dict[str, Any] = {
-        "serve_tp_sizes": ran,
-        "serve_tp_skipped": skipped,
-        "tokens": n_tokens,
-        "n_requests": len(requests),
-        "n_slots": n_slots,
-        "max_len": max_len,
-        "chunk": chunk,
-    }
-    for tp in ran:
-        tps = n_tokens / timings[tp]
-        result[f"serve_tp{tp}_tokens_per_sec"] = tps
-        result[f"serve_tp{tp}_scaling_eff"] = (
-            tps / (n_tokens / timings[base])) / (tp / base)
-    return result

@@ -357,7 +357,7 @@ class SimFleet:
             shadow_max_bytes=shadow_max_bytes, clock=self.clock)
         if knee_rps is not None:
             # Demand-sized advisor over the same virtual clock: the
-            # knee a real bench would have written.
+            # knee a load report would have given.
             self.router.advisor = alerts_mod.CapacityAdvisor(
                 self.sampler, alerts=self.alerts,
                 registry=self.registry,
@@ -861,27 +861,3 @@ def run_sim_campaign(*, seed: "int | None" = None,
         return report
     finally:
         fleet.close()
-
-
-def measure_simfleet(*, seed: "int | None" = None,
-                     n_replicas: "int | None" = None,
-                     n_requests: "int | None" = None) -> dict:
-    """The ``serve_simfleet_*`` bench arm: one seeded campaign at the
-    configured scale, reporting throughput-in-virtual-time, goodput
-    retention, and the oracle verdict (the gate key)."""
-    report = run_sim_campaign(seed=seed, n_replicas=n_replicas,
-                              n_requests=n_requests)
-    return {
-        "serve_simfleet_seed": report["seed"],
-        "serve_simfleet_replicas": report["n_replicas"],
-        "serve_simfleet_requests": report["n_requests"],
-        "serve_simfleet_virtual_s": report["virtual_s"],
-        "serve_simfleet_wall_s": report["wall_s"],
-        "serve_simfleet_virtual_rps": (
-            report["n_requests"] / report["virtual_s"]
-            if report["virtual_s"] else 0.0),
-        "serve_simfleet_ok_fraction": report["ok_fraction"],
-        "serve_simfleet_failovers": report["failovers"],
-        "serve_simfleet_respawns": report["respawns"],
-        "serve_simfleet_oracles_ok": report["ok"],
-    }

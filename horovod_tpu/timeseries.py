@@ -519,8 +519,8 @@ def maybe_sampler(registry: metrics_mod.MetricsRegistry | None = None,
     """A sampler per the env contract: ``HVD_TPU_SAMPLE_S`` (default
     1.0) is the cadence, ``<= 0`` disables.  A
     :class:`~horovod_tpu.metrics.NullRegistry` gets no sampler —
-    there's nothing to remember (and the bench's null arm must not pay
-    for one)."""
+    there's nothing to remember (and an engine with instrumentation
+    off must not pay for one)."""
     if isinstance(registry, metrics_mod.NullRegistry):
         return None
     sample_s = env_float("HVD_TPU_SAMPLE_S", 1.0)

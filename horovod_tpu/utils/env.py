@@ -164,7 +164,7 @@ def compile_cache_dir(checkout: str) -> str:
     is set in code; otherwise the cache goes to the fixed
     ``<checkout>/.jax_cache``.  The path is part of the cache key, so it
     has no per-host, per-process or per-run component.  For entry scripts
-    (``chip_smoke.py``, ``bench.py``), before their first compilation.
+    (``chip_smoke.py``, ``benchmark/run.py``), before their first compilation.
     """
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if path:

@@ -514,12 +514,22 @@ def test_advisor_scales_up_sized_by_knee_demand():
     assert adv.report()["last"] == rec
 
 
-def test_advisor_scale_up_defaults_to_one_without_knee(tmp_path):
+@pytest.mark.parametrize("configured", ["missing_path", "nothing"])
+def test_advisor_scale_up_defaults_to_one_without_knee(
+        tmp_path, monkeypatch, configured):
+    # A report that merely lies in the working directory is not looked
+    # up: the knee is what the advisor was given, or nothing.
+    (tmp_path / "serve_load_report.json").write_text(
+        json.dumps({"serve_load_knee_goodput_rps": 4.0}))
+    monkeypatch.chdir(tmp_path)
     gauges = {i: {"serve.goodput": 0.5,
                   "router.replicas_healthy": 1.0,
                   "serve.queue_depth": 3.0 * i}
               for i in range(1, 7)}
-    _, adv = _advised(gauges, knee=str(tmp_path / "missing.json"))
+    _, adv = _advised(gauges, knee=(str(tmp_path / "missing.json")
+                                    if configured == "missing_path"
+                                    else None))
+    assert adv.load_knee() is None
     rec = adv.recommend()
     assert rec["action"] == "scale_up" and rec["n"] == 1
     assert rec["evidence"]["knee_goodput_rps"] is None

@@ -66,3 +66,32 @@ def test_server_toy_with_tp(smoke):
 
 def test_eager_toy(smoke):
     assert smoke.phase_eager()["first_call_s"] > 0
+
+
+def test_compile_cache_dir_env_set_leaves_config_alone(tmp_path, monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set jax reads it itself: the program
+    reports that directory and sets nothing in code."""
+    from horovod_tpu.utils.env import compile_cache_dir
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "outside"))
+    assert compile_cache_dir(str(tmp_path)) == str(tmp_path / "outside")
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_dir_default_is_checkout(tmp_path, monkeypatch):
+    """Unset: the fixed <checkout>/.jax_cache — the path is part of the
+    cache key, so nothing about the host, process or time goes into it."""
+    from horovod_tpu.utils.env import compile_cache_dir
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        want = os.path.join(str(tmp_path), ".jax_cache")
+        assert compile_cache_dir(str(tmp_path)) == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert compile_cache_dir(str(tmp_path)) == want     # and stays put
+    finally:
+        # The config is process-global: restore so later suite compiles
+        # don't write into this test's deleted tmp dir.
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -483,27 +483,6 @@ def test_compare_trips_on_mfu_regression(tmp_path):
     assert main(["--compare", str(po), str(pb)]) == 1
 
 
-def test_perf_gate_folds_device_as_seventh_gate(tmp_path):
-    import importlib.util
-    import os as _os
-    spec = importlib.util.spec_from_file_location(
-        "perf_gate", _os.path.join(
-            _os.path.dirname(_os.path.dirname(_os.path.abspath(
-                __file__))), "tools", "perf_gate.py"))
-    pg = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(pg)
-    assert "device" in pg.GATES and len(pg.GATES) == 7
-    po, pb = tmp_path / "old.json", tmp_path / "bad.json"
-    po.write_text(json.dumps(_report_with(1e12, 1e9)))
-    pb.write_text(json.dumps(_report_with(1e12, 0.5e9)))
-    ok = pg.run_gates({"device": (str(po), str(po))})
-    assert ok["ok"]
-    bad = pg.run_gates({"device": (str(po), str(pb))})
-    assert not bad["ok"]
-    assert bad["gates"][0]["gate"] == "device"
-    assert any("mfu" in p for p in bad["gates"][0]["problems"])
-
-
 # ---------------------------------------------------------------------------
 # Profiler nesting: the device_sync split rides the phase report.
 # ---------------------------------------------------------------------------

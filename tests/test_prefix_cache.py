@@ -28,9 +28,7 @@ from horovod_tpu.models import llama
 from horovod_tpu.models.llama import BlockPool
 from horovod_tpu.prefix_cache import RadixPrefixCache, chunk_path_digests
 from horovod_tpu.serving import FAILED, OK, Request
-from horovod_tpu.serving_scheduler import (
-    ServeEngine, measure_prefix_throughput,
-)
+from horovod_tpu.serving_scheduler import ServeEngine
 
 pytestmark = pytest.mark.prefix
 
@@ -398,19 +396,3 @@ def test_timeline_prefix_counters(world, tmp_path):
         "hits", "blocks_reused", "tokens_skipped", "evictions"}
     assert prefix_events[-1]["args"] == eng.prefix_counters
     assert prefix_events[-1]["args"]["hits"] > 0
-
-
-def test_measure_prefix_throughput_smoke(world):
-    """The bench arm's engine-side helper: hit rate > 0 on the warm
-    timed pass, internal cache-on/off parity assert holds, and every
-    ``serve_prefix_*`` metric is emitted."""
-    cfg, params = world
-    reqs = _shared_prefix_requests()
-    got = measure_prefix_throughput(
-        params, cfg, reqs, n_slots=2, max_len=24, chunk=4)
-    assert got["serve_prefix_hit_rate"] > 0
-    assert got["serve_prefix_tokens_skipped"] > 0
-    assert got["serve_prefix_tokens_per_sec"] > 0
-    assert got["serve_prefix_off_tokens_per_sec"] > 0
-    assert got["serve_prefix_speedup"] > 0
-    assert got["n_requests"] == len(reqs)

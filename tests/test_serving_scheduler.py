@@ -29,7 +29,7 @@ import pytest
 from horovod_tpu import timeline as timeline_mod
 from horovod_tpu.models import llama
 from horovod_tpu.serving import REJECTED, Request
-from horovod_tpu.serving_scheduler import ServeEngine, measure_throughput
+from horovod_tpu.serving_scheduler import ServeEngine
 
 
 @pytest.fixture(scope="module")
@@ -222,30 +222,6 @@ def test_submit_validation(world):
     with pytest.raises(ValueError, match="trash block"):
         ServeEngine(params, cfg, n_slots=1, max_len=16, chunk=4,
                     n_blocks=3)
-
-
-def test_serve_throughput_beats_static(world):
-    """The acceptance bar: a staggered workload (each fixed batch pins
-    one long-budget request, so static batching drains mostly-idle
-    rows) where slot recycling backfills immediately.  The model is
-    sized so per-tick compute dominates per-step dispatch on CPU."""
-    del world
-    cfg = llama.llama_tiny(
-        dim=256, n_layers=4, n_heads=8, n_kv_heads=4, ffn_dim=512,
-        vocab_size=512, max_seq_len=128, dtype=jnp.float32)
-    params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    reqs = []
-    for i in range(4):
-        reqs += [Request(prompt=list(range(1, 21 + i)),
-                         max_new_tokens=40),
-                 Request(prompt=[3, 5, 7], max_new_tokens=2),
-                 Request(prompt=[2, 4, 6, 8], max_new_tokens=2),
-                 Request(prompt=[9, 11, 13], max_new_tokens=2)]
-    m = measure_throughput(params, cfg, reqs, n_slots=4, max_len=72,
-                           chunk=8)
-    assert m["tokens"] == sum(r.max_new_tokens for r in reqs)
-    assert m["serve_tokens_per_sec"] > 0
-    assert m["serve_vs_static_ratio"] > 1.0, m
 
 
 @pytest.mark.slow

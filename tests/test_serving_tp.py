@@ -42,9 +42,7 @@ from horovod_tpu.parallel.mesh import (
 )
 from horovod_tpu.router import LocalReplica
 from horovod_tpu.serving import Request
-from horovod_tpu.serving_scheduler import (
-    ServeEngine, measure_tp_throughput,
-)
+from horovod_tpu.serving_scheduler import ServeEngine
 from horovod_tpu.supervisor import clone_engine
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -266,26 +264,6 @@ def test_sharded_engine_under_local_replica(world, tp_devices):
         assert rep.probe()["tp_size"] == 2
     finally:
         rep.stop()
-
-
-@pytest.mark.tp
-def test_measure_tp_throughput_smoke(world, tp_devices):
-    """The bench helper's contract: per-tp tokens/s + scaling
-    efficiency keys, parity asserted inside, oversized tp skipped."""
-    cfg, params = world
-    out = measure_tp_throughput(
-        params, cfg, _requests(), n_slots=2, max_len=32, chunk=4,
-        tp_sizes=(1, 2, 16))
-    assert out["serve_tp_sizes"] == [1, 2]
-    assert out["serve_tp_skipped"] == [16]
-    assert out["serve_tp1_tokens_per_sec"] > 0
-    assert out["serve_tp2_tokens_per_sec"] > 0
-    assert out["serve_tp1_scaling_eff"] == 1.0
-    assert out["serve_tp2_scaling_eff"] > 0
-    assert out["tokens"] == sum(r.max_new_tokens for r in _requests())
-
-
-# -- fresh-process worker: forced XLA_FLAGS + the HVD_TPU_TP env knob --------
 
 
 def test_tp_worker_subprocess(world):

@@ -448,47 +448,6 @@ def test_trace_report_cli_render_and_compare_gate(tmp_path, capsys):
         "serve.request", "serve.decode"}
 
 
-def test_perf_gate_folds_compares_into_one_verdict(tmp_path, capsys):
-    sys.path.insert(0, str(REPO_ROOT / "tools"))
-    import perf_gate
-    import trace_report
-    recs = _synthetic_spans("g", 0.2)
-    old = {k: v for k, v in trace_report.build_report(recs).items()
-           if k != "_forest"}
-    new = json.loads(json.dumps(old))
-    new["mean_critical_s"] = old["mean_critical_s"] * 3.0
-    ok_p = tmp_path / "ok.json"
-    bad_p = tmp_path / "bad.json"
-    ok_p.write_text(json.dumps(old))
-    bad_p.write_text(json.dumps(new))
-
-    verdict = perf_gate.run_gates({"trace": (str(ok_p), str(ok_p))})
-    assert verdict["ok"] and verdict["n_regressed"] == 0
-    assert perf_gate.main(["--trace", str(ok_p), str(ok_p)]) == 0
-    capsys.readouterr()
-    assert perf_gate.main(["--trace", str(ok_p), str(bad_p)]) == 1
-    out = capsys.readouterr().out
-    assert "FAIL  trace" in out and "REGRESSION:" in out
-    assert "perf gate: FAILED" in out
-
-    # a gate that cannot run must not pass: unreadable report counts
-    # as regressed instead of throwing out of the verdict
-    junk = tmp_path / "junk.json"
-    junk.write_text("not json {")
-    verdict = perf_gate.run_gates({"trace": (str(junk), str(ok_p)),
-                                   "load": (str(ok_p), str(ok_p))})
-    assert not verdict["ok"] and verdict["n_regressed"] == 2
-    by = {g["gate"]: g for g in verdict["gates"]}
-    assert not by["trace"]["ok"] and by["trace"]["problems"]
-    assert not by["load"]["ok"]         # a trace report is not a sweep
-    # CLI refuses to run with zero gates supplied
-    with pytest.raises(SystemExit):
-        perf_gate.main([])
-
-
-# -- loadgen: trace ids on records, exemplars at the knee --------------------
-
-
 def test_loadgen_stamps_trace_ids_and_rung_exemplars(world, monkeypatch):
     cfg, params = world
     monkeypatch.setenv("HVD_TPU_TRACE_SAMPLE", "1")

@@ -18,7 +18,7 @@ diffs the same as a live scrape); anything else is a saved report JSON
 — a prior ``--json`` dump, a raw ``/device`` body, or a full
 ``metrics_snapshot()`` (its ``"device"`` key is used).
 
-Regression gate (gate #7 in ``tools/perf_gate.py``):
+Regression gate:
 
     python tools/device_report.py --compare old.json new.json \\
         [--threshold 10]

@@ -1,7 +1,7 @@
 """Render and diff open-loop saturation-sweep reports in the terminal.
 
-``horovod_tpu.loadgen.measure_saturation`` (and the ``serve_load_*``
-bench arm) emits one JSON report per sweep: the offered-RPS ladder,
+``horovod_tpu.loadgen.measure_saturation`` emits one JSON report per
+sweep: the offered-RPS ladder,
 per-rung client-observed percentiles, SLO goodput, the goodput knee,
 and the per-phase end-to-end latency attribution.  This tool renders
 it:
@@ -42,7 +42,7 @@ COVERAGE_BAR = 0.95
 
 def load_report(source: str) -> dict:
     """A saved sweep report JSON: a ``measure_saturation`` return
-    value, or a bench extras dump carrying one under ``serve_load``."""
+    value, or a dump carrying one under ``serve_load``."""
     with open(source) as f:
         data = json.load(f)
     if "rungs" in data:

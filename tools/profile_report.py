@@ -14,8 +14,7 @@ window is still renderable); anything else is a saved report JSON — a
 prior ``--json`` dump, a raw ``/profile`` body, or a full
 ``metrics_snapshot()`` (its ``"profile"`` key is used).
 
-Regression gate (the per-phase complement to the bench trajectory's
-whole-run numbers):
+Regression gate (per phase):
 
     python tools/profile_report.py --compare old.json new.json \\
         [--threshold 10] [--floor-ms 0.05]
