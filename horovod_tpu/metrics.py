@@ -234,6 +234,8 @@ METRIC_HELP: dict[str, str] = {
     "moe.experts_touched": "Held experts (summed over layers) the last decode tick computed",
     "dsa.keys_visible": "Cached keys the sparse indexer scored (summed over queries and full layers)",
     "dsa.keys_selected": "Keys the indexer's exact top-k kept for attention",
+    "dsa.queries": "Queries of the full layers (per dispatched tick or chunk: rows x tokens a row x full layers)",
+    "dsa.mask_queries": "Queries whose selection was kept as a mask over key tiles and not sorted into a list (the programs latent_moe.mask_reach sends that way)",
     "attn.blocks_visited": "Block-table entries paged attention read (per dispatched tick or chunk: rows x blocks up to the longest live row)",
     "attn.blocks_in_table": "Block-table entries of the rows of every dispatched tick or chunk (rows x blocks a table holds)",
     # mem.* — host-side observability footprint (approximate)

@@ -23,9 +23,18 @@ layer input:
   ``I[t, s] = sum_j w[t, j] ReLU(q_I[t, j] . k_I[s])`` with ``q_I = c_q W_Iq``
   (``index_heads`` heads), ``k_I = LayerNorm(h W_Ik)`` and
   ``w = h W_Iw / sqrt(index_heads * index_dim)``, and attention runs over the
-  **exact** ``index_topk`` largest ``s <= t`` (``lax.top_k``, never an
-  approximate one).  A sigmoid gate per head, ``sigmoid(h W_g)``, scales the
-  heads' outputs before ``W_o``.
+  **exact** ``index_topk`` largest ``s <= t``, never an approximation of
+  them.  The set is one; it is held in two forms.  A program that has few
+  queries a row (the tick, the verify round) sorts (``lax.top_k``) and
+  gathers the selected latents as a *list*.  A program whose queries share a
+  row's keys many times over (the chunk of prefill: ``T * index_topk``
+  listed rows against the ``m`` its table holds) finds each query's k-th
+  largest score by counting, keeps the selection as a *mask*, and scores
+  every visible key a tile at a time along the block table with a running
+  softmax, while the visible context is short enough for that to be the
+  cheaper (:func:`mask_reach`, ``MASK_REACH_TOPKS``); beyond it the chunk
+  takes the list too.  A sigmoid gate per head, ``sigmoid(h W_g)``, scales
+  the heads' outputs before ``W_o``.
 * *window*: the same latent attention with the ``w_*`` sizes, no indexer, over
   the query's own position and the ``window - 1`` before it.
 * *experts*: ``s = sigmoid(h W_r)`` over all ``n_experts``; the ``top_k``
@@ -83,11 +92,20 @@ CHOICES_TOTAL, CHOICES_HELD, KEYS_VISIBLE, KEYS_SELECTED, TOUCHED, LOAD0 = \
 _LO_BITS = 24                 # a running sum is hi * 2**24 + lo, both int32
 LANES = 128
 #: how much of a long computation one loop step takes: cached keys the
-#: indexer scores at once, queries whose selected latents are gathered at
-#: once, and the rows of one expert's tile in a tick and in a chunk
+#: indexer scores at once, queries that attend at once (their selected
+#: latents gathered, or a tile of keys scored for them), and the rows of one
+#: expert's tile in a tick and in a chunk
 INDEX_STEP_KEYS = 2048
 QUERY_BLOCK = 128
 TILE_ROWS = (8, 128)
+#: the selection is kept as a mask over key tiles (and never made into a
+#: list) while a program's last query sees no more than this many times
+#: ``index_topk`` keys; beyond it scoring every visible key costs more than
+#: the sort and the gather it spares (:func:`mask_reach`).  Measured on one
+#: v5e at dots3's widths (PERF.md, PR 30): a 512-token chunk breaks even at
+#: 25 k of context, and key tiles of one 512-position block are fastest.
+MASK_REACH_TOPKS = 12
+MASK_KEY_TILE = 512
 
 
 def _lanes(n: int) -> int:
@@ -354,7 +372,11 @@ def publish_paged_metrics(metrics, cfg: LatentMoEConfig,
     ``stats_host`` and ``programs`` (at construction) the per-pool sizes; with
     ``stats_host`` (after a tick's readback) the counters, and from
     ``row_blocks`` (blocks mapped by each live row) what a window-sized pool
-    would free."""
+    would free.  ``programs`` holds ``(rows, tokens a row, longest row's
+    length)`` of each program a step dispatched: ``dsa.queries`` counts their
+    queries times the full layers and ``dsa.mask_queries`` those of the
+    programs that kept the selection as a mask, by :func:`mask_reach`, the
+    function the programs' own branch comes from; nothing is read back."""
     if stats_host is None and not programs:     # once, at construction
         per_block = paged_pool_bytes(pcache)
         metrics.gauge("kv.latent_block_bytes").set(per_block["latent"])
@@ -362,8 +384,16 @@ def publish_paged_metrics(metrics, cfg: LatentMoEConfig,
         metrics.gauge("kv.window_block_bytes").set(per_block["window"])
         metrics.gauge("kv.window_bytes_beyond_window").set(0)
         for name in ("moe.choices_total", "moe.choices_held",
-                     "dsa.keys_visible", "dsa.keys_selected"):
+                     "dsa.keys_visible", "dsa.keys_selected",
+                     "dsa.queries", "dsa.mask_queries"):
             metrics.counter(name)
+    m = pcache.logical_len
+    k = min(cfg.index_topk, m)
+    metrics.counter("dsa.queries").inc(cfg.n_of(FULL) * sum(
+        rows * t for rows, t, _ in programs))
+    metrics.counter("dsa.mask_queries").inc(cfg.n_of(FULL) * sum(
+        rows * t for rows, t, longest in programs
+        if longest + t <= mask_reach(t, m, k)))
     if stats_host is None:          # nothing was read back: no tick ran
         return
     keep = -(-(cfg.window - 1) // pcache.block_size) + 1
@@ -449,12 +479,20 @@ def _phys(table: jax.Array, pos: jax.Array, bs: int) -> jax.Array:
     return blk * bs + pos % bs
 
 
-def _index_select(cfg: LatentMoEConfig, lp: dict, h, c_q, qpos, index_flat,
+def _blocks_a_step(keys: int, bs: int, per: int) -> int:
+    """Blocks of a row's table that one loop step takes: about ``keys``
+    positions, at least one block, and a divisor of the table."""
+    group = max(min(keys // bs, per), 1)
+    while per % group:
+        group -= 1
+    return group
+
+
+def _index_scores(cfg: LatentMoEConfig, lp: dict, h, c_q, qpos, index_flat,
                   off, table, bs: int):
-    """The indexer: scores over every cached key, a group of blocks at a time
-    and no further than the longest row reaches, then the exact top-k.
-    Returns the selected logical positions [B, T, k] and which of them are
-    real (fewer than k keys are visible early in a sequence)."""
+    """The indexer's scores [B, T, m] (float32) over every cached key, a
+    group of blocks at a time and no further than the longest row reaches;
+    a key the query does not see, or that no step reached, reads ``-inf``."""
     dt = cfg.dtype
     b, t, _ = h.shape
     per = table.shape[1]
@@ -467,9 +505,7 @@ def _index_select(cfg: LatentMoEConfig, lp: dict, h, c_q, qpos, index_flat,
         q_i_pad = _pad_last(q_i, index_flat.shape[-1] - idim)
         wgt = (_dot(h, lp["w_iw"], dt).astype(jnp.float32)
                * (ih ** -0.5 * idim ** -0.5))
-        group = max(min(INDEX_STEP_KEYS // bs, per), 1)
-        while per % group:
-            group -= 1
+        group = _blocks_a_step(INDEX_STEP_KEYS, bs, per)
         kb = group * bs
         n_groups = jnp.minimum((jnp.max(qpos) + kb) // kb, per // group)
 
@@ -486,9 +522,19 @@ def _index_select(cfg: LatentMoEConfig, lp: dict, h, c_q, qpos, index_flat,
                           -jnp.inf)
             return lax.dynamic_update_slice_in_dim(acc, s, j * kb, axis=2)
 
-        scores = lax.fori_loop(
+        return lax.fori_loop(
             0, n_groups, scores_of,
             jnp.full((b, t, m), -jnp.inf, jnp.float32))
+
+
+def _index_select(cfg: LatentMoEConfig, lp: dict, h, c_q, qpos, index_flat,
+                  off, table, bs: int):
+    """The selection as a list: the indexer's scores, then the exact top-k.
+    Returns the selected logical positions [B, T, k] and which of them are
+    real (fewer than k keys are visible early in a sequence)."""
+    scores = _index_scores(cfg, lp, h, c_q, qpos, index_flat, off, table, bs)
+    m = scores.shape[-1]
+    kb = _blocks_a_step(INDEX_STEP_KEYS, bs, m // bs) * bs
     with jax.named_scope("dsa.select"):
         k = min(cfg.index_topk, m)
         # the exact top-k is a sort, whose cost grows faster than its width:
@@ -503,6 +549,64 @@ def _index_select(cfg: LatentMoEConfig, lp: dict, h, c_q, qpos, index_flat,
             branch, [partial(lambda w, s: lax.top_k(s[..., :w], k), w)
                      for w in widths], scores)
     return idx, vals > -jnp.inf
+
+
+def mask_reach(t: int, m: int, k: int) -> int:
+    """How many keys the last query of a program may see for the program to
+    keep the selection as a mask over key tiles; 0 where it never does.  A
+    program of ``t`` tokens a row over a table of ``m`` positions gathers
+    ``t * k`` selected rows as a list and reads at most ``m`` as a mask, so
+    only a program whose list is the longer (a chunk of prefill, not a tick)
+    has the mask path at all, and takes it up to ``MASK_REACH_TOPKS`` times
+    ``k`` visible keys, where scoring every one of them passes what the
+    sort and the gather cost.  The device's branch and the host's counters
+    (:func:`publish_paged_metrics`) both come from here."""
+    return min(MASK_REACH_TOPKS * k, m) if t * k > m else 0
+
+
+def _ordered_bits(scores: jax.Array) -> jax.Array:
+    """float32 -> uint32 in the floats' total order: ``-0.0`` below ``+0.0``,
+    as ``lax.top_k`` orders them on the CPU and on the TPU alike (the
+    indexer's ``relu(...) * w`` makes zeros of both signs)."""
+    bits = lax.bitcast_convert_type(scores, jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+def _kth_largest(u: jax.Array, k: int, n_steps, step: int):
+    """Per query of ``u`` [B, T, m] (uint32) the k-th largest value among
+    the first ``n_steps * step`` keys, by counting: the largest ``v`` that
+    at least ``k`` keys reach, built a bit at a time from the top (32 passes
+    of compare-and-count, no sort); and how many of the keys equal to it the
+    top-k takes, ``k`` less the keys above it.  With fewer than ``k`` keys
+    above a masked one (``-inf``) the value is at or below the masked keys'."""
+    def count(reached, cand):
+        def one_step(j, n):
+            part = lax.dynamic_slice_in_dim(u, j * step, step, axis=2)
+            return n + jnp.sum(reached(part, cand[..., None]), axis=-1,
+                               dtype=jnp.int32)
+        return lax.fori_loop(0, n_steps, one_step,
+                             jnp.zeros(u.shape[:2], jnp.int32))
+
+    def one_bit(i, thr):
+        cand = thr | (jnp.uint32(1 << 31) >> jnp.asarray(i, jnp.uint32))
+        return jnp.where(count(jnp.greater_equal, cand) >= k, cand, thr)
+
+    thr = lax.fori_loop(0, 32, one_bit, jnp.zeros(u.shape[:2], jnp.uint32))
+    return thr, k - count(jnp.greater, thr)
+
+
+def _take(u, seen, thr, quota, taken):
+    """Which keys of one tile are in the top-k: ``u`` [Q, W] and ``seen``
+    [Q, W] (the key is visible to the query), ``thr`` and ``quota`` [Q] from
+    :func:`_kth_largest`, ``taken`` [Q] the keys equal to ``thr`` that
+    earlier tiles took.  Every visible key above the threshold, and of the
+    visible keys equal to it the lowest positions until the quota is used:
+    what ``lax.top_k`` returns, as a set.  Returns the mask and ``taken``
+    after this tile."""
+    tie = seen & (u == thr[:, None])
+    rank = taken[:, None] + jnp.cumsum(tie, axis=-1, dtype=jnp.int32)
+    sel = (seen & (u > thr[:, None])) | (tie & (rank <= quota[:, None]))
+    return sel, rank[:, -1]
 
 
 def _index_keys(cfg: LatentMoEConfig, lp: dict, h, qpos):
@@ -529,14 +633,34 @@ def _query_block(n_queries: int) -> int:
 def _full_attention(cfg, lp, h, qpos, table, bs, latent_flat, index_flat,
                     off, wflat):
     """One full layer's attention: write the chunk's latent and index key,
-    select, gather the selected latents only, attend.  Returns the residual
-    update and the two pools."""
+    then attend over the indexer's exact top-k, kept as a mask over key
+    tiles or made into a list by what :func:`mask_reach` says of the
+    program's shape and of how far its last query sees.  Returns the
+    residual update and the two pools."""
     a = cfg.attn(FULL)
-    b, t, _ = h.shape
+    t, m = h.shape[1], table.shape[1] * bs
     c_q, q_abs, latent, w_v = _latents(cfg, a, lp, h, qpos)
     latent_flat = latent_flat.at[wflat + off].set(latent)
     index_flat = index_flat.at[wflat + off].set(_pad_last(
         _index_keys(cfg, lp, h, qpos), index_flat.shape[-1] - cfg.index_dim))
+    args = (cfg, lp, h, c_q, q_abs, qpos, table, bs, latent_flat, index_flat,
+            off)
+    reach = mask_reach(t, m, min(cfg.index_topk, m))
+    if reach:
+        o_lat = lax.cond(jnp.max(qpos) < reach, lambda: _attend_mask(*args),
+                         lambda: _attend_list(*args))
+    else:
+        o_lat = _attend_list(*args)
+    return (_finish_attention(cfg, a, lp, h, o_lat, w_v), latent_flat,
+            index_flat)
+
+
+def _attend_list(cfg, lp, h, c_q, q_abs, qpos, table, bs, latent_flat,
+                 index_flat, off):
+    """Attention over the selection as a list: the sort, then the selected
+    latents gathered a block of queries at a time.  [B, T, H, kr]."""
+    a = cfg.attn(FULL)
+    b, t, _ = h.shape
     idx, real = _index_select(cfg, lp, h, c_q, qpos, index_flat, off, table,
                               bs)
     k = idx.shape[-1]
@@ -559,9 +683,78 @@ def _full_attention(cfg, lp, h, qpos, table, bs, latent_flat, index_flat,
 
     o_lat = lax.map(attend, (blocks(q_abs.reshape(b * t, *q_abs.shape[2:])),
                              blocks(phys), blocks(real.reshape(b * t, k))))
-    o_lat = o_lat.reshape(b, t, a["h"], a["kr"])
-    return (_finish_attention(cfg, a, lp, h, o_lat, w_v), latent_flat,
-            index_flat)
+    return o_lat.reshape(b, t, a["h"], a["kr"])
+
+
+def _attend_mask(cfg, lp, h, c_q, q_abs, qpos, table, bs, latent_flat,
+                 index_flat, off):
+    """Attention over the selection as a mask: the k-th largest score of
+    each query by counting, then every visible key scored a tile at a time
+    along the row's block table with the mask broadcast over the heads and
+    a running softmax (maximum, sum and accumulator float32, as
+    :func:`llama._paged_attend` walks its tiles).  The same set as the
+    list's, key for key; each latent is read once per block of queries and
+    nothing is sorted or gathered by key.  [B, T, H, kr]."""
+    a = cfg.attn(FULL)
+    b, t, _ = h.shape
+    per = table.shape[1]
+    m = per * bs
+    scores = _index_scores(cfg, lp, h, c_q, qpos, index_flat, off, table, bs)
+    with jax.named_scope("dsa.select"):
+        step = _blocks_a_step(INDEX_STEP_KEYS, bs, per) * bs
+        u = _ordered_bits(scores)
+        thr, quota = _kth_largest(
+            u, min(cfg.index_topk, m),
+            jnp.minimum(jnp.max(qpos) // step + 1, m // step), step)
+    g = _blocks_a_step(MASK_KEY_TILE, bs, per)
+    w = g * bs
+    qb = _query_block(t)
+    scale = (a["nope"] + a["rope"]) ** -0.5
+    blocks_of = latent_flat.reshape(-1, bs, latent_flat.shape[-1])
+    first = off // bs                   # the layer's stripe, in blocks
+    stat = (qb, a["h"])
+
+    def attend(args):
+        q, pos, u_q, thr_q, quota_q, tab = args     # one row's qb queries
+
+        def tile(j, acc):
+            mx, den, o, taken = acc
+            lat = blocks_of[lax.dynamic_slice_in_dim(tab, j * g, g) + first]
+            lat = lat.reshape(w, lat.shape[-1])
+            kpos = j * w + jnp.arange(w)
+            sel, taken = _take(
+                lax.dynamic_slice_in_dim(u_q, j * w, w, axis=1),
+                kpos[None, :] <= pos[:, None], thr_q, quota_q, taken)
+            s = jnp.einsum("qhr,kr->qhk", q, lat,
+                           preferred_element_type=jnp.float32) * scale
+            s = jnp.where(sel[:, None, :], s, NEG)
+            mx_new = jnp.maximum(mx, jnp.max(s, axis=-1))
+            p = jnp.exp(s - mx_new[..., None])
+            fade = jnp.exp(mx - mx_new)
+            den = fade * den + jnp.sum(p, axis=-1)
+            o = fade[..., None] * o + jnp.einsum(
+                "qhk,kr->qhr", p.astype(cfg.dtype), lat[:, :a["kr"]],
+                preferred_element_type=jnp.float32)
+            return mx_new, den, o, taken
+
+        # a query whose first tiles hold none of its keys carries a maximum
+        # of NEG and weights of exp(0) until its first selected key (it has
+        # one: its own position is visible) fades them to exact zeros
+        _, den, o, _ = lax.fori_loop(
+            0, jnp.minimum(jnp.max(pos) // w + 1, per // g), tile,
+            (jnp.full(stat, NEG, jnp.float32), jnp.zeros(stat, jnp.float32),
+             jnp.zeros(stat + (a["kr"],), jnp.float32),
+             jnp.zeros((qb,), jnp.int32)))
+        return o / den[..., None]
+
+    def blocks(x):                      # [B, T, ...] -> [B * T/qb, qb, ...]
+        return x.reshape(b * t // qb, qb, *x.shape[2:])
+
+    with jax.named_scope("dsa.attend_mask"):
+        o_lat = lax.map(attend, (
+            blocks(q_abs), blocks(qpos), blocks(u), blocks(thr),
+            blocks(quota), jnp.repeat(table, t // qb, axis=0)))
+    return o_lat.reshape(b, t, a["h"], a["kr"])
 
 
 def _window_attention(cfg, lp, h, qpos, table, bs, window_flat, off, wflat):

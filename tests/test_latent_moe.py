@@ -1,8 +1,9 @@
 """models/latent_moe.py against the benchmark's plain reference
 (benchmark/reference/dots3.py, the one copy), at the tiny preset on the CPU
 with seeded weights: the whole forward and chunked prefill followed by decoding
-through the three pools, the discrete choices (the indexer's selected sets and
-the router's experts), bfloat16, the tie between the chip's share and the uncut
+through the three pools, the discrete choices (the indexer's selected sets,
+kept as a mask over key tiles or sorted into a list, and the router's
+experts), bfloat16, the tie between the chip's share and the uncut
 model, the engine and the router over the model interface, and the counters."""
 
 import functools
@@ -85,16 +86,29 @@ def test_preset_matches_the_tiny_configuration():
     assert fam.model_config(TINY, 64) == lm.latent_moe_tiny()
 
 
-def test_forward_equals_the_reference_with_the_same_choices(monkeypatch):
+@pytest.mark.parametrize("form", ["list", "mask"])
+def test_forward_equals_the_reference_with_the_same_choices(monkeypatch,
+                                                            form):
+    """The whole forward is one program of 24 tokens a row over a table of
+    24, which keeps the selection as a mask; with the reach at 0 it sorts it
+    into a list.  Either way the logits and the chosen sets are the
+    reference's."""
+    if form == "list":
+        monkeypatch.setattr(lm, "MASK_REACH_TOPKS", 0)
     cfg, mc, params = tiny()
     seq = tokens(24)                    # beyond top-6 and the window of 5
     selected, routed = [], []
-    select, route = lm._index_select, lm.route
+    select, take, route = lm._index_select, lm._take, lm.route
 
     def spy_select(*a, **k):
         idx, real = select(*a, **k)
         selected.append(np.where(np.asarray(real), np.asarray(idx), -1)[0])
         return idx, real
+
+    def spy_take(*a, **k):              # one tile holds the whole table
+        sel, taken = take(*a, **k)
+        selected.append([np.flatnonzero(row) for row in np.asarray(sel)])
+        return sel, taken
 
     def spy_route(*a, **k):
         experts, weights = route(*a, **k)
@@ -102,6 +116,7 @@ def test_forward_equals_the_reference_with_the_same_choices(monkeypatch):
         return experts, weights
 
     monkeypatch.setattr(lm, "_index_select", spy_select)
+    monkeypatch.setattr(lm, "_take", spy_take)
     monkeypatch.setattr(lm, "route", spy_route)
     with jax.disable_jit():
         got = lm.forward(params, jnp.asarray([seq], jnp.int32), mc)[0]
@@ -117,6 +132,70 @@ def test_forward_equals_the_reference_with_the_same_choices(monkeypatch):
     assert len(routed) == len(moe) == 4
     for mine, theirs in zip(routed, moe):
         assert (np.sort(mine, -1) == np.sort(theirs, -1)).all()
+
+
+def _mask_by_tiles(scores, k, tile):
+    """The mask path's selection over ``scores`` [Q, m] as the program makes
+    it: the k-th largest by counting, then a tile of keys at a time."""
+    q, m = scores.shape
+    u = lm._ordered_bits(scores)
+    thr, quota = lm._kth_largest(u[None], k, m // tile, tile)
+    taken = jnp.zeros((q,), jnp.int32)
+    out = []
+    for j in range(0, m, tile):
+        sel, taken = lm._take(u[:, j:j + tile],
+                              scores[:, j:j + tile] > -jnp.inf, thr[0],
+                              quota[0], taken)
+        out.append(np.asarray(sel))
+    return np.concatenate(out, axis=-1)
+
+
+def _score_cases():
+    rng = np.random.default_rng(11)
+    q, m = 5, 32
+    inf = np.float32(-np.inf)
+    random = rng.standard_normal((q, m)).astype(np.float32)
+    ties = rng.integers(0, 3, (q, m)).astype(np.float32)   # ten of a value
+    zeros = np.where(rng.random((q, m)) < 0.5, np.float32(0.0),
+                     np.float32(-0.0))
+    zeros[:, ::7] = rng.standard_normal((q, len(range(0, m, 7))))
+    causal = np.where(np.arange(m)[None] <= np.arange(q)[:, None] + 3,
+                      random, inf)                       # 4..8 keys visible
+    exactly = np.where(np.arange(m)[None] < 8, ties, inf)
+    first = np.where(np.arange(m)[None] < 1, random, inf)
+    return {"random": random, "ties_at_the_threshold": ties,
+            "zeros_of_both_signs": zeros, "fewer_than_k_visible": causal,
+            "exactly_k_visible": exactly, "all_but_the_first_masked": first}
+
+
+@pytest.mark.parametrize("tile", [32, 8])
+@pytest.mark.parametrize("case", sorted(_score_cases()))
+def test_the_mask_holds_the_set_that_top_k_returns(case, tile):
+    """Key for key: every score above the k-th largest, of the scores equal
+    to it the lowest positions until k are taken, every visible key where
+    fewer than k are, never a masked one; in one tile and carried over four."""
+    scores = jnp.asarray(_score_cases()[case])
+    k = 8
+    vals, idx = jax.lax.top_k(scores, k)
+    got = _mask_by_tiles(scores, k, tile)
+    for row in range(scores.shape[0]):
+        want = set(np.asarray(idx[row])[np.asarray(vals[row]) > -np.inf])
+        assert set(np.flatnonzero(got[row])) == want, (case, row)
+    assert (got.sum(-1) == np.minimum(
+        k, (np.asarray(scores) > -np.inf).sum(-1))).all()
+
+
+@pytest.mark.parametrize("t, m, k, topks", [
+    (512, 32768, 2048, lm.MASK_REACH_TOPKS),    # the chunk of prefill
+    (512, 8192, 2048, 4),               # no further than the table
+    (1, 32768, 2048, 0),                # the tick lists 2,048 rows of 32,768
+    (4, 32768, 2048, 0),                # the verify round
+    (16, 32768, 2048, 0),               # exactly the table: the list stays
+    (17, 32768, 2048, lm.MASK_REACH_TOPKS)])
+def test_the_mask_is_for_programs_whose_list_outgrows_the_table(t, m, k,
+                                                                topks):
+    assert 4 < lm.MASK_REACH_TOPKS <= 16
+    assert lm.mask_reach(t, m, k) == topks * k
 
 
 def _serve_by_hand(mc, params, seq, n_prompt, chunk, max_len=48):
@@ -170,6 +249,55 @@ def test_long_computations_taken_in_steps_equal_the_reference(monkeypatch):
                             max_len=96)
     np.testing.assert_allclose(got, reference_logits(cfg, seq), atol=1e-4,
                                rtol=0)
+
+
+#: ``MASK_REACH_TOPKS`` that sends every chunk down the mask path, none, and
+#: the first two of three (a reach of 24 keys with top-6)
+REACHES = {"mask": 12, "list": 0, "mask_then_list": 4}
+
+
+@pytest.mark.parametrize("path", sorted(REACHES))
+def test_chunked_prefill_equals_the_reference_on_either_path(monkeypatch,
+                                                             path):
+    """A chunk of 16 tokens over a table of 48 lists 96 rows where the table
+    holds 48, so it may keep the selection as a mask; top-6 is far below the
+    context, so a mask that was ignored would fail.  Tiles of one block and
+    blocks of 8 queries make the mask path take several of each."""
+    monkeypatch.setattr(lm, "MASK_REACH_TOPKS", REACHES[path])
+    monkeypatch.setattr(lm, "MASK_KEY_TILE", 16)
+    monkeypatch.setattr(lm, "QUERY_BLOCK", 8)
+    if path == "list":                  # and is not even compiled in
+        monkeypatch.delattr(lm, "_attend_mask")
+    cfg, mc, params = tiny()
+    seq = tokens(47, seed=8)
+    got, _ = _serve_by_hand(mc, params, seq, n_prompt=41, chunk=16)
+    np.testing.assert_allclose(got, reference_logits(cfg, seq), atol=1e-4,
+                               rtol=0)
+
+
+def test_the_mask_path_attends_the_selection_and_not_every_key(monkeypatch):
+    """The control of the test above: with the mask made of every visible
+    key the same chunks leave the reference."""
+    monkeypatch.setattr(
+        lm, "_take", lambda u, seen, thr, quota, taken: (seen, taken))
+    cfg, mc, params = tiny()
+    seq = tokens(47, seed=8)
+    got, _ = _serve_by_hand(mc, params, seq, n_prompt=41, chunk=16)
+    assert np.max(np.abs(got - reference_logits(cfg, seq))) > 1e-2
+
+
+def test_each_row_of_a_batch_walks_its_own_table_under_the_mask():
+    """The mask path takes blocks of queries row by row, each with its own
+    row's block table and threshold: two sequences in one program read as
+    each does alone."""
+    cfg, mc, params = tiny()
+    seqs = [tokens(24, seed=9), tokens(24, seed=10)]
+    assert lm.mask_reach(24, 24, 6) == 24
+    both = lm.forward(params, jnp.asarray(seqs, jnp.int32), mc)
+    for row, seq in enumerate(seqs):
+        np.testing.assert_allclose(np.asarray(both[row]),
+                                   reference_logits(cfg, seq), atol=1e-4,
+                                   rtol=0)
 
 
 def test_bfloat16_stays_near_float32_but_for_flipped_choices():
@@ -257,6 +385,73 @@ def test_engine_run_equals_cache_free_generate(served):
     assert [r.status for r in out] == ["OK"] * 3
     assert [list(r) for r in out] == want
     assert eng.compile_cache_sizes() == {"tick": 1, "chunk": 1, "set_row": 1}
+
+
+def _dispatched(monkeypatch):
+    """Every program an engine built from here on hands to the model's
+    counters: ``(rows, tokens a row, longest row's length)``."""
+    seen, publish = [], lm.publish_paged_metrics
+
+    def spy(metrics, cfg, pcache, stats_host=None, row_blocks=(),
+            programs=()):
+        seen.extend(programs)
+        return publish(metrics, cfg, pcache, stats_host, row_blocks, programs)
+
+    monkeypatch.setattr(lm, "publish_paged_metrics", spy)
+    return seen
+
+
+@pytest.mark.parametrize("path", sorted(REACHES))
+def test_engine_serves_the_same_tokens_on_either_path(monkeypatch, served,
+                                                      path):
+    """Chunks of 16 may take the mask path (see above); the tokens are the
+    cache-free program's whichever path the reach sends them down, and
+    ``dsa.mask_queries`` / ``dsa.queries`` are what :func:`lm.mask_reach`
+    says of the programs that were dispatched."""
+    mc, params, prompts, want = served
+    monkeypatch.setattr(lm, "MASK_REACH_TOPKS", REACHES[path])
+    programs = _dispatched(monkeypatch)
+    eng = _engine(mc, params, chunk=16)
+    out = eng.run([Request(prompt=p, max_new_tokens=9) for p in prompts])
+    assert [list(r) for r in out] == want
+    assert eng.compile_cache_sizes() == {"tick": 1, "chunk": 1, "set_row": 1}
+    c = eng.metrics_snapshot()["counters"]
+    reach = {"mask": 48, "list": 0, "mask_then_list": 24}[path]
+    chunks = [p for p in programs if p[1] == 16]
+    ticks = [p for p in programs if p[1] == 1]
+    assert len(chunks) == 2 + 1 + 2 and len(chunks) + len(ticks) \
+        == len(programs)
+    assert c["dsa.queries"] == 2 * (16 * len(chunks) + 2 * len(ticks))
+    assert c["dsa.mask_queries"] == 2 * 16 * sum(
+        1 for _, t, longest in chunks if longest + t <= reach)
+    assert c["dsa.mask_queries"] == {"mask": 160, "list": 0,
+                                     "mask_then_list": 96}[path]
+    assert c["dsa.mask_queries"] == sum(
+        rows * t * 2 for rows, t, longest in programs
+        if longest + t <= lm.mask_reach(t, 48, 6))
+
+
+def test_ticks_alone_count_no_query_under_the_mask(monkeypatch, served):
+    """Once the prompts are in, the steps dispatch ticks only: two rows of
+    one token over a table of 48 list 6 rows each, the list path, whatever
+    the rows hold."""
+    mc, params, prompts, want = served
+    programs = _dispatched(monkeypatch)
+    eng = _engine(mc, params, chunk=16)
+    rid = eng.submit(Request(prompt=prompts[0], max_new_tokens=9))
+    while not programs or programs[-1][1] > 1:      # until the first tick
+        eng.step()
+    counters = lambda: eng.metrics_snapshot()["counters"]  # noqa: E731
+    before, n = counters(), len(programs)
+    assert before["dsa.mask_queries"] == before["dsa.queries"] - 2 * 2 > 0
+    while eng.pending():
+        eng.step()
+    assert list(eng.results[rid]) == want[0]
+    assert {t for _, t, _ in programs[n:]} == {1} and len(programs) > n
+    after = counters()
+    assert after["dsa.mask_queries"] == before["dsa.mask_queries"]
+    assert after["dsa.queries"] - before["dsa.queries"] \
+        == 2 * 2 * (len(programs) - n)
 
 
 def test_prefix_cache_hit_serves_the_same_tokens(served):
