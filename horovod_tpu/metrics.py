@@ -238,6 +238,20 @@ METRIC_HELP: dict[str, str] = {
     "dsa.mask_queries": "Queries whose selection was kept as a mask over key tiles and not sorted into a list (the programs latent_moe.mask_reach sends that way)",
     "attn.blocks_visited": "Block-table entries paged attention read (per dispatched tick or chunk: rows x blocks up to the longest live row)",
     "attn.blocks_in_table": "Block-table entries of the rows of every dispatched tick or chunk (rows x blocks a table holds)",
+    # models/shortconv_moe.py — the attention layers' pools, the
+    # convolution's state per slot and its snapshot per block, and the
+    # device-side counters (read back with the tick's tokens; beside each
+    # counter a gauge <name>.device, the device's own total as last read)
+    "kv.bytes_per_token": "Device bytes of keys and values one cached position holds (attention layers only)",
+    "kv.snapshot_block_bytes": "Device bytes of one block's snapshot of the recurrent state (all conv layers)",
+    "state.bytes_per_slot": "Device bytes of recurrent state one sequence carries (all conv layers)",
+    "conv.state_restores": "Rows mapped at a length past 0: their recurrent state came from a block's snapshot (prefix hits, replays)",
+    "conv.snapshots_written": "Blocks whose last position a program's counted tokens reached (each got its snapshot)",
+    "attn.keys_visible": "Cached keys the attention layers' queries saw (summed over queries and attention layers)",
+    "moe.choices_total.device": "This engine's device-side total of moe.choices_total as last read (the counter's next increment is reckoned from it)",
+    "conv.state_restores.device": "This engine's device-side total of conv.state_restores as last read",
+    "conv.snapshots_written.device": "This engine's device-side total of conv.snapshots_written as last read",
+    "attn.keys_visible.device": "This engine's device-side total of attn.keys_visible as last read",
     # mem.* — host-side observability footprint (approximate)
     "mem.registry_bytes": "Approximate host bytes held by the metrics registry",
     "mem.trace_ring_bytes": "Approximate host bytes of live traces + the SLO ring",

@@ -150,6 +150,7 @@ class LatentMoEConfig:
     top_k: int = 8
     n_shared: int = 1
     routed_scale: float = 1.0
+    route_norm_eps: float = 0.0        # added to the chosen scores' sum
     held_first: int = 0                # the experts this chip holds
     held_count: int = 32
     vocab_first: int = 0               # the first held row (for the record)
@@ -786,13 +787,17 @@ def _swiglu(x, w_gate, w_up, w_down, dt):
 
 def route(cfg: LatentMoEConfig, lp: dict, h2):
     """Sigmoid scores over the whole router, the ``top_k`` largest with the
-    bias chosen, weights normalised over the chosen: ``[N, k]`` both."""
+    bias chosen, weights normalised over the chosen (their sum plus
+    ``cfg.route_norm_eps``): ``[N, k]`` both.  ``cfg`` is any config with
+    ``top_k``, ``routed_scale`` and ``route_norm_eps``."""
     s = jax.nn.sigmoid(jnp.dot(h2.astype(jnp.float32),
                                lp["w_router"].astype(jnp.float32)))
     _, experts = lax.top_k(s + lp["router_bias"], cfg.top_k)
     picked = jnp.take_along_axis(s, experts, axis=-1)
-    weights = picked / jnp.sum(picked, axis=-1, keepdims=True)
-    return experts, weights * cfg.routed_scale
+    total = jnp.sum(picked, axis=-1, keepdims=True)
+    if cfg.route_norm_eps:
+        total = total + cfg.route_norm_eps
+    return experts, picked / total * cfg.routed_scale
 
 
 def _tile_rows(n_choices: int) -> int:
@@ -803,6 +808,9 @@ def _tile_rows(n_choices: int) -> int:
 def held_experts(cfg: LatentMoEConfig, lp: dict, h2, valid):
     """The held experts' part of the layer for tokens ``h2`` [N, d]: every
     choice that fell on a held expert is computed and none is dropped.
+    ``cfg`` is any config with the fields of :func:`route` and ``dtype``,
+    ``held_first`` and ``held_count``
+    (:mod:`horovod_tpu.models.shortconv_moe` shares this layer).
     ``valid`` [N] marks real tokens (pads and idle rows choose nothing).
     Returns the weighted sum per token and the per-held-expert load."""
     dt = cfg.dtype

@@ -840,6 +840,87 @@ def paged_blocks_walked(longest: int, t: int, block_size: int,
     return min(((longest + t - 1) // (g * block_size) + 1) * g, per)
 
 
+class TileWalk(NamedTuple):
+    """What one program's walk over key tiles shares between its layers:
+    ``table`` [B, n_tiles * g] (the rows' block tables, padded with trash to
+    whole tiles), ``g`` blocks a tile spans, ``n_live`` the tiles up to the
+    longest row's last query position (a traced bound: data, not shape),
+    and ``m`` the table's logical depth."""
+
+    table: jax.Array
+    g: int
+    n_live: jax.Array
+    m: int
+
+
+def tile_walk(table: jax.Array, qpos: jax.Array, bs: int) -> TileWalk:
+    """The walk of a program whose queries stand at ``qpos`` [B, T] under
+    block tables ``table`` [B, per] of ``bs``-position blocks."""
+    per = table.shape[1]
+    g = _tile_blocks(bs, per)               # blocks a key tile spans
+    n_tiles = -(-per // g)
+    return TileWalk(
+        table=jnp.pad(table, ((0, 0), (0, n_tiles * g - per))),  # with trash
+        g=g, n_live=jnp.minimum(jnp.max(qpos) // (g * bs) + 1, n_tiles),
+        m=per * bs)
+
+
+def paged_attend_tiles(q, k, v, kf, vf, layer, walk: TileWalk, qpos, wflat,
+                       n_blocks: int, bs: int, scale: float | None = None):
+    """One layer's paged attention, for any model whose keys and values are
+    ``[.., KVH, Dh]`` rows of flat pools: scatter the chunk's ``k`` / ``v``
+    [B, T, KVH, Dh] into ``kf`` / ``vf`` ``[n_pool_layers * n_blocks * bs,
+    KVH, Dh]`` at ``wflat`` [B, T] within pool layer ``layer``'s stripe, then
+    attend ``q`` [B, T, H, Dh] (rotated, unscaled) over the row's blocks a
+    key tile at a time with a running softmax, no further than
+    ``walk.n_live`` tiles, scores scaled by ``scale`` (``1 / sqrt(Dh)``
+    where none is given).  A tile past a shorter row's frontier (its table
+    points at trash there) is masked to an exact-zero softmax term.
+    Products of K and V as stored, accumulated in float32; maximum, sum and
+    output accumulator float32; one division after the loop.  Returns the
+    heads' outputs [B, T, KVH, H / KVH, Dh] (float32) and the two pools."""
+    b, t, n_heads, dh = q.shape
+    kvh = k.shape[2]
+    n_rep = n_heads // kvh
+    scale = 1.0 / (dh ** 0.5) if scale is None else scale
+    g, w = walk.g, walk.g * bs
+    off = layer * (n_blocks * bs)           # the layer's flat positions
+    kf = kf.at[wflat + off].set(k)
+    vf = vf.at[wflat + off].set(v)
+    kb = kf.reshape(-1, bs, kvh, dh)        # a bitcast
+    vb = vf.reshape(-1, bs, kvh, dh)
+    qg = q.reshape(b, t, kvh, n_rep, dh)
+    stat = (b, kvh, n_rep, t)
+
+    def tile(j, acc):
+        mx, den, o = acc
+        blk = lax.dynamic_slice_in_dim(walk.table, j * g, g, axis=1)
+        blk = blk + layer * n_blocks                      # [B, G]
+        kt = kb[blk].reshape(b, w, kvh, dh)
+        vt = vb[blk].reshape(b, w, kvh, dh)
+        s = jnp.einsum("bqkrd,bmkd->bkrqm", qg, kt,
+                       preferred_element_type=jnp.float32) * scale
+        kpos = j * w + jnp.arange(w)
+        seen = (kpos <= qpos[:, :, None]) & (kpos < walk.m)   # [B, T, W]
+        s = jnp.where(seen[:, None, None], s, NEG_INF_LOGIT)
+        mx_new = jnp.maximum(mx, jnp.max(s, axis=-1))
+        p = jnp.exp(s - mx_new[..., None])            # [B,KVH,R,T,W]
+        fade = jnp.exp(mx - mx_new)
+        den = fade * den + jnp.sum(p, axis=-1)
+        o = fade[..., None] * o + jnp.einsum(
+            "bkrqm,bmkd->bkrqd", p, vt.astype(jnp.float32))
+        return mx_new, den, o
+
+    # every query sees key 0, so the first tile makes `mx` a real
+    # maximum and a masked term is exp(-1e30 - mx) == 0 from there on
+    _, den, o = lax.fori_loop(
+        0, walk.n_live, tile,
+        (jnp.full(stat, NEG_INF_LOGIT, jnp.float32),
+         jnp.zeros(stat, jnp.float32),
+         jnp.zeros(stat + (dh,), jnp.float32)))
+    return jnp.moveaxis(o / den[..., None], 3, 1), kf, vf  # [B,T,KVH,R,Dh]
+
+
 def _paged_attend(params, tokens, cfg: LlamaConfig, kv_k, kv_v,
                   qpos, wflat, table):
     """Shared body of the paged decode paths: scatter the chunk's K/V at
@@ -847,12 +928,9 @@ def _paged_attend(params, tokens, cfg: LlamaConfig, kv_k, kv_v,
     through ``table`` [B, blocks_per_row] (the rows' block tables) with a
     running softmax: a loop over key tiles of whole pool blocks that ends
     at the longest row's last query position, a traced bound read from
-    ``qpos``.  Nothing as deep as the table is gathered or scored; a tile
-    past a shorter row's frontier (its table points at trash there) is
-    masked to an exact-zero softmax term, as dense pad slots are.  The
-    numbers are :func:`decode_chunk`'s up to the order of summation:
-    products of K and V as stored, accumulated in float32; maximum, sum
-    and output accumulator float32; one division after the loop.
+    ``qpos`` (:func:`tile_walk`, :func:`paged_attend_tiles`).  Nothing as
+    deep as the table is gathered or scored.  The numbers are
+    :func:`decode_chunk`'s up to the order of summation.
 
     The pool is written IN PLACE: ``kv_k`` / ``kv_v`` ride the layer scan
     as CARRIES (flattened to ``[L * n_blocks * bs, KVH, Dh]``, a bitcast),
@@ -863,21 +941,11 @@ def _paged_attend(params, tokens, cfg: LlamaConfig, kv_k, kv_v,
     layer slice is copied and the pool is held once."""
     b, t = tokens.shape
     nl, n_blocks, bs, kvh, dh = kv_k.shape
-    per = table.shape[1]
-    m = per * bs                            # the table's logical depth
     dt = cfg.dtype
     x = params["embed"][tokens].astype(dt)                # [B, T, D]
     cos, sin = rope_tables(cfg, qpos)
-    n_rep = cfg.n_heads // cfg.n_kv_heads
-    scale = 1.0 / (cfg.head_dim ** 0.5)
     stripe = n_blocks * bs                  # one layer's flat positions
-    g = _tile_blocks(bs, per)               # blocks a key tile spans
-    w = g * bs
-    n_tiles = -(-per // g)
-    table = jnp.pad(table, ((0, 0), (0, n_tiles * g - per)))  # with trash
-    # tiles up to the longest row's last query position: data, not shape
-    n_live = jnp.minimum(jnp.max(qpos) // w + 1, n_tiles)
-    stat = (b, kvh, n_rep, t)
+    walk = tile_walk(table, qpos, bs)
 
     def layer(carry, lp):
         x, kf, vf, i = carry                # kf/vf [L * stripe, KVH, Dh]
@@ -887,40 +955,8 @@ def _paged_attend(params, tokens, cfg: LlamaConfig, kv_k, kv_v,
         v = (h @ lp["wv"].astype(dt)).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-        off = i * stripe
-        kf = kf.at[wflat + off].set(k)
-        vf = vf.at[wflat + off].set(v)
-        kb = kf.reshape(nl * n_blocks, bs, kvh, dh)       # a bitcast
-        vb = vf.reshape(nl * n_blocks, bs, kvh, dh)
-        qg = q.reshape(b, t, cfg.n_kv_heads, n_rep, cfg.head_dim)
-
-        def tile(j, acc):
-            mx, den, o = acc
-            blk = lax.dynamic_slice_in_dim(table, j * g, g, axis=1)
-            blk = blk + i * n_blocks                      # [B, G]
-            kt = kb[blk].reshape(b, w, kvh, dh)
-            vt = vb[blk].reshape(b, w, kvh, dh)
-            s = jnp.einsum("bqkrd,bmkd->bkrqm", qg, kt,
-                           preferred_element_type=jnp.float32) * scale
-            kpos = j * w + jnp.arange(w)
-            seen = (kpos <= qpos[:, :, None]) & (kpos < m)    # [B, T, W]
-            s = jnp.where(seen[:, None, None], s, NEG_INF_LOGIT)
-            mx_new = jnp.maximum(mx, jnp.max(s, axis=-1))
-            p = jnp.exp(s - mx_new[..., None])            # [B,KVH,R,T,W]
-            fade = jnp.exp(mx - mx_new)
-            den = fade * den + jnp.sum(p, axis=-1)
-            o = fade[..., None] * o + jnp.einsum(
-                "bkrqm,bmkd->bkrqd", p, vt.astype(jnp.float32))
-            return mx_new, den, o
-
-        # every query sees key 0, so the first tile makes `mx` a real
-        # maximum and a masked term is exp(-1e30 - mx) == 0 from there on
-        _, den, o = lax.fori_loop(
-            0, n_live, tile,
-            (jnp.full(stat, NEG_INF_LOGIT, jnp.float32),
-             jnp.zeros(stat, jnp.float32),
-             jnp.zeros(stat + (dh,), jnp.float32)))
-        o = jnp.moveaxis(o / den[..., None], 3, 1)        # [B,T,KVH,R,Dh]
+        o, kf, vf = paged_attend_tiles(q, k, v, kf, vf, i, walk, qpos,
+                                       wflat, n_blocks, bs)
         x = x + o.astype(dt).reshape(b, t, cfg.dim) @ lp["wo"].astype(dt)
         h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
         gate = jax.nn.silu(h @ lp["w_gate"].astype(dt))

@@ -2,8 +2,10 @@
 
 The engine names no model: it serves any config whose module implements
 the paged model interface (:mod:`horovod_tpu.models.paged`) — a
-``LlamaConfig`` (described below) or a ``LatentMoEConfig`` (three latent
-pools behind the same block table, ``docs/inference.md``).
+``LlamaConfig`` (described below), a ``LatentMoEConfig`` (three latent
+pools behind the same block table) or a ``ShortConvMoEConfig`` (attention
+pools, and beside them a recurrent state per slot with a snapshot per
+block: the interface's second kind of state, ``docs/inference.md``).
 
 :class:`~horovod_tpu.serving.ContinuousBatcher` admits into a fixed slot
 pool but each admission runs its whole prefill at once and the pool's
@@ -93,8 +95,10 @@ prompt-lookup decoding in the continuous batch — see
   a fixed ``(draft_k + 1)``-window per dispatch, greedy
   longest-matching-prefix acceptance runs on device, and the per-row
   cache length advances by ``1 + accepted`` — rejected positions roll
-  back by the length alone (write-before-read: the frontier rewrites
-  them before they can be read);
+  back by the length alone where the state is per position
+  (write-before-read: the frontier rewrites them before they can be
+  read); a model's recurrent state is left by its own round as after
+  the accepted tokens;
 * acceptance only ever keeps the model's own argmax, so spec on/off
   is bit-identical to the solo greedy run for any draft quality, and
   ``compile_cache_sizes()`` stays frozen at one signature per program
@@ -113,7 +117,9 @@ Scheduler invariants:
    ``tests/test_serving_faults.py``).
 3. *Fixed signature*: host state (queue, slot states, free blocks) makes
    every decision; device programs only ever see [n_slots]-shaped data.
-   Preempt/requeue/cancel/timeout paths reuse the same programs, and
+   Preempt/requeue/cancel/timeout paths reuse the same programs (a
+   model with state per sequence restores it inside ``_set_row`` from
+   the snapshot of the block the row is mapped up to), and
    scheduler policies (:mod:`horovod_tpu.scheduling`) only reorder
    host decisions — invariant 2 makes any admission order or victim
    choice output-preserving.
@@ -642,7 +648,12 @@ class ServeEngine:
             # preempt, cancel, timeout, fail) reuses the same compiled
             # programs.  `length` is 0 except on a prefix-cache hit,
             # where it is the cached frontier so the first prefill
-            # window continues from the first uncached token.
+            # window continues from the first uncached token.  A model
+            # with state that is not per position says in its own
+            # `set_row` what a slot holds when its row is (re)mapped
+            # (models/paged.py): same program, same signature.
+            if hasattr(model, "set_row"):
+                return model.set_row(pcache, slot, row, length)
             return pcache._replace(
                 block_table=pcache.block_table.at[slot].set(row),
                 length=pcache.length.at[slot].set(length))
