@@ -229,6 +229,8 @@ METRIC_HELP: dict[str, str] = {
     "kv.window_block_bytes": "Device bytes of one block of the window layers' latent pool",
     "kv.window_bytes_beyond_window": "Window-pool bytes live rows hold for positions older than the window (what a window-sized pool would free)",
     "moe.choices_total": "Token-choices routed (tokens x top_k x expert layers)",
+    "moe.choices_in_place": "Choices of the dispatched programs whose expert layers computed over their rows in place (rows x tokens a row x top_k x expert layers, idle rows counted; the programs latent_moe.rows_in_place sends that way)",
+    "moe.layers_batched": "Expert layers in place that computed every held expert at once and not one touched expert a step (device-side count)",
     "moe.choices_held": "Token-choices that fell on an expert this chip holds",
     "moe.held_load": "Token-choices per held expert since start (moe.held_load.<expert>)",
     "moe.experts_touched": "Held experts (summed over layers) the last decode tick computed",
@@ -249,6 +251,7 @@ METRIC_HELP: dict[str, str] = {
     "conv.snapshots_written": "Blocks whose last position a program's counted tokens reached (each got its snapshot)",
     "attn.keys_visible": "Cached keys the attention layers' queries saw (summed over queries and attention layers)",
     "moe.choices_total.device": "This engine's device-side total of moe.choices_total as last read (the counter's next increment is reckoned from it)",
+    "moe.layers_batched.device": "This engine's device-side total of moe.layers_batched as last read",
     "conv.state_restores.device": "This engine's device-side total of conv.state_restores as last read",
     "conv.snapshots_written.device": "This engine's device-side total of conv.snapshots_written as last read",
     "attn.keys_visible.device": "This engine's device-side total of attn.keys_visible as last read",

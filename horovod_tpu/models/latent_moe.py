@@ -41,12 +41,17 @@ layer input:
   largest ``s + router_bias`` are chosen and weighted ``s_e / sum_sel s``
   (times ``routed_scale``); this chip **holds** experts ``held_first ..
   held_first + held_count`` and adds what they give for the tokens that chose
-  them, plus the shared expert.  No capacity exists and no token is dropped:
-  the held choices are sorted by expert into tile-aligned segments and a loop
-  over the tiles in use multiplies each by its expert's matrices, so the work
-  and the expert weights read follow the choices that were made.  What the
-  absent experts would add is left out — on one chip the layer runs without
-  its exchange — and the partial result goes on to the next layer.
+  them, plus the shared expert.  No capacity exists and no token is dropped.
+  A program of at most ``IN_PLACE_ROWS`` rows (a tick, a short chunk) leaves
+  its rows where they are: every row through each computed expert, the row's
+  weight on it selected onto the outcome, all held experts at once where
+  most are touched and one touched expert a step where few are.  A longer
+  program sorts the held choices by expert into tile-aligned segments and a
+  loop over the tiles in use multiplies each by its expert's matrices.  Only
+  the form that takes all experts at once reads one that no row chose, and
+  it runs where at least three quarters are touched.  What the absent
+  experts would add is left out — on one chip the layer runs without its
+  exchange — and the partial result goes on to the next layer.
 
 **The absorbed form.**  Nothing per head is cached.  ``W_kvb``'s key half is
 folded into the query (``q_nope W_kvb_k -> [heads, kv_rank]``) and its value
@@ -86,7 +91,9 @@ from horovod_tpu.models.llama import rmsnorm
 FULL, WINDOW = "full", "window"
 LN_EPS = 1e-6                 # the indexer's LayerNorm
 NEG = -1e30
-#: stats columns: four running sums, one gauge, then the held experts' load
+#: stats columns: four running sums, one gauge, the held experts' load, and
+#: last the expert layers that computed every held expert at once
+#: (:func:`layers_batched`)
 CHOICES_TOTAL, CHOICES_HELD, KEYS_VISIBLE, KEYS_SELECTED, TOUCHED, LOAD0 = \
     0, 1, 2, 3, 4, 5
 _LO_BITS = 24                 # a running sum is hi * 2**24 + lo, both int32
@@ -94,10 +101,19 @@ LANES = 128
 #: how much of a long computation one loop step takes: cached keys the
 #: indexer scores at once, queries that attend at once (their selected
 #: latents gathered, or a tile of keys scored for them), and the rows of one
-#: expert's tile in a tick and in a chunk
+#: expert's tile in a program that sorts its choices into tiles
 INDEX_STEP_KEYS = 2048
 QUERY_BLOCK = 128
-TILE_ROWS = (8, 128)
+TILE_ROWS = 128
+#: a program of at most this many rows computes its experts over the rows in
+#: place and sorts no choice into tiles (:func:`held_experts`).  Every row
+#: then goes through every expert that is computed: 6·d·f operations a row
+#: and expert against the expert's 6·d·f bytes, so up to 197 TFLOP/s / 819
+#: GB/s = 240 rows the products hide behind the weights' read whatever the
+#: widths.  Measured on one v5e at lfm2's widths (PERF.md, PR 32): a tick of
+#: 128 rows 23.2 -> 16.6 ms, a chunk of 256 rows (the balance point) 21.0 ->
+#: 16.1 ms; a chunk of 512 would spend twice the MXU's time of its read.
+IN_PLACE_ROWS = 256
 #: the selection is kept as a mask over key tiles (and never made into a
 #: list) while a program's last query sees no more than this many times
 #: ``index_topk`` keys; beyond it scoring every visible key costs more than
@@ -290,8 +306,8 @@ class LatentPagedCache(NamedTuple):
     """Three pools behind one block table (block 0 is trash in each):
     ``latent`` / ``index`` ``[n_full, n_blocks, bs, width]``, ``window``
     ``[n_window, n_blocks, bs, width]``; ``block_table`` [B, blocks_per_slot]
-    int32, ``length`` [B] int32, and ``stats`` [2, 5 + held_count] int32, the
-    device-side counters (row 0 high words, row 1 low words)."""
+    int32, ``length`` [B] int32, and ``stats`` [2, 5 + held_count + 1] int32,
+    the device-side counters (row 0 high words, row 1 low words)."""
 
     latent: jax.Array
     index: jax.Array
@@ -336,7 +352,7 @@ def init_paged_cache(
                     _lanes(cfg.w_kv_rank + cfg.w_rope_dim)),
         block_table=jnp.zeros((n_slots, per), jnp.int32),
         length=jnp.zeros((n_slots,), jnp.int32),
-        stats=jnp.zeros((2, LOAD0 + cfg.held_count), jnp.int32))
+        stats=jnp.zeros((2, LOAD0 + cfg.held_count + 1), jnp.int32))
 
 
 def paged_pool_bytes(pcache: LatentPagedCache) -> dict:
@@ -361,7 +377,8 @@ def read_counters(stats_host: np.ndarray) -> dict:
             "keys_visible": int(total[KEYS_VISIBLE]),
             "keys_selected": int(total[KEYS_SELECTED]),
             "experts_touched": int(s[1, TOUCHED]),
-            "held_load": [int(x) for x in total[LOAD0:]]}
+            "held_load": [int(x) for x in total[LOAD0:-1]],
+            "layers_batched": int(total[-1])}
 
 
 def publish_paged_metrics(metrics, cfg: LatentMoEConfig,
@@ -377,7 +394,9 @@ def publish_paged_metrics(metrics, cfg: LatentMoEConfig,
     length)`` of each program a step dispatched: ``dsa.queries`` counts their
     queries times the full layers and ``dsa.mask_queries`` those of the
     programs that kept the selection as a mask, by :func:`mask_reach`, the
-    function the programs' own branch comes from; nothing is read back."""
+    function the programs' own branch comes from; nothing is read back.
+    ``moe.choices_in_place`` is reckoned the same way
+    (:func:`choices_in_place`)."""
     if stats_host is None and not programs:     # once, at construction
         per_block = paged_pool_bytes(pcache)
         metrics.gauge("kv.latent_block_bytes").set(per_block["latent"])
@@ -385,6 +404,7 @@ def publish_paged_metrics(metrics, cfg: LatentMoEConfig,
         metrics.gauge("kv.window_block_bytes").set(per_block["window"])
         metrics.gauge("kv.window_bytes_beyond_window").set(0)
         for name in ("moe.choices_total", "moe.choices_held",
+                     "moe.choices_in_place", "moe.layers_batched",
                      "dsa.keys_visible", "dsa.keys_selected",
                      "dsa.queries", "dsa.mask_queries"):
             metrics.counter(name)
@@ -395,6 +415,8 @@ def publish_paged_metrics(metrics, cfg: LatentMoEConfig,
     metrics.counter("dsa.mask_queries").inc(cfg.n_of(FULL) * sum(
         rows * t for rows, t, longest in programs
         if longest + t <= mask_reach(t, m, k)))
+    metrics.counter("moe.choices_in_place").inc(
+        choices_in_place(cfg, programs))
     if stats_host is None:          # nothing was read back: no tick ran
         return
     keep = -(-(cfg.window - 1) // pcache.block_size) + 1
@@ -404,6 +426,7 @@ def publish_paged_metrics(metrics, cfg: LatentMoEConfig,
     c = read_counters(stats_host)
     _set_counter(metrics.counter("moe.choices_total"), c["choices_total"])
     _set_counter(metrics.counter("moe.choices_held"), c["choices_held"])
+    _set_counter(metrics.counter("moe.layers_batched"), c["layers_batched"])
     _set_counter(metrics.counter("dsa.keys_visible"), c["keys_visible"])
     _set_counter(metrics.counter("dsa.keys_selected"), c["keys_selected"])
     metrics.gauge("moe.experts_touched").set(c["experts_touched"])
@@ -800,11 +823,6 @@ def route(cfg: LatentMoEConfig, lp: dict, h2):
     return experts, picked / total * cfg.routed_scale
 
 
-def _tile_rows(n_choices: int) -> int:
-    """Rows of one expert's tile: small in a tick, MXU-sized in a chunk."""
-    return TILE_ROWS[0] if n_choices <= 256 else TILE_ROWS[1]
-
-
 def held_experts(cfg: LatentMoEConfig, lp: dict, h2, valid):
     """The held experts' part of the layer for tokens ``h2`` [N, d]: every
     choice that fell on a held expert is computed and none is dropped.
@@ -812,18 +830,119 @@ def held_experts(cfg: LatentMoEConfig, lp: dict, h2, valid):
     ``held_first`` and ``held_count``
     (:mod:`horovod_tpu.models.shortconv_moe` shares this layer).
     ``valid`` [N] marks real tokens (pads and idle rows choose nothing).
-    Returns the weighted sum per token and the per-held-expert load."""
-    dt = cfg.dtype
-    n, d = h2.shape
-    e, k = cfg.held_count, cfg.top_k
+    Returns the weighted sum per token and the per-held-expert load.  A
+    program of few rows computes them in place (:func:`rows_in_place`), one
+    of many sorts its choices into tiles."""
+    n = h2.shape[0]
+    e = cfg.held_count
     with jax.named_scope("moe.route"):
         experts, weights = route(cfg, lp, h2)
         local = experts - cfg.held_first
         held = (local >= 0) & (local < e) & valid[:, None]          # [N, k]
-        group = jnp.where(held, local, e).reshape(n * k)
+        group = jnp.where(held, local, e).reshape(n * cfg.top_k)
         load = jnp.sum(jax.nn.one_hot(group, e + 1, dtype=jnp.int32),
                        axis=0)[:e]                                   # [E]
-        tile = _tile_rows(n * k)
+    if rows_in_place(n):
+        return _experts_in_place(cfg, lp, h2, group, weights, load), load
+    return _experts_in_tiles(cfg, lp, h2, held, group, weights, load), load
+
+
+def rows_in_place(n_rows: int) -> bool:
+    """Whether a program of ``n_rows`` tokens computes its experts over the
+    rows where they stand."""
+    return n_rows <= IN_PLACE_ROWS
+
+
+def _most_experts_touched(n_touched, e: int):
+    """Whether computing all ``e`` held experts at once is cheaper than one
+    touched expert a step.  Measured on one v5e at lfm2's widths (PERF.md,
+    PR 32): an expert costs 30 us among all 32 at once (its 22 MB at 90 % of
+    the memory's peak) and 41 us as a step of the loop, so the two cross at
+    23 of 32 touched."""
+    return n_touched * 4 >= e * 3
+
+
+def layers_batched(n_rows: int, load) -> jax.Array:
+    """1 where an expert layer of ``n_rows`` tokens with this per-expert
+    ``load`` computed all its held experts at once, else 0: the predicates
+    :func:`held_experts` itself goes by."""
+    if not rows_in_place(n_rows):
+        return jnp.int32(0)
+    return _most_experts_touched(
+        jnp.sum(load > 0, dtype=jnp.int32), load.shape[0]).astype(jnp.int32)
+
+
+def choices_in_place(cfg, programs: tuple) -> int:
+    """The choices of the dispatched programs whose expert layers computed in
+    place: ``rows x tokens a row x top_k x expert layers`` of each program
+    ``(rows, tokens a row, longest row)`` within :func:`rows_in_place`, the
+    function the program's own form comes from.  Every row counts, idle and
+    padded ones too (the layer computes over them), where
+    ``moe.choices_total`` counts the real tokens' on the device: over
+    programs whose rows are all live the two are alike."""
+    return cfg.top_k * (cfg.n_layers - cfg.first_dense) * sum(
+        rows * t for rows, t, _ in programs if rows_in_place(rows * t))
+
+
+def _experts_in_place(cfg, lp, h2, group, weights, load):
+    """Every row through every expert that is computed, the row's weight on
+    the expert applied to the outcome (zero where it did not choose it): no
+    choice is sorted, gathered or scattered.  An expert's outcome is rounded
+    to ``cfg.dtype`` as :func:`_swiglu` rounds it and the sum over a row's
+    experts is float32, by expert index.  Rows that chose nothing come out
+    exactly zero whatever they hold (a select, not a product with zero)."""
+    dt = cfg.dtype
+    n, d = h2.shape
+    e = cfg.held_count
+    with jax.named_scope("moe.route"):
+        # [E, N]: a token chooses an expert at most once
+        on = group.reshape(n, -1).T[:, None, :] == jnp.arange(e)[None, :, None]
+        chosen = jnp.any(on, axis=0)
+        w = jnp.sum(jnp.where(on, weights.T[:, None, :], 0.0), axis=0)
+        touched = load > 0
+        n_touched = jnp.sum(touched, dtype=jnp.int32)
+
+    def weighed(out, c, wt):
+        return jnp.where(c[..., None], wt[..., None] * out.astype(jnp.float32),
+                         0.0)
+
+    def batched():
+        gate = jnp.einsum("nd,edf->enf", h2, lp["e_gate"].astype(dt))
+        up = jnp.einsum("nd,edf->enf", h2, lp["e_up"].astype(dt))
+        out = jnp.einsum("enf,efd->end", jax.nn.silu(gate) * up,
+                         lp["e_down"].astype(dt))
+        return jnp.sum(weighed(out, chosen, w), axis=0)
+
+    def looped():
+        # the touched experts' ids, compacted once: nothing is searched for
+        # inside the loop
+        ids = jnp.zeros((e,), jnp.int32).at[
+            jnp.where(touched, jnp.cumsum(touched) - 1, e)].set(
+                jnp.arange(e, dtype=jnp.int32), mode="drop")
+
+        def one_expert(i, y):
+            j = ids[i]
+            out = _swiglu(h2, lp["e_gate"][j], lp["e_up"][j], lp["e_down"][j],
+                          dt)
+            return y + weighed(out, chosen[j], w[j])
+
+        return lax.fori_loop(0, n_touched, one_expert,
+                             jnp.zeros((n, d), jnp.float32))
+
+    with jax.named_scope("moe.experts"):
+        y = lax.cond(_most_experts_touched(n_touched, e), batched, looped)
+    return y.astype(dt)
+
+
+def _experts_in_tiles(cfg, lp, h2, held, group, weights, load):
+    """The choices sorted by expert into tile-aligned segments, a loop over
+    the tiles in use (one expert's weights a tile), and each token's
+    outcomes gathered back and summed by rank."""
+    dt = cfg.dtype
+    n, d = h2.shape
+    e, k = cfg.held_count, cfg.top_k
+    with jax.named_scope("moe.route"):
+        tile = TILE_ROWS
         padded = -(-load // tile) * tile
         seg_end = jnp.cumsum(padded)
         seg_start = seg_end - padded
@@ -855,7 +974,7 @@ def held_experts(cfg: LatentMoEConfig, lp: dict, h2, valid):
             dest.reshape(n, k)]                                  # [N, k, d]
         y = jnp.sum(picked.astype(jnp.float32)
                     * jnp.where(held, weights, 0.0)[..., None], axis=1)
-    return y.astype(dt), load
+    return y.astype(dt)
 
 
 def _expert_layer(cfg, lp, h, valid):
@@ -897,7 +1016,7 @@ def _forward_paged(params, tokens, cfg: LatentMoEConfig,
     x = params["embed"][tokens].astype(dt)
     n_full = n_window = 0
     load = jnp.zeros((cfg.held_count,), jnp.int32)
-    touched = jnp.int32(0)
+    touched = batched = jnp.int32(0)
     for i, (kind, lp) in enumerate(zip(cfg.layer_kinds, params["layers"])):
         h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
         if kind == FULL:
@@ -921,6 +1040,7 @@ def _forward_paged(params, tokens, cfg: LatentMoEConfig,
             x = x + y
             load = load + layer_load
             touched = touched + jnp.sum(layer_load > 0, dtype=jnp.int32)
+            batched = batched + layers_batched(tokens.size, layer_load)
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = _dot(x, params["lm_head"], dt).astype(jnp.float32)
     n_valid = jnp.sum(valid, dtype=jnp.int32)
@@ -930,7 +1050,8 @@ def _forward_paged(params, tokens, cfg: LatentMoEConfig,
     n_moe = cfg.n_layers - cfg.first_dense
     add = jnp.concatenate([
         jnp.stack([n_valid * (cfg.top_k * n_moe), jnp.sum(load),
-                   seen * n_full, chosen * n_full, jnp.int32(0)]), load])
+                   seen * n_full, chosen * n_full, jnp.int32(0)]), load,
+        batched[None]])
     stats = _add_stats(pcache.stats, add, touched if set_touched else None)
     return logits, pcache._replace(
         latent=latent_f.reshape(pcache.latent.shape),

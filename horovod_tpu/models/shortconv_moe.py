@@ -81,7 +81,7 @@ from horovod_tpu.models.llama import rmsnorm
 
 CONV, ATTN = "conv", "attn"
 #: stats columns (``latent_moe``'s layout: running sums, the touched gauge at
-#: ``TOUCHED``, then the experts' load from ``LOAD0``)
+#: ``TOUCHED``, the experts' load from ``LOAD0``, last the layers batched)
 CHOICES_TOTAL, RESTORES, SNAPSHOTS, KEYS_VISIBLE = 0, 1, 2, 3
 
 
@@ -243,7 +243,7 @@ class ShortConvPagedCache(NamedTuple):
     (K - 1) * dim]``, each slot's state at its length; ``snap`` ``[n_conv,
     n_blocks, (K - 1) * dim]``, each full block's state at its last position;
     ``block_table`` [B, blocks_per_slot] int32, ``length`` [B] int32, and
-    ``stats`` [2, 5 + held_count] int32, the device-side counters."""
+    ``stats`` [2, 5 + held_count + 1] int32, the device-side counters."""
 
     k: jax.Array
     v: jax.Array
@@ -287,7 +287,7 @@ def init_paged_cache(
         snap=jnp.zeros((n_conv, n_blocks, cfg.state_width), cfg.dtype),
         block_table=jnp.zeros((n_slots, per), jnp.int32),
         length=jnp.zeros((n_slots,), jnp.int32),
-        stats=jnp.zeros((2, LOAD0 + cfg.held_count), jnp.int32))
+        stats=jnp.zeros((2, LOAD0 + cfg.held_count + 1), jnp.int32))
 
 
 def paged_pool_bytes(pcache: ShortConvPagedCache) -> dict:
@@ -312,7 +312,8 @@ def read_counters(stats_host: np.ndarray) -> dict:
             "snapshots_written": int(total[SNAPSHOTS]),
             "keys_visible": int(total[KEYS_VISIBLE]),
             "experts_touched": int(s[1, TOUCHED]),
-            "held_load": [int(x) for x in total[LOAD0:]]}
+            "held_load": [int(x) for x in total[LOAD0:-1]],
+            "layers_batched": int(total[-1])}
 
 
 def publish_paged_metrics(metrics, cfg: ShortConvMoEConfig,
@@ -326,7 +327,9 @@ def publish_paged_metrics(metrics, cfg: ShortConvMoEConfig,
     (``attn.blocks_*``, as :mod:`llama` counts them from ``programs``) and,
     where a tick's readback brought ``stats_host``, the device's counters:
     ``conv.state_restores`` counts rows mapped at a length past 0, which took
-    their state from a block's snapshot (:func:`set_row`)."""
+    their state from a block's snapshot (:func:`set_row`).
+    ``moe.choices_in_place`` is :func:`latent_moe.choices_in_place` of the
+    step's programs, not read back."""
     if stats_host is None and not programs:     # once, at construction
         per_block = paged_pool_bytes(pcache)
         metrics.gauge("kv.bytes_per_token").set(
@@ -339,6 +342,8 @@ def publish_paged_metrics(metrics, cfg: ShortConvMoEConfig,
         for _, read, _ in _counted(metrics):
             read.set(0)
     llama.publish_paged_metrics(metrics, cfg, pcache, programs=programs)
+    metrics.counter("moe.choices_in_place").inc(
+        latent_moe.choices_in_place(cfg, programs))
     if stats_host is None:          # nothing was read back: no tick ran
         return
     c = read_counters(stats_host)
@@ -358,6 +363,8 @@ def _counted(metrics) -> tuple:
     return (
         (metrics.counter("moe.choices_total"),
          metrics.gauge("moe.choices_total.device"), "choices_total"),
+        (metrics.counter("moe.layers_batched"),
+         metrics.gauge("moe.layers_batched.device"), "layers_batched"),
         (metrics.counter("conv.state_restores"),
          metrics.gauge("conv.state_restores.device"), "state_restores"),
         (metrics.counter("conv.snapshots_written"),
@@ -469,7 +476,7 @@ def _forward_paged(params, tokens, cfg: ShortConvMoEConfig,
     i_attn = i_conv = 0
     zs = []
     load = jnp.zeros((cfg.held_count,), jnp.int32)
-    touched = jnp.int32(0)
+    touched = batched = jnp.int32(0)
     for i, (kind, lp) in enumerate(zip(cfg.layer_kinds, params["layers"])):
         u = rmsnorm(x, lp["op_norm"], cfg.norm_eps)
         if kind == CONV:
@@ -492,6 +499,7 @@ def _forward_paged(params, tokens, cfg: ShortConvMoEConfig,
             x = x + y.reshape(b, t, cfg.dim)
             load = load + layer_load
             touched = touched + jnp.sum(layer_load > 0, dtype=jnp.int32)
+            batched = batched + latent_moe.layers_batched(b * t, layer_load)
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = jnp.einsum("btd,vd->btv", x, params["embed"].astype(dt),
                         preferred_element_type=jnp.float32)
@@ -500,7 +508,7 @@ def _forward_paged(params, tokens, cfg: ShortConvMoEConfig,
     n_moe = cfg.n_layers - cfg.first_dense
     add = jnp.concatenate([
         jnp.stack([n_valid * (cfg.top_k * n_moe), jnp.int32(0), jnp.int32(0),
-                   seen * n_attn, jnp.int32(0)]), load])
+                   seen * n_attn, jnp.int32(0)]), load, batched[None]])
     stats = _add_stats(pcache.stats, add, touched if set_touched else None)
     return logits, _Ran(kf.reshape(pcache.k.shape),
                         vf.reshape(pcache.v.shape), jnp.stack(zs), stats)

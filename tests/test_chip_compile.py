@@ -4,14 +4,21 @@ topology is described inside a fixture (never while a module is imported), in
 this one file, and the tests skip where it cannot be described."""
 
 import os
+import re
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 import pytest
+from jax import lax
 from jax.sharding import SingleDeviceSharding
 
+from horovod_tpu.models import latent_moe as lm
 from horovod_tpu.models import shortconv_moe as sm
+
+#: scratch of the third model's tick while its experts ran in sorted tiles
+#: (PR 31's program, compiled the same way)
+PARENT_TICK_TEMP_BYTES = 161_400_000
 
 
 @pytest.fixture(scope="module")
@@ -44,13 +51,11 @@ def _avals(tree, sharding):
         x.shape, x.dtype, sharding=sharding), tree)
 
 
-@pytest.mark.parametrize("program", ["tick", "chunk"])
-def test_shortconv_programs_hold_no_second_pool(one_chip, no_compile_cache,
-                                                program):
-    """The cell's own size: 128 slots of 2048 over 1,041 blocks of 256.  With
-    key rows of 64 lanes every attention layer's scatter copied the pool
-    twice (5.8 GB of scratch); packed to 128 (``kv_pack``) a program's
-    scratch is a fraction of one pool."""
+@pytest.fixture(scope="module")
+def shortconv_compiled(one_chip, no_compile_cache):
+    """``compiled(program)`` of the third model's tick and chunk at the
+    cell's own size (128 slots of 2048 over 1,041 blocks of 256), each
+    compiled once, and the cache's shapes."""
     cfg = sm.ShortConvMoEConfig()
     n_slots = 128
     assert cfg.kv_pack == 2
@@ -75,18 +80,151 @@ def test_shortconv_programs_hold_no_second_pool(one_chip, no_compile_cache,
                                                 slot, new_length=new_len)
         return pcache, last_logits.at[slot].set(out[0, sel])
 
-    if program == "tick":
-        args = (params, cache, logits, jax.ShapeDtypeStruct(
-            (n_slots,), jnp.int32, sharding=one_chip))
-        compiled = tick.lower(*args).compile()
-    else:
-        args = (params, cache, logits, jax.ShapeDtypeStruct(
-            (1, 256), jnp.int32, sharding=one_chip), i32, i32, i32)
-        compiled = chunk.lower(*args).compile()
-    mem = compiled.memory_analysis()
+    lowered = {
+        "tick": lambda: tick.lower(params, cache, logits, jax.ShapeDtypeStruct(
+            (n_slots,), jnp.int32, sharding=one_chip)),
+        "chunk": lambda: chunk.lower(
+            params, cache, logits, jax.ShapeDtypeStruct(
+                (1, 256), jnp.int32, sharding=one_chip), i32, i32, i32)}
+    done = {}
+
+    def compiled(program):
+        if program not in done:
+            done[program] = lowered[program]().compile()
+        return done[program]
+
+    return compiled, cache
+
+
+@pytest.mark.parametrize("program", ["tick", "chunk"])
+def test_shortconv_programs_hold_no_second_pool(shortconv_compiled, program):
+    """With key rows of 64 lanes every attention layer's scatter copied the
+    pool twice (5.8 GB of scratch); packed to 128 (``kv_pack``) a program's
+    scratch is a fraction of one pool."""
+    compiled, cache = shortconv_compiled
+    mem = compiled(program).memory_analysis()
     pool = cache.k.size * cache.k.dtype.itemsize
     assert pool == 818_675_712
     assert mem.temp_size_in_bytes < pool // 2, mem.temp_size_in_bytes
     # weights and cache are arguments held once, the cache aliased in place
     assert mem.alias_size_in_bytes >= 2 * pool
     assert mem.argument_size_in_bytes < 11.2e9
+
+
+def _computations(hlo: str) -> dict:
+    """Each computation of an optimised HLO module's text by name."""
+    out, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            out[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            out[name].append(line)
+    return {k: "\n".join(v) for k, v in out.items()}
+
+
+def _reaches_a_while(comps: dict, name: str, seen: set) -> bool:
+    """Whether computation ``name``, or one it calls, holds a ``while``."""
+    if name in seen:
+        return False
+    seen.add(name)
+    body = comps[name]
+    return " while(" in body or any(
+        _reaches_a_while(comps, ref, seen)
+        for ref in re.findall(r"%([\w.\-]+)", body) if ref in comps)
+
+
+def test_the_shortconv_tick_computes_its_experts_in_place(shortconv_compiled):
+    """A tick of 128 rows is within :data:`latent_moe.IN_PLACE_ROWS`: its
+    twelve expert layers sort, gather and scatter no choice, so no loop
+    holds a second loop (the tiles' loop searched its expert with one), the
+    only sorts left are the router's ``top_k`` and the scratch is no larger
+    than the sorted tiles' was."""
+    cfg = sm.ShortConvMoEConfig()
+    assert lm.rows_in_place(128)
+    compiled, _ = shortconv_compiled
+    hlo = compiled("tick").as_text()
+    comps = _computations(hlo)
+    bodies = re.findall(r" while\(.*?body=%([\w.\-]+)", hlo)
+    assert bodies and all(b in comps for b in bodies)
+    nested = [b for b in bodies if _reaches_a_while(comps, b, set())]
+    assert not nested, nested
+    sorts = [line for line in hlo.splitlines() if " sort(" in line]
+    assert len(sorts) == cfg.n_layers - cfg.first_dense
+    assert all("moe.route/top_k" in line for line in sorts)
+    assert compiled("tick").memory_analysis().temp_size_in_bytes \
+        <= PARENT_TICK_TEMP_BYTES
+
+
+def _held_experts_in_tiles_only(cfg, lp, h2, valid):
+    """``latent_moe.held_experts`` as it was before any program computed in
+    place (PR 31), kept to compare lowerings with."""
+    dt = cfg.dtype
+    n, d = h2.shape
+    e, k = cfg.held_count, cfg.top_k
+    experts, weights = lm.route(cfg, lp, h2)
+    local = experts - cfg.held_first
+    held = (local >= 0) & (local < e) & valid[:, None]
+    group = jnp.where(held, local, e).reshape(n * k)
+    load = jnp.sum(jax.nn.one_hot(group, e + 1, dtype=jnp.int32),
+                   axis=0)[:e]
+    tile = lm.TILE_ROWS
+    padded = -(-load // tile) * tile
+    seg_end = jnp.cumsum(padded)
+    seg_start = seg_end - padded
+    order = jnp.argsort(group, stable=True)
+    place = jnp.zeros((n * k,), jnp.int32).at[order].set(
+        jnp.arange(n * k, dtype=jnp.int32))
+    before = jnp.cumsum(load) - load
+    g = jnp.minimum(group, e - 1)
+    rows = n * k + e * tile
+    dest = jnp.where(group < e, seg_start[g] + place - before[g], rows)
+    token = jnp.arange(n * k, dtype=jnp.int32) // k
+    src = jnp.full((rows,), n, jnp.int32).at[dest].set(token, mode="drop")
+    x_rows = jnp.concatenate([h2, jnp.zeros((1, d), dt)])[src]
+    n_tiles = seg_end[-1] // tile
+
+    def one_tile(i, y):
+        j = jnp.searchsorted(seg_end, i * tile, side="right")
+        x = lax.dynamic_slice_in_dim(x_rows, i * tile, tile)
+        out = lm._swiglu(x, lp["e_gate"][j], lp["e_up"][j], lp["e_down"][j],
+                         dt)
+        return lax.dynamic_update_slice_in_dim(y, out, i * tile, axis=0)
+
+    y_rows = lax.fori_loop(0, n_tiles, one_tile, jnp.zeros((rows, d), dt))
+    picked = jnp.concatenate([y_rows, jnp.zeros((1, d), dt)])[
+        dest.reshape(n, k)]
+    y = jnp.sum(picked.astype(jnp.float32)
+                * jnp.where(held, weights, 0.0)[..., None], axis=1)
+    return y.astype(dt), load
+
+
+def test_a_chunk_of_many_rows_lowers_to_the_program_it_was(monkeypatch):
+    """The second model's chunk of 512 rows, at the published widths, is
+    over :data:`latent_moe.IN_PLACE_ROWS`: letter for letter the program it
+    was when every program sorted its choices into tiles.  (Lowered for the
+    host; nothing is compiled.)"""
+    cfg = lm.LatentMoEConfig()
+    assert not lm.rows_in_place(512)
+    params = jax.eval_shape(lambda: lm.init_params(cfg, jax.random.key(0)))
+    cache = jax.eval_shape(lambda: lm.init_paged_cache(
+        cfg, 8, 32768, block_size=512))
+    logits = jax.ShapeDtypeStruct((8, cfg.vocab_size), jnp.float32)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32)
+    args = (params, cache, logits,
+            jax.ShapeDtypeStruct((1, 512), jnp.int32), i32, i32, i32)
+
+    def lowered():
+        @partial(jax.jit, donate_argnums=(1, 2))
+        def chunk(params, pcache, last_logits, toks, slot, new_len, sel):
+            out, pcache = lm.decode_chunk_paged_row(
+                params, toks, cfg, pcache, slot, new_length=new_len)
+            return pcache, last_logits.at[slot].set(out[0, sel])
+        return chunk.lower(*args).as_text()
+
+    now = lowered()
+    monkeypatch.setattr(lm, "held_experts", _held_experts_in_tiles_only)
+    assert now == lowered()

@@ -238,11 +238,14 @@ def test_long_computations_taken_in_steps_equal_the_reference(monkeypatch):
     """At real sizes the indexer scores a few blocks of keys at a time and no
     further than the rows reach, the top-k sorts the shortest width that
     holds the visible keys, the selected latents are gathered a block of
-    queries at a time, and an expert's tile has 128 rows: here the same code
-    with steps small enough for the tiny preset to take several."""
+    queries at a time, and a program of more than 256 rows sorts its choices
+    into tiles of 128: here the same code with steps small enough for the
+    tiny preset to take several (chunks of 8 rows in tiles of 4, ticks in
+    place)."""
     monkeypatch.setattr(lm, "INDEX_STEP_KEYS", 8)
     monkeypatch.setattr(lm, "QUERY_BLOCK", 4)
-    monkeypatch.setattr(lm, "TILE_ROWS", (2, 4))
+    monkeypatch.setattr(lm, "TILE_ROWS", 4)
+    monkeypatch.setattr(lm, "IN_PLACE_ROWS", 4)
     cfg, mc, params = tiny()
     seq = tokens(70, seed=7)
     got, _ = _serve_by_hand(mc, params, seq, n_prompt=61, chunk=8,
@@ -318,6 +321,141 @@ def test_bfloat16_stays_near_float32_but_for_flipped_choices():
     assert np.median(err) < 0.08, np.median(err)
     assert np.mean(err < 0.3) >= 0.75, np.sort(err)[::-1][:8]
     assert np.isfinite(got).all()
+
+
+def _dense_experts(mc, lp, h2, valid):
+    """The held experts' part of the layer, token by token and choice by
+    choice in float64, from the layer's own choices."""
+    experts, weights = (np.asarray(a) for a in lm.route(mc, lp, h2))
+    gate, up, down = (np.asarray(lp[k], np.float64)
+                      for k in ("e_gate", "e_up", "e_down"))
+    x = np.asarray(h2, np.float64)
+    y = np.zeros(x.shape)
+    load = np.zeros((mc.held_count,), np.int64)
+    for i in np.flatnonzero(np.asarray(valid)):
+        for e, w in zip(experts[i] - mc.held_first, weights[i]):
+            if 0 <= e < mc.held_count:
+                g = x[i] @ gate[e]
+                y[i] += w * ((g / (1 + np.exp(-g)) * (x[i] @ up[e])) @ down[e])
+                load[e] += 1
+    return y, load
+
+
+def _forms_run(monkeypatch):
+    """``(form, rows)`` of every expert layer :func:`lm.held_experts` lays
+    out from here on, in order."""
+    ran = []
+    for name in ("_experts_in_place", "_experts_in_tiles"):
+        def spy(*a, _name=name, _form=getattr(lm, name)):
+            ran.append((_name, a[2].shape[0]))
+            return _form(*a)
+        monkeypatch.setattr(lm, name, spy)
+    return ran
+
+
+#: rows, the valid rows, the held range's first expert, a bias that steers
+#: every row's choice (None: the seeded one), and what the case is
+IN_PLACE_CASES = {
+    "every_row_valid": (16, "all", 0, None),
+    "some_rows_idle": (16, "some", 0, None),
+    "one_row_live": (16, "one", 0, None),
+    "all_rows_choose_the_same_held_experts": (16, "all", 0, (0, 1, 2, 3)),
+    "no_held_expert_chosen": (16, "all", 0, (8, 9, 10, 11)),
+    "held_range_is_a_strict_subset": (16, "some", 4, None),
+    "held_subset_chosen_by_all": (16, "all", 4, (2, 3, 4, 5)),
+    "rows_at_the_threshold": (lm.IN_PLACE_ROWS, "some", 0, None),
+    "rows_over_the_threshold": (lm.IN_PLACE_ROWS + 8, "some", 0, None),
+    "one_row_under_the_threshold": (1, "all", 0, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(IN_PLACE_CASES))
+def test_experts_in_place_equal_the_sorted_tiles_and_the_dense_sum(
+        monkeypatch, case):
+    """A program of at most ``IN_PLACE_ROWS`` rows computes its experts over
+    the rows where they stand, all of them at once where most are touched
+    and one touched expert a step where few are; over the threshold the
+    choices are sorted into tiles as before.  Each form is the dense sum of
+    every held choice, and idle rows come out exactly zero."""
+    n, live, first, steer = IN_PLACE_CASES[case]
+    threshold = lm.IN_PLACE_ROWS
+    mc = lm.latent_moe_tiny(held_first=first)
+    lp = dict(lm.init_params(mc, jax.random.key(SEED))["layers"][1])
+    if steer is not None:
+        lp["router_bias"] = jnp.zeros((mc.n_experts,)).at[
+            jnp.asarray(steer)].set(100.0)
+    h2 = jax.random.normal(jax.random.key(n), (n, mc.dim), jnp.float32)
+    valid = {"all": np.ones((n,), bool), "some": np.arange(n) % 3 != 1,
+             "one": np.arange(n) == n // 2}[live]
+    ran = _forms_run(monkeypatch)
+    y, load = lm.held_experts(mc, lp, h2, jnp.asarray(valid))
+    assert ran == [("_experts_in_place" if n <= threshold
+                    else "_experts_in_tiles", n)]
+    want, want_load = _dense_experts(mc, lp, h2, valid)
+    np.testing.assert_allclose(np.asarray(y), want, atol=1e-5, rtol=0)
+    assert (np.asarray(load) == want_load).all()
+    assert (np.asarray(y)[~valid] == 0).all()
+    if steer is not None:
+        held = [e - first for e in steer if 0 <= e - first < mc.held_count]
+        assert want_load[held].tolist() == [n] * len(held)
+        assert want_load.sum() == n * len(held)
+    # the other forms: the tiles whatever the rows, and in place each branch
+    monkeypatch.setattr(lm, "IN_PLACE_ROWS", 0)
+    tiles, tiles_load = lm.held_experts(mc, lp, h2, jnp.asarray(valid))
+    assert ran[-1][0] == "_experts_in_tiles"
+    np.testing.assert_allclose(np.asarray(tiles), want, atol=1e-5, rtol=0)
+    assert (np.asarray(tiles_load) == want_load).all()
+    if n <= threshold:
+        for batched in (True, False):
+            monkeypatch.setattr(lm, "_most_experts_touched",
+                                lambda n_touched, e, b=batched: b)
+            monkeypatch.setattr(lm, "IN_PLACE_ROWS", n)
+            got, _ = lm.held_experts(mc, lp, h2, jnp.asarray(valid))
+            assert ran[-1][0] == "_experts_in_place"
+            np.testing.assert_allclose(np.asarray(got), want, atol=1e-5,
+                                       rtol=0)
+            assert (np.asarray(got)[~valid] == 0).all()
+
+
+def test_the_touched_experts_pick_the_form_in_place():
+    """Few touched experts are walked one a step (none that no row chose is
+    read), most of them are computed at once: the count decides inside the
+    program."""
+    mc = lm.latent_moe_tiny()
+    lp = dict(lm.init_params(mc, jax.random.key(SEED))["layers"][1])
+    h2 = jax.random.normal(jax.random.key(3), (16, mc.dim), jnp.float32)
+    valid = jnp.ones((16,), bool)
+    _, spread = lm.held_experts(mc, lp, h2, valid)
+    few = dict(lp, router_bias=jnp.zeros((mc.n_experts,)).at[
+        jnp.asarray([0, 1, 8, 9])].set(100.0))
+    _, narrow = lm.held_experts(mc, few, h2, valid)
+    touched = lambda load: int((np.asarray(load) > 0).sum())  # noqa: E731
+    assert touched(narrow) == 2 and touched(spread) >= 6    # of 8 held
+    assert not lm._most_experts_touched(touched(narrow), mc.held_count)
+    assert lm._most_experts_touched(touched(spread), mc.held_count)
+
+
+@pytest.mark.parametrize("poison", [np.inf, np.nan])
+def test_an_idle_row_that_is_not_finite_spoils_no_live_row(poison):
+    """An idle row may hold anything (a slot's stale state): its outcome is
+    selected away, not multiplied by zero, whichever form runs."""
+    mc = lm.latent_moe_tiny()
+    lp = lm.init_params(mc, jax.random.key(SEED))["layers"][1]
+    h2 = jax.random.normal(jax.random.key(4), (16, mc.dim), jnp.float32)
+    valid = np.arange(16) % 4 != 2
+    clean = jnp.where(valid[:, None], h2, 0.0)
+    bad = jnp.where(valid[:, None], h2, poison)
+    for batched in (True, False):
+        form = jax.jit(functools.partial(lm.held_experts, mc))
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(lm, "_most_experts_touched",
+                       lambda n_touched, e, b=batched: b)
+            want, want_load = form(lp, clean, jnp.asarray(valid))
+            got, got_load = form(lp, bad, jnp.asarray(valid))
+        assert np.abs(np.asarray(want)[valid]).min() > 0
+        assert (np.asarray(got) == np.asarray(want)).all()
+        assert (np.asarray(got)[~valid] == 0).all()
+        assert (np.asarray(got_load) == np.asarray(want_load)).all()
 
 
 def test_the_eight_shares_add_up_to_the_uncut_layer_and_head():
@@ -452,6 +590,42 @@ def test_ticks_alone_count_no_query_under_the_mask(monkeypatch, served):
     assert after["dsa.mask_queries"] == before["dsa.mask_queries"]
     assert after["dsa.queries"] - before["dsa.queries"] \
         == 2 * 2 * (len(programs) - n)
+
+
+@pytest.mark.parametrize("threshold", [0, 8, 256])
+def test_choices_in_place_are_those_of_the_programs_of_few_rows(
+        monkeypatch, served, threshold):
+    """Chunks of 16 rows and ticks of 2: with the threshold between them the
+    ticks alone compute their experts in place, at 256 (the module's own)
+    every program does and at 0 none; ``moe.choices_in_place`` says so from
+    the dispatched programs' rows, the tokens and ``moe.choices_total`` are
+    the same under each, and the device's count of the layers that took
+    every expert at once stays within the layers in place."""
+    mc, params, prompts, want = served
+    monkeypatch.setattr(lm, "IN_PLACE_ROWS", threshold)
+    programs = _dispatched(monkeypatch)
+    forms = _forms_run(monkeypatch)
+    eng = _engine(mc, params, chunk=16)
+    out = eng.run([Request(prompt=p, max_new_tokens=9) for p in prompts])
+    assert [list(r) for r in out] == want
+    # 4 expert layers a program, traced once each: set_row has none
+    assert sorted(set(forms)) == sorted(
+        ("_experts_in_place" if rows <= threshold else "_experts_in_tiles",
+         rows) for rows in (2, 16))
+    c = eng.metrics_snapshot()["counters"]
+    assert {(rows, t) for rows, t, _ in programs} == {(1, 16), (2, 1)}
+    ticks = sum(1 for rows, t, _ in programs if t == 1)
+    chunks = len(programs) - ticks
+    assert c["moe.choices_in_place"] == 4 * 4 * (
+        2 * ticks * (2 <= threshold) + 16 * chunks * (16 <= threshold))
+    assert c["moe.choices_in_place"] == lm.choices_in_place(mc, programs)
+    assert c["moe.layers_batched"] <= 4 * (
+        ticks * (2 <= threshold) + chunks * (16 <= threshold))
+    # 16 rows x top-4 over 8 held of 16 experts touch most of them
+    if threshold != 8:
+        assert (c["moe.layers_batched"] > 0) == (threshold == 256)
+    assert c["moe.choices_total"] == 4 * 4 * sum(
+        len(p) + 9 for p in prompts)
 
 
 def test_prefix_cache_hit_serves_the_same_tokens(served):

@@ -204,3 +204,41 @@ def test_counters_equal_what_the_run_did(served):
         c["moe.choices_total"]
     assert 0 < gauges["moe.experts_touched"] <= 5 * 2
     assert c["attn.blocks_visited"] == c["attn.blocks_in_table"] > 0
+
+
+@pytest.mark.parametrize("threshold", [0, 4, 256])
+def test_choices_in_place_are_those_of_the_programs_of_few_rows(
+        monkeypatch, served, threshold):
+    """Chunks of 8 rows and ticks of 2: with the threshold between them the
+    ticks alone compute their experts in place
+    (:func:`latent_moe.rows_in_place`), at 256 (the module's own) every
+    program and at 0 none; ``moe.choices_in_place`` is reckoned from the
+    dispatched programs' rows, and the tokens and ``moe.choices_total`` are
+    the same under each."""
+    from horovod_tpu.models import latent_moe as lm
+
+    mc, params, prompts, want = served
+    monkeypatch.setattr(lm, "IN_PLACE_ROWS", threshold)
+    programs, publish = [], sm.publish_paged_metrics
+
+    def spy(metrics, cfg, pcache, stats_host=None, row_blocks=(),
+            programs_=()):
+        programs.extend(programs_)
+        return publish(metrics, cfg, pcache, stats_host, row_blocks, programs_)
+
+    monkeypatch.setattr(sm, "publish_paged_metrics", spy)
+    eng = _engine(mc, params)
+    out = eng.run(_requests(prompts[:2]))
+    assert [list(r) for r in out] == want[:2]
+    assert {(rows, t) for rows, t, _ in programs} == {(1, 8), (2, 1)}
+    ticks = sum(1 for rows, t, _ in programs if t == 1)
+    chunks = len(programs) - ticks
+    c = _counters(eng)
+    assert c["moe.choices_in_place"] == mc.top_k * 5 * (
+        2 * ticks * (2 <= threshold) + 8 * chunks * (8 <= threshold))
+    assert c["moe.choices_in_place"] == lm.choices_in_place(mc, programs)
+    assert c["moe.layers_batched"] <= 5 * (
+        ticks * (2 <= threshold) + chunks * (8 <= threshold))
+    assert (c["moe.layers_batched"] > 0) == (threshold == 256)
+    assert c["moe.choices_total"] == mc.top_k * 5 * sum(
+        len(p) + N_NEW for p in prompts[:2])
