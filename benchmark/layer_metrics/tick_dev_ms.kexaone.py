@@ -1,0 +1,7 @@
+"""``tick_dev_ms``: device time of one run of the decode tick program."""
+
+from benchmark import serve_stats
+
+
+def read(rec: dict):
+    return serve_stats.program_ms(rec, "_tick")

@@ -245,8 +245,8 @@ METRIC_HELP: dict[str, str] = {
     # device-side counters (read back with the tick's tokens; beside each
     # counter a gauge <name>.device, the device's own total as last read)
     "kv.bytes_per_token": "Device bytes of keys and values one cached position holds (attention layers only)",
-    "kv.snapshot_block_bytes": "Device bytes of one block's snapshot of the recurrent state (all conv layers)",
-    "state.bytes_per_slot": "Device bytes of recurrent state one sequence carries (all conv layers)",
+    "kv.snapshot_block_bytes": "Device bytes of one block's snapshot of the per-sequence state (all conv layers; all sliding layers' rings)",
+    "state.bytes_per_slot": "Device bytes of per-sequence state one slot carries (all conv layers; the sliding layers' ring of the last window keys and values)",
     "conv.state_restores": "Rows mapped at a length past 0: their recurrent state came from a block's snapshot (prefix hits, replays)",
     "conv.snapshots_written": "Blocks whose last position a program's counted tokens reached (each got its snapshot)",
     "attn.keys_visible": "Cached keys the attention layers' queries saw (summed over queries and attention layers)",
@@ -255,6 +255,18 @@ METRIC_HELP: dict[str, str] = {
     "conv.state_restores.device": "This engine's device-side total of conv.state_restores as last read",
     "conv.snapshots_written.device": "This engine's device-side total of conv.snapshots_written as last read",
     "attn.keys_visible.device": "This engine's device-side total of attn.keys_visible as last read",
+    # models/window_moe.py — the full layers' pools, the sliding layers'
+    # ring per slot and its snapshot per block, what the live rows hold,
+    # and the device-side counters (as above: <name>.device beside each)
+    "moe.load_max": "Token-choices of the busiest held expert since start",
+    "kv.tokens_live": "Positions the slots hold, as the last decode tick left them",
+    "kv.full_bytes_live": "Pool bytes (keys and values of the full layers) of the blocks the live rows' tables map",
+    "kv.window_bytes_live": "Bytes the live rows hold for their sliding layers: a ring a row and a snapshot a mapped block, whatever their lengths",
+    "window.state_restores": "Rows mapped at a length past 0: their sliding layers' ring came from a block's snapshot (prefix hits, replays)",
+    "window.snapshots_written": "Blocks whose last position a program's counted tokens reached (each got its snapshot of the ring)",
+    "moe.choices_held.device": "This engine's device-side total of moe.choices_held as last read",
+    "window.state_restores.device": "This engine's device-side total of window.state_restores as last read",
+    "window.snapshots_written.device": "This engine's device-side total of window.snapshots_written as last read",
     # mem.* — host-side observability footprint (approximate)
     "mem.registry_bytes": "Approximate host bytes held by the metrics registry",
     "mem.trace_ring_bytes": "Approximate host bytes of live traces + the SLO ring",

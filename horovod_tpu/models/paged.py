@@ -35,8 +35,9 @@ The engine names no model.  A model is a module with these functions, which
 immutable once written, which is why a block can be shared, cached and
 replayed into by table writes alone.  A model may keep, beside them, state
 that is *per sequence*: a fixed-size recurrent state a slot, carried by the
-tick and by a row's prefill chunks (``shortconv_moe``'s convolution inputs).
-Such a model gives the interface one more, optional function:
+tick and by a row's prefill chunks (``shortconv_moe``'s convolution inputs;
+``window_moe``'s ring of the last ``window`` keys and values of its sliding
+layers, which need nothing older).  Such a model gives the interface one more, optional function:
 
 * ``set_row(pcache, slot, row, length)`` — the whole of the engine's table
   write (``block_table[slot] = row``, ``length[slot] = length``) and what the
@@ -49,7 +50,13 @@ preemption with replay and a cloned engine ignorant of that state is the
 **snapshot rule**: the model keeps, per physical block and under the block's
 id, the state at the block's last position; whichever program's counted
 tokens reach a block's last position writes it; ``set_row`` at a length past
-0 restores the slot's state from the block that ends there.
+0 restores the slot's state from the block that ends there.  What the rule's
+users share is here: :func:`block_before` (whose snapshot a row mapped at a
+length takes), :func:`block_ends` (the blocks a program's counted tokens
+fill), and of their counters :func:`read_stats` (the device's ``stats`` as
+Python ints), :func:`count_from_device` (device-side sums under counters of a
+registry that may outlive the engine) and :func:`publish_state_metrics` (what
+both publish alike).
 """
 
 from __future__ import annotations
@@ -57,10 +64,14 @@ from __future__ import annotations
 from types import ModuleType
 from typing import Any
 
+import jax.numpy as jnp
+import numpy as np
+
 
 def paged_model(cfg: Any) -> ModuleType:
     """The model module for a config object, by the config's type."""
-    from horovod_tpu.models import latent_moe, llama, shortconv_moe
+    from horovod_tpu.models import (latent_moe, llama, shortconv_moe,
+                                    window_moe)
 
     if isinstance(cfg, llama.LlamaConfig):
         return llama
@@ -68,6 +79,101 @@ def paged_model(cfg: Any) -> ModuleType:
         return latent_moe
     if isinstance(cfg, shortconv_moe.ShortConvMoEConfig):
         return shortconv_moe
+    if isinstance(cfg, window_moe.WindowMoEConfig):
+        return window_moe
     raise TypeError(
-        f"ServeEngine serves a LlamaConfig, a LatentMoEConfig or a "
-        f"ShortConvMoEConfig, not a {type(cfg).__name__}")
+        f"ServeEngine serves a LlamaConfig, a LatentMoEConfig, a "
+        f"ShortConvMoEConfig or a WindowMoEConfig, not a "
+        f"{type(cfg).__name__}")
+
+
+def block_before(row, length, bs: int):
+    """The block of table ``row`` that ends at ``length`` (a whole number of
+    blocks): the one whose snapshot a row mapped there takes.  At 0 the
+    table's first, which the caller does not use."""
+    return row[jnp.maximum(length // bs - 1, 0)]
+
+
+def block_ends(pos, n, t: int, table, bs: int, n_blocks: int) -> tuple:
+    """The block ends a program of ``t`` tokens a row can reach, at most
+    ``ceil(t / bs)`` a row: ``(j, reached, dest)``, each [B, ends].  ``j`` is
+    the end's place among the row's tokens (its rows started at ``pos`` [B]
+    under ``table``), ``reached`` whether it is among the ``n`` [B] that
+    count, ``dest`` the physical block it fills, ``n_blocks`` (past the pool:
+    a scatter drops it) where it is not reached."""
+    per = table.shape[1]
+    first_end = (pos // bs + 1) * bs - 1                         # [B]
+    end_pos = first_end[:, None] + bs * jnp.arange(-(-t // bs))[None, :]
+    j = end_pos - pos[:, None]
+    reached = j < n[:, None]
+    blk = jnp.take_along_axis(table, jnp.clip(end_pos // bs, 0, per - 1),
+                              axis=1)
+    return j, reached, jnp.where(reached, blk, n_blocks)
+
+
+def count_from_device(counted: tuple, totals: dict) -> None:
+    """Move each registry counter by what the device's running sum gained
+    since it was last read.  ``counted`` holds ``(counter, gauge, key)``: the
+    gauge (``<name>.device``) keeps this engine's device total as last read,
+    so a registry that outlives an engine (``supervisor.clone_engine``, whose
+    clone counts from zero) keeps counting; ``totals[key]`` is the total
+    now."""
+    for counter, read, key in counted:
+        counter.inc(totals[key] - int(read.value))
+        read.set(totals[key])
+
+
+def read_stats(stats_host, head: tuple, tail: tuple) -> dict:
+    """A ``stats`` array of :mod:`latent_moe`'s layout as Python ints (sums
+    exact past 2**31): the running sums that ``head`` names from column 0 on
+    and ``tail`` from the end back, the touched gauge (``experts_touched``)
+    and, between the two, the held experts' load (``held_load``)."""
+    from horovod_tpu.models import latent_moe
+
+    s = np.asarray(stats_host).astype(np.int64)
+    total = (s[0] << latent_moe._LO_BITS) + s[1]
+    cut = len(total) - len(tail)
+    out = {name: int(x) for name, x in zip(head, total)}
+    out["experts_touched"] = int(s[1, latent_moe.TOUCHED])
+    out["held_load"] = [int(x) for x in total[latent_moe.LOAD0:cut]]
+    out.update((name, int(x)) for name, x in zip(tail, total[cut:]))
+    return out
+
+
+def publish_state_metrics(metrics, cfg: Any, pcache: Any, stats_host,
+                          programs: tuple, *, per_block: dict,
+                          slot_bytes: int, counted: tuple,
+                          read) -> dict | None:
+    """What the snapshot rule's users publish alike, for their
+    ``publish_paged_metrics``.  At construction (no ``stats_host``, no
+    ``programs``) what a cached token, a block's snapshot and a slot's state
+    hold, and the ``.device`` gauges of ``counted`` at zero (a registry may
+    outlive an engine, ``supervisor.clone_engine``: this engine's device
+    counts from zero); after a step the share of the tables attention walked
+    (``attn.blocks_*``, as :mod:`llama` counts them from ``programs``) and
+    ``moe.choices_in_place`` (:func:`latent_moe.choices_in_place` of the
+    step's programs, not read back); where a tick's readback brought
+    ``stats_host``, the device's counters (``read(stats_host)``, the
+    model's ``read_counters``) under ``counted``, the experts touched and
+    each held expert's load.  Returns the counters read, ``None`` where no
+    tick ran."""
+    from horovod_tpu.models import latent_moe, llama
+
+    if stats_host is None and not programs:     # once, at construction
+        metrics.gauge("kv.bytes_per_token").set(
+            (per_block["k"] + per_block["v"]) // pcache.block_size)
+        metrics.gauge("kv.snapshot_block_bytes").set(per_block["snap"])
+        metrics.gauge("state.bytes_per_slot").set(slot_bytes)
+        for _, device_total, _ in counted:
+            device_total.set(0)
+    llama.publish_paged_metrics(metrics, cfg, pcache, programs=programs)
+    metrics.counter("moe.choices_in_place").inc(
+        latent_moe.choices_in_place(cfg, programs))
+    if stats_host is None:          # nothing was read back: no tick ran
+        return None
+    c = read(stats_host)
+    count_from_device(counted, c)
+    metrics.gauge("moe.experts_touched").set(c["experts_touched"])
+    for e, n in enumerate(c["held_load"]):
+        metrics.gauge(f"moe.held_load.{cfg.held_first + e}").set(n)
+    return c

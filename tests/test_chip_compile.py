@@ -228,3 +228,111 @@ def test_a_chunk_of_many_rows_lowers_to_the_program_it_was(monkeypatch):
     now = lowered()
     monkeypatch.setattr(lm, "held_experts", _held_experts_in_tiles_only)
     assert now == lowered()
+
+
+#: what one v5e chip leaves a program: 15.75 GiB less the runtime's 258 MiB
+V5E_USABLE_BYTES = (15.75 * 1024 - 258) * 2**20
+
+
+def _mixedq_engine() -> dict:
+    """The engine of the one cell that serves the fourth model, from its
+    traffic file: the size is written down there alone."""
+    import json
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "traffic",
+            "mixedq.json")) as f:
+        return json.load(f)["engine"]
+
+
+@pytest.fixture(scope="module")
+def window_compiled(one_chip, no_compile_cache):
+    """``compiled(program)`` of the fourth model's tick, chunk and table write
+    at the cell's own size (``kexaone_mixedq``: 64 slots of 32,768 over
+    blocks of 1,024, the chunk's size), each compiled once, and the cache's
+    shapes."""
+    from horovod_tpu.models import window_moe as wm
+
+    cfg = wm.WindowMoEConfig()
+    e = _mixedq_engine()
+    n_slots, chunk_len = e["n_slots"], e["chunk"]
+    params = _avals(jax.eval_shape(
+        lambda: wm.init_params(cfg, jax.random.key(0))), one_chip)
+    cache = _avals(jax.eval_shape(lambda: wm.init_paged_cache(
+        cfg, n_slots, e["max_len"], block_size=chunk_len,
+        n_blocks=e["n_blocks"])), one_chip)
+    logits = jax.ShapeDtypeStruct((n_slots, cfg.vocab_size), jnp.float32,
+                                  sharding=one_chip)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+
+    @partial(jax.jit, donate_argnums=(1, 2))
+    def tick(params, pcache, last_logits, active):
+        tok = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
+        out, pcache = wm.decode_chunk_paged(params, tok[:, None], cfg, pcache,
+                                            advance=active)
+        return tok, out[:, 0], pcache
+
+    @partial(jax.jit, donate_argnums=(1, 2))
+    def chunk(params, pcache, last_logits, toks, slot, new_len, sel):
+        out, pcache = wm.decode_chunk_paged_row(params, toks, cfg, pcache,
+                                                slot, new_length=new_len)
+        return pcache, last_logits.at[slot].set(out[0, sel])
+
+    set_row = jax.jit(wm.set_row, donate_argnums=(0,))
+    lowered = {
+        "tick": lambda: tick.lower(params, cache, logits, jax.ShapeDtypeStruct(
+            (n_slots,), jnp.int32, sharding=one_chip)),
+        "chunk": lambda: chunk.lower(
+            params, cache, logits, jax.ShapeDtypeStruct(
+                (1, chunk_len), jnp.int32, sharding=one_chip), i32, i32, i32),
+        "set_row": lambda: set_row.lower(cache, i32, jax.ShapeDtypeStruct(
+            (e["max_len"] // chunk_len,), jnp.int32, sharding=one_chip),
+            i32)}
+    done = {}
+
+    def compiled(program):
+        if program not in done:
+            done[program] = lowered[program]().compile()
+        return done[program]
+
+    return compiled, cache
+
+
+@pytest.mark.parametrize("program", ["tick", "chunk", "set_row"])
+def test_window_programs_fit_the_chip_and_hold_no_second_pool(
+        window_compiled, program):
+    """11.96 GB of weights, the pools with their snapshots and the rings are
+    held once and written in place, and the programs' scratch beside them
+    leaves 0.3 GB of the chip: the full layers walk blocks of 1,024 in pieces
+    of 256 (``window_moe.GATHER_ROWS``; gathered whole, each step of the walk
+    copied the keys' pool), and a block's snapshot is written in a loop over
+    the ends reached, not gathered a row."""
+    compiled, cache = window_compiled
+    mem = compiled(program).memory_analysis()
+    state = sum(a.size * a.dtype.itemsize for a in cache)
+    pool = cache.k.size * cache.k.dtype.itemsize
+    n_blocks = cache.k.shape[1]
+    assert cache.k.shape == (2, n_blocks, 1024, 8, 128)
+    assert cache.ring.shape == (2, 6, 64, 128, 8, 128)
+    assert cache.snap.shape == (2, 6, n_blocks, 128, 8, 128)
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < pool // 4, mem.temp_size_in_bytes
+    spare = V5E_USABLE_BYTES - mem.argument_size_in_bytes \
+        - mem.temp_size_in_bytes
+    assert spare > 0.3e9, spare
+
+
+def test_the_cells_pool_is_what_the_chip_leaves(window_compiled):
+    """The cell's rule for its pool: what is left beside the weights, the
+    rings and the largest program's scratch with 0.3 GB to spare, and no
+    less: eight more blocks (of keys, values and snapshot) would not fit
+    under it."""
+    compiled, cache = window_compiled
+    block = sum(a.size // a.shape[a.shape.index(cache.k.shape[1])]
+                * a.dtype.itemsize for a in (cache.k, cache.v, cache.snap))
+    assert block == 2 * 2 * 1024 * 8 * 128 * 2 + 2 * 6 * 128 * 8 * 128 * 2
+    spare = min(V5E_USABLE_BYTES - mem.argument_size_in_bytes
+                - mem.temp_size_in_bytes
+                for mem in (compiled(p).memory_analysis()
+                            for p in ("tick", "chunk")))
+    assert 0.3e9 < spare < 0.3e9 + 8 * block, spare
