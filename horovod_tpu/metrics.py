@@ -238,7 +238,8 @@ METRIC_HELP: dict[str, str] = {
     "dsa.keys_selected": "Keys the indexer's exact top-k kept for attention",
     "dsa.queries": "Queries of the full layers (per dispatched tick or chunk: rows x tokens a row x full layers)",
     "dsa.mask_queries": "Queries whose selection was kept as a mask over key tiles and not sorted into a list (the programs latent_moe.mask_reach sends that way)",
-    "attn.blocks_visited": "Block-table entries paged attention read (per dispatched tick or chunk: rows x blocks up to the longest live row)",
+    "attn.blocks_visited": "Block-table entries paged attention read (per dispatched tick or chunk: each row whole key tiles up to the bound of its group of rows of like length, one tile for a row whose output nobody reads; in the entries of the table the walk reads, which for window_moe are pieces of a block)",
+    "attn.blocks_live": "Block-table entries the read rows' own positions span (per dispatched tick or chunk: what a walk with no tile and no group would read; attn.blocks_visited over it is 1.0 for a perfect walk)",
     "attn.blocks_in_table": "Block-table entries of the rows of every dispatched tick or chunk (rows x blocks a table holds)",
     # models/shortconv_moe.py — the attention layers' pools, the
     # convolution's state per slot and its snapshot per block, and the

@@ -390,8 +390,8 @@ def publish_paged_metrics(metrics, cfg: LatentMoEConfig,
     ``stats_host`` and ``programs`` (at construction) the per-pool sizes; with
     ``stats_host`` (after a tick's readback) the counters, and from
     ``row_blocks`` (blocks mapped by each live row) what a window-sized pool
-    would free.  ``programs`` holds ``(rows, tokens a row, longest row's
-    length)`` of each program a step dispatched: ``dsa.queries`` counts their
+    would free.  ``programs`` holds each program a step dispatched
+    (:class:`paged.Dispatched`): ``dsa.queries`` counts their
     queries times the full layers and ``dsa.mask_queries`` those of the
     programs that kept the selection as a mask, by :func:`mask_reach`, the
     function the programs' own branch comes from; nothing is read back.
@@ -411,10 +411,10 @@ def publish_paged_metrics(metrics, cfg: LatentMoEConfig,
     m = pcache.logical_len
     k = min(cfg.index_topk, m)
     metrics.counter("dsa.queries").inc(cfg.n_of(FULL) * sum(
-        rows * t for rows, t, _ in programs))
+        p.rows * p.t for p in programs))
     metrics.counter("dsa.mask_queries").inc(cfg.n_of(FULL) * sum(
-        rows * t for rows, t, longest in programs
-        if longest + t <= mask_reach(t, m, k)))
+        p.rows * p.t for p in programs
+        if p.longest + p.t <= mask_reach(p.t, m, k)))
     metrics.counter("moe.choices_in_place").inc(
         choices_in_place(cfg, programs))
     if stats_host is None:          # nothing was read back: no tick ran
@@ -875,13 +875,13 @@ def layers_batched(n_rows: int, load) -> jax.Array:
 def choices_in_place(cfg, programs: tuple) -> int:
     """The choices of the dispatched programs whose expert layers computed in
     place: ``rows x tokens a row x top_k x expert layers`` of each program
-    ``(rows, tokens a row, longest row)`` within :func:`rows_in_place`, the
+    (:class:`paged.Dispatched`) within :func:`rows_in_place`, the
     function the program's own form comes from.  Every row counts, idle and
     padded ones too (the layer computes over them), where
     ``moe.choices_total`` counts the real tokens' on the device: over
     programs whose rows are all live the two are alike."""
     return cfg.top_k * (cfg.n_layers - cfg.first_dense) * sum(
-        rows * t for rows, t, _ in programs if rows_in_place(rows * t))
+        p.rows * p.t for p in programs if rows_in_place(p.rows * p.t))
 
 
 def _experts_in_place(cfg, lp, h2, group, weights, load):

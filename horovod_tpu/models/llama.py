@@ -599,9 +599,10 @@ def decode_chunk(
 # Those programs donate the pool and ``_paged_attend`` carries it through
 # the layer scan, so it is updated in place: a step moves the positions it
 # writes and the blocks attention reads, never a block it does not touch.
-# Attention's work follows the longest live row: it walks the block tables
-# tile by tile with a running softmax, up to a bound read from the rows'
-# lengths, and neither gathers nor scores the depth a table could hold.
+# Attention's work follows the live rows: it walks the block tables tile by
+# tile with a running softmax, the rows in groups of like length, each group
+# up to a bound read from its rows' lengths, and neither gathers nor scores
+# the depth a table could hold.
 #
 # Block 0 is the TRASH block: it is never allocated, and unallocated table
 # entries point at it.  Free/idle rows that tick along with the batch (the
@@ -629,7 +630,7 @@ class PagedKVCache(NamedTuple):
     def logical_len(self) -> int:
         """Positions each row's table can map (== max_len): the bound on a
         row's length, not a width attention pays (``_paged_attend`` reads
-        the blocks up to the longest live row)."""
+        the blocks up to the longest row of each group of live rows)."""
         return self.block_table.shape[1] * self.k.shape[2]
 
 
@@ -675,18 +676,24 @@ def paged_counters(pcache: PagedKVCache) -> None:
 
 
 def publish_paged_metrics(metrics, cfg, pcache, stats_host=None,
-                          row_blocks=(), programs=()) -> None:
-    """Beside the engine's ``kv.*`` this model has two counters: the share
-    of the block tables its attention walks, ``attn.blocks_visited`` over
-    ``attn.blocks_in_table``.  ``programs`` holds ``(rows, tokens a row,
-    longest row's length)`` of each program a step dispatched, which the
-    host knows from its slots; nothing is read back."""
-    bs, per = pcache.block_size, pcache.block_table.shape[1]
-    metrics.counter("attn.blocks_visited").inc(sum(
-        b * paged_blocks_walked(longest, t, bs, per)
-        for b, t, longest in programs))
+                          row_blocks=(), programs=(), *,
+                          walk_split: int = 1) -> None:
+    """Beside the engine's ``kv.*`` this model has three counters, of how
+    closely its attention's walk follows the rows: ``attn.blocks_live`` (the
+    blocks the read rows' own positions span) within ``attn.blocks_visited``
+    (what the programs read) within ``attn.blocks_in_table``.  ``programs``
+    holds each program a step dispatched as the host knows it from its slots
+    (:class:`paged.Dispatched`); nothing is read back.  ``walk_split``: the
+    entries the walk's table has for one block of the pool's (a model that
+    walks a block in pieces counts in pieces)."""
+    bs, per = pcache.block_size // walk_split, \
+        pcache.block_table.shape[1] * walk_split
+    walked = [paged_blocks_walked(p.lengths, p.active, p.t, bs, per)
+              for p in programs]
+    metrics.counter("attn.blocks_visited").inc(sum(v for v, _ in walked))
+    metrics.counter("attn.blocks_live").inc(sum(n for _, n in walked))
     metrics.counter("attn.blocks_in_table").inc(
-        per * sum(b for b, _, _ in programs))
+        per * sum(p.rows for p in programs))
 
 
 def tp_split_dims(cfg: LlamaConfig) -> tuple:
@@ -824,58 +831,135 @@ class BlockPool:
 # size alone.
 _KEY_TILE = 512
 
+# Rows of a program that walk their key tiles together, to one bound: the
+# group's longest row's.  A program of more rows is walked group by group,
+# its rows in the order of their own bounds, so a short row stops where the
+# short rows beside it stop.  Measured on one v5e (PERF.md, PR 34): the tick
+# of 64 rows over tables of 64 tiles, 32 of them decoding at 1.3-31 k, takes
+# 39.8 ms as one group, 21.3 in groups of 16 rows, 19.4 of 8, 18.6 of 4,
+# 18.9 of 2; a least-squares fit of those readings gives 13.3 ms + 3.1 us a
+# trip of a group's loop + 3.8 us a row and tile.  Eight and not four: four
+# gains 4 % on that mix (read), and by the fit, not by a reading (no cell
+# holds such rows at depth), where every row is as long as the longest, so
+# that no grouping saves a byte, the trips of 8 rows a group would cost 6 %
+# and of 4 rows 13 %.
+_ROW_GROUP = 8
+
 
 def _tile_blocks(block_size: int, per: int) -> int:
     """Pool blocks of each row that one step of the key loop reads."""
     return max(1, min(per, _KEY_TILE // block_size))
 
 
-def paged_blocks_walked(longest: int, t: int, block_size: int,
-                        per: int) -> int:
-    """Blocks of each row's table that a program over ``t`` tokens a row
-    reads when its longest row holds ``longest`` positions: whole key tiles
-    up to the last query position, no further than the table.  The host's
-    form of the trip count :func:`_paged_attend` reads from ``qpos``."""
+def _row_groups(rows: int, n_tiles: int) -> tuple[int, int]:
+    """``(groups, rows a group)`` of a program's walk: groups of
+    :data:`_ROW_GROUP` rows, but no more groups than the table is deep in
+    tiles, since the groups' bounds can differ by no more than that and each
+    group is a loop of its own (a table of four tiles under 128 rows is
+    walked in four groups of 32).  One group is the whole program: a chunk's
+    one row, and any program of no more rows than a group holds."""
+    r = -(-rows // max(1, min(-(-rows // _ROW_GROUP), n_tiles)))
+    return -(-rows // r), r             # no group is all padding
+
+
+def paged_blocks_walked(lengths, active, t: int, block_size: int,
+                        per: int) -> tuple[int, int]:
+    """``(visited, live)`` of a program over ``t`` tokens a row whose rows
+    hold ``lengths`` [B] positions and of whose rows ``active`` [B] are read.
+    ``visited``: the blocks of the rows' tables the program reads, each row
+    whole key tiles up to its group's bound, no further than the table: the
+    host's form of the trip counts :func:`tile_walk` reads from ``qpos``.
+    A table entry counts once: the places :func:`tile_walk` pads the first
+    group with walk the shortest row's tiles again, to the group's bound as
+    that row does, and read no entry it does not (``pad x bound[0]`` tiles a
+    layer of traffic that this count leaves out; fewer places than a group).
+    ``live``: the blocks the active rows' own query positions span, what a
+    walk with no tile and no group would read."""
     g = _tile_blocks(block_size, per)
-    return min(((longest + t - 1) // (g * block_size) + 1) * g, per)
+    n_tiles = -(-per // g)
+    end = np.asarray(lengths, np.int64) + (t - 1)   # the last query positions
+    active = np.asarray(active) > 0
+    last = np.where(active,
+                    np.minimum(end // (g * block_size) + 1, n_tiles), 1)
+    groups, r = _row_groups(len(last), n_tiles)
+    # as the device orders them: by last tile, padded in front with the
+    # shortest row (fewer places than a group), a group's bound its last row's
+    pad = groups * r - len(last)
+    bound = np.sort(last)[np.arange(1, groups + 1) * r - (pad + 1)]
+    in_group = np.full(groups, r)
+    in_group[0] -= pad
+    visited = int(np.dot(np.minimum(bound * g, per), in_group))
+    live = int(np.minimum(end // block_size + 1, per)[active].sum())
+    return visited, live
 
 
 class TileWalk(NamedTuple):
-    """What one program's walk over key tiles shares between its layers:
-    ``table`` [B, n_tiles * g] (the rows' block tables, padded with trash to
-    whole tiles), ``g`` blocks a tile spans, ``n_live`` the tiles up to the
-    longest row's last query position (a traced bound: data, not shape),
-    and ``m`` the table's logical depth."""
+    """What one program's walk over key tiles shares between its layers.
+    The rows stand in groups (:func:`_row_groups`), in the order of their own
+    last tiles: ``table`` [groups, R, n_tiles * g] (the rows' block tables,
+    padded with trash to whole tiles) and ``qpos`` [groups, R, T] in that
+    order, ``g`` blocks a tile spans, ``n_live`` [groups] the tiles up to
+    each group's longest row's last query position (traced bounds: data, not
+    shape), ``m`` the table's logical depth, and between the program's rows
+    and the walk's ``order`` [groups * R] (the row at each place; ``None``
+    where one group holds the rows as they stand) and ``place`` [B] (each
+    row's place)."""
 
     table: jax.Array
+    qpos: jax.Array
     g: int
     n_live: jax.Array
     m: int
+    order: jax.Array | None
+    place: jax.Array | None
 
 
-def tile_walk(table: jax.Array, qpos: jax.Array, bs: int) -> TileWalk:
+def tile_walk(table: jax.Array, qpos: jax.Array, bs: int,
+              active: jax.Array | None = None) -> TileWalk:
     """The walk of a program whose queries stand at ``qpos`` [B, T] under
-    block tables ``table`` [B, per] of ``bs``-position blocks."""
-    per = table.shape[1]
+    block tables ``table`` [B, per] of ``bs``-position blocks, computed once
+    for all its layers.  ``active`` [B] marks the rows whose outputs are read
+    (default: all).  A row that is not walks one tile whatever its length: a
+    free slot, a row still prefilling, a row held in place.  Every query
+    still sees key 0, so its softmax has a real maximum and a sum above
+    zero; its output is of a prefix of its keys, and nobody reads it."""
+    b, per = table.shape
     g = _tile_blocks(bs, per)               # blocks a key tile spans
     n_tiles = -(-per // g)
+    table = jnp.pad(table, ((0, 0), (0, n_tiles * g - per)))  # with trash
+    last = jnp.minimum(qpos[:, -1] // (g * bs) + 1, n_tiles)  # [B] tiles
+    if active is not None:
+        last = jnp.where(jnp.asarray(active) > 0, last, 1)
+    groups, r = _row_groups(b, n_tiles)
+    if groups == 1:     # today's loop: the rows as they stand, one bound
+        return TileWalk(table=table[None], qpos=qpos[None], g=g,
+                        n_live=jnp.max(last)[None], m=per * bs,
+                        order=None, place=None)
+    by_last = jnp.argsort(last)
+    pad = groups * r - b                    # places in front: the shortest
+    order = jnp.concatenate([jnp.broadcast_to(by_last[:1], (pad,)), by_last])
+    place = jnp.zeros((b,), jnp.int32).at[by_last].set(
+        pad + jnp.arange(b, dtype=jnp.int32))
     return TileWalk(
-        table=jnp.pad(table, ((0, 0), (0, n_tiles * g - per))),  # with trash
-        g=g, n_live=jnp.minimum(jnp.max(qpos) // (g * bs) + 1, n_tiles),
-        m=per * bs)
+        table=table[order].reshape(groups, r, -1),
+        qpos=qpos[order].reshape(groups, r, -1), g=g,
+        n_live=last[order].reshape(groups, r)[:, -1], m=per * bs,
+        order=order, place=place)
 
 
-def paged_attend_tiles(q, k, v, kf, vf, layer, walk: TileWalk, qpos, wflat,
+def paged_attend_tiles(q, k, v, kf, vf, layer, walk: TileWalk, wflat,
                        n_blocks: int, bs: int, scale: float | None = None):
     """One layer's paged attention, for any model whose keys and values are
     ``[.., KVH, Dh]`` rows of flat pools: scatter the chunk's ``k`` / ``v``
     [B, T, KVH, Dh] into ``kf`` / ``vf`` ``[n_pool_layers * n_blocks * bs,
     KVH, Dh]`` at ``wflat`` [B, T] within pool layer ``layer``'s stripe, then
     attend ``q`` [B, T, H, Dh] (rotated, unscaled) over the row's blocks a
-    key tile at a time with a running softmax, no further than
-    ``walk.n_live`` tiles, scores scaled by ``scale`` (``1 / sqrt(Dh)``
-    where none is given).  A tile past a shorter row's frontier (its table
-    points at trash there) is masked to an exact-zero softmax term.
+    key tile at a time with a running softmax, each of ``walk``'s groups of
+    rows no further than its own ``walk.n_live`` tiles, scores scaled by
+    ``scale`` (``1 / sqrt(Dh)`` where none is given).  A tile past a shorter
+    row's frontier (its table points at trash there) is masked to an
+    exact-zero softmax term, so within a row the walk's grouping changes
+    nothing: the same tiles in the same order, and past them terms of zero.
     Products of K and V as stored, accumulated in float32; maximum, sum and
     output accumulator float32; one division after the loop.  Returns the
     heads' outputs [B, T, KVH, H / KVH, Dh] (float32) and the two pools."""
@@ -890,46 +974,59 @@ def paged_attend_tiles(q, k, v, kf, vf, layer, walk: TileWalk, qpos, wflat,
     kb = kf.reshape(-1, bs, kvh, dh)        # a bitcast
     vb = vf.reshape(-1, bs, kvh, dh)
     qg = q.reshape(b, t, kvh, n_rep, dh)
-    stat = (b, kvh, n_rep, t)
 
-    def tile(j, acc):
-        mx, den, o = acc
-        blk = lax.dynamic_slice_in_dim(walk.table, j * g, g, axis=1)
-        blk = blk + layer * n_blocks                      # [B, G]
-        kt = kb[blk].reshape(b, w, kvh, dh)
-        vt = vb[blk].reshape(b, w, kvh, dh)
-        s = jnp.einsum("bqkrd,bmkd->bkrqm", qg, kt,
-                       preferred_element_type=jnp.float32) * scale
-        kpos = j * w + jnp.arange(w)
-        seen = (kpos <= qpos[:, :, None]) & (kpos < walk.m)   # [B, T, W]
-        s = jnp.where(seen[:, None, None], s, NEG_INF_LOGIT)
-        mx_new = jnp.maximum(mx, jnp.max(s, axis=-1))
-        p = jnp.exp(s - mx_new[..., None])            # [B,KVH,R,T,W]
-        fade = jnp.exp(mx - mx_new)
-        den = fade * den + jnp.sum(p, axis=-1)
-        o = fade[..., None] * o + jnp.einsum(
-            "bkrqm,bmkd->bkrqd", p, vt.astype(jnp.float32))
-        return mx_new, den, o
+    def rows_walk(group):
+        q_rows, qpos, table, n_live = group     # [R, T, ..], [R, T], [R, ..]
+        r = q_rows.shape[0]
+        stat = (r, kvh, n_rep, t)
 
-    # every query sees key 0, so the first tile makes `mx` a real
-    # maximum and a masked term is exp(-1e30 - mx) == 0 from there on
-    _, den, o = lax.fori_loop(
-        0, walk.n_live, tile,
-        (jnp.full(stat, NEG_INF_LOGIT, jnp.float32),
-         jnp.zeros(stat, jnp.float32),
-         jnp.zeros(stat + (dh,), jnp.float32)))
-    return jnp.moveaxis(o / den[..., None], 3, 1), kf, vf  # [B,T,KVH,R,Dh]
+        def tile(j, acc):
+            mx, den, o = acc
+            blk = lax.dynamic_slice_in_dim(table, j * g, g, axis=1)
+            blk = blk + layer * n_blocks                      # [R, G]
+            kt = kb[blk].reshape(r, w, kvh, dh)
+            vt = vb[blk].reshape(r, w, kvh, dh)
+            s = jnp.einsum("bqkrd,bmkd->bkrqm", q_rows, kt,
+                           preferred_element_type=jnp.float32) * scale
+            kpos = j * w + jnp.arange(w)
+            seen = (kpos <= qpos[:, :, None]) & (kpos < walk.m)  # [R, T, W]
+            s = jnp.where(seen[:, None, None], s, NEG_INF_LOGIT)
+            mx_new = jnp.maximum(mx, jnp.max(s, axis=-1))
+            p = jnp.exp(s - mx_new[..., None])            # [R,KVH,Rep,T,W]
+            fade = jnp.exp(mx - mx_new)
+            den = fade * den + jnp.sum(p, axis=-1)
+            o = fade[..., None] * o + jnp.einsum(
+                "bkrqm,bmkd->bkrqd", p, vt.astype(jnp.float32))
+            return mx_new, den, o
+
+        # every query sees key 0, so the first tile makes `mx` a real
+        # maximum and a masked term is exp(-1e30 - mx) == 0 from there on
+        _, den, o = lax.fori_loop(
+            0, n_live, tile,
+            (jnp.full(stat, NEG_INF_LOGIT, jnp.float32),
+             jnp.zeros(stat, jnp.float32),
+             jnp.zeros(stat + (dh,), jnp.float32)))
+        return jnp.moveaxis(o / den[..., None], 3, 1)     # [R,T,KVH,Rep,Dh]
+
+    if walk.order is None:      # one group: no outer loop, no permutation
+        return rows_walk((qg, walk.qpos[0], walk.table[0],
+                          walk.n_live[0])), kf, vf
+    r = walk.table.shape[1]
+    o = lax.map(rows_walk, (qg[walk.order].reshape((-1, r) + qg.shape[1:]),
+                            walk.qpos, walk.table, walk.n_live))
+    return o.reshape((-1,) + o.shape[2:])[walk.place], kf, vf
 
 
 def _paged_attend(params, tokens, cfg: LlamaConfig, kv_k, kv_v,
-                  qpos, wflat, table):
+                  qpos, wflat, table, active=None):
     """Shared body of the paged decode paths: scatter the chunk's K/V at
     flat physical positions ``wflat`` [B, T], then attend block-wise
     through ``table`` [B, blocks_per_row] (the rows' block tables) with a
-    running softmax: a loop over key tiles of whole pool blocks that ends
-    at the longest row's last query position, a traced bound read from
-    ``qpos`` (:func:`tile_walk`, :func:`paged_attend_tiles`).  Nothing as
-    deep as the table is gathered or scored.  The numbers are
+    running softmax: a loop over key tiles of whole pool blocks that ends,
+    for each group of rows of like length, at its longest row's last query
+    position, traced bounds read from ``qpos`` and ``active`` [B], the rows
+    whose outputs are read (:func:`tile_walk`, :func:`paged_attend_tiles`).
+    Nothing as deep as the table is gathered or scored.  The numbers are
     :func:`decode_chunk`'s up to the order of summation.
 
     The pool is written IN PLACE: ``kv_k`` / ``kv_v`` ride the layer scan
@@ -945,7 +1042,7 @@ def _paged_attend(params, tokens, cfg: LlamaConfig, kv_k, kv_v,
     x = params["embed"][tokens].astype(dt)                # [B, T, D]
     cos, sin = rope_tables(cfg, qpos)
     stripe = n_blocks * bs                  # one layer's flat positions
-    walk = tile_walk(table, qpos, bs)
+    walk = tile_walk(table, qpos, bs, active)
 
     def layer(carry, lp):
         x, kf, vf, i = carry                # kf/vf [L * stripe, KVH, Dh]
@@ -955,8 +1052,8 @@ def _paged_attend(params, tokens, cfg: LlamaConfig, kv_k, kv_v,
         v = (h @ lp["wv"].astype(dt)).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-        o, kf, vf = paged_attend_tiles(q, k, v, kf, vf, i, walk, qpos,
-                                       wflat, n_blocks, bs)
+        o, kf, vf = paged_attend_tiles(q, k, v, kf, vf, i, walk, wflat,
+                                       n_blocks, bs)
         x = x + o.astype(dt).reshape(b, t, cfg.dim) @ lp["wo"].astype(dt)
         h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
         gate = jax.nn.silu(h @ lp["w_gate"].astype(dt))
@@ -977,6 +1074,7 @@ def _paged_attend(params, tokens, cfg: LlamaConfig, kv_k, kv_v,
 def decode_chunk_paged(
     params: dict, tokens: jax.Array, cfg: LlamaConfig,
     pcache: PagedKVCache, *, advance: jax.Array | None = None,
+    active: jax.Array | None = None,
 ) -> tuple[jax.Array, PagedKVCache]:
     """Paged :func:`decode_chunk`: T tokens per row against the block
     pool; token j of row r lands in the physical block its table maps
@@ -986,7 +1084,11 @@ def decode_chunk_paged(
     fixed-signature serving tick can hold idle rows in place — idle rows
     still compute (one program for the whole pool) but their writes land
     in their table's blocks (trash for free rows) and their length stays
-    put.  ``None`` advances every row by T."""
+    put.  ``None`` advances every row by T.
+
+    ``active`` [B]: the rows whose logits are read (default: the rows that
+    advance).  Attention walks the others' keys no further than one tile
+    (:func:`tile_walk`): their logits are of a prefix of their keys."""
     b, t = tokens.shape
     bs = pcache.block_size
     per = pcache.block_table.shape[1]
@@ -997,11 +1099,13 @@ def decode_chunk_paged(
     wblk = jnp.take_along_axis(
         pcache.block_table, jnp.clip(qpos // bs, 0, per - 1), axis=1)
     wflat = wblk * bs + qpos % bs                         # [B, T]
-    logits, ks, vs = _paged_attend(
-        params, tokens, cfg, pcache.k, pcache.v, qpos, wflat,
-        pcache.block_table)
     adv = (jnp.asarray(t, jnp.int32) if advance is None
            else jnp.asarray(advance, jnp.int32))
+    if active is None and advance is not None:
+        active = adv
+    logits, ks, vs = _paged_attend(
+        params, tokens, cfg, pcache.k, pcache.v, qpos, wflat,
+        pcache.block_table, active)
     return logits, pcache._replace(k=ks, v=vs, length=pos + adv)
 
 
@@ -1036,10 +1140,13 @@ def spec_verify_paged(
     [B, V] (seeding the next round), and the advanced cache.
 
     The round is generic over the wide tick: ``decode`` (default
-    :func:`decode_chunk_paged`) is any model's function of that signature
-    whose cache has a per-row ``length`` that alone rolls back.
+    :func:`decode_chunk_paged` with ``active`` for its ``active``: the round
+    holds every row's length, so the advance cannot say whose logits are
+    read) is any model's function of that signature whose cache has a
+    per-row ``length`` that alone rolls back.
     """
-    decode = decode_chunk_paged if decode is None else decode
+    if decode is None:
+        decode = partial(decode_chunk_paged, active=active)
     b, k = drafts.shape
     tok = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)       # [B]
     chunk = jnp.concatenate([tok[:, None], drafts], axis=1)   # [B, K+1]

@@ -29,7 +29,7 @@ The engine names no model.  A model is a module with these functions, which
 * ``publish_paged_metrics(metrics, cfg, pcache, stats_host, row_blocks,
   programs)`` — the model's own gauges and counters into the engine's
   registry: at construction, and after every step that dispatched a program
-  (``programs``: rows, tokens a row and the longest row's length of each).
+  (``programs``: a :class:`Dispatched` each).
 
 **State of a second kind.**  The pools hold state that is per position and
 immutable once written, which is why a block can be shared, cached and
@@ -62,7 +62,7 @@ both publish alike).
 from __future__ import annotations
 
 from types import ModuleType
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -85,6 +85,25 @@ def paged_model(cfg: Any) -> ModuleType:
         f"ServeEngine serves a LlamaConfig, a LatentMoEConfig, a "
         f"ShortConvMoEConfig or a WindowMoEConfig, not a "
         f"{type(cfg).__name__}")
+
+
+class Dispatched(NamedTuple):
+    """One program of a step as the host knows it from its slots, for the
+    counters a model reckons without a read-back: ``t`` tokens a row, the
+    ``lengths`` [B] its rows held before it, and ``active`` [B], 1 where the
+    row's output is read (a chunk's one row; a tick's decoding rows)."""
+
+    t: int
+    lengths: Any                # a sequence or an array of ints
+    active: Any
+
+    @property
+    def rows(self) -> int:
+        return len(self.lengths)
+
+    @property
+    def longest(self) -> int:
+        return int(max(self.lengths))
 
 
 def block_before(row, length, bs: int):
@@ -143,14 +162,15 @@ def read_stats(stats_host, head: tuple, tail: tuple) -> dict:
 def publish_state_metrics(metrics, cfg: Any, pcache: Any, stats_host,
                           programs: tuple, *, per_block: dict,
                           slot_bytes: int, counted: tuple,
-                          read) -> dict | None:
+                          read, walk_split: int = 1) -> dict | None:
     """What the snapshot rule's users publish alike, for their
     ``publish_paged_metrics``.  At construction (no ``stats_host``, no
     ``programs``) what a cached token, a block's snapshot and a slot's state
     hold, and the ``.device`` gauges of ``counted`` at zero (a registry may
     outlive an engine, ``supervisor.clone_engine``: this engine's device
     counts from zero); after a step the share of the tables attention walked
-    (``attn.blocks_*``, as :mod:`llama` counts them from ``programs``) and
+    (``attn.blocks_*``, as :mod:`llama` counts them from ``programs`` and
+    ``walk_split``) and
     ``moe.choices_in_place`` (:func:`latent_moe.choices_in_place` of the
     step's programs, not read back); where a tick's readback brought
     ``stats_host``, the device's counters (``read(stats_host)``, the
@@ -166,7 +186,8 @@ def publish_state_metrics(metrics, cfg: Any, pcache: Any, stats_host,
         metrics.gauge("state.bytes_per_slot").set(slot_bytes)
         for _, device_total, _ in counted:
             device_total.set(0)
-    llama.publish_paged_metrics(metrics, cfg, pcache, programs=programs)
+    llama.publish_paged_metrics(metrics, cfg, pcache, programs=programs,
+                                walk_split=walk_split)
     metrics.counter("moe.choices_in_place").inc(
         latent_moe.choices_in_place(cfg, programs))
     if stats_host is None:          # nothing was read back: no tick ran

@@ -386,7 +386,7 @@ def _short_conv(cfg: ShortConvMoEConfig, lp: dict, u, carry):
 
 
 def _attention(cfg: ShortConvMoEConfig, lp: dict, u, cos, sin, kf, vf, layer,
-               walk, qpos, wflat, n_blocks, bs):
+               walk, wflat, n_blocks, bs):
     """One attention layer: q/k norm, rotary, then the shared walk over the
     row's live blocks, over packed heads (:attr:`ShortConvMoEConfig.
     kv_pack`): ``p`` neighbouring key heads are one pool row ``p * Dh`` wide,
@@ -407,8 +407,8 @@ def _attention(cfg: ShortConvMoEConfig, lp: dict, u, cos, sin, kf, vf, layer,
     q = (q.reshape(b, t, rows, p, n_rep, 1, hd)
          * own[:, None, :, None]).reshape(b, t, cfg.n_heads, p * hd)
     o, kf, vf = llama.paged_attend_tiles(
-        q, k.reshape(b, t, rows, p * hd), v, kf, vf, layer, walk, qpos,
-        wflat, n_blocks, bs, scale=hd ** -0.5)
+        q, k.reshape(b, t, rows, p * hd), v, kf, vf, layer, walk, wflat,
+        n_blocks, bs, scale=hd ** -0.5)
     o = o.reshape(b, t, rows, p, n_rep, p, hd)
     o = jnp.stack([o[:, :, :, i, :, i] for i in range(p)], axis=3)
     return _dot(o.astype(dt).reshape(b, t, cfg.n_heads * hd), lp["wo"],
@@ -430,8 +430,9 @@ def _forward_paged(params, tokens, cfg: ShortConvMoEConfig,
     """The shared body of the paged programs: ``tokens`` [B, T] at positions
     ``qpos`` under block tables ``table`` [B, per], the rows' convolution
     carries ``carry`` [n_conv, B, (K - 1) * d]; ``valid`` [B, T] marks the
-    tokens that count (for the counters and the routing).  Writes keys and
-    values; the convolution's state is the caller's to commit."""
+    tokens that count (for the counters and the routing; a row with none is
+    one whose output nobody reads, and attention walks it one tile).  Writes
+    keys and values; the convolution's state is the caller's to commit."""
     dt = cfg.dtype
     b, t = tokens.shape
     n_attn, n_blocks, bs, kvh, hd = pcache.k.shape
@@ -442,7 +443,7 @@ def _forward_paged(params, tokens, cfg: ShortConvMoEConfig,
     kf = pcache.k.reshape(n_attn * n_blocks * bs, kvh, hd)
     vf = pcache.v.reshape(n_attn * n_blocks * bs, kvh, hd)
     cos, sin = llama.rope_tables(cfg, qpos)
-    walk = llama.tile_walk(table, qpos, bs)
+    walk = llama.tile_walk(table, qpos, bs, jnp.any(valid, axis=1))
     x = params["embed"][tokens].astype(dt)
     i_attn = i_conv = 0
     zs = []
@@ -458,7 +459,7 @@ def _forward_paged(params, tokens, cfg: ShortConvMoEConfig,
         else:
             with jax.named_scope("attn.gqa"):
                 o, kf, vf = _attention(cfg, lp, u, cos, sin, kf, vf, i_attn,
-                                       walk, qpos, wflat, n_blocks, bs)
+                                       walk, wflat, n_blocks, bs)
             i_attn += 1
         x = x + o
         h = rmsnorm(x, lp["ffn_norm"], cfg.norm_eps)

@@ -312,7 +312,8 @@ def publish_paged_metrics(metrics, cfg: WindowMoEConfig,
                     ) * pcache.ring.dtype.itemsize
     c = paged.publish_state_metrics(
         metrics, cfg, pcache, stats_host, programs, per_block=per_block,
-        slot_bytes=ring_slot, counted=_counted(metrics), read=read_counters)
+        slot_bytes=ring_slot, counted=_counted(metrics), read=read_counters,
+        walk_split=_walk_split(pcache.block_size))
     if c is None:
         return
     metrics.gauge("moe.load_max").set(max(c["held_load"]))
@@ -419,6 +420,13 @@ def _window_attend(cfg: WindowMoEConfig, q, k, v, ring_k, ring_v, pos):
     return o.reshape(b, nb * c, n_heads, hd)[:, :t]
 
 
+def _walk_split(bs: int) -> int:
+    """The pieces the full layers' walk sees a block of ``bs`` positions as:
+    the fewest whose size is within :data:`GATHER_ROWS`."""
+    return bs // max(p for p in range(1, min(bs, GATHER_ROWS) + 1)
+                     if bs % p == 0)
+
+
 class _Ran(NamedTuple):
     """What a program's forward pass leaves for :func:`_commit`."""
 
@@ -434,8 +442,9 @@ def _forward_paged(params, tokens, cfg: WindowMoEConfig,
     """The shared body of the paged programs: ``tokens`` [B, T] at positions
     ``qpos`` under block tables ``table`` [B, per], the rows' rings ``ring``
     [2, n_sliding, B, window, KVH, Dh]; ``valid`` [B, T] marks the tokens
-    that count (for the counters and the routing).  Writes the full layers'
-    keys and values; the rings are the caller's to commit."""
+    that count (for the counters and the routing; a row with none is one
+    whose output nobody reads, and the full layers walk it one tile).  Writes
+    the full layers' keys and values; the rings are the caller's to commit."""
     dt = cfg.dtype
     b, t = tokens.shape
     n_full, n_blocks, bs, kvh, hd = pcache.k.shape
@@ -448,11 +457,11 @@ def _forward_paged(params, tokens, cfg: WindowMoEConfig,
     cos, sin = llama.rope_tables(cfg, qpos)
     # the walk sees each block as `split` pieces of `piece` positions: the
     # same flat positions of the same pools under a finer table
-    piece = max(p for p in range(1, min(bs, GATHER_ROWS) + 1) if bs % p == 0)
-    split = bs // piece
+    split = _walk_split(bs)
+    piece = bs // split
     walk = llama.tile_walk(
         (table[:, :, None] * split + jnp.arange(split)).reshape(b, -1), qpos,
-        piece)
+        piece, jnp.any(valid, axis=1))
     x = params["embed"][tokens].astype(dt)
     i_full = i_sl = 0
     own = []
@@ -475,8 +484,8 @@ def _forward_paged(params, tokens, cfg: WindowMoEConfig,
         else:
             with jax.named_scope("attn.full"):
                 o, kf, vf = llama.paged_attend_tiles(
-                    q, k, v, kf, vf, i_full, walk, qpos, wflat,
-                    n_blocks * split, piece)
+                    q, k, v, kf, vf, i_full, walk, wflat, n_blocks * split,
+                    piece)
             i_full += 1
         o = _dot(o.astype(dt).reshape(b, t, cfg.n_heads * hd), lp["wo"], dt)
         x = x + rmsnorm(o, lp["attn_norm"], cfg.norm_eps)

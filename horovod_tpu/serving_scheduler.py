@@ -155,7 +155,7 @@ from horovod_tpu import timeseries as timeseries_mod
 from horovod_tpu import tracing as tracing_mod
 from horovod_tpu.metrics import Trace
 from horovod_tpu.models.llama import BlockPool
-from horovod_tpu.models.paged import paged_model
+from horovod_tpu.models.paged import Dispatched, paged_model
 from horovod_tpu.parallel.mesh import tensor_parallel_mesh
 from horovod_tpu.prefix_cache import RadixPrefixCache
 from horovod_tpu.serving import (
@@ -1643,9 +1643,9 @@ class ServeEngine:
                     self._starve_steps = 0
                     more, _ = self._admit_ready()  # head admits this step
                     progress += more
-        # (rows, tokens a row, longest row) of every program this step
-        # dispatches: what the model's own counters are reckoned from
-        programs: list[tuple[int, int, int]] = []
+        # every program this step dispatches, as the slots know it: what
+        # the model's own counters are reckoned from
+        programs: list[Dispatched] = []
         with prof.sub("admit.prefill_dispatch"):
             for slot, s in enumerate(self._slots):
                 if s.state != PREFILL:
@@ -1669,7 +1669,8 @@ class ServeEngine:
                 t_chunk = time.monotonic() if traced else 0.0
                 try:
                     self.faults.check("serve.prefill", key=s.request_id)
-                    programs.append((1, self.chunk, self._row_length(s)))
+                    programs.append(Dispatched(
+                        self.chunk, (self._row_length(s),), (1,)))
                     self.pcache, self.last_logits = self._chunk(
                         self.params, self.pcache, self.last_logits,
                         jnp.asarray(toks), jnp.asarray(slot, jnp.int32),
@@ -1721,13 +1722,15 @@ class ServeEngine:
                     self._bump_spec("proposed", len(prop))
         if decoding:
             prof.mark("decode_dispatch")
+            tick_exc: Exception | None = None
             try:
                 active = np.zeros((self.n_slots,), np.int32)
                 active[decoding] = 1
                 accept_host = None
-                programs.append((
-                    self.n_slots, self.draft_k + 1 if spec else 1,
-                    max(self._row_length(s) for s in self._slots)))
+                programs.append(Dispatched(
+                    self.draft_k + 1 if spec else 1,
+                    np.array([self._row_length(s) for s in self._slots]),
+                    active))
                 if spec:
                     tok, accept, self.last_logits, self.pcache = \
                         self._spec_tick(
@@ -1749,40 +1752,58 @@ class ServeEngine:
                         "spec_tick" if spec else "tick",
                         h2d_bytes=active.nbytes + (
                             drafts_host.nbytes if spec else 0))
-                # np.asarray on the device token array is the readback
-                # boundary: everything the tick queued must complete
-                # first, so this wait is the device-time share.
-                prof.mark("device_sync")
-                t_sync0 = time.perf_counter()
-                tok_host = np.asarray(tok)
-                if spec:
-                    accept_host = np.asarray(accept)
-                stats_host = None if stats is None else np.asarray(stats)
-                if self.device is not None:
-                    # split the measured readback wait into the cost
-                    # model's predicted device-compute share vs host
-                    # stall; the profiler gets the same split as nested
-                    # device_sync.* intervals so phase tables can show
-                    # where the wait went.
-                    t_sync1 = time.perf_counter()
-                    d2h = tok_host.nbytes + (
-                        accept_host.nbytes
-                        if accept_host is not None else 0)
-                    est, stall = self.device.on_sync(
-                        "spec_tick" if spec else "tick",
-                        t_sync0, t_sync1, d2h_bytes=d2h)
-                    prof.add("device_sync.compute_est",
-                             t_sync0, t_sync0 + est)
-                    prof.add("device_sync.host_stall",
-                             t_sync0 + est, t_sync1)
-                # spec engines account their acceptance/emission loop
-                # as `verify`; plain engines keep the classic name
-                prof.mark("verify" if spec else "sample_postprocess")
             except Exception as exc:
+                tick_exc = exc
+            if tick_exc is None:
+                # what the model reckons from the dispatched programs
+                # alone is reckoned here, while the device runs them (at
+                # the step's end the device would wait for it), and
+                # outside the tick's fault handling: host code of the
+                # metrics is no fault of a row's
+                self.model.publish_paged_metrics(
+                    self.metrics, self.cfg, self.pcache, None, (),
+                    tuple(programs))
+                programs.clear()
+                try:
+                    # np.asarray on the device token array is the
+                    # readback boundary: everything the tick queued must
+                    # complete first, so this wait is the device-time
+                    # share.
+                    prof.mark("device_sync")
+                    t_sync0 = time.perf_counter()
+                    tok_host = np.asarray(tok)
+                    if spec:
+                        accept_host = np.asarray(accept)
+                    stats_host = (None if stats is None
+                                  else np.asarray(stats))
+                    if self.device is not None:
+                        # split the measured readback wait into the cost
+                        # model's predicted device-compute share vs host
+                        # stall; the profiler gets the same split as
+                        # nested device_sync.* intervals so phase tables
+                        # can show where the wait went.
+                        t_sync1 = time.perf_counter()
+                        d2h = tok_host.nbytes + (
+                            accept_host.nbytes
+                            if accept_host is not None else 0)
+                        est, stall = self.device.on_sync(
+                            "spec_tick" if spec else "tick",
+                            t_sync0, t_sync1, d2h_bytes=d2h)
+                        prof.add("device_sync.compute_est",
+                                 t_sync0, t_sync0 + est)
+                        prof.add("device_sync.host_stall",
+                                 t_sync0 + est, t_sync1)
+                    # spec engines account their acceptance/emission
+                    # loop as `verify`; plain engines keep the classic
+                    # name
+                    prof.mark("verify" if spec else "sample_postprocess")
+                except Exception as exc:
+                    tick_exc = exc
+            if tick_exc is not None:
                 # a whole-tick failure cannot be attributed to one row;
                 # quarantine every decoding row (transients replay)
                 for slot in decoding:
-                    self._row_fault(slot, exc)
+                    self._row_fault(slot, tick_exc)
                 progress += len(decoding)
             else:
                 progress += len(decoding)
@@ -1827,7 +1848,7 @@ class ServeEngine:
                             self._terminate(slot, OK)
                             break
         prof.mark("bookkeeping")
-        if programs:
+        if programs or stats_host is not None:
             self.model.publish_paged_metrics(
                 self.metrics, self.cfg, self.pcache, stats_host,
                 tuple(s.n_blocks for s in self._slots if s.state != FREE),

@@ -139,22 +139,28 @@ def _reaches_a_while(comps: dict, name: str, seen: set) -> bool:
 
 def test_the_shortconv_tick_computes_its_experts_in_place(shortconv_compiled):
     """A tick of 128 rows is within :data:`latent_moe.IN_PLACE_ROWS`: its
-    twelve expert layers sort, gather and scatter no choice, so no loop
-    holds a second loop (the tiles' loop searched its expert with one), the
-    only sorts left are the router's ``top_k`` and the scratch is no larger
-    than the sorted tiles' was."""
+    twelve expert layers sort, gather and scatter no choice, so no loop of
+    theirs holds a second loop (the tiles' loop searched its expert with
+    one): the only loops that do are the attention layers' walks, one each,
+    over the groups of rows (128 rows over a table of four tiles: four groups
+    of 32).  The only sorts left are the router's ``top_k`` and the walk's
+    order of the rows, once a program, and the scratch is no larger than the
+    sorted tiles' was."""
     cfg = sm.ShortConvMoEConfig()
     assert lm.rows_in_place(128)
     compiled, _ = shortconv_compiled
     hlo = compiled("tick").as_text()
     comps = _computations(hlo)
-    bodies = re.findall(r" while\(.*?body=%([\w.\-]+)", hlo)
-    assert bodies and all(b in comps for b in bodies)
-    nested = [b for b in bodies if _reaches_a_while(comps, b, set())]
-    assert not nested, nested
+    loops = re.findall(r"^.* while\(.*?body=%([\w.\-]+).*$", hlo, re.M)
+    assert loops and all(b in comps for b in loops)
+    nested = [line for line in hlo.splitlines() if " while(" in line
+              and _reaches_a_while(comps, re.search(
+                  r"body=%([\w.\-]+)", line).group(1), set())]
+    assert len(nested) == sum(k == sm.ATTN for k in cfg.layer_kinds)
+    assert all("attn.gqa" in line for line in nested), nested
     sorts = [line for line in hlo.splitlines() if " sort(" in line]
-    assert len(sorts) == cfg.n_layers - cfg.first_dense
-    assert all("moe.route/top_k" in line for line in sorts)
+    assert len(sorts) == cfg.n_layers - cfg.first_dense + 1
+    assert sum("moe.route/top_k" in line for line in sorts) == len(sorts) - 1
     assert compiled("tick").memory_analysis().temp_size_in_bytes \
         <= PARENT_TICK_TEMP_BYTES
 

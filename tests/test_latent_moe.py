@@ -555,18 +555,18 @@ def test_engine_serves_the_same_tokens_on_either_path(monkeypatch, served,
     assert eng.compile_cache_sizes() == {"tick": 1, "chunk": 1, "set_row": 1}
     c = eng.metrics_snapshot()["counters"]
     reach = {"mask": 48, "list": 0, "mask_then_list": 24}[path]
-    chunks = [p for p in programs if p[1] == 16]
-    ticks = [p for p in programs if p[1] == 1]
+    chunks = [p for p in programs if p.t == 16]
+    ticks = [p for p in programs if p.t == 1]
     assert len(chunks) == 2 + 1 + 2 and len(chunks) + len(ticks) \
         == len(programs)
     assert c["dsa.queries"] == 2 * (16 * len(chunks) + 2 * len(ticks))
     assert c["dsa.mask_queries"] == 2 * 16 * sum(
-        1 for _, t, longest in chunks if longest + t <= reach)
+        1 for p in chunks if p.longest + p.t <= reach)
     assert c["dsa.mask_queries"] == {"mask": 160, "list": 0,
                                      "mask_then_list": 96}[path]
     assert c["dsa.mask_queries"] == sum(
-        rows * t * 2 for rows, t, longest in programs
-        if longest + t <= lm.mask_reach(t, 48, 6))
+        p.rows * p.t * 2 for p in programs
+        if p.longest + p.t <= lm.mask_reach(p.t, 48, 6))
 
 
 def test_ticks_alone_count_no_query_under_the_mask(monkeypatch, served):
@@ -577,7 +577,7 @@ def test_ticks_alone_count_no_query_under_the_mask(monkeypatch, served):
     programs = _dispatched(monkeypatch)
     eng = _engine(mc, params, chunk=16)
     rid = eng.submit(Request(prompt=prompts[0], max_new_tokens=9))
-    while not programs or programs[-1][1] > 1:      # until the first tick
+    while not programs or programs[-1].t > 1:       # until the first tick
         eng.step()
     counters = lambda: eng.metrics_snapshot()["counters"]  # noqa: E731
     before, n = counters(), len(programs)
@@ -585,7 +585,7 @@ def test_ticks_alone_count_no_query_under_the_mask(monkeypatch, served):
     while eng.pending():
         eng.step()
     assert list(eng.results[rid]) == want[0]
-    assert {t for _, t, _ in programs[n:]} == {1} and len(programs) > n
+    assert {p.t for p in programs[n:]} == {1} and len(programs) > n
     after = counters()
     assert after["dsa.mask_queries"] == before["dsa.mask_queries"]
     assert after["dsa.queries"] - before["dsa.queries"] \
@@ -613,8 +613,8 @@ def test_choices_in_place_are_those_of_the_programs_of_few_rows(
         ("_experts_in_place" if rows <= threshold else "_experts_in_tiles",
          rows) for rows in (2, 16))
     c = eng.metrics_snapshot()["counters"]
-    assert {(rows, t) for rows, t, _ in programs} == {(1, 16), (2, 1)}
-    ticks = sum(1 for rows, t, _ in programs if t == 1)
+    assert {(p.rows, p.t) for p in programs} == {(1, 16), (2, 1)}
+    ticks = sum(1 for p in programs if p.t == 1)
     chunks = len(programs) - ticks
     assert c["moe.choices_in_place"] == 4 * 4 * (
         2 * ticks * (2 <= threshold) + 16 * chunks * (16 <= threshold))

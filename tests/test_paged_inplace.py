@@ -17,8 +17,9 @@ Each at toy size on the CPU:
 3. the jaxpr has the pool among the scan's carries and neither among its
    scanned inputs nor its stacked outputs;
 4. a toy engine's compiled tick and chunk hold less scratch than one pool, and
-   its ``attn.blocks_visited`` / ``attn.blocks_in_table`` add up to what the
-   schedule says.
+   its ``attn.blocks_live`` / ``attn.blocks_visited`` / ``attn.blocks_in_table``
+   add up to what the schedule says (``tests/test_row_groups.py`` holds the
+   walk of a program of more rows than one group).
 """
 
 import jax
@@ -36,11 +37,12 @@ N_SLOTS, MAX_LEN, BLOCK, N_BLOCKS = 4, 32, 8, 12
 
 
 def _paged_attend_full_width(params, tokens, cfg, kv_k, kv_v, qpos, wflat,
-                             table):
+                             table, active=None):
     """The body as it was before the pool became a carry and attention
     walked the live blocks: ``kv_k`` / ``kv_v`` scanned in layer by layer,
     each written slice stacked out, and every row's whole table gathered,
-    scored and put through one dense softmax."""
+    scored and put through one dense softmax (whether or not the row's output
+    is read: ``active`` is not looked at)."""
     b, t = tokens.shape
     nl, n_blocks, bs, kvh, dh = kv_k.shape
     gflat = (table[:, :, None] * bs
@@ -351,7 +353,10 @@ def test_attention_counters_follow_the_schedule(monkeypatch):
     """Row A: 6 prompt tokens (one chunk), 3 answers; row B: 19 (chunks of
     8, 8, 3), 2 answers; tables of 4 blocks of 8, one block a key tile.  A
     chunk from length ``n`` walks ``(n + 7) // 8 + 1`` blocks of its one row,
-    a tick ``longest // 8 + 1`` blocks of both rows, each of a table of 4."""
+    which are the blocks it spans; a tick's two rows are one group, which
+    walks to the longest row that decodes (a row that does not walks one
+    tile), and the blocks live are those of the rows that decode; each of a
+    table of 4."""
     monkeypatch.setattr(llama, "_KEY_TILE", 8)
     cfg = llama.llama_tiny(dtype=jnp.float32)
     params = llama.init_params(cfg, jax.random.key(0))
@@ -361,24 +366,23 @@ def test_attention_counters_follow_the_schedule(monkeypatch):
     eng.submit(Request(prompt=list(range(1, 7)), max_new_tokens=3))
     eng.submit(Request(prompt=list(range(1, 20)), max_new_tokens=2))
     counters = lambda: eng.metrics.snapshot()["counters"]  # noqa: E731
-    assert counters()["attn.blocks_visited"] == 0
-    assert counters()["attn.blocks_in_table"] == 0
+    names = [f"attn.blocks_{n}" for n in ("live", "visited", "in_table")]
+    assert [counters()[n] for n in names] == [0, 0, 0]
     by_hand = [
         # A's chunk from 0, B's from 0; tick with A at 6, B (prefilling) at 8
-        (1 + 1 + 2 * 2, 4 + 4 + 8),
-        # B's chunk from 8; tick with A at 7, B at 16
-        (2 + 2 * 3, 4 + 8),
-        # B's last chunk from 16; tick with A at 8, B at 19
-        (3 + 2 * 3, 4 + 8),
+        (1 + 1 + 1, 1 + 1 + 2 * 1, 4 + 4 + 8),
+        # B's chunk from 8; tick with A at 7, B (prefilling) at 16
+        (2 + 1, 2 + 2 * 1, 4 + 8),
+        # B's last chunk from 16; tick with A at 8, B at 19: both decode
+        (3 + 2 + 3, 3 + 2 * 3, 4 + 8),
         # A has its 3 answers and is free; tick with B at 20
-        (2 * 3, 8),
+        (3, 2 * 3, 8),
     ]
-    seen = (0, 0)
-    for visited, in_table in by_hand:
+    seen = [0, 0, 0]
+    for want in by_hand:
         eng.step()
-        now = (counters()["attn.blocks_visited"],
-               counters()["attn.blocks_in_table"])
-        assert (now[0] - seen[0], now[1] - seen[1]) == (visited, in_table)
+        now = [counters()[n] for n in names]
+        assert tuple(a - b for a, b in zip(now, seen)) == want
         seen = now
     assert not eng.pending()
 

@@ -329,6 +329,31 @@ def test_permanent_tick_fault_keeps_tokens_so_far(world):
     assert eng.free_block_count() == eng.pcache.k.shape[1] - 1
 
 
+def test_an_error_in_the_models_own_counters_is_no_rows_fault(
+        world, monkeypatch):
+    """The model's host-side count of a step's programs runs between the
+    tick's dispatch and its readback, and outside the tick's fault handling:
+    an error there leaves ``step()`` as it did from the step's end, and no
+    row is quarantined or retried for it."""
+    cfg, params = world
+    eng = ServeEngine(params, cfg, n_slots=2, max_len=16, chunk=4)
+    rid = eng.submit(Request(prompt=[5, 17, 42], max_new_tokens=4))
+    publish = llama.publish_paged_metrics
+
+    def broken(metrics, cfg, pcache, stats_host=None, row_blocks=(),
+               programs=()):
+        if any(p.rows > 1 for p in programs):       # a tick's
+            raise ZeroDivisionError("the count")
+        publish(metrics, cfg, pcache, stats_host, row_blocks, programs)
+
+    monkeypatch.setattr(llama, "publish_paged_metrics", broken)
+    with pytest.raises(ZeroDivisionError, match="the count"):
+        while eng.pending():
+            eng.step()
+    assert rid not in eng.results and eng.counters["retries"] == 0
+    assert not [e for e in eng.events if e.kind in ("retry", "fail")]
+
+
 def test_transient_faults_retry_to_parity(world):
     """Transient faults at every engine site (admit, prefill window,
     decode readback) retry within bounds and the request still ends OK

@@ -203,7 +203,9 @@ def test_counters_equal_what_the_run_did(served):
     assert sum(gauges[f"moe.held_load.{e}"] for e in range(8)) == \
         c["moe.choices_total"]
     assert 0 < gauges["moe.experts_touched"] <= 5 * 2
-    assert c["attn.blocks_visited"] == c["attn.blocks_in_table"] > 0
+    # a table of 48 positions is one key tile: no walk can read less
+    assert c["attn.blocks_visited"] == c["attn.blocks_in_table"] \
+        > c["attn.blocks_live"] > 0
 
 
 @pytest.mark.parametrize("threshold", [0, 4, 256])
@@ -230,8 +232,8 @@ def test_choices_in_place_are_those_of_the_programs_of_few_rows(
     eng = _engine(mc, params)
     out = eng.run(_requests(prompts[:2]))
     assert [list(r) for r in out] == want[:2]
-    assert {(rows, t) for rows, t, _ in programs} == {(1, 8), (2, 1)}
-    ticks = sum(1 for rows, t, _ in programs if t == 1)
+    assert {(p.rows, p.t) for p in programs} == {(1, 8), (2, 1)}
+    ticks = sum(1 for p in programs if p.t == 1)
     chunks = len(programs) - ticks
     c = _counters(eng)
     assert c["moe.choices_in_place"] == mc.top_k * 5 * (

@@ -534,7 +534,9 @@ def test_counters_equal_what_the_run_did(served):
     assert sum(load) == c["moe.choices_held"]
     assert gauges["moe.load_max"] == max(load)
     assert 0 < gauges["moe.experts_touched"] <= N_MOE * 8
-    assert c["attn.blocks_visited"] == c["attn.blocks_in_table"] > 0
+    # a table of 48 positions is one key tile: no walk can read less
+    assert c["attn.blocks_visited"] == c["attn.blocks_in_table"] \
+        > c["attn.blocks_live"] > 0
     assert c["moe.choices_in_place"] > 0
 
 
