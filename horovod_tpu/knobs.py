@@ -108,9 +108,10 @@ ENV_KNOBS = (
     ("HVD_TPU_PEAK_FLOPS", "",
      "Per-chip peak FLOP/s override for the serving-MFU denominator."),
     ("HVD_TPU_PROFILE", "0",
-     "Per-tick phase profiling in ServeEngine (serve.phase.* metrics)."),
+     "Feed the step rows' phases to the serve.phase.* histograms and the "
+     "event log."),
     ("HVD_TPU_PROFILE_WINDOW", "256",
-     "Ticks in the profiler's rolling per-phase report window."),
+     "Step rows in the rolling per-phase report (/profile)."),
     ("HVD_TPU_RETRACE_FATAL", "0",
      "Raise when the retrace sentry sees a jit cache grow mid-serve."),
     ("HVD_TPU_ROUTER_DRAIN_S", "5.0",

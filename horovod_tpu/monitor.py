@@ -138,16 +138,11 @@ class _Handler(BaseHTTPRequestHandler):
                             "text/plain; charset=utf-8")
         elif path == "/profile":
             prof = getattr(mon.engine, "prof", None)
-            rep = prof.report() if prof is not None else None
-            if rep is None:
-                self._reply(
-                    404, "profiling off: the step's phases are spans on "
-                         "jax's profiler trace only; construct the "
-                         "engine with profile=True or set "
-                         "HVD_TPU_PROFILE=1 for host-clock numbers\n",
-                    "text/plain")
+            if prof is None:
+                self._reply(404, "no engine attached\n", "text/plain")
             else:
-                self._reply(200, json.dumps(rep), "application/json")
+                self._reply(200, json.dumps(prof.report()),
+                            "application/json")
         elif path == "/device":
             dev = getattr(mon.engine, "device", None)
             if dev is None:

@@ -448,14 +448,14 @@ class ServeEngine:
         self._trace_fraction = tracing_mod.env_sample_fraction()
         self._trace_seed = tracing_mod.env_trace_seed()
         # The phases of step(): None = env-driven (HVD_TPU_PROFILE=1).
-        # Off, they are spans on jax's profiler trace and nothing else
-        # (PhaseSpans: free while no trace is taken); on, TickProfiler
-        # adds host clocks, histograms and report().
+        # Either way they are spans on jax's profiler trace and one row
+        # a step in self.prof.log (PhaseSpans); on, TickProfiler feeds
+        # the rows to the serve.phase.*_s histograms and the event log.
         if profile is None:
             profile = os.environ.get("HVD_TPU_PROFILE", "") == "1"
-        self.prof = (profiler_mod.TickProfiler(
+        self.prof = (profiler_mod.TickProfiler if profile
+                     else profiler_mod.PhaseSpans)(
             self.metrics, window=profile_window)
-            if profile else profiler_mod.PhaseSpans())
         # Device telemetry plane (horovod_tpu.device_telemetry): XLA
         # cost model + compile ledger + HBM polling + the device_sync
         # compute/stall split.  None = env-driven
@@ -777,9 +777,7 @@ class ServeEngine:
             # router needs to know about THIS replica's cached prefixes
             # (rides /snapshot via the monitor for free).
             snap["prefix"] = self.prefix.key_digest()
-        profile = self.prof.report()
-        if profile is not None:
-            snap["profile"] = profile
+        snap["profile"] = self.prof.report()
         if self.device is not None:
             snap["device"] = self.device.report()
         if self.sampler is not None:
@@ -915,13 +913,12 @@ class ServeEngine:
             f" shard_total="
             f"{self._shard_block_bytes * self.pool.n_blocks}")
         rep = self.prof.report()
-        if rep is not None:
-            lines.append(
-                "  profile (mean ms over last "
-                f"{rep['n']} ticks): " + " ".join(
-                    f"{p}={rep['phases'][p]['mean_s'] * 1e3:.3f}"
-                    for p in rep["phases"] if "." not in p)
-                + f" tick={rep['tick']['mean_s'] * 1e3:.3f}")
+        lines.append(
+            "  profile (mean ms over last "
+            f"{rep['n']} ticks): " + " ".join(
+                f"{p}={rep['phases'][p]['mean_s'] * 1e3:.3f}"
+                for p in rep["phases"] if "." not in p)
+            + f" tick={rep['tick']['mean_s'] * 1e3:.3f}")
         if self.device is not None:
             drep = self.device.report()
             mfu = drep["win"]["mfu"]
@@ -1403,8 +1400,6 @@ class ServeEngine:
         if tpot is not None:
             self.metrics.histogram("serve.tpot_s").observe(tpot)
         self.metrics.counter("serve.requests_completed").inc()
-        if tr.n_tokens:
-            self.metrics.counter("serve.tokens_emitted").inc(tr.n_tokens)
         if self.timeline is not None:
             self.timeline.async_end("serving.requests", "REQ", rid)
 
@@ -1589,7 +1584,7 @@ class ServeEngine:
         # The phases are mark-based: begin() opens the tick in `expire`
         # and each mark() is the boundary at which the named phase
         # starts, so they tile the tick — as spans on the profiler
-        # trace and, with profiling on, as host-clock shares.
+        # trace and as the durations of the step's row.
         prof = self.prof
         prof.begin(self.step_index)
         try:
@@ -1693,6 +1688,8 @@ class ServeEngine:
                     tr.prefill_chunks += 1
                 if final:
                     s.state = DECODE      # joins this step's tick
+        n_chunks = len(programs)
+        tick_rows = n_tokens = n_first = 0
         decoding = [i for i, s in enumerate(self._slots)
                     if s.state == DECODE]
         spec = self.spec and bool(decoding)
@@ -1807,6 +1804,7 @@ class ServeEngine:
                 progress += len(decoding)
             else:
                 progress += len(decoding)
+                tick_rows = len(decoding)
                 if spec:
                     self._bump_spec("rounds")
                 for slot in decoding:
@@ -1830,6 +1828,7 @@ class ServeEngine:
                         self._row_fault(slot, exc)
                         continue
                     if not s.prior and not s.out:
+                        n_first += 1
                         tr = self.traces.get(s.request_id)
                         if tr is not None and tr.first_token_ts is None:
                             tr.first_token_ts = time.monotonic()
@@ -1841,6 +1840,7 @@ class ServeEngine:
                     # over-advanced device length dies with the slot
                     for t in emit:
                         s.out.append(t)
+                        n_tokens += 1
                         s.budget -= 1
                         if s.draft is not None:
                             s.draft.extend((t,))
@@ -1875,11 +1875,15 @@ class ServeEngine:
         # once per step, plus the step odometer — available with no
         # timeline attached (the scrape path).
         self.metrics.counter("serve.steps").inc()
+        if n_tokens:
+            self.metrics.counter("serve.tokens_emitted").inc(n_tokens)
         self.metrics.gauge("serve.queue_depth").set(len(self._queue))
         self.metrics.gauge("serve.decoding").set(len(decoding))
         self.metrics.gauge("serve.prefilling").set(
             sum(1 for s in self._slots if s.state == PREFILL))
         self.metrics.gauge("serve.free_blocks").set(len(self._free_blocks))
+        prof.counts(chunks=n_chunks, tick_rows=tick_rows, tokens=n_tokens,
+                    first_tokens=n_first)
         self.metrics.gauge("serve.cached_blocks").set(
             self.pool.cached_count())
         if self.prefix is not None:

@@ -58,6 +58,7 @@ import time
 from typing import Any, Callable, Sequence
 
 from horovod_tpu import faults as faults_mod
+from horovod_tpu import profiler as profiler_mod
 from horovod_tpu.monitor import env_float
 from horovod_tpu.router import LocalReplica, ReplicaHandle, RouterServer
 from horovod_tpu.serving import Request
@@ -89,7 +90,7 @@ def clone_engine(eng: Any) -> Any:
         monitor=False,
         slo_window=eng.slo._traces.maxlen,
         slo_e2e_s=eng.slo.slo_e2e_s,
-        profile=eng.prof.report() is not None,
+        profile=isinstance(eng.prof, profiler_mod.TickProfiler),
         spec=eng.spec,
         draft_k=eng.draft_k,
         policy=eng.policy,

@@ -199,6 +199,12 @@ def trace_annotation(name: str):
     (:class:`horovod_tpu.profiler.PhaseSpans`, ``serve.step[.<phase>]``),
     the sections of ``LocalReplica``'s pump loop (``replica.pump.*``) and
     ``RouterServer.route`` (``router.*``).
+
+    A span is the record on the trace's clock and nothing else: it names
+    the device's idle gaps and keeps no duration outside a profiler
+    session.  What the phases of a step cost is kept by the profiler's
+    own clock reads at the same boundaries, one row a step on every
+    engine (:class:`horovod_tpu.profiler.StepLog`).
     """
     return jax.profiler.TraceAnnotation(name)
 

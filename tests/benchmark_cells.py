@@ -1,0 +1,36 @@
+"""What the shims ``test_benchmark_lfm2.py`` and ``test_benchmark_kexaone.py``
+put in the place of one accepted test each.
+
+``benchmark/tests/test_lfm2.py`` and ``test_kexaone.py`` pin their cell to
+eleven per-layer metrics, all with the family's suffix.  Since PR 35 every
+serving cell also reports the metrics read from the program's step log
+(``.batch``), and a file the benchmark already had is not that kind of PR's to
+edit: under tier-1 the shims collect the same test with the step log's metrics
+counted apart.  Run directly (``python -m pytest benchmark/tests``) the two
+accepted cases fail on ``len(want) == 11`` until a ``benchmark`` issue edits
+that line (PERF.md section 7)."""
+
+import json
+import math
+import os
+
+import rehearse
+
+STEP_LOG = {"step_host_ms.batch", "step_sync_wait_ms.batch",
+            "between_steps_ms.batch", "chunk_dispatch_ms.batch",
+            "attn_walk_over_live.batch"}
+
+
+def traced_cell(copy: str, cell: str, suffix: str, reported: set) -> dict:
+    """The accepted test's assertions on a traced toy run of ``cell``, and
+    that it prints every step-log metric too; returns the result line."""
+    rc, last, out, err = rehearse.run_in_copy(copy, cell, trace=1)
+    assert rc == 0 and last is not None, (out[-2000:], err[-2000:])
+    with open(os.path.join(copy, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    want = {m["name"] for m in spec["per_layer"] if cell in m["workloads"]}
+    own = {n for n in want if n.endswith(suffix)}
+    assert len(own) == 11 and want - own == STEP_LOG
+    assert reported | STEP_LOG <= set(last["metrics"]) <= want
+    assert all(math.isfinite(last["metrics"][n]["value"]) for n in STEP_LOG)
+    return last

@@ -268,17 +268,18 @@ def test_exporter_endpoints(world):
         assert code == 200 and ctype == "application/json"
         snap = json.loads(body)
         # Engine attached → the engine's view, SLO + memory reports
-        # embedded, plus the env-default health plane ("profile"
-        # appears only with profiling on).
+        # embedded, the step rows' report ("profile", on every engine)
+        # plus the env-default health plane.
         assert set(snap) == {"counters", "gauges", "histograms", "slo",
-                             "memory", "timeseries", "alerts",
+                             "memory", "profile", "timeseries", "alerts",
                              "advice"}
         assert snap["counters"]["monitor.scrapes"] >= 1
         assert snap["slo"]["goodput"] == eng.slo.goodput()
         assert snap["memory"]["kv"]["block_bytes"] == eng._block_bytes
-        # profiling off → /profile 404s with a hint
-        code, _, body = _get(mon, "/profile")
-        assert code == 404 and "HVD_TPU_PROFILE" in body
+        # profiling off → /profile still answers, from the step rows
+        code, ctype, body = _get(mon, "/profile")
+        assert code == 200 and ctype == "application/json"
+        assert json.loads(body)["ticks"] == eng.step_index
         code, _, body = _get(mon, "/healthz")
         hz = json.loads(body)
         assert code == 200 and hz["ok"] is True

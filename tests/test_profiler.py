@@ -25,6 +25,11 @@ The acceptance criteria, pinned:
    tiled by ``serve.step.<phase>``, the sub-phases nest inside
    ``admit``, an exception out of the step leaves nothing open, and
    taking the trace changes neither tokens nor compile counts.
+7. *One row a step, on every engine*: the default class keeps
+   ``ROW_FIELDS`` a step in a bounded ``StepLog`` (tiling phases sum to
+   ``ended - began``, the counts follow the schedule, the ring counts
+   what it dropped), ``step_logs()`` outlives the engine, and
+   ``TickProfiler`` adds only the histograms and the event.
 """
 
 from __future__ import annotations
@@ -43,8 +48,9 @@ from horovod_tpu import profiler as profiler_mod
 from horovod_tpu.metrics import MetricsRegistry
 from horovod_tpu.models import llama
 from horovod_tpu.monitor import MonitorServer
-from horovod_tpu.profiler import (PHASES, SUB_PHASES, PhaseSpans,
-                                  TickProfiler)
+from horovod_tpu.profiler import (CARRIED, COUNTS, PHASES, ROW_FIELDS,
+                                  SPEC_PHASES, SUB_PHASES, TILING,
+                                  PhaseSpans, TickProfiler)
 from horovod_tpu.serving import OK, Request
 from horovod_tpu.serving_scheduler import ServeEngine
 
@@ -141,8 +147,12 @@ def test_profile_on_off_parity_and_phase_sum(world):
                                         "set_row": 1}
     assert on.metrics.counter("serve.retrace").value == 0
     snap = on.metrics_snapshot()
-    assert "profile" in snap and "profile" not in off.metrics_snapshot()
     rep = snap["profile"]
+    # the default engine reports the same steps in the same schema
+    rep_off = off.metrics_snapshot()["profile"]
+    assert rep_off["ticks"] == rep["ticks"] == on.step_index
+    assert set(rep_off["phases"]) == set(rep["phases"])
+    assert rep_off["coverage"] == pytest.approx(1.0, rel=1e-6)
     # phase sum within 10 % of measured wall step time (the tiling
     # construction makes it exact vs the profiler's own tick clock;
     # vs the OUTER wall clock only the between-step run() overhead
@@ -165,9 +175,10 @@ def test_profile_env_knob(world, monkeypatch):
     eng = _engine(world)
     assert type(eng.prof) is TickProfiler and eng.prof.report()["n"] == 0
     monkeypatch.delenv("HVD_TPU_PROFILE")
-    # off: the spans alone, which keep no numbers
+    # off: the spans and the rows, no histograms
     off = _engine(world)
-    assert type(off.prof) is PhaseSpans and off.prof.report() is None
+    assert type(off.prof) is PhaseSpans and off.prof.report()["n"] == 0
+    assert "serve.phase.tick_s" not in off.metrics.snapshot()["histograms"]
     # explicit argument beats the env
     monkeypatch.setenv("HVD_TPU_PROFILE", "1")
     assert type(_engine(world, profile=False).prof) is PhaseSpans
@@ -289,9 +300,11 @@ def test_event_log_bytes_accounted(world, tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def test_profile_endpoint_over_socket(world):
+@pytest.mark.parametrize("profile", [False, True],
+                         ids=["spans", "profiler"])
+def test_profile_endpoint_over_socket(world, profile):
     import urllib.request
-    eng = _engine(world, profile=True)
+    eng = _engine(world, profile=profile)
     mon = MonitorServer(eng.metrics, eng, port=0).start()
     try:
         eng.run(_reqs(3))
@@ -479,5 +492,198 @@ def test_exception_out_of_step_leaves_no_span_open(world, host_trace,
     raised = tr.children(line, steps[0])
     assert raised[-1][0] == "serve.step.bookkeeping"
     assert raised[-1][2] <= steps[0][2] <= steps[1][1]
-    if profile:                          # the aborted tick still counted
-        assert eng.prof.report()["ticks"] == eng.step_index + 1
+    # the aborted tick still left a whole row (step_index did not advance)
+    assert eng.prof.report()["ticks"] == eng.step_index + 1
+    rows = eng.prof.log.rows()
+    aborted = dict(zip(ROW_FIELDS, rows[-1 - (len(steps) - 1)]))
+    assert aborted["ended"] > aborted["began"] and aborted["bookkeeping"] > 0
+    assert sum(aborted[p] for p in TILING) == pytest.approx(
+        aborted["ended"] - aborted["began"], abs=1e-9)
+
+
+# ---------------------------------------------------------------------------
+# Acceptance 7: one row a step, on every engine.
+# ---------------------------------------------------------------------------
+
+
+def _as_dicts(log):
+    return [dict(zip(ROW_FIELDS, r)) for r in log.rows()]
+
+
+def test_row_fields_are_the_vocabulary():
+    assert ROW_FIELDS[:3] == ("step", "began", "ended")
+    assert set(TILING) == set(PHASES) | set(SPEC_PHASES)
+    assert ROW_FIELDS == (("step", "began", "ended") + TILING + SUB_PHASES
+                          + COUNTS + CARRIED)
+    assert COUNTS == ("chunks", "tick_rows", "tokens", "first_tokens")
+    assert len(set(ROW_FIELDS)) == len(ROW_FIELDS)
+
+
+def test_default_class_rows_tile_the_step():
+    prof = PhaseSpans(MetricsRegistry(event_log=None), window=8)
+    for step in range(3):
+        prof.begin(step)
+        prof.mark("admit")
+        with prof.sub("admit.prefill_dispatch"):
+            pass
+        prof.mark("decode_dispatch")
+        prof.mark("device_sync")
+        prof.add("device_sync.compute_est", 1.0, 1.5)
+        prof.mark("sample_postprocess")
+        prof.mark("bookkeeping")
+        prof.counts(chunks=2, tick_rows=step, tokens=step)
+        prof.end()
+    rows = _as_dicts(prof.log)
+    assert [r["step"] for r in rows] == [0, 1, 2]
+    for a, b in zip(rows, rows[1:]):
+        assert a["ended"] <= b["began"]
+    for i, r in enumerate(rows):
+        # to well under a microsecond: differences of one clock's reads
+        assert sum(r[p] for p in TILING) == pytest.approx(
+            r["ended"] - r["began"], abs=1e-9)
+        assert r["draft"] == r["verify"] == 0.0
+        assert 0 < r["admit.prefill_dispatch"] <= r["admit"]
+        assert r["device_sync.compute_est"] == 0.5
+        assert (r["chunks"], r["tick_rows"], r["tokens"]) == (2, i, i)
+        assert r["first_tokens"] == 0
+        assert all(r[c] == 0 for c in CARRIED)      # none in this registry
+    rep = prof.report()
+    assert rep["n"] == rep["ticks"] == 3
+    assert rep["coverage"] == pytest.approx(1.0, rel=1e-9)
+    assert set(rep["phases"]) == set(PHASES) | set(SUB_PHASES)
+    assert rep["phases"]["device_sync.compute_est"]["total_s"] == 1.5
+    # the default class feeds no histogram
+    assert not prof.log.metrics.snapshot()["histograms"]
+
+
+def test_step_log_is_bounded_and_counts_what_it_dropped(monkeypatch):
+    monkeypatch.setattr(profiler_mod, "STEP_LOG_ROWS", 4)
+    prof = PhaseSpans(MetricsRegistry(event_log=None))
+    assert prof.log.dropped == 0 and len(prof.log.rows()) == 0
+    for step in range(6):
+        prof.begin(step)
+        prof.end()
+    log = prof.log
+    assert (log.written, log.dropped) == (6, 2)
+    assert [r[0] for r in log.rows()] == [2, 3, 4, 5]      # oldest first
+    assert [r[0] for r in log.rows(last=2)] == [4, 5]
+    began = log.rows()[:, ROW_FIELDS.index("began")]
+    assert list(began) == sorted(began)
+    # the window of report() is not the ring's bound
+    assert prof.report()["n"] == 4 and prof.report()["ticks"] == 6
+    # a copy: a later step does not move what a reader holds
+    kept = log.rows()
+    prof.begin(6)
+    prof.end()
+    assert [r[0] for r in kept] == [2, 3, 4, 5]
+
+
+def test_step_logs_outlive_their_engines_and_are_bounded(world):
+    eng = _engine(world)
+    out = eng.run(_reqs(3))
+    n_tokens = sum(len(r) for r in out)
+    log, steps = eng.prof.log, eng.step_index
+    assert profiler_mod.step_logs()[-1] is log
+    eng.params = eng.pcache = None
+    del eng
+    assert profiler_mod.step_logs()[-1] is log
+    assert log.written == steps and log.dropped == 0
+    counters = log.metrics.snapshot()["counters"]
+    assert counters["serve.steps"] == steps
+    assert counters["serve.tokens_emitted"] == n_tokens
+    # the last two, oldest first
+    made = [PhaseSpans(MetricsRegistry(event_log=None)).log
+            for _ in range(3)]
+    kept = profiler_mod.step_logs()
+    assert len(kept) == 2 and log not in kept and made[0] not in kept
+    assert all(a is b for a, b in zip(kept, made[1:]))
+
+
+@pytest.mark.parametrize("profile", [False, True],
+                         ids=["spans", "profiler"])
+def test_rows_follow_a_known_schedule(world, profile):
+    # one request of 11 prompt tokens (two windows of 8) and 3 to serve:
+    # step 0 admits it and dispatches window 0; step 1 dispatches the last
+    # window and the row joins that step's tick (its first token); steps 2
+    # and 3 tick once each; the third token retires it.
+    eng = _engine(world, profile=profile)
+    rid = eng.submit(Request(prompt=list(range(1, 12)), max_new_tokens=3))
+    emitted = eng.metrics.counter("serve.tokens_emitted")
+    walked = [eng.metrics.counter(c) for c in CARRIED[:2]]
+    seen, carried = [], []
+    while eng.pending():
+        eng.step()
+        seen.append(emitted.value)
+        carried.append(tuple(c.value for c in walked))
+    assert len(eng.results[rid]) == 3
+    rows = _as_dicts(eng.prof.log)
+    got = [tuple(int(r[c]) for c in COUNTS) for r in rows]
+    assert got == [(1, 0, 0, 0), (1, 1, 1, 1), (0, 1, 1, 0), (0, 1, 1, 0)]
+    # the counter moves when a token is emitted, not when its request ends
+    assert seen == [0, 1, 2, 3]
+    assert [int(r["step"]) for r in rows] == [0, 1, 2, 3]
+    # the model's counters as they stood at each step's end: every step
+    # dispatched a program, so both grew in every row
+    assert [(r["attn.blocks_visited"], r["attn.blocks_live"])
+            for r in rows] == carried
+    assert all(a[0] < b[0] and a[1] < b[1]
+               for a, b in zip([(0, 0)] + carried, carried))
+    assert all(r["dsa.queries"] == 0 for r in rows)     # not this model's
+    assert "dsa.queries" not in eng.metrics.snapshot()["counters"]
+    for r in rows:
+        assert sum(r[p] for p in TILING) == pytest.approx(
+            r["ended"] - r["began"], abs=1e-9)
+        ticked = r["tick_rows"] > 0
+        assert (r["device_sync"] > 0) == (r["decode_dispatch"] > 0) == ticked
+        assert 0 < r["admit.prefill_dispatch"] <= r["admit"]
+    assert eng.compile_cache_sizes() == {"tick": 1, "chunk": 1,
+                                         "set_row": 1}
+
+
+def test_spec_rows_carry_draft_and_verify_and_every_token(world):
+    eng = _engine(world, spec=True, draft_k=3)
+    out = eng.run(_reqs(3, new=8))
+    assert all(r.status == OK for r in out)
+    rows = _as_dicts(eng.prof.log)
+    ticking = [r for r in rows if r["tick_rows"] > 0]
+    assert ticking and all(r["draft"] > 0 and r["verify"] > 0
+                           and r["sample_postprocess"] == 0
+                           for r in ticking)
+    assert sum(r["tokens"] for r in rows) == sum(len(r) for r in out)
+    assert sum(r["first_tokens"] for r in rows) == len(out)
+    # spec phases join the report once a row of the window had them
+    rep = eng.prof.report()
+    assert set(rep["phases"]) == (set(PHASES) | set(SPEC_PHASES)
+                                  | set(SUB_PHASES))
+    assert rep["phases"]["sample_postprocess"]["count"] == 0
+
+
+def test_tick_profiler_adds_only_histograms_and_the_event(world, tmp_path):
+    log = str(tmp_path / "events.jsonl")
+    reg = MetricsRegistry(event_log=metrics_mod.EventLog(log))
+    eng = _engine(world, metrics=reg, profile=True, prefix_cache=True)
+    eng.run(_reqs(4))
+    rows = _as_dicts(eng.prof.log)
+    hists = reg.snapshot()["histograms"]
+    assert hists["serve.phase.tick_s"]["count"] == len(rows)
+    assert hists["serve.phase.tick_s"]["sum"] == pytest.approx(
+        sum(r["ended"] - r["began"] for r in rows), rel=1e-9)
+    for phase, name in (("device_sync", "serve.phase.device_sync_s"),
+                        ("admit.cache_acquire",
+                         "serve.phase.admit_cache_acquire_s")):
+        had = [r[phase] for r in rows if r[phase] > 0]
+        assert hists[name]["count"] == len(had) > 0
+        assert hists[name]["sum"] == pytest.approx(sum(had), rel=1e-9)
+    events = [json.loads(ln) for ln in open(log)]
+    ticks = [e for e in events if e["kind"] == "serve.profile_tick"]
+    assert [e["step"] for e in ticks] == [r["step"] for r in rows]
+    for e, r in zip(ticks, rows):
+        assert e["phases"] == {p: r[p] for p in TILING + SUB_PHASES
+                               if r[p] > 0}
+
+
+def test_clone_engine_asks_the_class(world):
+    from horovod_tpu.supervisor import clone_engine
+    assert type(clone_engine(_engine(world)).prof) is PhaseSpans
+    assert type(clone_engine(_engine(world, profile=True)).prof) \
+        is TickProfiler
