@@ -356,7 +356,7 @@ def _serve_and_probe(eng, reqs, probes, http: int) -> tuple[list, dict]:
     # the AOT compiles: admission, recycling and prefix hits never retraced a
     # pinned program, and lowering one mints no entry.
     sizes = eng.compile_cache_sizes()
-    assert sizes == {"tick": 1, "chunk": 1, "set_row": 1}, sizes
+    assert sizes == {"sample": 1, "tick": 1, "chunk": 1, "set_row": 1}, sizes
     return logits, times
 
 

@@ -65,7 +65,7 @@ from horovod_tpu import metrics as metrics_mod
 
 #: The pinned jit programs the engine captures, in capture order
 #: (``spec_tick`` only on spec engines).
-PROGRAMS = ("tick", "chunk", "set_row", "spec_tick")
+PROGRAMS = ("sample", "tick", "chunk", "set_row", "spec_tick")
 
 #: Dense per-chip peak FLOP/s by accelerator generation (bf16/fp32 as
 #: served — published TPU peak matmul numbers), matched as lowercase
@@ -279,20 +279,25 @@ class DeviceTelemetry:
             p["bytes_accessed"] += entry["bytes_accessed"]
         p["h2d_bytes"] += h2d_bytes
 
-    def on_sync(self, name: str, t0: float, t1: float,
+    def on_sync(self, names, t0: float, t1: float,
                 d2h_bytes: int = 0) -> tuple[float, float]:
         """Split one measured ``device_sync`` readback wait ``[t0, t1]``
         into (device-compute estimate, host stall) using the cost
-        model's predicted device time for program ``name`` — predicted
-        = flops / peak.  With no honest peak (CPU rehearsals) the split
-        degenerates to all-compute: we cannot prove any stall, so none
-        is claimed.  Returns ``(compute_est_s, host_stall_s)``."""
+        model's predicted device time for the programs ``names`` that
+        the wait was for (the engine's read waits for the tick in
+        flight and the step's chunks; each counted whole, so the
+        estimate is an upper bound where a program began before the
+        wait did) — predicted = flops / peak.  With no honest peak (CPU
+        rehearsals) the split degenerates to all-compute: we cannot
+        prove any stall, so none is claimed.  Returns
+        ``(compute_est_s, host_stall_s)``."""
         sync_s = max(t1 - t0, 0.0)
         est = sync_s
         if self.peak_flops:
-            entry = self.programs.get(name)
-            if entry is not None and entry["flops"] > 0.0:
-                est = min(entry["flops"] / self.peak_flops, sync_s)
+            flops = sum(self.programs[n]["flops"] for n in names
+                        if n in self.programs)
+            if flops > 0.0:
+                est = min(flops / self.peak_flops, sync_s)
         stall = sync_s - est
         p = self._pend
         p["d2h_bytes"] += d2h_bytes

@@ -160,6 +160,7 @@ METRIC_HELP: dict[str, str] = {
     "serve.e2e_s": "Seconds from submit to terminal status",
     "serve.tpot_s": "Seconds per output token after the first (decode cadence)",
     "serve.steps": "Engine scheduler steps executed",
+    "serve.step.host_bound": "Ticking steps whose sampled tokens were ready before the host asked: the device had run out of work first",
     "serve.queue_depth": "Requests waiting for admission",
     "serve.decoding": "Slots actively decoding",
     "serve.prefilling": "Slots mid-prefill",

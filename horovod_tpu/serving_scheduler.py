@@ -22,10 +22,18 @@ step toward a production scheduler (Orca OSDI '22 / vLLM SOSP '23):
   admission allocates only the blocks a request needs (host free-list),
   retirement returns them — recycling reuses memory without
   re-allocating device buffers or re-compiling anything;
-* a **fixed-shape compiled tick**: every device program (`tick`,
-  `prefill chunk`, `table write`) has one jit signature for the life of
-  the server — admission/retirement changes table *data*, never shapes,
-  so XLA never re-traces (pinned by ``compile_cache_sizes`` in tests).
+* a **fixed-shape compiled tick**: every device program (`sample`,
+  `tick`, `prefill chunk`, `table write`) has one jit signature for the
+  life of the server — admission/retirement changes table *data*, never
+  shapes, so XLA never re-traces (pinned by ``compile_cache_sizes`` in
+  tests);
+* **a tick left in flight**: the token a step hands out is the argmax of
+  the logits the step *before* left on the device, so it comes from a
+  small sampling program dispatched in front of the tick, and the step
+  returns without waiting for the tick.  Postprocess, bookkeeping, the
+  caller's turn and the next step's admissions and dispatches run beside
+  the tick; the next step's read holds the host to one tick ahead
+  (``docs/inference.md``, "The anatomy of a step").
 
 Request lifecycle & fault tolerance (the production layer the above
 schedulers treat as first-class scheduler transitions, not crashes):
@@ -102,7 +110,9 @@ prompt-lookup decoding in the continuous batch — see
 * acceptance only ever keeps the model's own argmax, so spec on/off
   is bit-identical to the solo greedy run for any draft quality, and
   ``compile_cache_sizes()`` stays frozen at one signature per program
-  (``spec_tick`` replacing ``tick``).
+  (``spec_tick`` replacing ``sample`` and ``tick``: the tokens and the
+  accepted counts are the verify program's own result, so a spec engine
+  reads them in lock-step).
 
 Scheduler invariants:
 
@@ -294,8 +304,9 @@ class ServeEngine:
     row's chunk with per-row greedy longest-prefix acceptance; rejected
     positions roll back by the row's length alone (write-before-read).
     One extra jit signature for the life of the server (``spec_tick``
-    replaces ``tick`` in ``compile_cache_sizes()``), every output stays
-    bit-identical to solo greedy generate, and a round can emit up to
+    replaces ``sample`` and ``tick`` in ``compile_cache_sizes()``),
+    every output stays bit-identical to solo greedy generate, and a
+    round can emit up to
     ``1 + draft_k`` tokens per row.  ``None`` reads ``HVD_TPU_SPEC`` /
     ``HVD_TPU_DRAFT_K`` (off / 4).
 
@@ -478,6 +489,10 @@ class ServeEngine:
         self._retrace_fatal = os.environ.get(
             "HVD_TPU_RETRACE_FATAL", "") == "1"
         self.metrics.counter("serve.retrace")
+        # Steps whose tokens were ready before the host asked for them:
+        # over serve.steps, how often the host and not the device set
+        # the pace (registered up front: schema-stable from step 0).
+        self.metrics.counter("serve.step.host_bound")
         self._t0 = time.monotonic()
         self._last_step_ts: float | None = None
         # SLO goodput window: every terminal trace lands here; the
@@ -591,6 +606,11 @@ class ServeEngine:
                          "cancellations": 0, "rejections": 0,
                          "retries": 0, "failures": 0}
         self.step_index = 0
+        # The rows (slot -> request id) of the tick in flight: a step
+        # returns without waiting for its tick, so a fault of tick N
+        # surfaces at step N+1's read and is theirs.  Emptied when the
+        # engine goes idle and the tick has been waited for.
+        self._tick_rows: dict[int, int] = {}
 
         # Sharded program signatures: explicit in/out shardings pin the
         # GSPMD layout at every jit boundary (params Megatron-split, KV
@@ -602,8 +622,10 @@ class ServeEngine:
         # single-device engine.
         if self.tp_size > 1:
             _p, _c, _r = self._param_sh, self._cache_sh, self._repl_sh
+            _sample_sh = dict(in_shardings=(_r, _r),
+                              out_shardings=(_r, _r))
             _tick_sh = dict(in_shardings=(_p, _c, _r, _r),
-                            out_shardings=(_r, _r, _c))
+                            out_shardings=(_r, _c))
             _chunk_sh = dict(in_shardings=(_p, _c, _r, _r, _r, _r, _r),
                              out_shardings=(_c, _r))
             _row_sh = dict(in_shardings=(_c, _r, _r, _r),
@@ -611,12 +633,26 @@ class ServeEngine:
             _spec_sh = dict(in_shardings=(_p, _c, _r, _r, _r),
                             out_shardings=(_r, _r, _r, _c))
         else:
-            _tick_sh = _chunk_sh = _row_sh = _spec_sh = {}
+            _sample_sh = _tick_sh = _chunk_sh = _row_sh = _spec_sh = {}
+
+        @partial(jax.jit, **_sample_sh)
+        def _sample(last_logits, counters):
+            # what a step hands out, in a program of its own in front of
+            # the tick: every row's token is the argmax of the logits the
+            # step before left, known before the tick's layers run, so
+            # the host reads it from here and leaves the tick in flight.
+            # `counters` is the model's cumulative device counters (or
+            # None) as they stand at this point, copied because the tick
+            # behind donates the cache they live in.  Donates nothing.
+            tok = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
+            return tok, jax.tree.map(jnp.copy, counters)
 
         @partial(jax.jit, donate_argnums=(1, 2), **_tick_sh)
         def _tick(params, pcache, last_logits, active):
             # the fixed-signature decode tick: every row argmaxes its
-            # last logits and decodes one position; `active` [B] gates
+            # last logits (the token `_sample` hands the host, computed
+            # again here so that the tick takes nothing from it) and
+            # decodes one position; `active` [B] gates
             # the length advance so idle/prefilling rows hold position
             # (their garbage write lands in their own blocks or trash —
             # invariant 1).  Donation matters: decode cost IS cache
@@ -627,7 +663,7 @@ class ServeEngine:
             tok = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
             logits, pcache = model.decode_chunk_paged(
                 params, tok[:, None], cfg, pcache, advance=active)
-            return tok, logits[:, 0], pcache
+            return logits[:, 0], pcache
 
         @partial(jax.jit, donate_argnums=(1, 2), **_chunk_sh)
         def _chunk(params, pcache, last_logits, toks, slot, new_len, sel):
@@ -673,6 +709,7 @@ class ServeEngine:
             self._spec_tick = _spec_tick
         else:
             self._spec_tick = None
+        self._sample = _sample
         self._tick = _tick
         self._chunk = _chunk
         self._set_row = _set_row
@@ -693,8 +730,10 @@ class ServeEngine:
         """Per-program jit cache entry counts — the no-retrace pin:
         admission/recycling/preemption must keep every count constant.
         A spec engine adds the ``spec_tick`` key (its always-wide verify
-        program, which replaces ``tick`` so that count stays 0)."""
+        program, which replaces ``sample`` and ``tick`` so that those
+        counts stay 0)."""
         sizes = {
+            "sample": self._sample._cache_size(),
             "tick": self._tick._cache_size(),
             "chunk": self._chunk._cache_size(),
             "set_row": self._set_row._cache_size(),
@@ -716,11 +755,14 @@ class ServeEngine:
         p_av = jax.tree.map(aval, self.params)
         c_av = jax.tree.map(aval, self.pcache)
         ll_av = aval(self.last_logits)
+        counters_av = jax.tree.map(
+            aval, self.model.paged_counters(self.pcache))
         i32 = jax.ShapeDtypeStruct((), jnp.int32)
         active_av = jax.ShapeDtypeStruct((self.n_slots,), jnp.int32)
         toks_av = jax.ShapeDtypeStruct((1, self.chunk), jnp.int32)
         row_av = jax.ShapeDtypeStruct((self.blocks_per_slot,), jnp.int32)
         progs = {
+            "sample": (self._sample, ll_av, counters_av),
             "tick": (self._tick, p_av, c_av, ll_av, active_av),
             "chunk": (self._chunk, p_av, c_av, ll_av, toks_av,
                       i32, i32, i32),
@@ -1313,12 +1355,16 @@ class ServeEngine:
                      if s.state == DECODE and self._replayable(s)]
             if not cands:
                 break
-            slot = self.policy.victim(cands)
-            self._event("preempt", slot, self._slots[slot].request_id)
-            self._bump_counter("preemptions")
-            self._requeue(slot, retried=False)
+            self._preempt_row(self.policy.victim(cands))
             preempted += 1
         return preempted
+
+    def _preempt_row(self, slot: int) -> None:
+        """Take a decoding row off its slot and re-queue it for replay,
+        with nothing charged to it."""
+        self._event("preempt", slot, self._slots[slot].request_id)
+        self._bump_counter("preemptions")
+        self._requeue(slot, retried=False)
 
     def _terminate(self, slot: int, status: str,
                    error: BaseException | None = None) -> RequestResult:
@@ -1578,9 +1624,10 @@ class ServeEngine:
     def step(self) -> dict[int, RequestResult]:
         """One engine step: expire deadlines, admit (preempting for a
         starved head if enabled), run one prefill window per admitting
-        slot, then one decode tick over the pool.  Returns
-        ``{request_id: RequestResult}`` for every request that reached a
-        terminal state during the step."""
+        slot, then sample every decoding row's token and dispatch one
+        decode tick over the pool, which is still running when the step
+        returns.  Returns ``{request_id: RequestResult}`` for every
+        request that reached a terminal state during the step."""
         # The phases are mark-based: begin() opens the tick in `expire`
         # and each mark() is the boundary at which the named phase
         # starts, so they tile the tick — as spans on the profiler
@@ -1719,32 +1766,53 @@ class ServeEngine:
                     self._bump_spec("proposed", len(prop))
         if decoding:
             prof.mark("decode_dispatch")
+            # what the read below waits for besides this step's chunks:
+            # the tick the last ticking step left in flight, and its rows
+            in_flight = self._tick_rows
+            blamed = decoding
             tick_exc: Exception | None = None
             try:
                 active = np.zeros((self.n_slots,), np.int32)
                 active[decoding] = 1
-                accept_host = None
+                accept = accept_host = None
                 programs.append(Dispatched(
                     self.draft_k + 1 if spec else 1,
                     np.array([self._row_length(s) for s in self._slots]),
                     active))
                 if spec:
+                    # lock-step: the tokens and the accepted counts are
+                    # the verify program's own result, and so are the
+                    # counters behind them
                     tok, accept, self.last_logits, self.pcache = \
                         self._spec_tick(
                             self.params, self.pcache, self.last_logits,
                             jnp.asarray(drafts_host),
                             jnp.asarray(active))
+                    stats = self.model.paged_counters(self.pcache)
                 else:
-                    tok, self.last_logits, self.pcache = self._tick(
+                    # the step's tokens (and the model's device-side
+                    # counters, None for a model that keeps none) come
+                    # from the sampling program in front of the tick;
+                    # nothing the tick returns is read in this step, so
+                    # it runs while the host goes on: postprocess,
+                    # bookkeeping, the caller's turn and the next step's
+                    # admissions and dispatches, up to that step's read
+                    tok, stats = self._sample(
+                        self.last_logits,
+                        self.model.paged_counters(self.pcache))
+                    self.last_logits, self.pcache = self._tick(
                         self.params, self.pcache, self.last_logits,
                         jnp.asarray(active))
-                # the model's device-side counters (None for a model
-                # that keeps none) start their way to the host behind
-                # the tokens: read below, after the sync that is there
-                stats = self.model.paged_counters(self.pcache)
+                    self._tick_rows = {
+                        slot: self._slots[slot].request_id
+                        for slot in decoding}
+                # on their way to the host as soon as they are computed
+                tok.copy_to_host_async()
                 if stats is not None:
                     stats.copy_to_host_async()
                 if self.device is not None:
+                    if not spec:
+                        self.device.dispatch("sample")
                     self.device.dispatch(
                         "spec_tick" if spec else "tick",
                         h2d_bytes=active.nbytes + (
@@ -1763,10 +1831,16 @@ class ServeEngine:
                 programs.clear()
                 try:
                     # np.asarray on the device token array is the
-                    # readback boundary: everything the tick queued must
-                    # complete first, so this wait is the device-time
-                    # share.
+                    # readback boundary: everything dispatched in front
+                    # of the tokens' program must complete first (the
+                    # tick in flight and this step's chunks; on a spec
+                    # engine this step's verify too), so this wait is
+                    # the device-time share.  Tokens that are ready
+                    # before the host asks mean the device ran out of
+                    # work first: the host set this step's pace.
                     prof.mark("device_sync")
+                    if tok.is_ready():
+                        self.metrics.counter("serve.step.host_bound").inc()
                     t_sync0 = time.perf_counter()
                     tok_host = np.asarray(tok)
                     if spec:
@@ -1775,17 +1849,23 @@ class ServeEngine:
                                   else np.asarray(stats))
                     if self.device is not None:
                         # split the measured readback wait into the cost
-                        # model's predicted device-compute share vs host
-                        # stall; the profiler gets the same split as
-                        # nested device_sync.* intervals so phase tables
-                        # can show where the wait went.
+                        # model's predicted device time of the programs
+                        # it waited for (whole, though the tick in
+                        # flight began a step ago: an upper bound) vs
+                        # host stall; the profiler gets the same split
+                        # as nested device_sync.* intervals so phase
+                        # tables can show where the wait went.
                         t_sync1 = time.perf_counter()
                         d2h = tok_host.nbytes + (
                             accept_host.nbytes
                             if accept_host is not None else 0)
+                        awaited = ["chunk"] * n_chunks
+                        if spec:
+                            awaited.append("spec_tick")
+                        elif in_flight:
+                            awaited.append("tick")
                         est, stall = self.device.on_sync(
-                            "spec_tick" if spec else "tick",
-                            t_sync0, t_sync1, d2h_bytes=d2h)
+                            awaited, t_sync0, t_sync1, d2h_bytes=d2h)
                         prof.add("device_sync.compute_est",
                                  t_sync0, t_sync0 + est)
                         prof.add("device_sync.host_stall",
@@ -1796,11 +1876,25 @@ class ServeEngine:
                     prof.mark("verify" if spec else "sample_postprocess")
                 except Exception as exc:
                     tick_exc = exc
+                    if not spec:
+                        # the read waited for nothing of this step's
+                        # tick: the fault is of the tick in flight, and
+                        # of the rows that decoded in it
+                        blamed = [
+                            slot for slot in decoding
+                            if in_flight.get(slot)
+                            == self._slots[slot].request_id] or decoding
             if tick_exc is not None:
                 # a whole-tick failure cannot be attributed to one row;
-                # quarantine every decoding row (transients replay)
+                # quarantine every row of the tick at fault (transients
+                # replay).  A row that joined with this step has lost
+                # its token with the read, and its tick has advanced it:
+                # it replays like a preempted row, with nothing charged
                 for slot in decoding:
-                    self._row_fault(slot, tick_exc)
+                    if slot in blamed:
+                        self._row_fault(slot, tick_exc)
+                    else:
+                        self._preempt_row(slot)
                 progress += len(decoding)
             else:
                 progress += len(decoding)
@@ -1848,6 +1942,16 @@ class ServeEngine:
                             self._terminate(slot, OK)
                             break
         prof.mark("bookkeeping")
+        if self._tick_rows and not self.pending():
+            # going idle with a tick in flight: its counters are read
+            # here, behind it (the one wait for a tick's own result), so
+            # that the registry's totals are whole at the end of a drain.
+            # A fault of that tick raises from here: every row of it has
+            # been answered, so there is none to charge it to.
+            self._tick_rows = {}
+            final = self.model.paged_counters(self.pcache)
+            if final is not None:
+                stats_host = np.asarray(final)
         if programs or stats_host is not None:
             self.model.publish_paged_metrics(
                 self.metrics, self.cfg, self.pcache, stats_host,

@@ -72,7 +72,7 @@ def main() -> None:
     toks_p = [list(r) for r in out_p]
     assert toks_s == toks_p, (toks_s, toks_p)
     sizes = sharded.compile_cache_sizes()
-    assert sizes == {"tick": 0, "chunk": 1, "set_row": 1,
+    assert sizes == {"sample": 0, "tick": 0, "chunk": 1, "set_row": 1,
                      "spec_tick": 1}, sizes
 
     print("WORKER_OK " + json.dumps(

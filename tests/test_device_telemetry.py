@@ -158,8 +158,10 @@ def test_cost_capture_all_four_programs(world):
     assert rep["programs"]["chunk"]["dispatches"] > 0
     assert rep["programs"]["set_row"]["dispatches"] > 0
     assert rep["programs"]["spec_tick"]["dispatches"] > 0
-    # spec engines never call plain tick: captured, zero dispatches
+    # spec engines never call plain tick, nor the sampling program in
+    # front of it: captured, zero dispatches
     assert rep["programs"]["tick"]["dispatches"] == 0
+    assert rep["programs"]["sample"]["dispatches"] == 0
     # compile ledger: one timed AOT compile per captured program
     assert rep["compiles"] == len(PROGRAMS)
     assert rep["compile_total_s"] > 0.0
@@ -198,7 +200,7 @@ def test_telemetry_on_off_parity(world):
     # AOT capture minted NO jit call-cache entries: one signature per
     # program, same as off — and the sentry never fired.
     assert on.compile_cache_sizes() == off.compile_cache_sizes() == \
-        {"tick": 1, "chunk": 1, "set_row": 1}
+        {"sample": 1, "tick": 1, "chunk": 1, "set_row": 1}
     assert on.metrics.counter("serve.retrace").value == 0
     snap = on.metrics_snapshot()
     assert "device" in snap
@@ -280,16 +282,16 @@ def test_sync_split_degenerates_without_peak():
     assert not t.peak_flops_known
     t.programs["tick"] = {"flops": 1e9, "bytes_accessed": 1.0,
                           "compile_s": 0.0, "dispatches": 0}
-    est, stall = t.on_sync("tick", 0.0, 0.5)
+    est, stall = t.on_sync(("tick",), 0.0, 0.5)
     assert est == 0.5 and stall == 0.0
     # with a peak the predicted device time caps at the measured wait
     t2 = DeviceTelemetry(MetricsRegistry(event_log=None),
                          peak_flops=1e10)
     t2.programs["tick"] = {"flops": 1e9, "bytes_accessed": 1.0,
                            "compile_s": 0.0, "dispatches": 0}
-    est, stall = t2.on_sync("tick", 0.0, 0.5)
+    est, stall = t2.on_sync(("tick",), 0.0, 0.5)
     assert est == pytest.approx(0.1) and stall == pytest.approx(0.4)
-    est, stall = t2.on_sync("tick", 0.0, 0.01)   # wait < prediction
+    est, stall = t2.on_sync(("tick",), 0.0, 0.01)   # wait < prediction
     assert est == pytest.approx(0.01) and stall == 0.0
 
 
@@ -354,7 +356,8 @@ def test_device_endpoint_over_socket(world):
             assert r.headers["Content-Type"] == "application/json"
             rep = json.loads(r.read())
         assert rep["ticks"] == eng.device.report()["ticks"]
-        assert set(rep["programs"]) == {"tick", "chunk", "set_row"}
+        assert set(rep["programs"]) == {"sample", "tick", "chunk",
+                                        "set_row"}
     finally:
         mon.stop()
     # telemetry off: /device 404s with the turn-it-on hint
@@ -386,7 +389,8 @@ def test_router_fleet_device_view(world):
             if n not in rep["replicas"]]
         assert rep["summary"]["n_reporting"] == 1
         (one,) = rep["replicas"].values()
-        assert set(one["programs"]) == {"tick", "chunk", "set_row"}
+        assert set(one["programs"]) == {"sample", "tick", "chunk",
+                                        "set_row"}
         # and over the wire
         url = f"http://{router.host}:{router.port}/device"
         with urllib.request.urlopen(url, timeout=10) as r:

@@ -296,10 +296,12 @@ def test_serve_engine_fault_schedule_fuzz(seed, prefix_cache, spec,
     # (spec engines swap the 1-wide tick for the K+1-wide verify tick)
     # and the whole block pool survive the sweep intact.
     if spec:
-        assert eng.compile_cache_sizes() == {"tick": 0, "chunk": 1,
+        assert eng.compile_cache_sizes() == \
+            {"sample": 0, "tick": 0, "chunk": 1,
                                              "set_row": 1, "spec_tick": 1}
     else:
-        assert eng.compile_cache_sizes() == {"tick": 1, "chunk": 1,
+        assert eng.compile_cache_sizes() == \
+            {"sample": 1, "tick": 1, "chunk": 1,
                                              "set_row": 1}
     if prefix_cache:
         # drained: no live references; every block is either free or

@@ -522,7 +522,8 @@ def test_engine_run_equals_cache_free_generate(served):
     out = eng.run([Request(prompt=p, max_new_tokens=9) for p in prompts])
     assert [r.status for r in out] == ["OK"] * 3
     assert [list(r) for r in out] == want
-    assert eng.compile_cache_sizes() == {"tick": 1, "chunk": 1, "set_row": 1}
+    assert eng.compile_cache_sizes() == \
+        {"sample": 1, "tick": 1, "chunk": 1, "set_row": 1}
 
 
 def _dispatched(monkeypatch):
@@ -552,7 +553,8 @@ def test_engine_serves_the_same_tokens_on_either_path(monkeypatch, served,
     eng = _engine(mc, params, chunk=16)
     out = eng.run([Request(prompt=p, max_new_tokens=9) for p in prompts])
     assert [list(r) for r in out] == want
-    assert eng.compile_cache_sizes() == {"tick": 1, "chunk": 1, "set_row": 1}
+    assert eng.compile_cache_sizes() == \
+        {"sample": 1, "tick": 1, "chunk": 1, "set_row": 1}
     c = eng.metrics_snapshot()["counters"]
     reach = {"mask": 48, "list": 0, "mask_then_list": 24}[path]
     chunks = [p for p in programs if p.t == 16]
@@ -751,7 +753,7 @@ def test_a_llama_engine_lowers_to_the_same_programs_as_before_the_interface():
         tok = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
         logits, pcache = llama.decode_chunk_paged(
             params, tok[:, None], cfg, pcache, advance=active)
-        return tok, logits[:, 0], pcache
+        return logits[:, 0], pcache     # the host reads `_sample`'s tokens
 
     @functools.partial(jax.jit, donate_argnums=(1, 2))
     def _chunk(params, pcache, last_logits, toks, slot, new_len, sel):

@@ -8,7 +8,7 @@ Pins the subsystem's acceptance contract from three sides:
    skipped by a radix hit, COW-divergent continuations of a shared
    prefix, and requests replayed after a preemption.
 2. *Fixed signature*: cache hits change block-table data, never shapes
-   — ``compile_cache_sizes()`` stays ``{"tick": 1, "chunk": 1,
+   — ``compile_cache_sizes()`` stays ``{"sample": 1, "tick": 1, "chunk": 1,
    "set_row": 1}`` through every admission.
 3. *Accounting*: a drained engine holds zero live references and every
    block is either free or parked zero-ref in a structurally sound
@@ -54,7 +54,7 @@ def _assert_drained_consistent(eng):
     if eng.prefix is not None:
         eng.prefix.check_consistency()
     assert eng.compile_cache_sizes() == {
-        "tick": 1, "chunk": 1, "set_row": 1}
+        "sample": 1, "tick": 1, "chunk": 1, "set_row": 1}
 
 
 # -- the pool ----------------------------------------------------------------

@@ -143,7 +143,7 @@ def test_profile_on_off_parity_and_phase_sum(world):
     assert [list(a) for a in out_on] == [list(b) for b in out_off]
     assert all(r.status == OK for r in out_on)
     # one jit signature per program, profiling on — and no retraces seen
-    assert on.compile_cache_sizes() == {"tick": 1, "chunk": 1,
+    assert on.compile_cache_sizes() == {"sample": 1, "tick": 1, "chunk": 1,
                                         "set_row": 1}
     assert on.metrics.counter("serve.retrace").value == 0
     snap = on.metrics_snapshot()
@@ -458,7 +458,7 @@ def test_trace_changes_neither_tokens_nor_compiles(world, host_trace):
     plain = _engine(world, prefix_cache=True)
     want = [list(r) for r in plain.run(reqs)]
     sizes = plain.compile_cache_sizes()
-    assert sizes == {"tick": 1, "chunk": 1, "set_row": 1}
+    assert sizes == {"sample": 1, "tick": 1, "chunk": 1, "set_row": 1}
     for profile in (False, True):
         eng = _engine(world, profile=profile, prefix_cache=True)
         with host_trace() as tr:
@@ -636,7 +636,7 @@ def test_rows_follow_a_known_schedule(world, profile):
         ticked = r["tick_rows"] > 0
         assert (r["device_sync"] > 0) == (r["decode_dispatch"] > 0) == ticked
         assert 0 < r["admit.prefill_dispatch"] <= r["admit"]
-    assert eng.compile_cache_sizes() == {"tick": 1, "chunk": 1,
+    assert eng.compile_cache_sizes() == {"sample": 1, "tick": 1, "chunk": 1,
                                          "set_row": 1}
 
 

@@ -242,7 +242,7 @@ def test_preemption_replay_bit_parity(world):
         assert res.status == OK
         _assert_solo_prefix(params, cfg, req, res, 16)
     assert eng.compile_cache_sizes() == {
-        "tick": 1, "chunk": 1, "set_row": 1}
+        "sample": 1, "tick": 1, "chunk": 1, "set_row": 1}
     assert eng.free_block_count() == 5
 
 
@@ -266,7 +266,7 @@ def test_preemption_under_churn_parity(world):
         assert res.status == OK
         _assert_solo_prefix(params, cfg, req, res, 16)
     assert eng.compile_cache_sizes() == {
-        "tick": 1, "chunk": 1, "set_row": 1}
+        "sample": 1, "tick": 1, "chunk": 1, "set_row": 1}
     assert eng.free_block_count() == 5
 
 
@@ -302,7 +302,7 @@ def test_permanent_prefill_fault_fails_only_that_request(world):
     assert res.status == OK
     _assert_solo_prefix(params, cfg, late, res, 16)
     assert eng.compile_cache_sizes() == {
-        "tick": 1, "chunk": 1, "set_row": 1}
+        "sample": 1, "tick": 1, "chunk": 1, "set_row": 1}
     assert eng.free_block_count() == eng.pcache.k.shape[1] - 1
 
 
@@ -326,6 +326,76 @@ def test_permanent_tick_fault_keeps_tokens_so_far(world):
     ok = eng.results[ids[1]]
     assert ok.status == OK
     _assert_solo_prefix(params, cfg, reqs[1], ok, 16)
+    assert eng.free_block_count() == eng.pcache.k.shape[1] - 1
+
+
+class _Poisoned:
+    """What the sampling program hands back when a program in front of it
+    failed on the device: the error surfaces where the host asks for the
+    tokens, not where the program was dispatched."""
+
+    def __init__(self, exc):
+        self.exc = exc
+
+    def copy_to_host_async(self):
+        pass
+
+    def is_ready(self):
+        return True
+
+    def __array__(self, *args, **kwargs):
+        raise self.exc
+
+
+def test_a_fault_of_tick_n_surfaces_at_step_n_plus_1_and_is_its_rows(world):
+    """A step returns with its tick in flight, so tick N's fault is met by
+    step N+1's read.  It is charged to the rows that decoded in tick N; a row
+    that joined with step N+1 (its token lost with the read, its tick already
+    dispatched) replays uncharged, like a preempted one."""
+    cfg, params = world
+    eng = ServeEngine(params, cfg, n_slots=3, max_len=16, chunk=4)
+    reqs = [Request(prompt=[5, 17, 42], max_new_tokens=8),
+            Request(prompt=[7, 8], max_new_tokens=7),
+            Request(prompt=[9, 1, 2, 3, 4, 5], max_new_tokens=4)]
+    ids = [eng.submit(r) for r in reqs]
+    inner, calls = eng._sample, []
+
+    def sample(last_logits, counters):
+        tok, counters = inner(last_logits, counters)
+        calls.append(eng.step_index)
+        if len(calls) == 2:             # the read behind tick 0
+            tok = _Poisoned(RuntimeError("tick 0 died on the device"))
+        return tok, counters
+
+    sample._cache_size = inner._cache_size
+    eng._sample = sample
+    # step 0: rows 0 and 1 end their one-window prompts and decode in tick
+    # 0; the third request is half-way through its prompt.  Nothing is wrong
+    # yet as far as the host can know.
+    assert eng.step() == {}
+    assert [len(s.out) for s in eng._slots] == [1, 1, 0]
+    assert eng.counters["retries"] == 0
+    # step 1: the third row joins the tick; the read meets tick 0's fault
+    assert eng.step() == {}
+    assert calls == [0, 1]
+    at_1 = [(e.kind, e.request_id) for e in eng.events if e.step == 1]
+    assert at_1 == [("retry", ids[0]), ("retry", ids[1]),
+                    ("preempt", ids[2])]
+    assert eng.counters["retries"] == 2
+    assert eng.counters["preemptions"] == 1
+    assert all(s.state == "free" for s in eng._slots)
+    assert sorted((e.rid, e.retries, len(e.prior)) for e in eng._queue) \
+        == [(ids[0], 1, 1), (ids[1], 1, 1), (ids[2], 0, 0)]
+    while eng.pending():
+        eng.step()
+    for rid, req in zip(ids, reqs):
+        res = eng.results[rid]
+        assert res.status == OK, (rid, res.status, res.error)
+        _assert_solo_prefix(params, cfg, req, res, 16)
+        assert res.trace.retries == (1 if rid != ids[2] else 0)
+    assert eng.counters["retries"] == 2 and eng.counters["failures"] == 0
+    assert eng.compile_cache_sizes() == {
+        "sample": 1, "tick": 1, "chunk": 1, "set_row": 1}
     assert eng.free_block_count() == eng.pcache.k.shape[1] - 1
 
 
@@ -377,7 +447,7 @@ def test_transient_faults_retry_to_parity(world):
         assert res.status == OK, (rid, res.status, res.error)
         _assert_solo_prefix(params, cfg, req, res, 16)
     assert eng.compile_cache_sizes() == {
-        "tick": 1, "chunk": 1, "set_row": 1}
+        "sample": 1, "tick": 1, "chunk": 1, "set_row": 1}
 
 
 def test_transient_fault_exhausts_retries_to_failed(world):

@@ -103,7 +103,7 @@ def test_no_retrace_across_admissions(world):
     eng = ServeEngine(params, cfg, n_slots=2, max_len=16, chunk=4)
     eng.run(_mixed_requests())
     sizes = eng.compile_cache_sizes()
-    assert sizes == {"tick": 1, "chunk": 1, "set_row": 1}
+    assert sizes == {"sample": 1, "tick": 1, "chunk": 1, "set_row": 1}
     eng.run([Request(prompt=[1, 2, 3, 4, 5, 6, 7], max_new_tokens=6),
              Request(prompt=[250], max_new_tokens=3)])
     assert eng.compile_cache_sizes() == sizes
@@ -224,6 +224,175 @@ def test_submit_validation(world):
                     n_blocks=3)
 
 
+# -- a step leaves its tick in flight -----------------------------------------
+#
+# The step's tokens come from the sampling program in front of the tick;
+# nothing the tick returns is read in the step that dispatched it.  The
+# oracle is the order the engine had before: the same engine, made to wait
+# after every step for all that the step dispatched.
+
+FAMILIES = ("llama", "latent_moe", "shortconv_moe", "window_moe")
+MODEL_COUNTERS = ("moe.", "dsa.", "attn.", "conv.", "window.")
+
+
+def _family(name):
+    import importlib
+    mod = importlib.import_module(f"horovod_tpu.models.{name}")
+    mc = (llama.llama_tiny(dtype=jnp.float32) if name == "llama"
+          else getattr(mod, f"{name}_tiny")())
+    return mod, mc, mod.init_params(mc, jax.random.key(0))
+
+
+def _toks(n, seed, vocab):
+    return np.random.default_rng(seed).integers(1, vocab, n).tolist()
+
+
+def _drive(eng, batch, lock_step):
+    """Serve ``batch`` to the end; what each step handed back, in order."""
+    ids = [eng.submit(r) for r in batch]
+    steps = []
+    while eng.pending():
+        out = eng.step()
+        if lock_step:
+            jax.block_until_ready((eng.pcache, eng.last_logits))
+        steps.append({rid: (r.status, list(r)) for rid, r in out.items()})
+    return [eng.results[i] for i in ids], steps
+
+
+def _model_counters(eng):
+    return {k: v for k, v in eng.metrics.snapshot()["counters"].items()
+            if k.startswith(MODEL_COUNTERS)}
+
+
+@pytest.fixture(scope="module", params=FAMILIES)
+def in_flight_and_lock_step(request):
+    """Two engines of one family over the same two batches: a pool of 7
+    blocks under three slots of up to 6 (the head starves: a preemption),
+    prompts of one to four chunks of which two share a template of two
+    blocks, then the first prompt again (a hit up to its last block) with
+    its own third token as ``eos``, beside a new tail on the template."""
+    from horovod_tpu import metrics as metrics_mod
+    mod, mc, params = _family(request.param)
+    v = mc.vocab_size
+    template = _toks(16, 3, v)
+    first = [Request(prompt=template + _toks(3, 4, v), max_new_tokens=9),
+             Request(prompt=_toks(7, 5, v), max_new_tokens=6),
+             Request(prompt=_toks(26, 6, v), max_new_tokens=9),
+             Request(prompt=template + _toks(11, 7, v), max_new_tokens=5)]
+    seen = {}
+    for lock_step in (True, False):
+        eng = ServeEngine(
+            params, mc, n_slots=3, max_len=48, chunk=8, n_blocks=8,
+            preempt_after=2, prefix_cache=True, monitor=False,
+            sampler=False,
+            metrics=metrics_mod.MetricsRegistry(event_log=None))
+        out1, steps1 = _drive(eng, first, lock_step)
+        after1 = _model_counters(eng)
+        second = [Request(prompt=first[0].prompt, max_new_tokens=9,
+                          eos_id=list(out1[0])[2]),
+                  Request(prompt=template + _toks(6, 8, v),
+                          max_new_tokens=4),
+                  Request(prompt=_toks(5, 9, v), max_new_tokens=3)]
+        out2, steps2 = _drive(eng, second, lock_step)
+        seen[lock_step] = dict(
+            eng=eng, out=out1 + out2, steps=steps1 + steps2,
+            counters=[after1, _model_counters(eng)],
+            events=[(e.kind, e.step, e.slot, e.request_id)
+                    for e in eng.events])
+    return mod, seen[False], seen[True]
+
+
+def test_tokens_equal_the_lock_step_order(in_flight_and_lock_step):
+    _, flight, lock = in_flight_and_lock_step
+    assert all(r.status == "OK" for r in flight["out"])
+    assert [list(r) for r in flight["out"]] == \
+        [list(r) for r in lock["out"]]
+    # step N hands out token N: each step returns the same results, and
+    # every decision of the scheduler falls in the same step
+    assert flight["steps"] == lock["steps"]
+    assert flight["events"] == lock["events"]
+    kinds = {e[0] for e in flight["events"]}
+    assert {"preempt", "hit", "recycle"} <= kinds
+    # a row ended by its eos before its budget, the others by budget
+    ended_early = flight["out"][4]
+    assert 1 <= len(ended_early) <= 3 < 9
+    assert list(ended_early) == list(flight["out"][0])[:len(ended_early)]
+    assert [len(r) for r in flight["out"][:4]] == [9, 6, 9, 5]
+    assert flight["eng"].compile_cache_sizes() == {
+        "sample": 1, "tick": 1, "chunk": 1, "set_row": 1}
+
+
+def test_counters_after_a_drain_hold_the_last_tick(in_flight_and_lock_step):
+    mod, flight, lock = in_flight_and_lock_step
+    # after each drain, not only at the end of the engine's life
+    assert flight["counters"] == lock["counters"]
+    assert any(flight["counters"][1].values())
+    eng = flight["eng"]
+    stats = mod.paged_counters(eng.pcache)
+    if stats is None:               # the model keeps none on the device
+        return
+    # the registry holds the device's own totals, the tick that ran
+    # behind the last token included
+    device = mod.read_counters(np.asarray(stats))
+    held = flight["counters"][1]
+    assert held["moe.choices_total"] == device["choices_total"] > 0
+    mirrored = {prefix + key: total for key, total in device.items()
+                for prefix in MODEL_COUNTERS
+                if prefix + key in held and not key.endswith("_live")}
+    assert len(mirrored) >= 3
+    assert {name: held[name] for name in mirrored} == mirrored
+
+
+def test_step_reads_nothing_of_its_own_tick(world):
+    cfg, params = world
+    eng = ServeEngine(params, cfg, n_slots=2, max_len=16, chunk=4)
+    inner, made = eng._tick, []
+
+    def tick(*args):
+        made.append(inner(*args))
+        return made[-1]
+
+    tick._cache_size = inner._cache_size        # the retrace sentry's
+    eng._tick = tick
+    for r in _mixed_requests()[:3]:
+        eng.submit(r)
+    handed = 0
+    while eng.pending():
+        n_ticks = len(made)
+        handed += sum(len(r) for r in eng.step().values())
+        for out in made[n_ticks:]:
+            # dispatched by this step, and not brought to the host by it
+            assert all(leaf._npy_value is None
+                       for leaf in jax.tree.leaves(out))
+    assert len(made) >= 6 and handed == 4 + 6 + 3
+
+
+def test_step_rows_tile_and_host_bound_is_bounded(world):
+    from horovod_tpu import metrics as metrics_mod
+    from horovod_tpu.profiler import ROW_FIELDS, TILING
+    cfg, params = world
+    reg = metrics_mod.MetricsRegistry(event_log=None)
+    eng = ServeEngine(params, cfg, n_slots=2, max_len=16, chunk=4,
+                      metrics=reg)
+    eng.run(_mixed_requests())
+    rows = eng.prof.log.rows()
+    col = {name: i for i, name in enumerate(ROW_FIELDS)}
+    assert len(rows) == eng.step_index
+    tiled = rows[:, [col[p] for p in TILING]].sum(axis=1)
+    np.testing.assert_allclose(
+        tiled, rows[:, col["ended"]] - rows[:, col["began"]],
+        rtol=0, atol=1e-9)
+    ticking = int((rows[:, col["tick_rows"]] > 0).sum())
+    # a ticking step waits in device_sync for its tokens, and hands out
+    # one token a row
+    assert (rows[:, col["device_sync"]] > 0).sum() == ticking
+    np.testing.assert_array_equal(rows[:, col["tokens"]],
+                                  rows[:, col["tick_rows"]])
+    counters = reg.snapshot()["counters"]
+    assert 0 <= counters["serve.step.host_bound"] <= ticking \
+        <= counters["serve.steps"] == eng.step_index
+
+
 @pytest.mark.slow
 def test_randomized_soak_parity(world):
     """Soak: random prompts/budgets/submission times over a small pool;
@@ -248,4 +417,4 @@ def test_randomized_soak_parity(world):
     results = [eng.results[i] for i in ids]
     _assert_parity(params, cfg, reqs, results, 24)
     assert eng.compile_cache_sizes() == {
-        "tick": 1, "chunk": 1, "set_row": 1}
+        "sample": 1, "tick": 1, "chunk": 1, "set_row": 1}

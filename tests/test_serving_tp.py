@@ -162,7 +162,7 @@ def test_sharded_token_parity(world, tp_devices, prefix_cache, spec):
         assert all(r.ok for r in out), [r.status for r in out]
         outs[tp] = [list(r) for r in out]
         live = {k: v for k, v in eng.compile_cache_sizes().items()
-                if not (k == "tick" and spec)}   # spec replaces tick
+                if not (k in ("sample", "tick") and spec)}   # spec: neither
         assert set(live.values()) == {1}, (tp, live)
     assert outs[2] == outs[1]
     # and both match the solo run (invariant 2, now across the mesh)
@@ -185,7 +185,7 @@ def test_sharded_compile_frozen_and_shard_gauges(world, tp_devices):
         out = eng.run(_requests())
         assert all(r.ok for r in out)
     assert eng.compile_cache_sizes() == {
-        "tick": 1, "chunk": 1, "set_row": 1}
+        "sample": 1, "tick": 1, "chunk": 1, "set_row": 1}
     snap = eng.metrics_snapshot()
     assert snap["counters"].get("serve.retrace", 0) == 0
     g = snap["gauges"]
@@ -215,6 +215,38 @@ def test_sharded_compile_frozen_and_shard_gauges(world, tp_devices):
 
 
 @pytest.mark.tp
+@pytest.mark.parametrize("tp", [1, 2])
+def test_sampling_program_keeps_one_signature(world, tp_devices, tp):
+    """The program a step reads its tokens from is pinned like the others:
+    one signature after 50 steps of arrivals, prefix hits, recycling and
+    preemption, on one device and across the mesh (replicated, as every
+    array the host reads), and the retrace sentry has counted nothing."""
+    cfg, params = world
+    reg = metrics_mod.MetricsRegistry(event_log=None)
+    eng = ServeEngine(params, cfg, tp_size=tp, n_slots=3, max_len=32,
+                      chunk=4, n_blocks=14, preempt_after=2,
+                      prefix_cache=True, metrics=reg)
+    rng = np.random.default_rng(5)
+    for _ in range(50):
+        if rng.random() < 0.4:
+            n = int(rng.integers(1, 13))
+            eng.submit(Request(
+                prompt=[1, 2, 3, 4] + rng.integers(
+                    5, cfg.vocab_size, n).tolist(),
+                max_new_tokens=int(rng.integers(1, 14))))
+        eng.step()
+    while eng.pending():
+        eng.step()
+    assert eng.step_index >= 50
+    assert eng.compile_cache_sizes() == {
+        "sample": 1, "tick": 1, "chunk": 1, "set_row": 1}
+    counters = reg.snapshot()["counters"]
+    assert counters["serve.retrace"] == 0
+    assert counters["serve.tokens_emitted"] > 50
+    assert {e.kind for e in eng.events} >= {"admit", "hit", "recycle"}
+
+
+@pytest.mark.tp
 def test_sharded_preempt_replay_parity(world, tp_devices):
     """Preemption-with-replay on the sharded engine: the starved head
     evicts a decoding victim, the replay resumes through the head-split
@@ -235,7 +267,7 @@ def test_sharded_preempt_replay_parity(world, tp_devices):
         np.testing.assert_array_equal(np.asarray(list(res), np.int64),
                                       want.astype(np.int64))
     assert eng.compile_cache_sizes() == {
-        "tick": 1, "chunk": 1, "set_row": 1}
+        "sample": 1, "tick": 1, "chunk": 1, "set_row": 1}
     assert eng.free_block_count() == 5
 
 
@@ -285,7 +317,7 @@ def test_tp_worker_subprocess(world):
     payload = json.loads(out.split("WORKER_OK ", 1)[1].splitlines()[0])
     assert payload["tp_size"] == 2
     assert payload["compile_cache_sizes"] == {
-        "tick": 0, "chunk": 1, "set_row": 1, "spec_tick": 1}
+        "sample": 0, "tick": 0, "chunk": 1, "set_row": 1, "spec_tick": 1}
     # greedy determinism across processes: the worker's sharded tokens
     # match this process's solo runs
     cfg, params = world

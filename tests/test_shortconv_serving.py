@@ -68,7 +68,8 @@ def test_engine_run_equals_cache_free_generate(served):
     out = eng.run(_requests(prompts))
     assert [r.status for r in out] == ["OK"] * 4
     assert [list(r) for r in out] == want
-    assert eng.compile_cache_sizes() == {"tick": 1, "chunk": 1, "set_row": 1}
+    assert eng.compile_cache_sizes() == \
+        {"sample": 1, "tick": 1, "chunk": 1, "set_row": 1}
     snap = eng.metrics.snapshot()
     assert snap["gauges"]["kv.bytes_per_token"] == 2 * 2 * 2 * 8 * 4
     assert snap["gauges"]["state.bytes_per_slot"] == 5 * 2 * 32 * 4
@@ -90,7 +91,8 @@ def test_a_prefix_hit_serves_the_solo_tokens_and_restores_the_state(served):
     assert eng.prefix_counters["hits"] == 2
     assert eng.prefix_counters["tokens_skipped"] == 32
     assert _counters(eng)["conv.state_restores"] == 2
-    assert eng.compile_cache_sizes() == {"tick": 1, "chunk": 1, "set_row": 1}
+    assert eng.compile_cache_sizes() == \
+        {"sample": 1, "tick": 1, "chunk": 1, "set_row": 1}
 
     broken = _engine(mc, params, prefix_cache=True)
     assert list(broken.run(_requests(prompts[:1]))[0]) == want[0]
@@ -135,7 +137,8 @@ def test_speculation_on_and_off_serve_the_same_tokens(served, prefix_cache):
             assert eng.spec_counters["rounds"] > 0
             assert eng.spec_counters["proposed"] > 0
             assert eng.compile_cache_sizes() == {
-                "tick": 0, "chunk": 1, "set_row": 1, "spec_tick": 1}
+                "sample": 0, "tick": 0, "chunk": 1, "set_row": 1,
+                "spec_tick": 1}
     assert outs[True] == outs[False] == solo
 
 
@@ -150,7 +153,7 @@ def test_a_cloned_engine_serves_the_same_tokens(served):
     assert clone.metrics is eng.metrics
     assert [list(r) for r in clone.run(_requests(prompts))] == want
     assert _counters(clone)["moe.choices_total"] == 2 * before
-    assert clone.compile_cache_sizes() == {"tick": 1, "chunk": 1,
+    assert clone.compile_cache_sizes() == {"sample": 1, "tick": 1, "chunk": 1,
                                            "set_row": 1}
 
 

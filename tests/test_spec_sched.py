@@ -284,7 +284,8 @@ def test_spec_preempt_replay_parity(prefix_cache):
         if sizes is None and eng.spec_counters["rounds"] >= 1:
             sizes = eng.compile_cache_sizes()      # post-warmup snapshot
     assert eng.counters["preemptions"] >= 1, "pool not overcommitted"
-    assert sizes == {"tick": 0, "chunk": 1, "set_row": 1, "spec_tick": 1}
+    assert sizes == \
+        {"sample": 0, "tick": 0, "chunk": 1, "set_row": 1, "spec_tick": 1}
     assert eng.compile_cache_sizes() == sizes      # frozen mid-serve
     for req, rid in zip(reqs, rids):
         res = eng.results[rid]
@@ -328,7 +329,7 @@ def test_spec_off_engine_is_untouched():
     eng = ServeEngine(params, cfg, n_slots=2, max_len=24, chunk=4,
                       metrics=mreg)
     _run_all(eng, [Request(prompt=[1, 2, 3], max_new_tokens=4)])
-    assert eng.compile_cache_sizes() == {"tick": 1, "chunk": 1,
+    assert eng.compile_cache_sizes() == {"sample": 1, "tick": 1, "chunk": 1,
                                          "set_row": 1}
     assert not eng.spec and eng._spec_tick is None
     assert all(s.draft is None for s in eng._slots)

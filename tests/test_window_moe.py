@@ -388,7 +388,8 @@ def test_engine_run_equals_cache_free_generate(served):
     out = eng.run(_requests(prompts))
     assert [r.status for r in out] == ["OK"] * 4
     assert [list(r) for r in out] == want
-    assert eng.compile_cache_sizes() == {"tick": 1, "chunk": 1, "set_row": 1}
+    assert eng.compile_cache_sizes() == \
+        {"sample": 1, "tick": 1, "chunk": 1, "set_row": 1}
     snap = eng.metrics.snapshot()
     # float32: 1 full layer and 4 sliding ones of 2 key heads of 8
     assert snap["gauges"]["kv.bytes_per_token"] == 2 * 1 * 2 * 8 * 4
@@ -413,7 +414,8 @@ def test_a_prefix_hit_serves_the_cold_tokens_and_restores_the_ring(served):
     assert eng.prefix_counters["hits"] == 2
     assert eng.prefix_counters["tokens_skipped"] == 32
     assert _counters(eng)["window.state_restores"] == 2
-    assert eng.compile_cache_sizes() == {"tick": 1, "chunk": 1, "set_row": 1}
+    assert eng.compile_cache_sizes() == \
+        {"sample": 1, "tick": 1, "chunk": 1, "set_row": 1}
 
     broken = _engine(mc, params, prefix_cache=True)
     assert list(broken.run(_requests(prompts[:1]))[0]) == want[0]
@@ -458,7 +460,8 @@ def test_speculation_on_and_off_serve_the_same_tokens(served, prefix_cache):
             assert eng.spec_counters["rounds"] > 0
             assert eng.spec_counters["proposed"] > 0
             assert eng.compile_cache_sizes() == {
-                "tick": 0, "chunk": 1, "set_row": 1, "spec_tick": 1}
+                "sample": 0, "tick": 0, "chunk": 1, "set_row": 1,
+                "spec_tick": 1}
     assert outs[True] == outs[False] == solo
 
 
@@ -478,7 +481,7 @@ def test_a_cloned_engine_serves_the_same_tokens(served):
                  "window.state_restores", "window.snapshots_written",
                  "attn.keys_visible"):
         assert after[name] == 2 * before[name], name
-    assert clone.compile_cache_sizes() == {"tick": 1, "chunk": 1,
+    assert clone.compile_cache_sizes() == {"sample": 1, "tick": 1, "chunk": 1,
                                            "set_row": 1}
 
 
