@@ -269,6 +269,19 @@ METRIC_HELP: dict[str, str] = {
     "moe.choices_held.device": "This engine's device-side total of moe.choices_held as last read",
     "window.state_restores.device": "This engine's device-side total of window.state_restores as last read",
     "window.snapshots_written.device": "This engine's device-side total of window.snapshots_written as last read",
+    # models/state_space_moe.py — the attention layers' pools, the
+    # state-space layers' state per slot, its snapshots under a budget
+    # (kv.snapshot_block_bytes is then one entry's bytes), and the
+    # device-side counters (as above: <name>.device beside each)
+    "ssm.state_restores": "Rows mapped at a length past 0: their state-space layers' state came from a snapshot entry (prefix hits, replays)",
+    "ssm.snapshots_written": "Block ends a chunk's counted tokens reached in a block the host had given a snapshot entry (each wrote the state there)",
+    "ssm.snapshots_evicted": "Snapshot entries taken from the least recently restored block because none was free (the block stays indexed)",
+    "ssm.snapshots_live": "Snapshot entries that hold a block's state, of the budget",
+    "ssm.state_bytes_moved": "Bytes of recurrent state the dispatched programs read and wrote for the rows that advanced (a tick's decoding rows, a chunk's one), reckoned on the host",
+    "ssm.state_restores.device": "This engine's device-side total of ssm.state_restores as last read",
+    "ssm.snapshots_written.device": "This engine's device-side total of ssm.snapshots_written as last read",
+    "prefix.blocks_matched": "Blocks the radix index matched for admissions under a snapshot budget, before the hit is rounded down",
+    "prefix.blocks_restored": "Blocks of those matches kept: up to the deepest that held a snapshot entry (the rest are recomputed)",
     # mem.* — host-side observability footprint (approximate)
     "mem.registry_bytes": "Approximate host bytes held by the metrics registry",
     "mem.trace_ring_bytes": "Approximate host bytes of live traces + the SLO ring",

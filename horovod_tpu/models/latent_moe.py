@@ -812,9 +812,15 @@ def route(cfg: LatentMoEConfig, lp: dict, h2):
     """Sigmoid scores over the whole router, the ``top_k`` largest with the
     bias chosen, weights normalised over the chosen (their sum plus
     ``cfg.route_norm_eps``): ``[N, k]`` both.  ``cfg`` is any config with
-    ``top_k``, ``routed_scale`` and ``route_norm_eps``."""
-    s = jax.nn.sigmoid(jnp.dot(h2.astype(jnp.float32),
-                               lp["w_router"].astype(jnp.float32)))
+    ``top_k``, ``routed_scale`` and ``route_norm_eps``.  A config that says
+    ``route_softmax_top_k`` has the second rule: the ``top_k`` largest
+    logits chosen, weights a softmax over the chosen (no bias, no scale)."""
+    logits = jnp.dot(h2.astype(jnp.float32),
+                     lp["w_router"].astype(jnp.float32))
+    if getattr(cfg, "route_softmax_top_k", False):
+        top, experts = lax.top_k(logits, cfg.top_k)
+        return experts, jax.nn.softmax(top, axis=-1)
+    s = jax.nn.sigmoid(logits)
     _, experts = lax.top_k(s + lp["router_bias"], cfg.top_k)
     picked = jnp.take_along_axis(s, experts, axis=-1)
     total = jnp.sum(picked, axis=-1, keepdims=True)

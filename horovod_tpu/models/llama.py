@@ -741,6 +741,10 @@ class BlockPool:
         self._ref: dict[int, int] = {}       # block -> live references
         self._indexed: set[int] = set()      # owned by a prefix index
         self._lru: dict[int, None] = {}      # zero-ref indexed, LRU order
+        #: called with a block as it returns to the free list (its content
+        #: is garbage from here on): what else is kept under the block's id
+        #: goes with it (a snapshot entry, paged.SnapshotBudget.drop)
+        self.on_free = None
 
     # -- counts ------------------------------------------------------------
 
@@ -785,8 +789,13 @@ class BlockPool:
         if block in self._indexed:
             self._lru[block] = None          # MRU end
         else:
-            self._free.append(block)
+            self._to_free_list(block)
         return 0
+
+    def _to_free_list(self, block: int) -> None:
+        self._free.append(block)
+        if self.on_free is not None:
+            self.on_free(block)
 
     # -- index ownership ----------------------------------------------------
 
@@ -804,7 +813,7 @@ class BlockPool:
                 f"references")
         self._indexed.discard(block)
         self._lru.pop(block, None)
-        self._free.append(block)
+        self._to_free_list(block)
 
     def lru_blocks(self) -> list[int]:
         """Zero-ref cached blocks, least-recently-used first (the

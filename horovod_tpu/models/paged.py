@@ -3,8 +3,9 @@ ServeEngine` asks of a model, and which module answers for a config.
 
 The engine names no model.  A model is a module with these functions, which
 :mod:`horovod_tpu.models.llama` has as it stands and
-:mod:`horovod_tpu.models.latent_moe` and
-:mod:`horovod_tpu.models.shortconv_moe` implement:
+:mod:`horovod_tpu.models.latent_moe`, :mod:`horovod_tpu.models.shortconv_moe`,
+:mod:`horovod_tpu.models.window_moe` and
+:mod:`horovod_tpu.models.state_space_moe` implement:
 
 * ``init_paged_cache(cfg, n_slots, max_len, *, block_size, n_blocks)`` — the
   paged state: a NamedTuple of device arrays with ``block_table``
@@ -37,7 +38,9 @@ replayed into by table writes alone.  A model may keep, beside them, state
 that is *per sequence*: a fixed-size recurrent state a slot, carried by the
 tick and by a row's prefill chunks (``shortconv_moe``'s convolution inputs;
 ``window_moe``'s ring of the last ``window`` keys and values of its sliding
-layers, which need nothing older).  Such a model gives the interface one more, optional function:
+layers, which need nothing older; ``state_space_moe``'s recurrent state of its
+state-space layers).  Such a model gives the interface one more, optional
+function:
 
 * ``set_row(pcache, slot, row, length)`` — the whole of the engine's table
   write (``block_table[slot] = row``, ``length[slot] = length``) and what the
@@ -56,7 +59,31 @@ length takes), :func:`block_ends` (the blocks a program's counted tokens
 fill), and of their counters :func:`read_stats` (the device's ``stats`` as
 Python ints), :func:`count_from_device` (device-side sums under counters of a
 registry that may outlive the engine) and :func:`publish_state_metrics` (what
-both publish alike).
+they publish alike).
+
+**The snapshot budget.**  A snapshot a block is affordable while a state is
+smaller than a block (``shortconv_moe``: 90 KB beside 1.5 MB;  ``window_moe``:
+3.15 MB beside 8.39 MB).  A model whose state is larger than a block
+(``state_space_moe``: 38 MB beside 4 MB) keeps the rule under a **budget**
+instead, and says so by a second optional function:
+
+* ``snapshot_budget(cfg, pcache, metrics)`` — a :class:`SnapshotBudget` over
+  the ``n`` entries the model's cache holds.  The engine then owns which
+  block holds which entry, and ``set_row`` gains one argument: ``set_row(
+  pcache, slot, row, length, snaps)``, ``snaps`` [blocks_per_slot] the entry
+  of each block of ``row`` (``n`` for none).  The model restores the slot's
+  state from the entry of the block that ends at ``length`` and writes a
+  snapshot at a block's end only into the entry that block was given.
+
+The budget's rule: entries are **granted where a snapshot is known to be
+wanted** — at the deepest block the radix index matched for an admitted
+prompt and at the prompt's last full block — and nowhere else; a prefix hit
+is **rounded down** to the deepest matched block that holds an entry (the
+blocks matched beyond it are released and recomputed); entries and blocks are
+**evicted apart** (the least recently restored entry goes when none is free,
+those never restored first, and its block stays indexed; a block that is
+freed gives its entry up); and at
+``insert`` an entry **follows the block that stays**.
 """
 
 from __future__ import annotations
@@ -71,7 +98,7 @@ import numpy as np
 def paged_model(cfg: Any) -> ModuleType:
     """The model module for a config object, by the config's type."""
     from horovod_tpu.models import (latent_moe, llama, shortconv_moe,
-                                    window_moe)
+                                    state_space_moe, window_moe)
 
     if isinstance(cfg, llama.LlamaConfig):
         return llama
@@ -81,10 +108,12 @@ def paged_model(cfg: Any) -> ModuleType:
         return shortconv_moe
     if isinstance(cfg, window_moe.WindowMoEConfig):
         return window_moe
+    if isinstance(cfg, state_space_moe.StateSpaceMoEConfig):
+        return state_space_moe
     raise TypeError(
         f"ServeEngine serves a LlamaConfig, a LatentMoEConfig, a "
-        f"ShortConvMoEConfig or a WindowMoEConfig, not a "
-        f"{type(cfg).__name__}")
+        f"ShortConvMoEConfig, a WindowMoEConfig or a StateSpaceMoEConfig, "
+        f"not a {type(cfg).__name__}")
 
 
 class Dispatched(NamedTuple):
@@ -104,6 +133,151 @@ class Dispatched(NamedTuple):
     @property
     def longest(self) -> int:
         return int(max(self.lengths))
+
+
+class SnapshotBudget:
+    """Host-side owner of a model's ``n`` snapshot entries and of the map
+    block -> entry (module docstring, *The snapshot budget*).  Policy-free
+    about *where* an entry is wanted (the engine asks); it tracks states:
+
+    * **free** — on the free list;
+    * **pending** — granted to a block whose end a live row's prefill has
+      yet to reach: the device will write it, so it is neither restorable
+      nor evictable until :meth:`commit` (the write is dispatched) or
+      :meth:`cancel` (the row left first).  If the block is freed meanwhile
+      the entry stays pending with no block and goes free at its commit;
+    * **held** — a block's snapshot, restorable.  :meth:`grant` evicts, when
+      none is free, the least recently restored: first, oldest first, the
+      entries no row was ever restored from (a prompt's last block is asked
+      for by every request and restored from by few: these must not push
+      out the ones that are), then the others by their last
+      :meth:`touch`.
+
+    ``evicted`` (a counter) and ``live`` (a gauge: entries held) are the
+    model's own metrics, or ``None``."""
+
+    def __init__(self, n: int, evicted=None, live=None):
+        if n < 1:
+            raise ValueError(f"a snapshot budget of {n} entries")
+        self.n = n
+        self._free = list(range(n - 1, -1, -1))     # pop() takes low ids first
+        self._held: dict[int, int] = {}             # block -> entry
+        self._cold: dict[int, None] = {}            # never restored, by commit
+        self._hot: dict[int, None] = {}             # by last restore
+        self._pending: dict[int, int | None] = {}   # entry -> block (or None)
+        self._evicted, self._live = evicted, live
+
+    @property
+    def none(self) -> int:
+        """What a row carries for a block without an entry: past the pool."""
+        return self.n
+
+    def entry(self, block: int) -> int | None:
+        """The entry that holds ``block``'s snapshot, if one does."""
+        return self._held.get(block)
+
+    def wanted(self, block: int) -> bool:
+        """Whether ``block`` holds an entry or is about to."""
+        return block in self._held or block in self._pending.values()
+
+    def pending_block(self, entry: int) -> int | None:
+        """The block pending ``entry`` is to be held by (``None``: it was
+        freed meanwhile, or the entry is not pending)."""
+        return self._pending.get(entry)
+
+    def held_count(self) -> int:
+        return len(self._held)
+
+    def pending_count(self) -> int:
+        return len(self._pending)
+
+    def grant(self, block: int, *, on_evidence: bool = True) -> int | None:
+        """An entry for ``block``, pending: a free one, else the least
+        recently restored held one (its block loses it); ``None`` where
+        every entry is pending.  Asked for without evidence that the
+        snapshot will be restored from (``on_evidence`` false: a prompt's
+        own end, not a prefix another request was seen to share), it takes
+        no entry that has been."""
+        if self._free:
+            e = self._free.pop()
+        elif self._cold or (self._hot and on_evidence):
+            e = self._unhold(next(iter(self._cold or self._hot)))
+            if self._evicted is not None:
+                self._evicted.inc()
+        else:
+            return None
+        self._pending[e] = block
+        self._gauge()
+        return e
+
+    def commit(self, entry: int) -> None:
+        """The write of pending ``entry`` is dispatched: its block holds it
+        from here on (the device runs programs in order), or it goes free
+        where the block was freed meanwhile."""
+        block = self._pending.pop(entry)
+        if block is None:
+            self._free.append(entry)
+        else:
+            if block in self._held:             # the same tokens' state:
+                self._free.append(self._unhold(block))      # alike; keep one
+            self._held[block] = entry
+            self._cold[block] = None
+        self._gauge()
+
+    def cancel(self, entry: int) -> None:
+        """The row that would have written pending ``entry`` left first."""
+        if self._pending.pop(entry, -1) != -1:
+            self._free.append(entry)
+
+    def touch(self, block: int) -> None:
+        """``block``'s entry was restored: most recently used."""
+        self._cold.pop(block, None)
+        self._hot.pop(block, None)
+        self._hot[block] = None
+
+    def drop(self, block: int) -> None:
+        """``block`` was freed (it left the index, or its row did): its
+        entry is free; one pending for it goes free at its commit."""
+        if block in self._held:
+            self._free.append(self._unhold(block))
+            self._gauge()
+        for p, b in self._pending.items():
+            if b == block:
+                self._pending[p] = None
+
+    def move(self, src: int, dst: int) -> None:
+        """The entry of ``src`` follows ``dst``, the block that stays for
+        the same tokens, unless ``dst`` holds one already."""
+        if src in self._held and dst not in self._held:
+            order = self._hot if src in self._hot else self._cold
+            self._held[dst] = self._unhold(src)
+            order[dst] = None
+
+    def _unhold(self, block: int) -> int:
+        self._cold.pop(block, None)
+        self._hot.pop(block, None)
+        return self._held.pop(block)
+
+    def _gauge(self) -> None:
+        if self._live is not None:
+            self._live.set(len(self._held))
+
+    def check_consistency(self) -> None:
+        """Every entry is in exactly one state, every held block in exactly
+        one order."""
+        seen = sorted([*self._free, *self._held.values(), *self._pending])
+        if seen != list(range(self.n)) or sorted(self._held) != sorted(
+                [*self._cold, *self._hot]):
+            raise AssertionError(
+                f"snapshot entries out of sync: free={self._free} "
+                f"held={self._held} cold={list(self._cold)} "
+                f"hot={list(self._hot)} pending={self._pending}")
+
+    def state_lines(self) -> list[str]:
+        return [f"snapshot budget: free={len(self._free)} "
+                f"held={len(self._held)} pending={len(self._pending)} "
+                f"of {self.n}; never restored (old->new)={list(self._cold)} "
+                f"restored (old->new)={list(self._hot)}"]
 
 
 def block_before(row, length, bs: int):

@@ -67,6 +67,7 @@ from typing import Iterable
 
 from horovod_tpu import metrics as metrics_mod
 from horovod_tpu.models.llama import BlockPool
+from horovod_tpu.models.paged import SnapshotBudget
 
 
 def _update_chunk(h: "hashlib._Hash", chunk: Iterable[int]) -> None:
@@ -137,10 +138,17 @@ class RadixPrefixCache:
     """
 
     def __init__(self, pool: BlockPool, block_size: int,
-                 metrics: "metrics_mod.MetricsRegistry | None" = None):
+                 metrics: "metrics_mod.MetricsRegistry | None" = None,
+                 snaps: "SnapshotBudget | None" = None):
         if block_size < 1:
             raise ValueError(f"block_size {block_size} must be >= 1")
         self.pool = pool
+        #: the snapshot budget of a model whose per-sequence state is too
+        #: large for a snapshot a block (models/paged.py): a hit is then
+        #: rounded down to the deepest matched block that holds an entry
+        self.snaps = snaps
+        #: every block the last ``acquire`` matched, rounded down or not
+        self.last_match: list[int] = []
         self.block_size = block_size
         self.metrics = metrics if metrics is not None else metrics_mod.NULL
         self._root = RadixNode(block=0, key=(), parent=None)
@@ -258,9 +266,23 @@ class RadixPrefixCache:
         holding the write frontier is always private (the COW rule: a
         full hit recomputes its final chunk into a fresh block rather
         than mutating the shared one).  Each returned block is
-        incref'd — pinned against eviction — until ``release``."""
+        incref'd — pinned against eviction — until ``release``.  Under
+        a snapshot budget (``snaps``) the match is rounded down to the
+        deepest block that holds an entry; ``last_match`` keeps all of
+        it for the caller that asks where a snapshot is wanted."""
         matched = self._walk(tokens, max(len(tokens) - 1, 0))
         blocks = [n.block for n in matched]
+        self.last_match = list(blocks)
+        if self.snaps is not None:
+            # under a snapshot budget the row's own state has to be restored
+            # where the hit ends: the deepest matched block that holds an
+            # entry, nothing where none does; the blocks matched beyond it
+            # are left as they were and the row recomputes them
+            held = [i for i, b in enumerate(blocks)
+                    if self.snaps.entry(b) is not None]
+            blocks = blocks[:held[-1] + 1] if held else []
+            if blocks:
+                self.snaps.touch(blocks[-1])
         for b in blocks:
             self.pool.incref(b)
         if blocks:
@@ -304,6 +326,10 @@ class RadixPrefixCache:
                 self._nodes[blocks[i]] = child
                 self.pool.mark_indexed(blocks[i])
                 added += 1
+            elif self.snaps is not None and child.block != blocks[i]:
+                # a recomputed duplicate: its snapshot, if it has one, is of
+                # the same tokens, and follows the block that stays
+                self.snaps.move(blocks[i], child.block)
             node = child
         if added:
             self._bump("inserted_blocks", added)

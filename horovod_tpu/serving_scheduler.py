@@ -230,6 +230,9 @@ class _Slot:
     slo_deadline: float | None = None    # enqueue + slo_s (EDF policy)
     admit_seq: int = -1                  # monotonic; max = youngest row
     draft: "drafting_mod.NgramDraftState | None" = None
+    # (block index, entry) of the snapshot entries this row's prefill has
+    # yet to write (models/paged.py, the snapshot budget)
+    snap_pending: list[tuple] = dataclasses.field(default_factory=list)
 
 
 class ServeEngine:
@@ -555,6 +558,16 @@ class ServeEngine:
         # block 0 is trash — never allocated; the pool's free list pops
         # low ids first, matching the classic free-list order
         self.pool = BlockPool(total)
+        # a model whose per-sequence state is too large for a snapshot a
+        # block keeps a budget of them (models/paged.py): the engine owns
+        # which block holds which entry and tells `set_row`
+        make_budget = getattr(model, "snapshot_budget", None)
+        self.snaps = (make_budget(cfg, self.pcache, self.metrics)
+                      if make_budget is not None else None)
+        if self.snaps is not None:
+            self.pool.on_free = self.snaps.drop
+            self._no_snaps = np.full((self.blocks_per_slot,),
+                                     self.snaps.none, np.int32)
         # legacy alias: the SAME list object the pool allocates from
         # (white-box tests drain it to force block starvation)
         self._free_blocks = self.pool._free
@@ -580,7 +593,8 @@ class ServeEngine:
         self.metrics.gauge("kv.shard_total_bytes").set(
             self._shard_block_bytes * total)
         self.prefix = (RadixPrefixCache(self.pool, block_size,
-                                        metrics=self.metrics)
+                                        metrics=self.metrics,
+                                        snaps=self.snaps)
                        if prefix_cache else None)
         self.prefix_counters = {"hits": 0, "blocks_reused": 0,
                                 "tokens_skipped": 0, "evictions": 0}
@@ -694,6 +708,13 @@ class ServeEngine:
                 block_table=pcache.block_table.at[slot].set(row),
                 length=pcache.length.at[slot].set(length))
 
+        if self.snaps is not None:
+            @partial(jax.jit, donate_argnums=(0,), **_row_sh)
+            def _set_row(pcache, slot, row, length, snaps):  # noqa: F811
+                # under a snapshot budget the model's `set_row` is also
+                # told the entry of each block of the row (`_map_row`)
+                return model.set_row(pcache, slot, row, length, snaps)
+
         if self.spec:
             @partial(jax.jit, donate_argnums=(1, 2), **_spec_sh)
             def _spec_tick(params, pcache, last_logits, drafts, active):
@@ -766,7 +787,8 @@ class ServeEngine:
             "tick": (self._tick, p_av, c_av, ll_av, active_av),
             "chunk": (self._chunk, p_av, c_av, ll_av, toks_av,
                       i32, i32, i32),
-            "set_row": (self._set_row, c_av, i32, row_av, i32),
+            "set_row": ((self._set_row, c_av, i32, row_av, i32)
+                        + ((row_av,) if self.snaps is not None else ())),
         }
         if self._spec_tick is not None:
             drafts_av = jax.ShapeDtypeStruct(
@@ -974,6 +996,8 @@ class ServeEngine:
                 f" compiles={drep['compiles']}"
                 f" retrace_est_s={drep['retrace_compile_est_s']:.3f}")
         lines += ["  " + ln for ln in self.pool.state_lines()]
+        if self.snaps is not None:
+            lines += ["  " + ln for ln in self.snaps.state_lines()]
         if self.prefix is not None:
             lines.append(
                 f"  prefix cache: indexed="
@@ -1135,14 +1159,83 @@ class ServeEngine:
 
     # -- scheduling --------------------------------------------------------
 
+    def _map_row(self, slot: int, row: np.ndarray, length: int,
+                 snaps: np.ndarray | None = None) -> None:
+        """The table write (``_set_row``): slot ``slot`` maps ``row`` at
+        ``length``; under a snapshot budget also the entry of each of the
+        row's blocks (none, by default)."""
+        budget = ()
+        if self.snaps is not None:
+            budget = (jnp.asarray(self._no_snaps if snaps is None
+                                  else snaps),)
+        if self.device is not None:
+            self.device.dispatch("set_row",
+                                 h2d_bytes=row.nbytes * (1 + len(budget)) + 8)
+        self.pcache = self._set_row(
+            self.pcache, jnp.asarray(slot, jnp.int32), jnp.asarray(row),
+            jnp.asarray(length, jnp.int32), *budget)
+
+    def _grant_snapshots(self, s: _Slot, blocks: list[int], n_hit: int,
+                         matched: list[int], L: int) -> np.ndarray:
+        """The snapshot entries of an admitted row's blocks: the one its
+        state is restored from (the hit's last block) and the ones its
+        prefill is to write, asked of the budget where a snapshot is known
+        to be wanted — at the deepest block the index matched for this
+        prompt and still holds, where none is held or on its way (for the
+        block that is indexed, not the row's recomputed copy: the next
+        bearer of this prefix hits as soon as the write is dispatched), and
+        at the prompt's last full block (a replay, a next turn: without
+        evidence yet, so it takes no entry that was restored from).  A
+        request the budget refuses is served all the same."""
+        snaps = self._no_snaps.copy()
+        # what the budget cost this admission: matched, and kept
+        self.metrics.counter("prefix.blocks_matched").inc(len(matched))
+        self.metrics.counter("prefix.blocks_restored").inc(n_hit)
+        if n_hit:
+            snaps[n_hit - 1] = self.snaps.entry(blocks[n_hit - 1])
+        wants = {}
+        # the blocks matched beyond the hit carry no reference of this
+        # row's: the eviction that made room for this very admission may
+        # have freed them (and handed them back to this row at another
+        # index), so the entry is asked for the deepest that is still
+        # indexed, and for none where none is
+        deep = len(matched)
+        while deep > n_hit and matched[deep - 1] not in self.prefix:
+            deep -= 1
+        if deep > n_hit and not self.snaps.wanted(matched[deep - 1]):
+            wants[deep - 1] = (matched[deep - 1], True)
+        last = L // self.block_size - 1
+        if last >= n_hit:
+            wants.setdefault(last, (blocks[last], False))
+        for i, (block, shared) in wants.items():
+            entry = self.snaps.grant(block, on_evidence=shared)
+            if entry is not None:
+                snaps[i] = entry
+                s.snap_pending.append((i, entry))
+        return snaps
+
+    def _commit_snapshots(self, s: _Slot, length: int) -> None:
+        """The row's prefill is dispatched up to ``length``: the entries
+        whose block ends lie within it are their blocks' from here on."""
+        still = []
+        for i, entry in s.snap_pending:
+            if (i + 1) * self.block_size <= length:
+                self.snaps.commit(entry)
+            else:
+                still.append((i, entry))
+        s.snap_pending = still
+
     def _admit_entry(self, e: _QueueEntry, slot: int,
-                     hit: list[int] | None = None) -> None:
+                     hit: list[int] | None = None,
+                     matched: list[int] | None = None) -> None:
         """Map a queue entry into a free slot.  ``hit`` is the
         prefix-cache match (already referenced by ``acquire``): its
         blocks lead the row's block table and prefill starts at the
         first position past them — the match is capped so the write
         frontier always lands in a freshly allocated private block
-        (the COW rule; see :mod:`horovod_tpu.prefix_cache`)."""
+        (the COW rule; see :mod:`horovod_tpu.prefix_cache`).  ``matched``
+        is every block the index matched, which under a snapshot budget
+        may be more than the hit (it was rounded down)."""
         hit = hit or []
         prompt = list(e.req.prompt) + list(e.prior)
         L = len(prompt)
@@ -1156,11 +1249,11 @@ class ServeEngine:
             blocks.append(b)
         row = self._trash_row.copy()
         row[:need] = blocks
-        if self.device is not None:
-            self.device.dispatch("set_row", h2d_bytes=row.nbytes + 8)
-        self.pcache = self._set_row(
-            self.pcache, jnp.asarray(slot, jnp.int32),
-            jnp.asarray(row), jnp.asarray(base, jnp.int32))
+        snaps = None
+        if self.snaps is not None and self.prefix is not None:
+            snaps = self._grant_snapshots(s, blocks, len(hit),
+                                          matched or hit, L)
+        self._map_row(slot, row, base, snaps)
         rem = L - base                    # tokens still to prefill (>= 1)
         n_win = -(-rem // self.chunk)
         padded = np.zeros((1, n_win * self.chunk), np.int32)
@@ -1281,7 +1374,9 @@ class ServeEngine:
                     self._event("retry", -1, e.rid)
                 continue
             self._queue.remove(e)
-            self._admit_entry(e, free[0], hit)
+            self._admit_entry(e, free[0], hit,
+                              self.prefix.last_match
+                              if self.prefix is not None else None)
             admitted += 1
         return admitted, None
 
@@ -1301,6 +1396,8 @@ class ServeEngine:
         LRU order instead of freeing; otherwise (cache off, or a FAILED
         / expired row whose frontier is not trusted) references drop
         straight back toward the free list, in the classic order."""
+        for _, entry in s.snap_pending:   # writes that will not come
+            self.snaps.cancel(entry)
         if self.prefix is not None and register and s.req is not None:
             toks = (list(s.req.prompt) + list(s.prior) + list(s.out))
             self.prefix.insert(toks, s.blocks, s.true_len + len(s.out))
@@ -1323,12 +1420,7 @@ class ServeEngine:
             deadline=s.deadline,
             slo_deadline=s.slo_deadline)
         self._release_row_blocks(s, register=True)
-        if self.device is not None:
-            self.device.dispatch(
-                "set_row", h2d_bytes=self._trash_row.nbytes + 8)
-        self.pcache = self._set_row(
-            self.pcache, jnp.asarray(slot, jnp.int32),
-            jnp.asarray(self._trash_row), jnp.asarray(0, jnp.int32))
+        self._map_row(slot, self._trash_row, 0)
         self._slots[slot] = _Slot()
         self._queue.append(entry)
 
@@ -1378,12 +1470,7 @@ class ServeEngine:
         self._finished[s.request_id] = res
         self._finalize_trace(s.request_id, res)
         self._release_row_blocks(s, register=status == OK)
-        if self.device is not None:
-            self.device.dispatch(
-                "set_row", h2d_bytes=self._trash_row.nbytes + 8)
-        self.pcache = self._set_row(
-            self.pcache, jnp.asarray(slot, jnp.int32),
-            jnp.asarray(self._trash_row), jnp.asarray(0, jnp.int32))
+        self._map_row(slot, self._trash_row, 0)
         kind = {OK: "recycle", TIMEOUT: "timeout",
                 CANCELLED: "cancel", FAILED: "fail"}[status]
         self._event(kind, slot, s.request_id)
@@ -1612,6 +1699,22 @@ class ServeEngine:
                     f"references but no live row maps it")
         if self.prefix is not None:
             self.prefix.check_consistency()
+        if self.snaps is not None:
+            self.snaps.check_consistency()
+            for slot, s in enumerate(self._slots):
+                # an entry a row is to write at its block `i`'s end is
+                # that block's, or the indexed block's of the same tokens
+                path = (self.prefix.path_blocks(
+                    list(s.req.prompt) + list(s.prior))
+                    if s.snap_pending and self.prefix is not None else [])
+                for i, entry in s.snap_pending:
+                    b = self.snaps.pending_block(entry)
+                    if b is not None and b != s.blocks[i] \
+                            and b != (path[i:i + 1] or [None])[0]:
+                        raise AssertionError(
+                            f"slot {slot} writes entry {entry} at its block "
+                            f"{i}'s end for block {b}, which holds other "
+                            f"tokens")
         total = self.pool.n_blocks - 1
         accounted = (len(free) + self.pool.cached_count()
                      + len(self.pool._ref))
@@ -1729,6 +1832,7 @@ class ServeEngine:
                                          h2d_bytes=toks.nbytes + 12)
                 s.w_done += 1
                 progress += 1
+                self._commit_snapshots(s, new_len)
                 if tr is not None:
                     if traced:
                         self._emit_chunk_span(tr, t_chunk, time.monotonic())

@@ -342,3 +342,197 @@ def test_the_cells_pool_is_what_the_chip_leaves(window_compiled):
                 for mem in (compiled(p).memory_analysis()
                             for p in ("tick", "chunk")))
     assert 0.3e9 < spare < 0.3e9 + 8 * block, spare
+
+
+def _toolcalls_engine() -> dict:
+    """The engine of the one cell that serves the fifth model, from its
+    traffic file: the size is written down there alone."""
+    import json
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "traffic",
+            "toolcalls.json")) as f:
+        return json.load(f)["engine"]
+
+
+@pytest.fixture(scope="module")
+def state_space_compiled(one_chip, no_compile_cache):
+    """``compiled(program)`` of the fifth model's tick, chunk and table write
+    at the cell's own size (``granite_toolcalls``: 64 slots of 9,216 over
+    blocks of 1,024 and a budget of 24 snapshots), each compiled once, and
+    the cache's shapes."""
+    from horovod_tpu.models import state_space_moe as ssm
+
+    e = _toolcalls_engine()
+    cfg = ssm.StateSpaceMoEConfig(snapshots=e["snapshots"],
+                                  max_seq_len=e["max_len"])
+    n_slots, chunk_len, bs = e["n_slots"], e["chunk"], e["block_size"]
+    params = _avals(jax.eval_shape(
+        lambda: ssm.init_params(cfg, jax.random.key(0))), one_chip)
+    cache = _avals(jax.eval_shape(lambda: ssm.init_paged_cache(
+        cfg, n_slots, e["max_len"], block_size=bs,
+        n_blocks=e["n_blocks"])), one_chip)
+    logits = jax.ShapeDtypeStruct((n_slots, cfg.vocab_size), jnp.float32,
+                                  sharding=one_chip)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    row = jax.ShapeDtypeStruct((e["max_len"] // bs,), jnp.int32,
+                               sharding=one_chip)
+
+    @partial(jax.jit, donate_argnums=(1, 2))
+    def tick(params, pcache, last_logits, active):
+        tok = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
+        out, pcache = ssm.decode_chunk_paged(params, tok[:, None], cfg,
+                                             pcache, advance=active)
+        return tok, out[:, 0], pcache
+
+    @partial(jax.jit, donate_argnums=(1, 2))
+    def chunk(params, pcache, last_logits, toks, slot, new_len, sel):
+        out, pcache = ssm.decode_chunk_paged_row(params, toks, cfg, pcache,
+                                                 slot, new_length=new_len)
+        return pcache, last_logits.at[slot].set(out[0, sel])
+
+    set_row = jax.jit(ssm.set_row, donate_argnums=(0,))
+    lowered = {
+        "tick": lambda: tick.lower(params, cache, logits, jax.ShapeDtypeStruct(
+            (n_slots,), jnp.int32, sharding=one_chip)),
+        "chunk": lambda: chunk.lower(
+            params, cache, logits, jax.ShapeDtypeStruct(
+                (1, chunk_len), jnp.int32, sharding=one_chip), i32, i32, i32),
+        "set_row": lambda: set_row.lower(cache, i32, row, i32, row)}
+    done = {}
+
+    def compiled(program):
+        if program not in done:
+            done[program] = lowered[program]().compile()
+        return done[program]
+
+    return compiled, cache
+
+
+@pytest.mark.parametrize("program", ["tick", "chunk", "set_row"])
+def test_state_space_programs_fit_the_chip_and_hold_no_second_state(
+        state_space_compiled, program):
+    """9.51 GB of weights, 2.44 GB of slots' states, the pools and the
+    snapshot entries are held once and written in place, and the programs'
+    scratch beside them leaves 0.3 GB of the chip: the tick updates every
+    slot's state where it stands, layer by layer; a chunk reads and writes
+    its one row's as a slice (scattered by an index array, the chunk copied
+    all 64 slots' states first: 2.25 GB of scratch)."""
+    compiled, cache = state_space_compiled
+    mem = compiled(program).memory_analysis()
+    held = sum(a.size * a.dtype.itemsize for a in cache)
+    states = cache.ssm.size * cache.ssm.dtype.itemsize
+    assert cache.ssm.shape == (9, 64, 128, 64, 128)
+    assert cache.snap_ssm.shape[:2] == (9, _toolcalls_engine()["snapshots"])
+    assert cache.snap_ssm.shape[1] * 20 < cache.k.shape[1]  # no snapshot a block
+    assert mem.alias_size_in_bytes >= held
+    # under half of the slots' states, and under the snapshot entries'
+    assert mem.temp_size_in_bytes < states // 2, mem.temp_size_in_bytes
+    assert mem.temp_size_in_bytes < cache.snap_ssm.size * 4
+    spare = V5E_USABLE_BYTES - mem.argument_size_in_bytes \
+        - mem.temp_size_in_bytes
+    assert spare > 0.3e9, spare
+
+
+def _run_twice(text: str, shape: tuple) -> list:
+    """The instructions of a compiled program's entry computation whose
+    result has ``shape`` and which the compiler rematerialised beside the
+    original (``<name>.remat`` with ``<name>`` still there): run twice."""
+    entry = text[text.index("ENTRY"):]
+    names = set(re.findall(r"^\s+(?:ROOT )?%([\w.\-]+) = ", entry, re.M))
+    dims = ",".join(str(d) for d in shape)
+    clones = re.findall(
+        r"^\s+%([\w.\-]+?)\.remat\d* = \w+\[" + re.escape(dims) + r"\]",
+        entry, re.M)
+    return [c for c in clones if c in names]
+
+
+@pytest.mark.parametrize("program", ["tick", "chunk", "set_row"])
+def test_no_state_space_program_updates_the_state_in_place_twice(
+        state_space_compiled, program):
+    """An update of the state where it stands is no value to compute again:
+    XLA:TPU's rematerialisation cloned the first layer's read-modify-write of
+    the slots' states in the tick at this size (the clone read what the
+    original had already written: every tick advanced that layer's state
+    twice; found on the chip by the benchmark's state probes, PERF.md, PR
+    38).  Since then a tick advances all the layers' states in one pass at
+    its end, and no compiled program may hold such a pair."""
+    compiled, cache = state_space_compiled
+    text = compiled(program).as_text()
+    assert "ENTRY" in text and re.search(r"%pcache_ssm[\w.]* = f32\[", text)
+    for a in (cache.ssm, cache.snap_ssm):
+        assert _run_twice(text, a.shape) == []
+    if program == "tick":
+        # one reader a layer (``S C``) and one read-modify-write of it all
+        entry = text[text.index("ENTRY"):]
+        writes = re.findall(
+            r"^\s+%[\w.\-]+ = f32\[9,64,128,64,128\]\S* fusion\(", entry,
+            re.M)
+        assert len(writes) == 1, writes
+
+
+def _route_as_it_was(cfg, lp, h2):
+    """``latent_moe.route`` before it had a second rule (PR 37), kept to
+    compare lowerings with."""
+    s = jax.nn.sigmoid(jnp.dot(h2.astype(jnp.float32),
+                               lp["w_router"].astype(jnp.float32)))
+    _, experts = lax.top_k(s + lp["router_bias"], cfg.top_k)
+    picked = jnp.take_along_axis(s, experts, axis=-1)
+    total = jnp.sum(picked, axis=-1, keepdims=True)
+    if cfg.route_norm_eps:
+        total = total + cfg.route_norm_eps
+    return experts, picked / total * cfg.routed_scale
+
+
+@pytest.mark.parametrize("model", ["shortconv", "window"])
+def test_the_snapshot_rules_two_users_lower_to_the_programs_they_were(
+        model, monkeypatch):
+    """lfm2's and K-EXAONE's tick, chunk and table write at their cells'
+    sizes are, letter for letter, the programs they were before the router
+    had a second rule; their ``set_row`` keeps its four arguments (the
+    engine jits the five-argument form only for a model that has a
+    ``snapshot_budget``).  (Lowered for the host; nothing is compiled.)"""
+    from horovod_tpu.models import window_moe as wm
+
+    if model == "shortconv":
+        mod, cfg = sm, sm.ShortConvMoEConfig()
+        n_slots, max_len, chunk_len, n_blocks = 128, 2048, 256, 1041
+    else:
+        mod, cfg = wm, wm.WindowMoEConfig()
+        e = _mixedq_engine()
+        n_slots, max_len, chunk_len, n_blocks = (
+            e["n_slots"], e["max_len"], e["chunk"], e["n_blocks"])
+    assert not hasattr(mod, "snapshot_budget")
+    params = jax.eval_shape(lambda: mod.init_params(cfg, jax.random.key(0)))
+    cache = jax.eval_shape(lambda: mod.init_paged_cache(
+        cfg, n_slots, max_len, block_size=chunk_len, n_blocks=n_blocks))
+    logits = jax.ShapeDtypeStruct((n_slots, cfg.vocab_size), jnp.float32)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32)
+    row = jax.ShapeDtypeStruct((max_len // chunk_len,), jnp.int32)
+
+    def lowered() -> dict:
+        @partial(jax.jit, donate_argnums=(1, 2))
+        def tick(params, pcache, last_logits, active):
+            tok = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
+            out, pcache = mod.decode_chunk_paged(
+                params, tok[:, None], cfg, pcache, advance=active)
+            return out[:, 0], pcache
+
+        @partial(jax.jit, donate_argnums=(1, 2))
+        def chunk(params, pcache, last_logits, toks, slot, new_len, sel):
+            out, pcache = mod.decode_chunk_paged_row(
+                params, toks, cfg, pcache, slot, new_length=new_len)
+            return pcache, last_logits.at[slot].set(out[0, sel])
+
+        set_row = jax.jit(mod.set_row, donate_argnums=(0,))
+        return {
+            "tick": tick.lower(params, cache, logits, jax.ShapeDtypeStruct(
+                (n_slots,), jnp.int32)).as_text(),
+            "chunk": chunk.lower(
+                params, cache, logits, jax.ShapeDtypeStruct(
+                    (1, chunk_len), jnp.int32), i32, i32, i32).as_text(),
+            "set_row": set_row.lower(cache, i32, row, i32).as_text()}
+
+    now = lowered()
+    monkeypatch.setattr(lm, "route", _route_as_it_was)
+    assert now == lowered()

@@ -101,7 +101,7 @@ def test_paged_model_answers_for_the_config():
     _, mc, _ = tiny()
     assert paged.paged_model(mc) is sm
     with pytest.raises(TypeError, match="a LlamaConfig, a LatentMoEConfig, a"
-                                        " ShortConvMoEConfig or"):
+                                        " ShortConvMoEConfig, a"):
         paged.paged_model(object())
     for fn in (lambda: sm.param_partition_specs(mc),
                lambda: sm.paged_cache_partition_specs(),
