@@ -354,7 +354,8 @@ def _serve_and_probe(eng, reqs, probes, http: int) -> tuple[list, dict]:
     times["scratch"] = _pool_held_once(eng)
     # Counts only grow, so once at the end covers run(), HTTP, the probes and
     # the AOT compiles: admission, recycling and prefix hits never retraced a
-    # pinned program, and lowering one mints no entry.
+    # pinned program, and lowering one mints no entry ("chunk": one signature
+    # a width of the chunk program, all compiled by the constructor).
     sizes = eng.compile_cache_sizes()
     assert sizes == {"sample": 1, "tick": 1, "chunk": 1, "set_row": 1}, sizes
     return logits, times

@@ -161,6 +161,9 @@ METRIC_HELP: dict[str, str] = {
     "serve.tpot_s": "Seconds per output token after the first (decode cadence)",
     "serve.steps": "Engine scheduler steps executed",
     "serve.step.host_bound": "Ticking steps whose sampled tokens were ready before the host asked: the device had run out of work first",
+    "serve.chunk.programs": "Prefill chunk programs dispatched",
+    "serve.chunk.rows": "Rows the prefill chunk programs carried (over serve.chunk.programs: rows that shared one read of the weights)",
+    "serve.chunk.max_rows": "Rows of the wide prefill chunk program the engine holds beside the one-row one (1: it holds no wide one)",
     "serve.queue_depth": "Requests waiting for admission",
     "serve.decoding": "Slots actively decoding",
     "serve.prefilling": "Slots mid-prefill",

@@ -34,6 +34,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from horovod_tpu.models import paged
 from horovod_tpu.parallel import attention as attn_mod
 
 
@@ -860,13 +861,21 @@ def _tile_blocks(block_size: int, per: int) -> int:
     return max(1, min(per, _KEY_TILE // block_size))
 
 
-def _row_groups(rows: int, n_tiles: int) -> tuple[int, int]:
-    """``(groups, rows a group)`` of a program's walk: groups of
-    :data:`_ROW_GROUP` rows, but no more groups than the table is deep in
-    tiles, since the groups' bounds can differ by no more than that and each
-    group is a loop of its own (a table of four tiles under 128 rows is
-    walked in four groups of 32).  One group is the whole program: a chunk's
-    one row, and any program of no more rows than a group holds."""
+def _row_groups(rows: int, n_tiles: int, t: int = 1) -> tuple[int, int]:
+    """``(groups, rows a group)`` of the walk of a program of ``t`` tokens a
+    row: groups of :data:`_ROW_GROUP` rows, but no more groups than the table
+    is deep in tiles, since the groups' bounds can differ by no more than
+    that and each group is a loop of its own (a table of four tiles under 128
+    rows is walked in four groups of 32).  One group is the whole program: a
+    chunk's one row, and any program of no more rows than a group holds.  A
+    row that brings more queries than a group has rows (a prefill chunk's)
+    is a group of its own: a tile then costs its row ``t`` times what it
+    costs a tick's, a loop's trip what it ever did, so rows of unlike length
+    that shared a bound would pay the longest's tiles ``t`` queries each
+    (``kexaone_mixedq``, PERF.md, PR 39: pairs of rows to their longer row's
+    bound made a program of two slower than two of one)."""
+    if t > _ROW_GROUP:
+        return rows, 1
     r = -(-rows // max(1, min(-(-rows // _ROW_GROUP), n_tiles)))
     return -(-rows // r), r             # no group is all padding
 
@@ -890,7 +899,7 @@ def paged_blocks_walked(lengths, active, t: int, block_size: int,
     active = np.asarray(active) > 0
     last = np.where(active,
                     np.minimum(end // (g * block_size) + 1, n_tiles), 1)
-    groups, r = _row_groups(len(last), n_tiles)
+    groups, r = _row_groups(len(last), n_tiles, t)
     # as the device orders them: by last tile, padded in front with the
     # shortest row (fewer places than a group), a group's bound its last row's
     pad = groups * r - len(last)
@@ -939,7 +948,7 @@ def tile_walk(table: jax.Array, qpos: jax.Array, bs: int,
     last = jnp.minimum(qpos[:, -1] // (g * bs) + 1, n_tiles)  # [B] tiles
     if active is not None:
         last = jnp.where(jnp.asarray(active) > 0, last, 1)
-    groups, r = _row_groups(b, n_tiles)
+    groups, r = _row_groups(b, n_tiles, qpos.shape[1])
     if groups == 1:     # today's loop: the rows as they stand, one bound
         return TileWalk(table=table[None], qpos=qpos[None], g=g,
                         n_live=jnp.max(last)[None], m=per * bs,
@@ -1027,7 +1036,7 @@ def paged_attend_tiles(q, k, v, kf, vf, layer, walk: TileWalk, wflat,
 
 
 def _paged_attend(params, tokens, cfg: LlamaConfig, kv_k, kv_v,
-                  qpos, wflat, table, active=None):
+                  qpos, wflat, table, active=None, sel=None):
     """Shared body of the paged decode paths: scatter the chunk's K/V at
     flat physical positions ``wflat`` [B, T], then attend block-wise
     through ``table`` [B, blocks_per_row] (the rows' block tables) with a
@@ -1036,7 +1045,9 @@ def _paged_attend(params, tokens, cfg: LlamaConfig, kv_k, kv_v,
     position, traced bounds read from ``qpos`` and ``active`` [B], the rows
     whose outputs are read (:func:`tile_walk`, :func:`paged_attend_tiles`).
     Nothing as deep as the table is gathered or scored.  The numbers are
-    :func:`decode_chunk`'s up to the order of summation.
+    :func:`decode_chunk`'s up to the order of summation.  With ``sel`` [B]
+    the logits are of each row's position ``sel`` alone, [B, V], picked
+    before the final norm and the head.
 
     The pool is written IN PLACE: ``kv_k`` / ``kv_v`` ride the layer scan
     as CARRIES (flattened to ``[L * n_blocks * bs, KVH, Dh]``, a bitcast),
@@ -1075,6 +1086,8 @@ def _paged_attend(params, tokens, cfg: LlamaConfig, kv_k, kv_v,
         (x, kv_k.reshape(nl * stripe, kvh, dh),
          kv_v.reshape(nl * stripe, kvh, dh), jnp.int32(0)),
         params["layers"])
+    if sel is not None:
+        x = x[jnp.arange(b), sel]
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = (x @ params["lm_head"].astype(dt)).astype(jnp.float32)
     return logits, kf.reshape(kv_k.shape), vf.reshape(kv_v.shape)
@@ -1170,35 +1183,53 @@ def spec_verify_paged(
     return tok, accept, next_logits, pcache
 
 
+def decode_chunk_paged_rows(
+    params: dict, tokens: jax.Array, cfg: LlamaConfig,
+    pcache: PagedKVCache, slots: jax.Array, *, new_length: jax.Array,
+    sel: jax.Array | None,
+) -> tuple[jax.Array, PagedKVCache]:
+    """A chunk of prefill for several rows in one program, one read of the
+    weights for all of them: ``tokens`` [R, T] continue the slots ``slots``
+    [R] (each at most once) from their current lengths, which become
+    ``new_length`` [R] (the true frontier: for a padded final window that is
+    less than ``length + T``, exactly :func:`prefill_chunked`'s contract).
+    Returns the logits of each row's position ``sel`` [R] alone, [R, V] (of
+    every position, [R, T, V], with ``sel`` ``None``), and the cache.  Only
+    these slots' blocks are touched, so in-flight rows are untouched
+    mid-prefill.  A row whose slot is past the slots (``n_slots``) is not
+    there: it writes no key and no length."""
+    slots = jnp.asarray(slots, jnp.int32)
+    nl, n_blocks, bs = pcache.k.shape[:3]
+    per = pcache.block_table.shape[1]
+    there, _, _, qpos, table = paged.chunk_rows(pcache, slots,
+                                                tokens.shape[1])
+    wblk = jnp.take_along_axis(table, jnp.clip(qpos // bs, 0, per - 1),
+                               axis=1)
+    # a row that is not there writes past every layer's stripe: dropped
+    wflat = jnp.where(there[:, None], wblk * bs + qpos % bs,
+                      nl * n_blocks * bs)
+    logits, ks, vs = _paged_attend(
+        params, tokens, cfg, pcache.k, pcache.v, qpos, wflat, table, there,
+        None if sel is None else jnp.asarray(sel, jnp.int32))
+    length = pcache.length.at[slots].set(
+        jnp.asarray(new_length, jnp.int32), mode="drop")
+    return logits, pcache._replace(k=ks, v=vs, length=length)
+
+
 def decode_chunk_paged_row(
     params: dict, tokens: jax.Array, cfg: LlamaConfig,
     pcache: PagedKVCache, slot: jax.Array, *, new_length: jax.Array,
 ) -> tuple[jax.Array, PagedKVCache]:
-    """One row's T-token chunk against the pool: the chunked-prefill
-    admission program.  ``tokens`` [1, T] continue slot ``slot`` from its
-    current length; the row's length becomes ``new_length`` (the true
-    frontier — for a padded final prefill window that is less than
-    ``length + T``, exactly :func:`prefill_chunked`'s contract).  Only
-    this slot's blocks (and trash, for pad overflow) are touched, so
-    in-flight rows are untouched mid-prefill."""
+    """:func:`decode_chunk_paged_rows` for one row, with the logits of every
+    position: ``tokens`` [1, T] continue slot ``slot`` from its current
+    length, which becomes ``new_length``; returns logits [1, T, V]."""
     b, t = tokens.shape
     if b != 1:
         raise ValueError(f"decode_chunk_paged_row is a B=1 program, "
                          f"got batch {b}")
-    bs = pcache.block_size
-    per = pcache.block_table.shape[1]
-    slot = jnp.asarray(slot, jnp.int32)
-    row_table = pcache.block_table[slot]                  # [per]
-    pos = pcache.length[slot]
-    qpos = (pos + jnp.arange(t))[None, :]                 # [1, T]
-    wblk = row_table[jnp.clip(qpos // bs, 0, per - 1)]
-    wflat = wblk * bs + qpos % bs
-    logits, ks, vs = _paged_attend(
-        params, tokens, cfg, pcache.k, pcache.v, qpos, wflat,
-        row_table[None])
-    length = pcache.length.at[slot].set(
-        jnp.asarray(new_length, jnp.int32))
-    return logits, pcache._replace(k=ks, v=vs, length=length)
+    return decode_chunk_paged_rows(
+        params, tokens, cfg, pcache, jnp.asarray(slot, jnp.int32)[None],
+        new_length=jnp.asarray(new_length, jnp.int32)[None], sel=None)
 
 
 def prefill_chunked(

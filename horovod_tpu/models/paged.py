@@ -15,7 +15,18 @@ The engine names no model.  A model is a module with these functions, which
 * ``decode_chunk_paged(params, tokens, cfg, pcache, *, advance)`` — the tick
   (``tokens`` [B, T], lengths advance by ``advance`` [B]);
 * ``decode_chunk_paged_row(params, tokens, cfg, pcache, slot, *,
-  new_length)`` — one row's chunk of prefill;
+  new_length)`` — one row's chunk of prefill, ``tokens`` [1, T], with the
+  logits of every position;
+* optionally ``decode_chunk_paged_rows(params, tokens, cfg, pcache, slots,
+  *, new_length, sel)`` — a chunk of prefill for R rows in one program, one
+  read of the weights for all of them: ``tokens`` [R, T] continue the slots
+  ``slots`` [R] to ``new_length`` [R]; returns the cache and the logits of
+  each row's position ``sel`` [R] alone, [R, V], picked before the final
+  norm and the head (of every position, [R, T, V], with ``sel`` ``None``).
+  A row whose slot is ``n_slots`` is not there and writes nothing
+  (:func:`chunk_rows`).  Where a model has it the engine dispatches the rows that
+  prefill in a step in whole groups of one wide width and the rest a row a
+  program (``ServeEngine.chunk_widths``), and one row a program where not;
 * ``spec_verify_paged(params, cfg, pcache, last_logits, drafts, active)`` —
   the speculative verify round, which leaves the cache as after each row's
   ``1 + accepted`` tokens: for state that is per position the lengths alone
@@ -278,6 +289,20 @@ class SnapshotBudget:
                 f"held={len(self._held)} pending={len(self._pending)} "
                 f"of {self.n}; never restored (old->new)={list(self._cold)} "
                 f"restored (old->new)={list(self._hot)}"]
+
+
+def chunk_rows(pcache: Any, slots, t: int) -> tuple:
+    """What a chunk program of ``t`` tokens a row reads of its rows ``slots``
+    [R]: ``(there, at, pos, qpos, table)``.  ``there`` [R] is false for a row
+    whose slot is past the slots (``n_slots``): such a row is not there, reads
+    the last slot's row at ``at`` and must write nothing.  ``pos`` [R] the
+    rows' lengths, ``qpos`` [R, t] their tokens' positions, ``table`` [R,
+    per] their block tables."""
+    n_slots = pcache.length.shape[0]
+    at = jnp.minimum(slots, n_slots - 1)
+    pos = pcache.length[at]
+    return (slots < n_slots, at, pos, pos[:, None] + jnp.arange(t)[None, :],
+            pcache.block_table[at])
 
 
 def block_before(row, length, bs: int):

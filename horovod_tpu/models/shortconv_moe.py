@@ -415,6 +415,42 @@ def _attention(cfg: ShortConvMoEConfig, lp: dict, u, cos, sin, kf, vf, layer,
                 dt), kf, vf
 
 
+@partial(jax.jit, static_argnums=(0, 1, 2))
+def _layer_once(fn, cfg, form, *args):
+    """``fn(cfg, *args)``, traced and lowered once for all the layers of a
+    program that call it on like shapes: the layers are a Python loop, and a
+    program's set-up is mostly the time to trace and lower it (14 layers
+    unrolled: 2.3 s a program on the chip's host, PERF.md, PR 39).  XLA
+    inlines the calls, so the compiled program is the one it was.  ``fn``
+    and ``form`` (what of the module's own constants decides the traced
+    form) are part of the key, so a function or a constant replaced for a
+    test is traced anew."""
+    return fn(cfg, *args)
+
+
+def _conv_op(cfg: ShortConvMoEConfig, lp: dict, x, carry):
+    """A conv layer's first half: ``x + Op(RMSNorm_op(x))`` and ``z``."""
+    with jax.named_scope("conv.short"):
+        o, z = _short_conv(cfg, lp, rmsnorm(x, lp["op_norm"], cfg.norm_eps),
+                           carry)
+    return x + o, z
+
+
+def _ffn_dense(cfg: ShortConvMoEConfig, lp: dict, x):
+    """A dense layer's second half: ``x + SwiGLU(RMSNorm_ffn(x))``."""
+    h = rmsnorm(x, lp["ffn_norm"], cfg.norm_eps)
+    return x + _swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"], cfg.dtype)
+
+
+def _ffn_experts(cfg: ShortConvMoEConfig, lp: dict, x, valid):
+    """An expert layer's second half, and the held experts' load."""
+    b, t, d = x.shape
+    y, load = latent_moe.held_experts(
+        cfg, lp, rmsnorm(x, lp["ffn_norm"], cfg.norm_eps).reshape(b * t, d),
+        valid.reshape(b * t))
+    return x + y.reshape(b, t, d), load
+
+
 class _Ran(NamedTuple):
     """What a program's forward pass leaves for :func:`_commit`."""
 
@@ -426,13 +462,16 @@ class _Ran(NamedTuple):
 
 def _forward_paged(params, tokens, cfg: ShortConvMoEConfig,
                    pcache: ShortConvPagedCache, qpos, table, carry, valid,
-                   set_touched: bool):
+                   set_touched: bool, sel=None, there=None):
     """The shared body of the paged programs: ``tokens`` [B, T] at positions
     ``qpos`` under block tables ``table`` [B, per], the rows' convolution
     carries ``carry`` [n_conv, B, (K - 1) * d]; ``valid`` [B, T] marks the
     tokens that count (for the counters and the routing; a row with none is
     one whose output nobody reads, and attention walks it one tile).  Writes
-    keys and values; the convolution's state is the caller's to commit."""
+    keys and values, none for a row that is not ``there`` [B] (default:
+    all are); the convolution's state is the caller's to commit.  With
+    ``sel`` [B] the logits are of each row's position ``sel`` alone, [B, V],
+    picked before the final norm and the head."""
     dt = cfg.dtype
     b, t = tokens.shape
     n_attn, n_blocks, bs, kvh, hd = pcache.k.shape
@@ -440,41 +479,50 @@ def _forward_paged(params, tokens, cfg: ShortConvMoEConfig,
     wblk = jnp.take_along_axis(table, jnp.clip(qpos // bs, 0, per - 1),
                                axis=1)
     wflat = wblk * bs + qpos % bs                                # [B, T]
+    if there is not None:       # past every layer's stripe: a dropped write
+        wflat = jnp.where(there[:, None], wflat, n_attn * n_blocks * bs)
     kf = pcache.k.reshape(n_attn * n_blocks * bs, kvh, hd)
     vf = pcache.v.reshape(n_attn * n_blocks * bs, kvh, hd)
     cos, sin = llama.rope_tables(cfg, qpos)
     walk = llama.tile_walk(table, qpos, bs, jnp.any(valid, axis=1))
     x = params["embed"][tokens].astype(dt)
+    # inside a program a layer's body is traced once (`_layer_once`); a call
+    # outside any (`forward` run eagerly) computes op by op as it always did
+    once = _layer_once if isinstance(x, jax.core.Tracer) else (
+        lambda fn, cfg, form, *args: fn(cfg, *args))
     i_attn = i_conv = 0
     zs = []
     load = jnp.zeros((cfg.held_count,), jnp.int32)
     touched = batched = jnp.int32(0)
     for i, (kind, lp) in enumerate(zip(cfg.layer_kinds, params["layers"])):
-        u = rmsnorm(x, lp["op_norm"], cfg.norm_eps)
         if kind == CONV:
-            with jax.named_scope("conv.short"):
-                o, z = _short_conv(cfg, lp, u, carry[i_conv])
+            x, z = once(_conv_op, cfg, None, lp, x, carry[i_conv])
             zs.append(z)
             i_conv += 1
         else:
+            u = rmsnorm(x, lp["op_norm"], cfg.norm_eps)
             with jax.named_scope("attn.gqa"):
                 o, kf, vf = _attention(cfg, lp, u, cos, sin, kf, vf, i_attn,
                                        walk, wflat, n_blocks, bs)
+            x = x + o
             i_attn += 1
-        x = x + o
-        h = rmsnorm(x, lp["ffn_norm"], cfg.norm_eps)
         if i < cfg.first_dense:
-            x = x + _swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"], dt)
+            x = once(_ffn_dense, cfg, None, lp, x)
         else:
-            y, layer_load = latent_moe.held_experts(
-                cfg, lp, h.reshape(b * t, cfg.dim), valid.reshape(b * t))
-            x = x + y.reshape(b, t, cfg.dim)
+            x, layer_load = once(
+                _ffn_experts, cfg,
+                (latent_moe.held_experts, latent_moe.IN_PLACE_ROWS), lp, x,
+                valid)
             load = load + layer_load
             touched = touched + jnp.sum(layer_load > 0, dtype=jnp.int32)
             batched = batched + latent_moe.layers_batched(b * t, layer_load)
+    if sel is not None:
+        x = x[jnp.arange(b), sel][:, None]
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = jnp.einsum("btd,vd->btv", x, params["embed"].astype(dt),
                         preferred_element_type=jnp.float32)
+    if sel is not None:
+        logits = logits[:, 0]
     n_valid = jnp.sum(valid, dtype=jnp.int32)
     seen = jnp.sum(jnp.where(valid, qpos + 1, 0), dtype=jnp.int32)
     n_moe = cfg.n_layers - cfg.first_dense
@@ -492,7 +540,8 @@ def _commit(cfg: ShortConvMoEConfig, pcache: ShortConvPagedCache, ran: _Ran,
     rows ``slots`` [B] (which started at ``pos`` [B] under ``table``): the
     pools as written, each slot's state the ``K - 1`` values of ``z`` that
     end at its ``n``-th token (its carry where ``n`` is 0), a snapshot in
-    every block whose last position is among the ``n``, and the lengths."""
+    every block whose last position is among the ``n``, and the lengths.  A
+    row whose slot is past the slots is not there, and leaves nothing."""
     n_conv, b, width, d = ran.zs.shape      # width = K - 1 + T
     bs = pcache.block_size
     n_blocks = pcache.snap.shape[1]
@@ -503,7 +552,8 @@ def _commit(cfg: ShortConvMoEConfig, pcache: ShortConvPagedCache, ran: _Ran,
     # z of tokens j - (K - 1) + 1 .. j stands at zs[j + 1 .. j + K - 1]
     idx = n[:, None] + jnp.arange(keep)[None, :]                 # [B, K-1]
     conv = pcache.conv.at[:, slots].set(
-        ran.zs[:, rows[:, None], idx].reshape(n_conv, b, keep * d))
+        ran.zs[:, rows[:, None], idx].reshape(n_conv, b, keep * d),
+        mode="drop")
     j, reached, dest = paged.block_ends(pos, n, t, table, bs, n_blocks)
     ends = j.shape[1]
     dest = dest.reshape(b * ends)
@@ -516,7 +566,7 @@ def _commit(cfg: ShortConvMoEConfig, pcache: ShortConvPagedCache, ran: _Ran,
         jnp.sum(reached, dtype=jnp.int32))
     return pcache._replace(
         k=ran.k, v=ran.v, conv=conv, snap=snap,
-        length=pcache.length.at[slots].set(pos + n),
+        length=pcache.length.at[slots].set(pos + n, mode="drop"),
         stats=_add_stats(ran.stats, add, None))
 
 
@@ -549,28 +599,47 @@ def decode_chunk_paged(
                            pcache.block_table, jnp.arange(b))
 
 
+def decode_chunk_paged_rows(
+    params: dict, tokens: jax.Array, cfg: ShortConvMoEConfig,
+    pcache: ShortConvPagedCache, slots: jax.Array, *, new_length: jax.Array,
+    sel: jax.Array | None,
+) -> tuple[jax.Array, ShortConvPagedCache]:
+    """A chunk of prefill for several rows in one program, one read of the
+    weights for all of them: ``tokens`` [R, T] continue the slots ``slots``
+    [R] (each at most once) from their lengths, which become ``new_length``
+    [R]; positions past it are padding and count for nothing, the
+    convolution's state included.  Returns the logits of each row's position
+    ``sel`` [R] alone, [R, V] (of every position, [R, T, V], with ``sel``
+    ``None``), and the cache.  A row whose slot is past the slots
+    (``n_slots``) is not there: it writes no key, no state, no snapshot and
+    no length."""
+    slots = jnp.asarray(slots, jnp.int32)
+    new_length = jnp.asarray(new_length, jnp.int32)
+    there, at, pos, qpos, table = paged.chunk_rows(pcache, slots,
+                                                   tokens.shape[1])
+    valid = (qpos < new_length[:, None]) & there[:, None]
+    logits, ran = _forward_paged(
+        params, tokens, cfg, pcache, qpos, table, pcache.conv[:, at], valid,
+        False, None if sel is None else jnp.asarray(sel, jnp.int32), there)
+    return logits, _commit(cfg, pcache, ran, pos,
+                           jnp.where(there, new_length - pos, 0), table,
+                           slots)
+
+
 def decode_chunk_paged_row(
     params: dict, tokens: jax.Array, cfg: ShortConvMoEConfig,
     pcache: ShortConvPagedCache, slot: jax.Array, *, new_length: jax.Array,
 ) -> tuple[jax.Array, ShortConvPagedCache]:
-    """One row's T-token chunk (chunked prefill): ``tokens`` [1, T] continue
-    slot ``slot`` from its length, which becomes ``new_length``; positions
-    past it are padding and count for nothing, the convolution's state
-    included."""
+    """:func:`decode_chunk_paged_rows` for one row, with the logits of every
+    position: ``tokens`` [1, T] continue slot ``slot`` from its length, which
+    becomes ``new_length``; returns logits [1, T, V]."""
     b, t = tokens.shape
     if b != 1:
         raise ValueError(f"decode_chunk_paged_row is a B=1 program, "
                          f"got batch {b}")
-    slot = jnp.asarray(slot, jnp.int32)
-    new_length = jnp.asarray(new_length, jnp.int32)
-    pos = pcache.length[slot][None]
-    qpos = pos[:, None] + jnp.arange(t)[None, :]
-    table = pcache.block_table[slot][None]
-    logits, ran = _forward_paged(
-        params, tokens, cfg, pcache, qpos, table,
-        pcache.conv[:, slot][:, None], qpos < new_length, False)
-    return logits, _commit(cfg, pcache, ran, pos, new_length[None] - pos,
-                           table, slot[None])
+    return decode_chunk_paged_rows(
+        params, tokens, cfg, pcache, jnp.asarray(slot, jnp.int32)[None],
+        new_length=jnp.asarray(new_length, jnp.int32)[None], sel=None)
 
 
 def spec_verify_paged(params, cfg, pcache, last_logits, drafts, active):

@@ -438,13 +438,16 @@ class _Ran(NamedTuple):
 
 def _forward_paged(params, tokens, cfg: WindowMoEConfig,
                    pcache: WindowPagedCache, qpos, table, ring, valid,
-                   set_touched: bool):
+                   set_touched: bool, sel=None, there=None):
     """The shared body of the paged programs: ``tokens`` [B, T] at positions
     ``qpos`` under block tables ``table`` [B, per], the rows' rings ``ring``
     [2, n_sliding, B, window, KVH, Dh]; ``valid`` [B, T] marks the tokens
     that count (for the counters and the routing; a row with none is one
     whose output nobody reads, and the full layers walk it one tile).  Writes
-    the full layers' keys and values; the rings are the caller's to commit."""
+    the full layers' keys and values, none for a row that is not ``there``
+    [B] (default: all are); the rings are the caller's to commit.  With
+    ``sel`` [B] the logits are of each row's position ``sel`` alone, [B, V],
+    picked before the final norm and the head."""
     dt = cfg.dtype
     b, t = tokens.shape
     n_full, n_blocks, bs, kvh, hd = pcache.k.shape
@@ -452,6 +455,8 @@ def _forward_paged(params, tokens, cfg: WindowMoEConfig,
     wblk = jnp.take_along_axis(table, jnp.clip(qpos // bs, 0, per - 1),
                                axis=1)
     wflat = wblk * bs + qpos % bs                                # [B, T]
+    if there is not None:       # past every layer's stripe: a dropped write
+        wflat = jnp.where(there[:, None], wflat, n_full * n_blocks * bs)
     kf = pcache.k.reshape(n_full * n_blocks * bs, kvh, hd)
     vf = pcache.v.reshape(n_full * n_blocks * bs, kvh, hd)
     cos, sin = llama.rope_tables(cfg, qpos)
@@ -504,6 +509,8 @@ def _forward_paged(params, tokens, cfg: WindowMoEConfig,
             touched = touched + jnp.sum(layer_load > 0, dtype=jnp.int32)
             batched = batched + latent_moe.layers_batched(b * t, layer_load)
         x = x + rmsnorm(m, lp["ffn_norm"], cfg.norm_eps)
+    if sel is not None:
+        x = x[jnp.arange(b), sel]
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = _dot(x, params["lm_head"], dt).astype(jnp.float32)
     n_valid = jnp.sum(valid, dtype=jnp.int32)
@@ -564,7 +571,8 @@ def _commit(cfg: WindowMoEConfig, pcache: WindowPagedCache, ran: _Ran,
     rows ``slots`` [B] (which started at ``pos`` [B] under ``table``): the
     pools as written, a snapshot in every block whose last position is among
     the ``n``, each slot's ring with the last ``window`` of its ``n`` tokens
-    written over the positions they push out, and the lengths.  ``live``: the
+    written over the positions they push out, and the lengths.  A row whose
+    slot is past the slots is not there, and leaves nothing.  ``live``: the
     program is over every slot, and sets the gauges of what they hold."""
     b, t, w = ran.own.shape[2], ran.own.shape[3], cfg.window
     bs = pcache.block_size
@@ -574,7 +582,7 @@ def _commit(cfg: WindowMoEConfig, pcache: WindowPagedCache, ran: _Ran,
     kept = (i < n[:, None]) & (i >= n[:, None] - w)
     at = jnp.where(kept, (pos[:, None] + i) % w, w)        # past the ring: drop
     ring = ring.at[:, :, slots[:, None], at].set(ran.own, mode="drop")
-    length = pcache.length.at[slots].set(pos + n)
+    length = pcache.length.at[slots].set(pos + n, mode="drop")
     add = jnp.zeros((pcache.stats.shape[1],), jnp.int32).at[
         -len(_TAIL)].set(n_snaps)
     stats = _add_stats(ran.stats, add, None)
@@ -619,27 +627,49 @@ def decode_chunk_paged(
                            pcache.block_table, jnp.arange(b), True)
 
 
+def decode_chunk_paged_rows(
+    params: dict, tokens: jax.Array, cfg: WindowMoEConfig,
+    pcache: WindowPagedCache, slots: jax.Array, *, new_length: jax.Array,
+    sel: jax.Array | None,
+) -> tuple[jax.Array, WindowPagedCache]:
+    """A chunk of prefill for several rows in one program, one read of the
+    weights for all of them: ``tokens`` [R, T] continue the slots ``slots``
+    [R] (each at most once) from their lengths, which become ``new_length``
+    [R]; positions past it are padding and count for nothing, the ring
+    included.  Returns the logits of each row's position ``sel`` [R] alone,
+    [R, V] (of every position, [R, T, V], with ``sel`` ``None``), and the
+    cache.  A row whose slot is past the slots (``n_slots``) is not there: it
+    writes no key, no ring, no snapshot and no length."""
+    slots = jnp.asarray(slots, jnp.int32)
+    new_length = jnp.asarray(new_length, jnp.int32)
+    r, t = tokens.shape
+    there, at, pos, qpos, table = paged.chunk_rows(pcache, slots, t)
+    valid = (qpos < new_length[:, None]) & there[:, None]
+    # a slice a row and not one gather by `at`: a ring is megabytes, and a
+    # gather by an index array may be served from a copy of every slot's
+    ring = jnp.stack([pcache.ring[:, :, at[i]] for i in range(r)], axis=2)
+    logits, ran = _forward_paged(
+        params, tokens, cfg, pcache, qpos, table, ring, valid, False,
+        None if sel is None else jnp.asarray(sel, jnp.int32), there)
+    return logits, _commit(cfg, pcache, ran, pos,
+                           jnp.where(there, new_length - pos, 0), table,
+                           slots, False)
+
+
 def decode_chunk_paged_row(
     params: dict, tokens: jax.Array, cfg: WindowMoEConfig,
     pcache: WindowPagedCache, slot: jax.Array, *, new_length: jax.Array,
 ) -> tuple[jax.Array, WindowPagedCache]:
-    """One row's T-token chunk (chunked prefill): ``tokens`` [1, T] continue
-    slot ``slot`` from its length, which becomes ``new_length``; positions
-    past it are padding and count for nothing, the ring included."""
+    """:func:`decode_chunk_paged_rows` for one row, with the logits of every
+    position: ``tokens`` [1, T] continue slot ``slot`` from its length, which
+    becomes ``new_length``; returns logits [1, T, V]."""
     b, t = tokens.shape
     if b != 1:
         raise ValueError(f"decode_chunk_paged_row is a B=1 program, "
                          f"got batch {b}")
-    slot = jnp.asarray(slot, jnp.int32)
-    new_length = jnp.asarray(new_length, jnp.int32)
-    pos = pcache.length[slot][None]
-    qpos = pos[:, None] + jnp.arange(t)[None, :]
-    table = pcache.block_table[slot][None]
-    logits, ran = _forward_paged(
-        params, tokens, cfg, pcache, qpos, table,
-        pcache.ring[:, :, slot][:, :, None], qpos < new_length, False)
-    return logits, _commit(cfg, pcache, ran, pos, new_length[None] - pos,
-                           table, slot[None], False)
+    return decode_chunk_paged_rows(
+        params, tokens, cfg, pcache, jnp.asarray(slot, jnp.int32)[None],
+        new_length=jnp.asarray(new_length, jnp.int32)[None], sel=None)
 
 
 def spec_verify_paged(params, cfg, pcache, last_logits, drafts, active):

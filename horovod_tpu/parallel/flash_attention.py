@@ -26,14 +26,31 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import importlib
 import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from horovod_tpu.parallel.attention import blockwise_attention
+
+
+class _OnFirstUse:
+    """A module imported when an attribute of it is first asked for.  Pallas
+    takes over a second to import (1.3 s of the 4.8 s that importing the
+    serving engine took: PERF.md, PR 39) and every process that imports
+    ``horovod_tpu.parallel`` paid it, the ones that never run a kernel
+    too."""
+
+    def __init__(self, name: str):
+        self._name = name
+
+    def __getattr__(self, attr: str):
+        return getattr(importlib.import_module(self._name), attr)
+
+
+pl = _OnFirstUse("jax.experimental.pallas")
+pltpu = _OnFirstUse("jax.experimental.pallas.tpu")
 
 NEG_INF = -1e30
 

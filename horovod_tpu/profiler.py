@@ -103,9 +103,10 @@ TILING = ("expire", "admit", "draft", "decode_dispatch", "device_sync",
 assert set(TILING) == set(PHASES + SPEC_PHASES)
 
 #: What ``_step`` counted (``counts()``): chunk programs dispatched, the
+#: rows they carried (a program prefills a window of each of its rows), the
 #: rows of the dispatched tick (0 with none), tokens handed to results
 #: and rows whose first token this was.
-COUNTS = ("chunks", "tick_rows", "tokens", "first_tokens")
+COUNTS = ("chunks", "chunk_rows", "tick_rows", "tokens", "first_tokens")
 
 #: Counters of the engine's registry that a row carries as they stood at
 #: the step's end: the model's own, reckoned from the programs each step

@@ -174,6 +174,19 @@ from horovod_tpu.serving import (
 
 FREE, PREFILL, DECODE = "free", "prefill", "decode"
 
+# Tokens a prefill program carries at most, rows x chunk: the rows that
+# prefill in a step share one read of the weights, `chunk_widths[0]` of them a
+# program.  A read of the weights is paid back at the chip's ridge, 197
+# TFLOP/s over 819 GB/s = 240 FLOP a byte on a v5e: 240 tokens over dense
+# bfloat16 weights, 8 times that where a token reads 4 of 32 experts.
+# Measured on one v5e (PERF.md, PR 39): lfm2's chunk of 256 tokens takes
+# 15.96 ms as a program of one row (9.3 GB read for 0.5 TFLOP: 71 % of its
+# byte roofline, 7.5 % of the FLOP peak) and about 50 ms as one of eight
+# (6.6 ms a row, 40 % of the peak: past the ridge, so a wider program would
+# gain nothing); Mistral's dense chunk of 256 is at the ridge as one row
+# (22.7 ms) and four rows a program gained 1 %.
+_CHUNK_TOKENS = 2048
+
 
 @dataclasses.dataclass
 class SchedulerEvent:
@@ -679,16 +692,29 @@ class ServeEngine:
                 params, tok[:, None], cfg, pcache, advance=active)
             return logits[:, 0], pcache
 
+        rows_entry = getattr(model, "decode_chunk_paged_rows", None)
+
         @partial(jax.jit, donate_argnums=(1, 2), **_chunk_sh)
-        def _chunk(params, pcache, last_logits, toks, slot, new_len, sel):
-            # one chunked-prefill window for one slot: [1, chunk] tokens
-            # continue the row from its current length; `sel` picks the
-            # window position whose logits seed decoding (only the final
-            # window's pick survives — later windows overwrite).
-            logits, pcache = model.decode_chunk_paged_row(
-                params, toks, cfg, pcache, slot, new_length=new_len)
-            last_logits = last_logits.at[slot].set(logits[0, sel])
-            return pcache, last_logits
+        def _chunk(params, pcache, last_logits, toks, slots, new_len, sel):
+            # one chunked-prefill window for each of the program's rows:
+            # [R, chunk] tokens continue the rows `slots` [R] from their
+            # current lengths to `new_len` [R]; `sel` [R] picks the window
+            # position whose logits seed decoding (only the final window's
+            # pick survives: later windows overwrite).  One signature a
+            # width R (`chunk_widths`: the wide program and the one-row
+            # one); a model without the rows entry keeps one row a
+            # program.  A row whose slot is `n_slots` is not there and
+            # writes nothing, its logits included.
+            if rows_entry is not None:
+                logits, pcache = rows_entry(
+                    params, toks, cfg, pcache, slots, new_length=new_len,
+                    sel=sel)
+            else:
+                logits, pcache = model.decode_chunk_paged_row(
+                    params, toks, cfg, pcache, slots[0],
+                    new_length=new_len[0])
+                logits = logits[:, sel[0]]
+            return pcache, last_logits.at[slots].set(logits, mode="drop")
 
         @partial(jax.jit, donate_argnums=(0,), **_row_sh)
         def _set_row(pcache, slot, row, length):
@@ -734,6 +760,14 @@ class ServeEngine:
         self._tick = _tick
         self._chunk = _chunk
         self._set_row = _set_row
+        # Rows and programs of prefill dispatched (serve.chunk.rows over
+        # serve.chunk.programs: how many rows shared a read of the weights)
+        self._c_chunk_rows = self.metrics.counter("serve.chunk.rows")
+        self._c_chunk_programs = self.metrics.counter("serve.chunk.programs")
+        #: The rows a chunk program carries, widest first: every one is
+        #: compiled here, before the constructor returns.
+        self.chunk_widths = self._compile_chunk_widths(
+            rows_entry is not None)
         # Device cost-model capture happens BEFORE the sentry baseline
         # on purpose: AOT lowering never mints jit call-cache entries,
         # and taking the baseline after it proves that property every
@@ -750,13 +784,18 @@ class ServeEngine:
     def compile_cache_sizes(self) -> dict[str, int]:
         """Per-program jit cache entry counts — the no-retrace pin:
         admission/recycling/preemption must keep every count constant.
+        ``chunk`` is 1 for "one signature a width": the chunk program has
+        as many as ``chunk_widths`` (compiled by the constructor where the
+        model offers the rows entry, else by the first request), and what
+        it holds beyond one a width is a retrace of some width.
         A spec engine adds the ``spec_tick`` key (its always-wide verify
         program, which replaces ``sample`` and ``tick`` so that those
         counts stay 0)."""
         sizes = {
             "sample": self._sample._cache_size(),
             "tick": self._tick._cache_size(),
-            "chunk": self._chunk._cache_size(),
+            "chunk": max(self._chunk._cache_size()
+                         - (len(self.chunk_widths) - 1), 0),
             "set_row": self._set_row._cache_size(),
         }
         if self._spec_tick is not None:
@@ -771,7 +810,8 @@ class ServeEngine:
         same before and after).  ``.compile().memory_analysis()`` of a
         lowered ``tick`` / ``chunk`` is where the in-place pool shows: their
         scratch holds no second pool (tests/test_paged_inplace.py,
-        chip_smoke.py)."""
+        chip_smoke.py).  ``chunk`` is the one-row program; a wider width's
+        avals are ``_chunk_arg_avals(rows)`` in place of its last four."""
         aval = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)  # noqa: E731
         p_av = jax.tree.map(aval, self.params)
         c_av = jax.tree.map(aval, self.pcache)
@@ -780,13 +820,12 @@ class ServeEngine:
             aval, self.model.paged_counters(self.pcache))
         i32 = jax.ShapeDtypeStruct((), jnp.int32)
         active_av = jax.ShapeDtypeStruct((self.n_slots,), jnp.int32)
-        toks_av = jax.ShapeDtypeStruct((1, self.chunk), jnp.int32)
         row_av = jax.ShapeDtypeStruct((self.blocks_per_slot,), jnp.int32)
         progs = {
             "sample": (self._sample, ll_av, counters_av),
             "tick": (self._tick, p_av, c_av, ll_av, active_av),
-            "chunk": (self._chunk, p_av, c_av, ll_av, toks_av,
-                      i32, i32, i32),
+            "chunk": (self._chunk, p_av, c_av, ll_av,
+                      *self._chunk_arg_avals(1)),
             "set_row": ((self._set_row, c_av, i32, row_av, i32)
                         + ((row_av,) if self.snaps is not None else ())),
         }
@@ -796,6 +835,78 @@ class ServeEngine:
             progs["spec_tick"] = (self._spec_tick, p_av, c_av, ll_av,
                                   drafts_av, active_av)
         return progs
+
+    def _chunk_arg_avals(self, rows: int) -> tuple:
+        """The avals of what a chunk program of ``rows`` rows is called
+        with: tokens, slots, new lengths, picked positions."""
+        row = jax.ShapeDtypeStruct((rows,), jnp.int32)
+        return (jax.ShapeDtypeStruct((rows, self.chunk), jnp.int32),
+                row, row, row)
+
+    def _device_room(self) -> int | None:
+        """Bytes the fullest of the engine's devices has left beside what
+        is allocated on it now, ``None`` where the device reports no limit
+        (a CPU)."""
+        devices = (self.mesh.devices.flat if self.mesh is not None
+                   else jax.devices()[:1])
+        room = None
+        for d in devices:
+            stats = d.memory_stats() or {}
+            if "bytes_limit" in stats:
+                left = stats["bytes_limit"] - stats.get("bytes_in_use", 0)
+                room = left if room is None else min(room, left)
+        return room
+
+    def _scratch_bytes(self, program: str, *chunk_rows: int) -> int:
+        """What one run of a pinned program (the chunk program at
+        ``chunk_rows`` rows) allocates beside its donated arguments: its
+        compiled scratch and the results that alias none of them.  The
+        compile is the jit call's own (jax keeps the lowering), so nothing
+        is traced or compiled twice."""
+        fn, *avals = self.pinned_programs()[program]
+        if chunk_rows:
+            avals[-4:] = self._chunk_arg_avals(*chunk_rows)
+        m = fn.lower(*avals).compile().memory_analysis()
+        return (m.temp_size_in_bytes + m.output_size_in_bytes
+                - m.alias_size_in_bytes)
+
+    def _compile_chunk_widths(self, rows_entry: bool) -> tuple:
+        """The widths of the chunk program this engine dispatches, widest
+        first, derived from what it can see.  A model without the rows
+        entry keeps one row a program, compiled by its first use as every
+        program of one signature is.  Where the model offers the entry
+        there are two: the one-row program, and a wide one of as many rows
+        as the slots and ``_CHUNK_TOKENS`` a program allow (a width costs
+        seconds of set-up to trace and load, every engine, so there is one
+        wide width and not a ladder of them: PERF.md, PR 39).  On a device
+        that reports its memory the wide one is halved until its compiled
+        scratch fits beside what the engine holds and a tick in flight,
+        and not tried at all where not even the one-row program's scratch
+        fits there twice.  Each width kept is compiled here, by a run over
+        rows that are not there (it writes nothing), so that none compiles
+        on first use mid-serve; the gauge ``serve.chunk.max_rows`` says
+        how wide the wide one is."""
+        self.metrics.gauge("serve.chunk.max_rows").set(1)
+        if not rows_entry:
+            return (1,)
+        wide = min(self.n_slots, _CHUNK_TOKENS // self.chunk)
+        room = self._device_room() if wide > 1 else None
+        if room is not None:
+            room -= self._scratch_bytes("spec_tick" if self.spec else "tick")
+            if 2 * self._scratch_bytes("chunk", 1) > room:
+                wide = 1
+            while wide > 1 and self._scratch_bytes("chunk", wide) > room:
+                wide //= 2
+        widths = (wide, 1) if wide > 1 else (1,)
+        for rows in widths:
+            absent = jnp.full((rows,), self.n_slots, jnp.int32)
+            zeros = jnp.zeros((rows,), jnp.int32)
+            self.pcache, self.last_logits = self._chunk(
+                self.params, self.pcache, self.last_logits,
+                jnp.zeros((rows, self.chunk), jnp.int32), absent, zeros,
+                zeros)
+        self.metrics.gauge("serve.chunk.max_rows").set(wide)
+        return widths
 
     def _device_capture_programs(
             self, dev: "device_telemetry_mod.DeviceTelemetry") -> None:
@@ -1724,6 +1835,64 @@ class ServeEngine:
                 f"cached={self.pool.cached_count()} "
                 f"referenced={len(self.pool._ref)} != {total}")
 
+    def _dispatch_chunk(self, group: list[int]) -> Dispatched | None:
+        """One chunk program over the next prefill window of each slot of
+        ``group`` (as many as one of ``chunk_widths``), and the rows'
+        bookkeeping behind it: a row whose last window this is joins the
+        step's tick.  An exception out of the program is charged to every
+        row of it, as the tick charges its decoding rows; returns the
+        program as dispatched, ``None`` where it was not."""
+        rows = [self._slots[j] for j in group]
+        n = len(rows)
+        program = Dispatched(
+            self.chunk, tuple(self._row_length(s) for s in rows), (1,) * n)
+        toks = np.empty((n, self.chunk), np.int32)
+        new_len = np.empty((n,), np.int32)
+        sel = np.zeros((n,), np.int32)
+        for i, s in enumerate(rows):
+            w = s.w_done
+            toks[i] = s.padded[0, w * self.chunk:(w + 1) * self.chunk]
+            # windows cover prompt[base:] — a prefix-cache hit rewound
+            # nothing: the row's length started at base, so positions
+            # [0, base) are the shared blocks' KV, never rewritten
+            if w == s.n_win - 1:            # the final window
+                new_len[i] = s.true_len
+                sel[i] = s.true_len - 1 - s.base - w * self.chunk
+            else:
+                new_len[i] = s.base + (w + 1) * self.chunk
+        traces = [self.traces.get(s.request_id) for s in rows]
+        traced = any(tr is not None and tr.trace_id is not None
+                     for tr in traces)
+        t_chunk = time.monotonic() if traced else 0.0
+        try:
+            self.pcache, self.last_logits = self._chunk(
+                self.params, self.pcache, self.last_logits,
+                jnp.asarray(toks), jnp.asarray(np.asarray(group, np.int32)),
+                jnp.asarray(new_len), jnp.asarray(sel))
+        except Exception as exc:
+            for j in group:
+                self._slot_fault(j, exc)
+            return None
+        self._c_chunk_programs.inc()
+        self._c_chunk_rows.inc(n)
+        t_done = time.monotonic() if traced else 0.0
+        for i, (s, tr) in enumerate(zip(rows, traces)):
+            if self.device is not None:
+                # the cost model's chunk is the one-row program: a program
+                # of n rows counts as n of it; per row the token window
+                # plus three int32 (slot / new_len / sel)
+                self.device.dispatch("chunk",
+                                     h2d_bytes=toks[i].nbytes + 12)
+            s.w_done += 1
+            self._commit_snapshots(s, int(new_len[i]))
+            if tr is not None:
+                if tr.trace_id is not None:
+                    self._emit_chunk_span(tr, t_chunk, t_done)
+                tr.prefill_chunks += 1
+            if s.w_done == s.n_win:
+                s.state = DECODE          # joins this step's tick
+        return program
+
     def step(self) -> dict[int, RequestResult]:
         """One engine step: expire deadlines, admit (preempting for a
         starved head if enabled), run one prefill window per admitting
@@ -1791,7 +1960,11 @@ class ServeEngine:
         # every program this step dispatches, as the slots know it: what
         # the model's own counters are reckoned from
         programs: list[Dispatched] = []
+        chunk_rows = 0
         with prof.sub("admit.prefill_dispatch"):
+            # the rows that prefill this step; back-off and the fault site
+            # are a request's own, and leave it out before any dispatch
+            ready = []
             for slot, s in enumerate(self._slots):
                 if s.state != PREFILL:
                     continue
@@ -1799,46 +1972,25 @@ class ServeEngine:
                     s.wait_steps -= 1
                     progress += 1
                     continue
-                w = s.w_done
-                final = w == s.n_win - 1
-                toks = s.padded[:, w * self.chunk:(w + 1) * self.chunk]
-                # windows cover prompt[base:] — a prefix-cache hit rewound
-                # nothing: the row's length started at base, so positions
-                # [0, base) are the shared blocks' KV, never rewritten
-                new_len = (s.true_len if final
-                           else s.base + (w + 1) * self.chunk)
-                sel = (s.true_len - 1 - s.base - w * self.chunk
-                       if final else 0)
-                tr = self.traces.get(s.request_id)
-                traced = tr is not None and tr.trace_id is not None
-                t_chunk = time.monotonic() if traced else 0.0
                 try:
                     self.faults.check("serve.prefill", key=s.request_id)
-                    programs.append(Dispatched(
-                        self.chunk, (self._row_length(s),), (1,)))
-                    self.pcache, self.last_logits = self._chunk(
-                        self.params, self.pcache, self.last_logits,
-                        jnp.asarray(toks), jnp.asarray(slot, jnp.int32),
-                        jnp.asarray(new_len, jnp.int32),
-                        jnp.asarray(sel, jnp.int32))
                 except Exception as exc:
                     self._slot_fault(slot, exc)
                     progress += 1
                     continue
-                if self.device is not None:
-                    # chunk args materialized per call: the token window
-                    # plus three int32 scalars (slot / new_len / sel).
-                    self.device.dispatch("chunk",
-                                         h2d_bytes=toks.nbytes + 12)
-                s.w_done += 1
-                progress += 1
-                self._commit_snapshots(s, new_len)
-                if tr is not None:
-                    if traced:
-                        self._emit_chunk_span(tr, t_chunk, time.monotonic())
-                    tr.prefill_chunks += 1
-                if final:
-                    s.state = DECODE      # joins this step's tick
+                ready.append(slot)
+            # one window each: whole groups of the wide width, the rest
+            # a row a program; no served program carries a row that is
+            # not there
+            at = 0
+            for width in self.chunk_widths:
+                while len(ready) - at >= width:
+                    program = self._dispatch_chunk(ready[at:at + width])
+                    if program is not None:
+                        programs.append(program)
+                        chunk_rows += width
+                    at += width
+                    progress += width
         n_chunks = len(programs)
         tick_rows = n_tokens = n_first = 0
         decoding = [i for i, s in enumerate(self._slots)
@@ -1963,7 +2115,7 @@ class ServeEngine:
                         d2h = tok_host.nbytes + (
                             accept_host.nbytes
                             if accept_host is not None else 0)
-                        awaited = ["chunk"] * n_chunks
+                        awaited = ["chunk"] * chunk_rows
                         if spec:
                             awaited.append("spec_tick")
                         elif in_flight:
@@ -2090,7 +2242,8 @@ class ServeEngine:
         self.metrics.gauge("serve.prefilling").set(
             sum(1 for s in self._slots if s.state == PREFILL))
         self.metrics.gauge("serve.free_blocks").set(len(self._free_blocks))
-        prof.counts(chunks=n_chunks, tick_rows=tick_rows, tokens=n_tokens,
+        prof.counts(chunks=n_chunks, chunk_rows=chunk_rows,
+                    tick_rows=tick_rows, tokens=n_tokens,
                     first_tokens=n_first)
         self.metrics.gauge("serve.cached_blocks").set(
             self.pool.cached_count())

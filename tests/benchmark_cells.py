@@ -4,7 +4,7 @@ put in the place of one accepted test each.
 ``benchmark/tests/test_lfm2.py`` and ``test_kexaone.py`` pin their cell to
 eleven per-layer metrics, all with the family's suffix.  Since PR 35 every
 serving cell also reports the metrics read from the program's step log
-(``.batch``), and a file the benchmark already had is not that kind of PR's to
+(``.batch``; PR 39 added ``rows_per_chunk.batch`` to them), and a file the benchmark already had is not that kind of PR's to
 edit: under tier-1 the shims collect the same test with the step log's metrics
 counted apart.  Run directly (``python -m pytest benchmark/tests``) the two
 accepted cases fail on ``len(want) == 11`` until a ``benchmark`` issue edits
@@ -18,7 +18,7 @@ import rehearse
 
 STEP_LOG = {"step_host_ms.batch", "step_sync_wait_ms.batch",
             "between_steps_ms.batch", "chunk_dispatch_ms.batch",
-            "attn_walk_over_live.batch"}
+            "attn_walk_over_live.batch", "rows_per_chunk.batch"}
 
 
 def traced_cell(copy: str, cell: str, suffix: str, reported: set) -> dict:

@@ -12,9 +12,12 @@ import hashlib
 import json
 import os
 import sys
+import types
 
 sys.path.append(os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark", "tests"))    # `import rehearse`
+
+import pytest  # noqa: E402
 
 from benchmark import lib  # noqa: E402
 from benchmark.tests import test_step_log as accepted  # noqa: E402
@@ -37,6 +40,53 @@ def test_every_new_entry_has_its_file_and_accepted_cells(monkeypatch):  # noqa: 
     cells = {w["name"] for w in spec["workloads"]}
     for m in spec["per_layer"][ACCEPTED_ENTRIES:]:
         assert lib.has_module("layer_metrics", m["name"]), m["name"]
-        assert set(m["workloads"]) <= cells and len(m["workloads"]) == 1
+        # a cell's own metrics, and PR 39's one more reader of the step rows
+        # in the cells of `chunk_dispatch_ms.batch`
+        assert set(m["workloads"]) <= cells and (
+            len(m["workloads"]) == 1
+            or (m["name"], m["workloads"]) == (
+                "rows_per_chunk.batch", accepted.BATCH_CELLS))
         assert set(m["workloads"]) <= set(lib.metric_cells(
             lib.find(spec["end_to_end"], m["moves"], "metric"), spec))
+
+
+def _rows_per_chunk(rec):
+    return lib.load_module("layer_metrics", "rows_per_chunk.batch").read(rec)
+
+
+def test_rows_per_chunk_over_hand_made_rows(program):  # noqa: F811
+    """PR 39's reader: the window's ``chunk_rows`` over its ``chunks``; the
+    warm-up's program of eight rows, before the window, is left out."""
+    rows = accepted._served()
+    rows[0]["chunks"], rows[0]["chunk_rows"] = 1, 8
+    for r, carried in zip(rows[1:], (5, 0, 0, 0, 1, 1)):    # 2 + 1 + 1 programs
+        r["chunk_rows"] = carried
+    program(accepted._log(rows))
+    assert _rows_per_chunk(accepted.REC) == 7 / 4
+
+
+@pytest.mark.parametrize("case", ["no_column", "no_chunk", "no_step_logs"])
+def test_rows_per_chunk_is_none_where_there_is_nothing_to_read(
+        program, monkeypatch, case):  # noqa: F811
+    """A program whose step rows have no ``chunk_rows`` column (the parent of
+    PR 39), a window that dispatched no chunk, a program without step logs:
+    ``None``, never a number and never an exception."""
+    if case == "no_step_logs":
+        monkeypatch.setattr(accepted.step_log_stats, "_profiler",
+                            lambda: None)
+    elif case == "no_chunk":
+        rows = [dict(r, chunks=0) for r in accepted._served()]
+        program(accepted._log(rows))
+    else:
+        log = accepted._log(accepted._served())
+        fields = tuple(f for f in accepted.profiler.ROW_FIELDS
+                       if f != "chunk_rows")
+        keep = [accepted.profiler.ROW_FIELDS.index(f) for f in fields]
+        old = types.SimpleNamespace(rows=lambda: log.rows()[:, keep],
+                                    dropped=0)
+        monkeypatch.setattr(
+            accepted.step_log_stats, "_profiler",
+            lambda: types.SimpleNamespace(
+                ROW_FIELDS=fields, TILING=accepted.profiler.TILING,
+                step_logs=lambda: [old]))
+    assert _rows_per_chunk(accepted.REC) is None

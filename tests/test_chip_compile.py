@@ -80,12 +80,23 @@ def shortconv_compiled(one_chip, no_compile_cache):
                                                 slot, new_length=new_len)
         return pcache, last_logits.at[slot].set(out[0, sel])
 
+    @partial(jax.jit, donate_argnums=(1, 2))
+    def chunk_rows(params, pcache, last_logits, toks, slots, new_len, sel):
+        out, pcache = sm.decode_chunk_paged_rows(
+            params, toks, cfg, pcache, slots, new_length=new_len, sel=sel)
+        return pcache, last_logits.at[slots].set(out, mode="drop")
+
+    rows = jax.ShapeDtypeStruct((WIDEST,), jnp.int32, sharding=one_chip)
     lowered = {
         "tick": lambda: tick.lower(params, cache, logits, jax.ShapeDtypeStruct(
             (n_slots,), jnp.int32, sharding=one_chip)),
         "chunk": lambda: chunk.lower(
             params, cache, logits, jax.ShapeDtypeStruct(
-                (1, 256), jnp.int32, sharding=one_chip), i32, i32, i32)}
+                (1, 256), jnp.int32, sharding=one_chip), i32, i32, i32),
+        "chunk_rows": lambda: chunk_rows.lower(
+            params, cache, logits, jax.ShapeDtypeStruct(
+                (WIDEST, 256), jnp.int32, sharding=one_chip), rows, rows,
+            rows)}
     done = {}
 
     def compiled(program):
@@ -94,6 +105,33 @@ def shortconv_compiled(one_chip, no_compile_cache):
         return done[program]
 
     return compiled, cache
+
+
+#: the widest chunk program ``lfm2_batchgen``'s engine holds: 2,048 tokens a
+#: program (``serving_scheduler._CHUNK_TOKENS``) over its chunk of 256
+WIDEST = 8
+
+
+def test_the_widest_chunk_holds_no_second_pool_and_one_position_s_logits(
+        shortconv_compiled):
+    """The chunk program of eight rows at the cell's size compiles for the
+    chip; its scratch is under half a pool, as the one-row program's, and
+    under the 537 MB that the logits of every position would be: the head is
+    asked one position a row, and nothing ``[R, T, V]`` stands in the
+    program."""
+    from horovod_tpu import serving_scheduler
+
+    assert WIDEST == serving_scheduler._CHUNK_TOKENS // 256
+    compiled, cache = shortconv_compiled
+    program = compiled("chunk_rows")
+    mem = program.memory_analysis()
+    pool = cache.k.size * cache.k.dtype.itemsize
+    assert mem.temp_size_in_bytes < pool // 2, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= 2 * pool
+    assert mem.temp_size_in_bytes < WIDEST * 256 * 65536 * 4
+    text = program.as_text()
+    for every_position in (f"[{WIDEST},256,65536]", f"[{WIDEST * 256},65536]"):
+        assert every_position not in text
 
 
 @pytest.mark.parametrize("program", ["tick", "chunk"])

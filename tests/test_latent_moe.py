@@ -740,7 +740,8 @@ def test_counters_carry_past_a_word():
 def test_a_llama_engine_lowers_to_the_same_programs_as_before_the_interface():
     """The engine reaches ``models.llama`` through the model interface; for a
     ``LlamaConfig`` its tick and chunk are, letter for letter, the programs
-    that named ``llama`` directly."""
+    that named ``llama`` directly (the chunk, since it carries rows, the
+    rows entry over a program of one)."""
     cfg = llama.llama_tiny()
     params = llama.init_params(cfg, jax.random.key(0))
     eng = ServeEngine(params, cfg, n_slots=2, max_len=32, chunk=8,
@@ -756,11 +757,10 @@ def test_a_llama_engine_lowers_to_the_same_programs_as_before_the_interface():
         return logits[:, 0], pcache     # the host reads `_sample`'s tokens
 
     @functools.partial(jax.jit, donate_argnums=(1, 2))
-    def _chunk(params, pcache, last_logits, toks, slot, new_len, sel):
-        logits, pcache = llama.decode_chunk_paged_row(
-            params, toks, cfg, pcache, slot, new_length=new_len)
-        last_logits = last_logits.at[slot].set(logits[0, sel])
-        return pcache, last_logits
+    def _chunk(params, pcache, last_logits, toks, slots, new_len, sel):
+        logits, pcache = llama.decode_chunk_paged_rows(
+            params, toks, cfg, pcache, slots, new_length=new_len, sel=sel)
+        return pcache, last_logits.at[slots].set(logits, mode="drop")
 
     progs = eng.pinned_programs()
     for name, before in (("tick", _tick), ("chunk", _chunk)):

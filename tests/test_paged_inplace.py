@@ -37,7 +37,7 @@ N_SLOTS, MAX_LEN, BLOCK, N_BLOCKS = 4, 32, 8, 12
 
 
 def _paged_attend_full_width(params, tokens, cfg, kv_k, kv_v, qpos, wflat,
-                             table, active=None):
+                             table, active=None, sel=None):
     """The body as it was before the pool became a carry and attention
     walked the live blocks: ``kv_k`` / ``kv_v`` scanned in layer by layer,
     each written slice stacked out, and every row's whole table gathered,

@@ -9,7 +9,8 @@ Pins the subsystem's acceptance contract from three sides:
    prefix, and requests replayed after a preemption.
 2. *Fixed signature*: cache hits change block-table data, never shapes
    — ``compile_cache_sizes()`` stays ``{"sample": 1, "tick": 1, "chunk": 1,
-   "set_row": 1}`` through every admission.
+   "set_row": 1}`` through every admission (``chunk``: one signature a
+   width of the chunk program, none added after construction).
 3. *Accounting*: a drained engine holds zero live references and every
    block is either free or parked zero-ref in a structurally sound
    radix index; the ``HVD_TPU_VERIFY_BLOCKS`` walker checks the same

@@ -5,7 +5,8 @@ The acceptance criteria, pinned:
 
 1. *Free and harmless*: profiling on vs off produces BIT-IDENTICAL
    engine outputs, and ``compile_cache_sizes()`` stays at one signature
-   per program — the profiler never touches a traced value.
+   per program (the chunk program: one a width, which its count reads as
+   1) — the profiler never touches a traced value.
 2. *Phases tile the tick*: the top-level phase totals sum to the
    profiler's measured tick wall time (coverage ~ 1.0), and that tick
    total is within 10 % of an independently measured wall time for the
@@ -515,7 +516,8 @@ def test_row_fields_are_the_vocabulary():
     assert set(TILING) == set(PHASES) | set(SPEC_PHASES)
     assert ROW_FIELDS == (("step", "began", "ended") + TILING + SUB_PHASES
                           + COUNTS + CARRIED)
-    assert COUNTS == ("chunks", "tick_rows", "tokens", "first_tokens")
+    assert COUNTS == ("chunks", "chunk_rows", "tick_rows", "tokens",
+                      "first_tokens")
     assert len(set(ROW_FIELDS)) == len(ROW_FIELDS)
 
 
@@ -618,7 +620,9 @@ def test_rows_follow_a_known_schedule(world, profile):
     assert len(eng.results[rid]) == 3
     rows = _as_dicts(eng.prof.log)
     got = [tuple(int(r[c]) for c in COUNTS) for r in rows]
-    assert got == [(1, 0, 0, 0), (1, 1, 1, 1), (0, 1, 1, 0), (0, 1, 1, 0)]
+    # (chunk programs, the rows they carried, ...): one row prefills alone
+    assert got == [(1, 1, 0, 0, 0), (1, 1, 1, 1, 1), (0, 0, 1, 1, 0),
+                   (0, 0, 1, 1, 0)]
     # the counter moves when a token is emitted, not when its request ends
     assert seen == [0, 1, 2, 3]
     assert [int(r["step"]) for r in rows] == [0, 1, 2, 3]

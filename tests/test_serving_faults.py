@@ -12,8 +12,10 @@ transition:
    ``llama.generate`` run emits; every non-``OK`` result's tokens-so-far
    are a prefix of that solo run.
 2. *Fixed signature*: preempt / requeue / cancel / timeout / fail all
-   ride the existing three compiled programs —
-   ``compile_cache_sizes()`` never moves.
+   ride the existing compiled programs —
+   ``compile_cache_sizes()`` never moves (its ``"chunk": 1`` is "one
+   signature a width" of the chunk program, whose rows a fault leaves
+   one by one).
 """
 
 from __future__ import annotations

@@ -96,7 +96,9 @@ def test_midflight_admission_parity(world):
 
 
 def test_no_retrace_across_admissions(world):
-    """The fixed-signature pin: one jit cache entry per program, and the
+    """The fixed-signature pin: one jit cache entry per program (for
+    ``chunk``, whose program carries rows, 1 reads "one signature a width":
+    ``ServeEngine.chunk_widths``, all compiled by the constructor), and the
     counts stay constant across admissions, recycles, and a full second
     workload on the same engine."""
     cfg, params = world

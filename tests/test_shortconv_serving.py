@@ -214,8 +214,9 @@ def test_counters_equal_what_the_run_did(served):
 @pytest.mark.parametrize("threshold", [0, 4, 256])
 def test_choices_in_place_are_those_of_the_programs_of_few_rows(
         monkeypatch, served, threshold):
-    """Chunks of 8 rows and ticks of 2: with the threshold between them the
-    ticks alone compute their experts in place
+    """Chunks of 8 tokens a row (one row, or the two that prefill together in
+    one program: 16 rows of tokens) and ticks of 2: with the threshold between
+    them the ticks alone compute their experts in place
     (:func:`latent_moe.rows_in_place`), at 256 (the module's own) every
     program and at 0 none; ``moe.choices_in_place`` is reckoned from the
     dispatched programs' rows, and the tokens and ``moe.choices_total`` are
@@ -235,15 +236,15 @@ def test_choices_in_place_are_those_of_the_programs_of_few_rows(
     eng = _engine(mc, params)
     out = eng.run(_requests(prompts[:2]))
     assert [list(r) for r in out] == want[:2]
-    assert {(p.rows, p.t) for p in programs} == {(1, 8), (2, 1)}
-    ticks = sum(1 for p in programs if p.t == 1)
-    chunks = len(programs) - ticks
+    assert {(p.rows, p.t) for p in programs} == {(1, 8), (2, 8), (2, 1)}
+    in_place = [p for p in programs if p.rows * p.t <= threshold]
+    assert {p.t for p in in_place} == {0: set(), 4: {1}, 256: {1, 8}}[
+        threshold]
     c = _counters(eng)
-    assert c["moe.choices_in_place"] == mc.top_k * 5 * (
-        2 * ticks * (2 <= threshold) + 8 * chunks * (8 <= threshold))
+    assert c["moe.choices_in_place"] == mc.top_k * 5 * sum(
+        p.rows * p.t for p in in_place)
     assert c["moe.choices_in_place"] == lm.choices_in_place(mc, programs)
-    assert c["moe.layers_batched"] <= 5 * (
-        ticks * (2 <= threshold) + chunks * (8 <= threshold))
+    assert c["moe.layers_batched"] <= 5 * len(in_place)
     assert (c["moe.layers_batched"] > 0) == (threshold == 256)
     assert c["moe.choices_total"] == mc.top_k * 5 * sum(
         len(p) + N_NEW for p in prompts[:2])
