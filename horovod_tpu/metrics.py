@@ -164,6 +164,7 @@ METRIC_HELP: dict[str, str] = {
     "serve.chunk.programs": "Prefill chunk programs dispatched",
     "serve.chunk.rows": "Rows the prefill chunk programs carried (over serve.chunk.programs: rows that shared one read of the weights)",
     "serve.chunk.max_rows": "Rows of the wide prefill chunk program the engine holds beside the one-row one (1: it holds no wide one)",
+    "serve.params_relaid_bytes": "Bytes of weights written anew for the model's serving tree by the last engine built on this registry that laid one out (0: none did; a clone serves its original's tree and leaves the gauge as it stands)",
     "serve.queue_depth": "Requests waiting for admission",
     "serve.decoding": "Slots actively decoding",
     "serve.prefilling": "Slots mid-prefill",

@@ -154,6 +154,53 @@ def param_partition_specs(cfg: LlamaConfig, *, tp_axis: str = "tp") -> dict:
     }
 
 
+_QKV = ("wq", "wk", "wv")
+
+
+# hvdlint: disable=HVD001 -- runs once an engine, at construction, before any served program: one program a (shapes, tp_size), nothing dispatched in a step
+@partial(jax.jit, static_argnums=3)
+def _fuse_qkv(wq, wk, wv, tp: int):
+    split = lambda w: w.reshape(*w.shape[:2], tp, -1)       # noqa: E731
+    return jnp.concatenate([split(wq), split(wk), split(wv)], axis=-1)
+
+
+def serving_params(params: dict, cfg: LlamaConfig, *, tp_size: int = 1) -> dict:
+    """The tree as the paged programs read it (:mod:`horovod_tpu.models.
+    paged`), made once from the tree :func:`init_params` describes: ``layers``
+    holds, in place of ``wq`` / ``wk`` / ``wv``, their columns side by side,
+
+      wqkv [L, D, tp, (H + 2 KVH) Dh / tp]
+
+    shard ``j`` of ``tp_size`` holding ``[q_j | k_j | v_j]``, the heads a
+    tensor-parallel shard computes, so that a layer reads them in one product
+    and slices within a shard (:func:`_qkv_heads`).  One program on the device
+    the weights are on writes it; every other leaf is the caller's own array,
+    and the caller's tree is not touched.  A tree that already holds ``wqkv``
+    (an engine's, handed to its clone) is returned as it is."""
+    layers = params["layers"]
+    if "wqkv" in layers:
+        if layers["wqkv"].shape[2] != tp_size:
+            raise ValueError(
+                f"a serving tree laid out for tp_size="
+                f"{layers['wqkv'].shape[2]} cannot serve tp_size={tp_size}")
+        return params
+    return _with_wqkv(params, _fuse_qkv(*(layers[k] for k in _QKV), tp_size))
+
+
+def serving_partition_specs(cfg: LlamaConfig, *, tp_axis: str = "tp") -> dict:
+    """:func:`param_partition_specs` of :func:`serving_params`' tree: the
+    shards of ``wqkv`` are its third axis."""
+    return _with_wqkv(param_partition_specs(cfg, tp_axis=tp_axis),
+                      P(None, None, tp_axis, None))
+
+
+def _with_wqkv(tree: dict, wqkv) -> dict:
+    """``tree`` (the parameters or their specs) with ``wqkv`` in the place of
+    its layers' ``wq`` / ``wk`` / ``wv``; a new tree over the same leaves."""
+    rest = {k: v for k, v in tree["layers"].items() if k not in _QKV}
+    return {**tree, "layers": {**rest, "wqkv": wqkv}}
+
+
 def paged_cache_partition_specs(*, tp_axis: str = "tp") -> "PagedKVCache":
     """Head-sharded layout for the paged KV pool over ``tp_axis``.
 
@@ -1035,9 +1082,35 @@ def paged_attend_tiles(q, k, v, kf, vf, layer, walk: TileWalk, wflat,
     return o.reshape((-1,) + o.shape[2:])[walk.place], kf, vf
 
 
+def _qkv_heads(h, lp, cfg: LlamaConfig):
+    """One layer's queries, keys and values of ``h`` [B, T, D], in heads
+    ([B, T, H, Dh] and twice [B, T, KVH, Dh]): one product with the layer's
+    ``wqkv`` [D, tp, n / tp] of the serving tree (:func:`serving_params`).
+
+    The product's output is **sliced before it is reshaped into heads**.
+    Compiled for the TPU, a product whose output goes straight into a
+    reshape to heads takes the reshape into itself, then wants its weight
+    with ``D`` minor, and gets it by staging each layer's slice of the
+    stacked weights out of HBM as an op of its own and transposing the copy
+    (16 % of ``mistral7b_chat``'s window: PERF.md, PR 42).  With a slice
+    between, the weight streams from HBM inside the product's fusion, as
+    ``wo`` and the MLP's do.  ``tests/test_chip_compile.py`` holds the
+    compiled programs to that."""
+    b, t, _ = h.shape
+    dh = cfg.head_dim
+    w = lp["wqkv"].astype(cfg.dtype)
+    qkv = jnp.einsum("btd,dsn->btsn", h, w)         # a shard's [q | k | v]
+    nq, nk = (n * dh // w.shape[1] for n in (cfg.n_heads, cfg.n_kv_heads))
+    q, k, v = qkv[..., :nq], qkv[..., nq:nq + nk], qkv[..., nq + nk:]
+    return (q.reshape(b, t, cfg.n_heads, dh),
+            k.reshape(b, t, cfg.n_kv_heads, dh),
+            v.reshape(b, t, cfg.n_kv_heads, dh))
+
+
 def _paged_attend(params, tokens, cfg: LlamaConfig, kv_k, kv_v,
                   qpos, wflat, table, active=None, sel=None):
-    """Shared body of the paged decode paths: scatter the chunk's K/V at
+    """Shared body of the paged decode paths, over ``params`` the serving
+    tree (:func:`serving_params`): scatter the chunk's K/V at
     flat physical positions ``wflat`` [B, T], then attend block-wise
     through ``table`` [B, blocks_per_row] (the rows' block tables) with a
     running softmax: a loop over key tiles of whole pool blocks that ends,
@@ -1067,9 +1140,7 @@ def _paged_attend(params, tokens, cfg: LlamaConfig, kv_k, kv_v,
     def layer(carry, lp):
         x, kf, vf, i = carry                # kf/vf [L * stripe, KVH, Dh]
         h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-        q = (h @ lp["wq"].astype(dt)).reshape(b, t, cfg.n_heads, cfg.head_dim)
-        k = (h @ lp["wk"].astype(dt)).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
-        v = (h @ lp["wv"].astype(dt)).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
+        q, k, v = _qkv_heads(h, lp, cfg)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
         o, kf, vf = paged_attend_tiles(q, k, v, kf, vf, i, walk, wflat,

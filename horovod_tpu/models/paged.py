@@ -36,6 +36,21 @@ The engine names no model.  A model is a module with these functions, which
 * ``param_partition_specs(cfg, tp_axis=)``,
   ``paged_cache_partition_specs(tp_axis=)``, ``tp_split_dims(cfg)`` — the
   tensor-parallel layout (a model may raise ``NotImplementedError``);
+* optionally ``serving_params(params, cfg, *, tp_size)`` — the **serving
+  tree**: the tree as the model's paged programs read it, made once from the
+  tree its ``init_params`` describes, where the layout that trains and loads
+  checkpoints is not the one the device reads fastest (``llama``: ``wq`` /
+  ``wk`` / ``wv`` side by side in one ``wqkv``, a shard's columns together,
+  so that a layer reads them inside one product).  The engine calls it at
+  construction, before the pool is allocated, keeps only what it returns
+  and hands only that to the functions above; ``serving_partition_specs(cfg,
+  tp_axis=)`` is then ``param_partition_specs`` of that tree.  It leaves the
+  caller's tree as it is, shares every leaf it does not lay out anew, and
+  returns a tree that is already a serving tree as it is (an engine's tree
+  handed to its clone).  While the caller still holds the public tree, what
+  was laid out anew is held twice (gauge ``serve.params_relaid_bytes``).
+  Without the function a model is served from the tree it was given.
+  :func:`serving_tree` is either, with the bytes written anew;
 * ``paged_counters(pcache)`` — a small device array of counters the engine
   reads back beside the tick's tokens, or ``None``;
 * ``publish_paged_metrics(metrics, cfg, pcache, stats_host, row_blocks,
@@ -102,6 +117,7 @@ from __future__ import annotations
 from types import ModuleType
 from typing import Any, NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -125,6 +141,21 @@ def paged_model(cfg: Any) -> ModuleType:
         f"ServeEngine serves a LlamaConfig, a LatentMoEConfig, a "
         f"ShortConvMoEConfig, a WindowMoEConfig or a StateSpaceMoEConfig, "
         f"not a {type(cfg).__name__}")
+
+
+def serving_tree(model: ModuleType, params: Any, cfg: Any, *,
+                 tp_size: int) -> tuple:
+    """``(tree, relaid_bytes)``: ``params`` as ``model``'s paged programs
+    read it (its ``serving_params``' tree; ``params`` itself where the model
+    has no such function) and the bytes of the leaves written anew for it,
+    which are held twice while the caller still holds ``params``."""
+    relay = getattr(model, "serving_params", None)
+    if relay is None:
+        return params, 0
+    given = {id(x) for x in jax.tree.leaves(params)}
+    tree = relay(params, cfg, tp_size=tp_size)
+    return tree, sum(x.nbytes for x in jax.tree.leaves(tree)
+                     if id(x) not in given)
 
 
 class Dispatched(NamedTuple):

@@ -165,7 +165,7 @@ from horovod_tpu import timeseries as timeseries_mod
 from horovod_tpu import tracing as tracing_mod
 from horovod_tpu.metrics import Trace
 from horovod_tpu.models.llama import BlockPool
-from horovod_tpu.models.paged import Dispatched, paged_model
+from horovod_tpu.models.paged import Dispatched, paged_model, serving_tree
 from horovod_tpu.parallel.mesh import tensor_parallel_mesh
 from horovod_tpu.prefix_cache import RadixPrefixCache
 from horovod_tpu.serving import (
@@ -409,9 +409,16 @@ class ServeEngine:
                         f"cfg.{dim_name}={dim}: every tp-sharded axis "
                         f"must split evenly across the mesh")
         self.tp_size = tp_size
+        # The tree as the model's paged programs read it, where the model
+        # lays one out (models/paged.py, `serving_params`): made once, here,
+        # and the only tree the engine keeps.  What it wrote anew is
+        # `serve.params_relaid_bytes`; the caller's tree is the caller's.
+        params, relaid_bytes = serving_tree(model, params, cfg,
+                                            tp_size=tp_size)
         if tp_size > 1:
             self.mesh = tensor_parallel_mesh(tp_size)
-            pspecs = model.param_partition_specs(cfg, tp_axis="tp")
+            pspecs = getattr(model, "serving_partition_specs",
+                             model.param_partition_specs)(cfg, tp_axis="tp")
             cspecs = model.paged_cache_partition_specs(tp_axis="tp")
             self._param_sh = jax.tree.map(
                 lambda s: NamedSharding(self.mesh, s), pspecs,
@@ -439,6 +446,12 @@ class ServeEngine:
         self.watchdog_steps = watchdog_steps
         self.faults = faults if faults is not None else faults_mod.DEFAULT
         self.metrics = metrics if metrics is not None else metrics_mod.DEFAULT
+        # the last construction on this registry that laid a tree out: an
+        # engine handed a serving tree (a clone, over its original's
+        # registry) wrote nothing and leaves the gauge as it stands
+        relaid = self.metrics.gauge("serve.params_relaid_bytes")
+        if relaid_bytes:
+            relaid.set(relaid_bytes)
         # Scheduler policy (admission order + preemption victim): FIFO
         # default is bit-compatible with the pre-policy engine.
         self.policy = scheduling_mod.resolve_policy(policy)

@@ -46,6 +46,14 @@ def no_compile_cache():
     cc.reset_cache()
 
 
+def _benchmark_json(*path) -> dict:
+    import json
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", *path)) as f:
+        return json.load(f)
+
+
 def _avals(tree, sharding):
     return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
         x.shape, x.dtype, sharding=sharding), tree)
@@ -281,12 +289,7 @@ V5E_USABLE_BYTES = (15.75 * 1024 - 258) * 2**20
 def _mixedq_engine() -> dict:
     """The engine of the one cell that serves the fourth model, from its
     traffic file: the size is written down there alone."""
-    import json
-
-    with open(os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "benchmark", "traffic",
-            "mixedq.json")) as f:
-        return json.load(f)["engine"]
+    return _benchmark_json("traffic", "mixedq.json")["engine"]
 
 
 @pytest.fixture(scope="module")
@@ -385,12 +388,7 @@ def test_the_cells_pool_is_what_the_chip_leaves(window_compiled):
 def _toolcalls_engine() -> dict:
     """The engine of the one cell that serves the fifth model, from its
     traffic file: the size is written down there alone."""
-    import json
-
-    with open(os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "benchmark", "traffic",
-            "toolcalls.json")) as f:
-        return json.load(f)["engine"]
+    return _benchmark_json("traffic", "toolcalls.json")["engine"]
 
 
 @pytest.fixture(scope="module")
@@ -574,3 +572,157 @@ def test_the_snapshot_rules_two_users_lower_to_the_programs_they_were(
     now = lowered()
     monkeypatch.setattr(lm, "route", _route_as_it_was)
     assert now == lowered()
+
+
+# -- the Mistral programs read wq / wk / wv inside one product (PR 42) -------
+
+#: scratch of ``mistral7b_chat``'s programs on the public tree (three
+#: products a layer, each from a staged and transposed slice), compiled the
+#: same way from the parent of PR 42
+PARENT_LLAMA_TEMP_BYTES = {"tick": 1_248_256, "chunk": 1_032_704,
+                           "chunk_rows": 60_564_992}
+
+#: a top-level op that hands on one layer's slice of a stacked q / k / v
+#: weight: the staged ``dynamic-slice`` fusion and the transposing ``copy``
+#: (``bf16[1,4096,n]``, or ``[1,4096,tp,n]`` of the fused tree)
+_WEIGHT_SLICE_OP = re.compile(
+    r"^\s*(?:ROOT )?%[\w.\-]+ = bf16\[1,4096(?:,\d+){1,2}\]\S* "
+    r"(?:copy|fusion)\(", re.M)
+
+
+@pytest.fixture(scope="module")
+def llama_compiled(one_chip, no_compile_cache):
+    """``compiled(program, tree)`` of ``mistral7b_chat``'s tick, one-row chunk
+    and wide chunk at the cell's own size (24 layers, 20 slots of 1280 over
+    blocks of 256, bf16), as ``ServeEngine`` jits them, over the ``"serving"``
+    tree (``llama.serving_params``) or the ``"public"`` one (for a caller
+    that has put a projection that reads it in ``llama._qkv_heads``' place);
+    each compiled once.  And the cache's shapes."""
+    from horovod_tpu import serving_scheduler
+    from horovod_tpu.models import llama
+
+    c = _benchmark_json("configs", "mistral-7b-v0.3.json")
+    e = _benchmark_json("traffic", "chat.json")["engine"]
+    cfg = llama.LlamaConfig(
+        vocab_size=c["vocab_size"], dim=c["hidden_size"],
+        n_layers=c["num_hidden_layers"], n_heads=c["num_attention_heads"],
+        n_kv_heads=c["num_key_value_heads"], ffn_dim=c["intermediate_size"],
+        rope_theta=c["rope_theta"], norm_eps=c["rms_norm_eps"],
+        max_seq_len=e["max_len"], dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16, attn_impl="dense", remat=False)
+    n_slots, chunk_len = e["n_slots"], e["chunk"]
+    wide = min(n_slots, serving_scheduler._CHUNK_TOKENS // chunk_len)
+    public = jax.eval_shape(lambda: llama.init_params(cfg, jax.random.key(0)))
+    trees = {"public": _avals(public, one_chip),
+             "serving": _avals(jax.eval_shape(
+                 lambda p: llama.serving_params(p, cfg, tp_size=1), public),
+                 one_chip)}
+    cache = _avals(jax.eval_shape(lambda: llama.init_paged_cache(
+        cfg, n_slots, e["max_len"], block_size=chunk_len)), one_chip)
+    logits = jax.ShapeDtypeStruct((n_slots, cfg.vocab_size), jnp.float32,
+                                  sharding=one_chip)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    @partial(jax.jit, donate_argnums=(1, 2))
+    def tick(params, pcache, last_logits, active):
+        tok = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
+        out, pcache = llama.decode_chunk_paged(params, tok[:, None], cfg,
+                                               pcache, advance=active)
+        return out[:, 0], pcache
+
+    @partial(jax.jit, donate_argnums=(1, 2))
+    def chunk(params, pcache, last_logits, toks, slots, new_len, sel):
+        out, pcache = llama.decode_chunk_paged_rows(
+            params, toks, cfg, pcache, slots, new_length=new_len, sel=sel)
+        return pcache, last_logits.at[slots].set(out, mode="drop")
+
+    rows = {"chunk": 1, "chunk_rows": wide}
+    done = {}
+
+    def compiled(program, tree="serving"):
+        if (program, tree) not in done:
+            params = trees[tree]
+            if program == "tick":
+                low = tick.lower(params, cache, logits, i32(n_slots))
+            else:
+                r = rows[program]
+                low = chunk.lower(params, cache, logits, i32(r, chunk_len),
+                                  i32(r), i32(r), i32(r))
+            done[program, tree] = low.compile()
+        return done[program, tree]
+
+    return compiled, cache
+
+
+def _top_level(hlo: str) -> str:
+    """The text of an optimised module outside its fused computations: the
+    ops the device runs one after another."""
+    return "\n".join(body for name, body in _computations(hlo).items()
+                     if not name.startswith("fused_computation"))
+
+
+@pytest.mark.parametrize("program", ["tick", "chunk", "chunk_rows"])
+def test_the_mistral_layer_loop_stages_and_transposes_no_qkv_weight(
+        llama_compiled, program):
+    """On the serving tree the layer loop hands no slice of a q / k / v
+    weight from op to op: the ``dynamic-slice`` sits inside the one product's
+    fusion, which streams ``wqkv`` from HBM as ``wo`` and the MLP's products
+    stream theirs.  (A product whose output is reshaped into heads directly
+    brings the staged slice and the transposing ``copy`` back:
+    ``llama._qkv_heads``.)"""
+    compiled, _ = llama_compiled
+    hlo = compiled(program).as_text()
+    assert not _WEIGHT_SLICE_OP.findall(_top_level(hlo))
+    # one product reads the stacked wqkv, with the slice inside its fusion
+    reads = [line for line in hlo.splitlines()
+             if re.search(r"dynamic-slice\(.*\bdynamic_slice_sizes="
+                          r"\{1,4096,1,6144\}", line)]
+    assert len(reads) == 1
+    products = [name for name, body in _computations(hlo).items()
+                if name.startswith("fused_computation")
+                and "btd,dsn->btsn/dot_general" in body
+                and " convolution(" in body]
+    assert len(products) == 1, products
+    assert len(re.findall(rf"kind=kOutput, calls=%{re.escape(products[0])}\b",
+                          hlo)) == 1
+
+
+def test_the_detector_finds_the_public_tree_s_three_slices_and_copies(
+        llama_compiled, monkeypatch):
+    """What the test above rules out is what the same tick compiles to with
+    the projection as it was, the public tree's three products each reshaped
+    into heads: a staged slice and a transposing copy each for ``wq``,
+    ``wk`` and ``wv``, a layer."""
+    from horovod_tpu.models import llama
+
+    def three_products(h, lp, cfg):
+        b, t, _ = h.shape
+        return tuple(
+            (h @ lp[name].astype(cfg.dtype)).reshape(b, t, n, cfg.head_dim)
+            for name, n in (("wq", cfg.n_heads), ("wk", cfg.n_kv_heads),
+                            ("wv", cfg.n_kv_heads)))
+
+    monkeypatch.setattr(llama, "_qkv_heads", three_products)
+    compiled, _ = llama_compiled
+    found = _WEIGHT_SLICE_OP.findall(_top_level(
+        compiled("tick", "public").as_text()))
+    assert len(found) == 6, found
+
+
+@pytest.mark.parametrize("program", ["tick", "chunk", "chunk_rows"])
+def test_the_mistral_programs_hold_one_pool_and_no_more_scratch(
+        llama_compiled, program):
+    """The pool is an argument aliased in place and the scratch is the
+    parent's or less; the tick may hold the fused product's output (20 rows
+    of 6,144) beside its three slices, which the three products had not."""
+    compiled, cache = llama_compiled
+    mem = compiled(program).memory_analysis()
+    pool = cache.k.size * cache.k.dtype.itemsize
+    assert pool == 1_270_874_112
+    assert mem.alias_size_in_bytes >= 2 * pool
+    room = 20 * 6144 * 2 if program == "tick" else 0
+    assert mem.temp_size_in_bytes <= PARENT_LLAMA_TEMP_BYTES[program] + room
+    # the weights once: 11.0 GB with the pool's 2.54, not 12.2
+    assert mem.argument_size_in_bytes < 13.7e9

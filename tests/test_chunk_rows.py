@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.models import llama, shortconv_moe, window_moe
+from horovod_tpu.models import llama, paged, shortconv_moe, window_moe
 
 N_SLOTS, MAX_LEN, BS, T = 5, 32, 4, 8
 PER = MAX_LEN // BS
@@ -58,7 +58,8 @@ def _world(model):
     window; slot 4 free (its table all trash)."""
     mod, make = MODELS[model]
     cfg = make()
-    params = mod.init_params(cfg, jax.random.key(0))
+    params, _ = paged.serving_tree(
+        mod, mod.init_params(cfg, jax.random.key(0)), cfg, tp_size=1)
     pc = mod.init_paged_cache(cfg, N_SLOTS, MAX_LEN, block_size=BS)
     table = 1 + np.random.default_rng(3).permutation(
         N_SLOTS * PER).reshape(N_SLOTS, PER).astype(np.int32)

@@ -88,12 +88,16 @@ def _paged_attend_full_width(params, tokens, cfg, kv_k, kv_v, qpos, wflat,
     return logits, ks, vs
 
 
-def _setup(dtype):
+def _setup(dtype, serving=False):
     """A pool full of noise (so a wrong gather or scatter shows) and four
     rows: 0 mid-block at 13, 1 free (table all trash, idle), 2 admitted at
-    length 0, 3 two short of the end of its table."""
+    length 0, 3 two short of the end of its table.  The public tree, which
+    the full-width reference reads, or the ``serving`` tree, which the body
+    under test reads."""
     cfg = llama.llama_tiny(dtype=dtype, param_dtype=dtype, n_layers=3)
     params = llama.init_params(cfg, jax.random.key(0))
+    if serving:
+        params = llama.serving_params(params, cfg)
     pc = llama.init_paged_cache(cfg, N_SLOTS, MAX_LEN, block_size=BLOCK,
                                 n_blocks=N_BLOCKS)
     kk, kv = jax.random.split(jax.random.key(1))
@@ -159,7 +163,9 @@ def _run_both(case, dtype, monkeypatch, tile_blocks):
     cfg, params, pc = _setup(dtype)
     run = CASES[case]
     monkeypatch.setattr(llama, "_KEY_TILE", tile_blocks * BLOCK)
-    got = jax.jit(lambda p, c: run(cfg, p, c))(params, pc)
+    got = jax.jit(lambda p, c: run(cfg, p, c))(
+        llama.serving_params(params, cfg), pc)
+    # (the reference reads the public tree's wq / wk / wv)
     monkeypatch.setattr(llama, "_paged_attend", _paged_attend_full_width)
     want = jax.jit(lambda p, c: run(cfg, p, c))(params, pc)
     return pc, got, want
@@ -248,7 +254,8 @@ def _short_rows(pc, n_live):
     (3, "spec_verify-idle_and_padded_drafts")])
 def test_blocks_past_the_longest_row_are_never_read(n_live, case,
                                                     monkeypatch):
-    cfg, params, pc = _setup(jnp.float32)
+    cfg, public, pc = _setup(jnp.float32)
+    params = llama.serving_params(public, cfg)
     clean, poisoned = _short_rows(pc, n_live)
     assert np.isnan(np.asarray(poisoned.k)).any()
     run = jax.jit(lambda p, c: CASES[case](cfg, p, c))
@@ -259,7 +266,7 @@ def test_blocks_past_the_longest_row_are_never_read(n_live, case,
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     # the full-width body multiplies the NaN by a zero probability
     monkeypatch.setattr(llama, "_paged_attend", _paged_attend_full_width)
-    old = jax.jit(lambda p, c: CASES[case](cfg, p, c))(params, poisoned)
+    old = jax.jit(lambda p, c: CASES[case](cfg, p, c))(public, poisoned)
     assert not np.isfinite(np.asarray(_logits(old))).all()
 
 
@@ -276,7 +283,8 @@ def _eqns(jaxpr):
 def test_nothing_table_deep_in_the_layer_scan(case, monkeypatch):
     # five blocks of 8: a depth of 40 is no other size of this model
     cfg = llama.llama_tiny(n_layers=3)
-    params = llama.init_params(cfg, jax.random.key(0))
+    params = llama.serving_params(
+        llama.init_params(cfg, jax.random.key(0)), cfg)
     depth = 5 * BLOCK
     pc = llama.init_paged_cache(cfg, N_SLOTS, depth, block_size=BLOCK,
                                 n_blocks=N_BLOCKS)
@@ -301,7 +309,7 @@ def _scans(jaxpr):
                                   "row-crosses_block",
                                   "spec_verify-idle_and_padded_drafts"])
 def test_pool_is_a_scan_carry(case):
-    cfg, params, pc = _setup(jnp.float32)
+    cfg, params, pc = _setup(jnp.float32, serving=True)
     jaxpr = jax.make_jaxpr(lambda p, c: CASES[case](cfg, p, c))(params, pc)
     (scan,) = list(_scans(jaxpr.jaxpr))
     n_consts, n_carry = scan.params["num_consts"], scan.params["num_carry"]

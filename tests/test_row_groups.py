@@ -24,7 +24,7 @@ import pytest
 from jax import lax
 
 from horovod_tpu import metrics as metrics_mod
-from horovod_tpu.models import llama
+from horovod_tpu.models import llama, paged
 from horovod_tpu.models import shortconv_moe as sm
 from horovod_tpu.models import window_moe as wm
 from horovod_tpu.serving_scheduler import Request, ServeEngine
@@ -192,7 +192,8 @@ def test_rows_that_are_read_get_the_walk_to_longests_numbers(
     mod, tiny = MODELS[model]
     cfg = tiny()
     run, t = PROGRAMS[program]
-    params = mod.init_params(cfg, jax.random.key(0))
+    params, _ = paged.serving_tree(
+        mod, mod.init_params(cfg, jax.random.key(0)), cfg, tp_size=1)
     active = jnp.asarray(SCENES[scene][1], jnp.int32)
     pc = _cache(mod, cfg, _lengths(scene, t))
     assert llama._row_groups(N_SLOTS, MAX_LEN // BLOCK) == (3, 3)
@@ -291,7 +292,8 @@ def test_a_program_of_one_group_has_no_outer_loop(model, toy_walk,
     in groups of three has one more a layer, the loop over its groups."""
     mod, tiny = MODELS[model]
     cfg = tiny()
-    params = mod.init_params(cfg, jax.random.key(0))
+    params, _ = paged.serving_tree(
+        mod, mod.init_params(cfg, jax.random.key(0)), cfg, tp_size=1)
     pc, small = _cache(mod, cfg, [0] * N_SLOTS), _cache(mod, cfg, [0] * GROUP)
 
     def lowerings():

@@ -132,8 +132,13 @@ def test_tp1_default_unsharded(world):
     eng = ServeEngine(params, cfg, n_slots=2, max_len=16, chunk=4,
                       metrics=reg)
     assert eng.tp_size == 1 and eng.mesh is None
-    # no device_put detour: the engine holds the caller's param tree
-    assert eng.params is params
+    # no device_put detour: the engine holds the caller's own arrays, in
+    # its model's serving tree (wq / wk / wv laid out anew as one wqkv)
+    given = {id(x) for x in jax.tree.leaves(params)}
+    assert [k for k, v in eng.params["layers"].items()
+            if id(v) not in given] == ["wqkv"]
+    assert all(eng.params[k] is params[k]
+               for k in ("embed", "final_norm", "lm_head"))
     g = eng.metrics_snapshot()["gauges"]
     assert g["tp.size"] == 1
     assert g["kv.shard_total_bytes"] == g["kv.total_bytes"]
