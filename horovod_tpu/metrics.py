@@ -296,6 +296,9 @@ METRIC_HELP: dict[str, str] = {
     "prefix.blocks_reused": "KV pages spliced from the prefix cache",
     "prefix.tokens_skipped": "Prompt tokens skipped via prefix reuse",
     "prefix.evictions": "Prefix-cache pages evicted under pressure",
+    "prefix.blocks_indexed_live": "Blocks that joined the radix index while their writer was live: at the dispatch of the chunk that filled them",
+    "prefix.admissions_held": "Candidates passed over at admission because a live row was writing the prefix they would otherwise prefill again (once a candidate)",
+    "prefix.held_steps": "Candidate-steps spent held for a prefix hit that was on its way",
     # monitor.* — the cross-rank observability layer itself
     "monitor.scrapes": "HTTP requests served by the /metrics exporter",
     "monitor.aggregations": "Cross-rank aggregate_snapshots() rounds completed",

@@ -220,7 +220,11 @@ class SnapshotBudget:
 
     def wanted(self, block: int) -> bool:
         """Whether ``block`` holds an entry or is about to."""
-        return block in self._held or block in self._pending.values()
+        return block in self._held or self.pending(block)
+
+    def pending(self, block: int) -> bool:
+        """Whether an entry is granted to ``block`` and not yet committed."""
+        return block in self._pending.values()
 
     def pending_block(self, entry: int) -> int | None:
         """The block pending ``entry`` is to be held by (``None``: it was

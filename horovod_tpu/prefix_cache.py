@@ -10,14 +10,32 @@ transparent — no client-side prefix handles, just longest-prefix match
 on admission.  This module is both, mapped onto the existing
 :class:`~horovod_tpu.models.llama.PagedKVCache` block tables:
 
-* **Full, immutable blocks only.**  A physical block enters the index
-  only once every one of its ``block_size`` positions holds the KV of a
-  known token path starting at sequence position 0.  Indexed blocks are
-  never written again — a row's write frontier is kept strictly inside
-  its own private blocks (see COW below) — so sharing needs no device
-  copies and no new compiled programs: a cache hit writes different
-  block-table *data* through the engine's existing ``_set_row``
-  program.
+* **Full, immutable blocks only.**  A physical block becomes a hit only
+  once every one of its ``block_size`` positions holds the KV of a
+  known token path starting at sequence position 0 — which, on a device
+  that runs its programs in the order they were dispatched, is from the
+  moment the prefill chunk that fills the block's last position **has
+  been dispatched** without a fault (``written``): whatever is
+  dispatched later reads the block whole, while its writer still runs.
+  Indexed blocks are never written again — a row's write frontier is
+  past them and is kept strictly inside its own private blocks (see COW
+  below) — so sharing needs no device copies and no new compiled
+  programs: a cache hit writes different block-table *data* through the
+  engine's existing ``_set_row`` program.
+
+* **Unwritten nodes: a hit that is on its way.**  The index knows a
+  block from the moment a row is admitted to write it: ``reserve`` makes
+  a node for every full block of the row's prompt that has none, marked
+  **unwritten** under the row's own reference.  An unwritten node is not
+  a hit, is not advertised (``key_digest``) and is never in the pool's
+  LRU set; it is where ``acquire``'s walk stops and says so (it returns
+  ``None`` and names the node in ``awaited``), so that the engine passes
+  the candidate over for a step instead of letting it prefill the same
+  tokens beside its neighbour.  ``written`` flips a node when its
+  chunk is dispatched; ``forget`` takes a row's still-unwritten nodes
+  (and whatever hangs below them, which is its own) out of the tree when
+  the row is freed first (requeue, failure, cancel, expiry), so a hold
+  never outlives the row it waits on.
 
 * **Radix tree keyed by token chunks.**  Each node is one full block;
   its edge key is the ``block_size``-token tuple the block holds, so a
@@ -31,7 +49,13 @@ on admission.  This module is both, mapped onto the existing
   maps carries a reference (:class:`~horovod_tpu.models.llama.BlockPool`);
   retirement *releases to cache* instead of freeing — zero-ref indexed
   blocks park in LRU order and are reclaimed leaf-first when admission
-  runs short, always BEFORE any live decoding row is preempted.
+  runs short, always BEFORE any live decoding row is preempted.  A
+  prompt's blocks are indexed by then (above); what retirement's
+  ``insert`` adds is the answer's blocks, and only for a row whose
+  frontier is trusted (OK, or a requeue).  A row that FAILED or expired
+  registers nothing more at its retirement, but the blocks it indexed
+  while it lived were each filled by a program that was dispatched
+  without a fault, and stay hits.
 
 * **Copy-on-write tail.**  The block containing a request's write
   frontier must be private.  A match is therefore capped at
@@ -108,21 +132,29 @@ class RadixNode:
     """One full, immutable KV block on the prefix tree.  ``key`` is the
     block's token chunk (the edge label from ``parent``); the
     root-to-here key concatenation is the token path whose KV the block
-    holds at positions ``[depth * block_size, (depth+1) * block_size)``."""
+    holds at positions ``[depth * block_size, (depth+1) * block_size)``.
+    ``written`` is false from its writer's admission until the chunk that
+    fills the block is dispatched; ``parent`` is ``None`` once the node
+    has left the tree; ``digest`` is the running hash of the token path,
+    made the first time ``key_digest`` emits the node."""
 
     block: int
     key: tuple[int, ...]
     parent: "RadixNode | None"
     children: dict[tuple[int, ...], "RadixNode"] = dataclasses.field(
         default_factory=dict)
+    written: bool = True
+    digest: "hashlib._Hash | None" = dataclasses.field(
+        default=None, repr=False, compare=False)
 
 
 class RadixPrefixCache:
     """The prefix index over a :class:`BlockPool`.
 
-    The cache never allocates: callers hand it blocks that are already
-    written (``insert``), and it hands back shared blocks with a
-    reference taken (``acquire``).  Eviction (``evict``) walks zero-ref
+    The cache never allocates: callers hand it blocks that are written
+    (``insert``) or that a live row is admitted to write (``reserve``,
+    then ``written`` or ``forget``), and it hands back shared blocks with
+    a reference taken (``acquire``).  Eviction (``evict``) walks zero-ref
     LRU blocks leaf-first and returns them to the pool's free list;
     interior nodes become leaves as their children go, so a cold
     subtree drains oldest-leaf-first without ever orphaning a path.
@@ -134,7 +166,9 @@ class RadixPrefixCache:
     mirrored into ``metrics`` as a ``prefix.<name>`` counter
     (:mod:`horovod_tpu.metrics`); the default ``NULL`` registry makes a
     standalone cache silent, while :class:`ServeEngine` passes its own
-    registry so the mirrors land in the engine's scrape.
+    registry so the mirrors land in the engine's scrape.  The registry
+    alone counts ``prefix.blocks_indexed_live``: the inserted blocks that
+    joined while their writer was live (``written``).
     """
 
     def __init__(self, pool: BlockPool, block_size: int,
@@ -149,9 +183,13 @@ class RadixPrefixCache:
         self.snaps = snaps
         #: every block the last ``acquire`` matched, rounded down or not
         self.last_match: list[int] = []
+        #: the node the last ``acquire`` stopped for: a better hit than it
+        #: could give is on its way there (``None``: it gave what there is)
+        self.awaited: RadixNode | None = None
         self.block_size = block_size
         self.metrics = metrics if metrics is not None else metrics_mod.NULL
-        self._root = RadixNode(block=0, key=(), parent=None)
+        self._root = RadixNode(block=0, key=(), parent=None,
+                               digest=hashlib.blake2b(digest_size=8))
         self._nodes: dict[int, RadixNode] = {}     # block -> node
         self.stats = {"hits": 0, "misses": 0, "blocks_reused": 0,
                       "tokens_skipped": 0, "inserted_blocks": 0,
@@ -190,9 +228,12 @@ class RadixPrefixCache:
         the cut; deep divergent tails are what truncation drops.  A
         router matches a prompt by digesting its own chunks and finding
         the deepest digest present here; no token ever leaves the
-        replica.  Cost is one ``blake2b.copy()`` + one chunk hash per
-        emitted path, so the summary is cheap enough to ride every
-        ``metrics_snapshot()``.
+        replica.  A node's chunk is hashed once, the first time a walk
+        emits it, and the running hash kept on the node for its children
+        (a block indexed while its row prefills changes the index every
+        few steps, and a walk that hashed 256 chunks of ``block_size``
+        tokens again each time stood between two steps of the engine), so
+        the summary is cheap enough to ride every ``metrics_snapshot()``.
 
         The monitor serves ``/snapshot`` from its own HTTP thread while
         the engine thread inserts/evicts nodes, so a scrape can land
@@ -217,17 +258,18 @@ class RadixPrefixCache:
 
     def _key_digest_walk(self, max_paths: int) -> dict:
         paths: list[str] = []
-        base = hashlib.blake2b(digest_size=8)
-        q: "collections.deque[tuple[RadixNode, hashlib._Hash]]" = \
-            collections.deque(
-                (child, base) for child in self._root.children.values())
+        q = collections.deque(self._root.children.values())
         while q and len(paths) < max_paths:
-            node, parent_h = q.popleft()
-            h = parent_h.copy()
-            _update_chunk(h, node.key)
-            paths.append(h.hexdigest())
-            for c in node.children.values():
-                q.append((c, h))
+            node = q.popleft()
+            parent = node.parent
+            if not node.written or parent is None:
+                continue        # not a hit yet, or gone mid-walk
+            if node.digest is None:     # its parent's is made: breadth-first
+                h = parent.digest.copy()
+                _update_chunk(h, node.key)
+                node.digest = h
+            paths.append(node.digest.hexdigest())
+            q.extend(node.children.values())
         return {
             "block_size": self.block_size,
             "indexed_blocks": len(self._nodes),
@@ -243,22 +285,27 @@ class RadixPrefixCache:
         """Longest-prefix match WITHOUT taking references (read-only
         peek, for tests/dumps): block ids covering the longest fully
         indexed chunk path of ``tokens``."""
-        return [n.block for n in self._walk(tokens, len(tokens))]
+        return [n.block for n in self._walk(tokens, len(tokens))[0]]
 
     # -- the hit path ------------------------------------------------------
 
-    def _walk(self, tokens: list[int], max_tokens: int) -> list[RadixNode]:
+    def _walk(self, tokens: list[int], max_tokens: int
+              ) -> tuple[list[RadixNode], RadixNode | None]:
+        """The written nodes along ``tokens``, and the unwritten node the
+        walk stopped at (``None`` where it stopped for another reason)."""
         bs = self.block_size
         node, out = self._root, []
         for i in range(min(len(tokens), max_tokens) // bs):
             child = node.children.get(tuple(tokens[i * bs:(i + 1) * bs]))
             if child is None:
                 break
+            if not child.written:
+                return out, child
             out.append(child)
             node = child
-        return out
+        return out, None
 
-    def acquire(self, tokens: list[int]) -> list[int]:
+    def acquire(self, tokens: list[int]) -> list[int] | None:
         """Longest-prefix match for an admission, references taken.
 
         Returns the physical blocks covering the longest indexed chunk
@@ -269,8 +316,15 @@ class RadixPrefixCache:
         incref'd — pinned against eviction — until ``release``.  Under
         a snapshot budget (``snaps``) the match is rounded down to the
         deepest block that holds an entry; ``last_match`` keeps all of
-        it for the caller that asks where a snapshot is wanted."""
-        matched = self._walk(tokens, max(len(tokens) - 1, 0))
+        it for the caller that asks where a snapshot is wanted.
+
+        Returns ``None``, with nothing taken or counted, where a deeper hit
+        than that is on its way: the prompt goes on into a block a live row
+        is admitted to write and has not (an unwritten node), or, under a
+        budget, a block matched beyond the hit has an entry granted and
+        not yet committed.  ``awaited`` is then that node, and
+        :meth:`on_its_way` says while it is worth waiting for."""
+        matched, self.awaited = self._walk(tokens, max(len(tokens) - 1, 0))
         blocks = [n.block for n in matched]
         self.last_match = list(blocks)
         if self.snaps is not None:
@@ -281,8 +335,14 @@ class RadixPrefixCache:
             held = [i for i, b in enumerate(blocks)
                     if self.snaps.entry(b) is not None]
             blocks = blocks[:held[-1] + 1] if held else []
-            if blocks:
-                self.snaps.touch(blocks[-1])
+            if self.awaited is None:
+                self.awaited = next(
+                    (n for n in matched[len(blocks):]
+                     if self.snaps.pending(n.block)), None)
+        if self.awaited is not None:
+            return None
+        if self.snaps is not None and blocks:
+            self.snaps.touch(blocks[-1])
         for b in blocks:
             self.pool.incref(b)
         if blocks:
@@ -293,6 +353,16 @@ class RadixPrefixCache:
             self._bump("misses")
         return blocks
 
+    def on_its_way(self, node: RadixNode) -> bool:
+        """Whether what ``acquire`` stopped for at ``node`` is still to
+        come: the node is in the tree and unwritten, or written with a
+        snapshot entry pending.  O(1), so a held candidate costs its step
+        no walk."""
+        if node.parent is None:             # its writer left first
+            return False
+        return not node.written or (
+            self.snaps is not None and self.snaps.pending(node.block))
+
     def release(self, blocks: Iterable[int]) -> None:
         """Drop one reference per block (row retirement / requeue /
         failed admission).  Indexed blocks reaching zero references
@@ -301,6 +371,61 @@ class RadixPrefixCache:
             self.pool.decref(b)
 
     # -- the insert path ---------------------------------------------------
+
+    def _index(self, node: RadixNode) -> None:
+        node.written = True
+        self._nodes[node.block] = node
+        self.pool.mark_indexed(node.block)
+
+    def reserve(self, tokens: list[int], blocks: list[int]
+                ) -> list[RadixNode | None]:
+        """A row is admitted to write ``tokens`` (its whole prompt) into
+        ``blocks``: every full block of the path that has no node gets an
+        unwritten one, pinned by the row's own reference.  Returns, per
+        full block, the node the row is to flip (:meth:`written`) when the
+        chunk that fills it is dispatched, ``None`` where the path has an
+        incumbent (a hit, or a block the row recomputes).  Below another
+        row's unwritten node nothing is reserved: what hangs below an
+        unwritten node is its writer's own and goes with it."""
+        bs = self.block_size
+        n = len(tokens) // bs
+        node, out = self._root, []
+        for i in range(n):
+            key = tuple(tokens[i * bs:(i + 1) * bs])
+            child = node.children.get(key)
+            if child is None:
+                child = RadixNode(block=blocks[i], key=key, parent=node,
+                                  written=False)
+                node.children[key] = child
+                out.append(child)
+            elif not child.written:
+                break
+            else:
+                out.append(None)
+            node = child
+        return out + [None] * (n - len(out))
+
+    def written(self, node: RadixNode, block: int) -> None:
+        """The chunk that fills ``block``, which its row reserved as
+        ``node``, has been dispatched: the block is a hit from here on."""
+        if node.written:
+            # a retiring row had the same tokens written first and took the
+            # node (insert): this block is the duplicate
+            if self.snaps is not None and node.block != block:
+                self.snaps.move(block, node.block)
+            return
+        self._index(node)
+        self._bump("inserted_blocks")
+        self.metrics.counter("prefix.blocks_indexed_live").inc()
+
+    def forget(self, nodes: Iterable[RadixNode | None]) -> None:
+        """The row that reserved ``nodes`` is freed: those it has not
+        written leave the tree (before its references are dropped)."""
+        for node in nodes:
+            if node is not None and not node.written \
+                    and node.parent is not None:
+                del node.parent.children[node.key]
+                node.parent = None
 
     def insert(self, tokens: list[int], blocks: list[int],
                frontier: int) -> int:
@@ -325,6 +450,12 @@ class RadixPrefixCache:
                 node.children[key] = child
                 self._nodes[blocks[i]] = child
                 self.pool.mark_indexed(blocks[i])
+                added += 1
+            elif not child.written:
+                # another row is admitted to write these tokens and has
+                # not: this one has, so the node is its block's
+                child.block = blocks[i]
+                self._index(child)
                 added += 1
             elif self.snaps is not None and child.block != blocks[i]:
                 # a recomputed duplicate: its snapshot, if it has one, is of
@@ -354,6 +485,7 @@ class RadixPrefixCache:
                 if node.children:
                     continue                          # interior: skip
                 del node.parent.children[node.key]
+                node.parent = None
                 del self._nodes[b]
                 self.pool.drop_indexed(b)             # -> free list
                 freed += 1
@@ -373,16 +505,29 @@ class RadixPrefixCache:
     def check_consistency(self) -> None:
         """Structural invariants (the env-gated debug walk): every
         indexed block has a tree node reachable from the root, parents
-        of every node are indexed (no dangling paths), and zero-ref
-        indexed blocks are exactly the pool's LRU set."""
+        of every node are indexed (no dangling paths), zero-ref
+        indexed blocks are exactly the pool's LRU set, and an unwritten
+        node is pinned by its writer and has only unwritten nodes below
+        it."""
         seen: dict[int, RadixNode] = {}
+        unwritten: set[int] = set()
         stack = list(self._root.children.values())
         while stack:
             n = stack.pop()
-            if n.block in seen:
+            if n.block in seen or n.block in unwritten:
                 raise AssertionError(
                     f"block {n.block} appears at two tree positions")
-            seen[n.block] = n
+            if n.written:
+                if not n.parent.written:
+                    raise AssertionError(
+                        f"block {n.block} is indexed below unwritten "
+                        f"block {n.parent.block}")
+                seen[n.block] = n
+            else:
+                if self.pool.refcount(n.block) == 0:
+                    raise AssertionError(
+                        f"unwritten block {n.block} has no writer")
+                unwritten.add(n.block)
             stack.extend(n.children.values())
         if seen.keys() != self._nodes.keys():
             raise AssertionError(

@@ -453,10 +453,12 @@ class LocalReplica(ReplicaHandle):
 
     def _prefix_digest_locked(self) -> "dict | None":
         """The engine's ``key_digest()``, walked again only when its
-        index changed.  The walk hashes up to 256 chunks of
-        ``block_size`` tokens (26 ms at 512-token blocks), and this
-        runs every turn of the pump: behind a step that only ticks it
-        was most of the step."""
+        index changed: this runs every turn of the pump, between two
+        steps of the engine.  (The index changes in every step that
+        fills a block of a prompt, so the walk itself hashes a node's
+        chunk once and keeps the hash on the node: hashing 256 chunks
+        of ``block_size`` tokens anew, 26 ms at 512-token blocks, was
+        most of a step that only ticks.)"""
         prefix = self.engine.prefix
         if prefix is None:
             return None

@@ -71,15 +71,20 @@ sharing + RadixAttention-style automatic indexing — see
 :mod:`horovod_tpu.prefix_cache`):
 
 * physical blocks become **reference-counted**
-  (:class:`~horovod_tpu.models.llama.BlockPool`) and retirement
-  **releases to cache** instead of freeing: every full, immutable
-  block of a cleanly finished row is registered in a radix tree keyed
-  by its token-chunk path, parking zero-ref blocks in LRU order;
+  (:class:`~horovod_tpu.models.llama.BlockPool`) and are registered in
+  a radix tree keyed by their token-chunk path: a prompt's full block
+  when the prefill chunk that fills it has been dispatched (its row
+  still runs; what is dispatched later reads it whole), an answer's at
+  a clean retirement, which **releases to cache** instead of freeing
+  and parks zero-ref blocks in LRU order;
 * admission does a **longest-prefix match** and maps the hit blocks
   straight into the new slot's block-table row — chunked prefill
   starts at the first uncached token (a full hit recomputes only the
   final chunk: the copy-on-write rule keeping the write-frontier block
-  private, and the source of the logits that seed decoding);
+  private, and the source of the logits that seed decoding).  A
+  candidate whose prompt goes on into blocks a live row is admitted to
+  write and has not is **held** for those few steps and admitted on the
+  hit, so a batch handed over at once prefills a shared prefix once;
 * under KV pressure, **cache evicts before rows preempt**: admission
   reclaims zero-ref LRU leaves first, and only a starved head that
   outlasts eviction triggers row preemption.  A preempted row's blocks
@@ -167,7 +172,7 @@ from horovod_tpu.metrics import Trace
 from horovod_tpu.models.llama import BlockPool
 from horovod_tpu.models.paged import Dispatched, paged_model, serving_tree
 from horovod_tpu.parallel.mesh import tensor_parallel_mesh
-from horovod_tpu.prefix_cache import RadixPrefixCache
+from horovod_tpu.prefix_cache import RadixNode, RadixPrefixCache
 from horovod_tpu.serving import (
     CANCELLED, FAILED, OK, REJECTED, TIMEOUT, Request, RequestResult,
 )
@@ -208,7 +213,10 @@ class _QueueEntry:
     """A queued request plus its lifecycle state.  ``prior`` holds
     tokens already emitted before a preemption/replay re-queue (the
     replay prompt is ``req.prompt + prior``); ``wait_steps`` is the
-    step-counted retry backoff; ``deadline`` is absolute monotonic."""
+    step-counted retry backoff; ``deadline`` is absolute monotonic;
+    ``held_on`` is the radix node admission last passed the entry over
+    for (a deeper prefix hit is on its way there), ``held_steps`` how
+    often it has."""
 
     rid: int
     req: Request
@@ -218,6 +226,8 @@ class _QueueEntry:
     queued_steps: int = 0
     deadline: float | None = None
     slo_deadline: float | None = None    # enqueue + slo_s (EDF policy)
+    held_on: "RadixNode | None" = None
+    held_steps: int = 0
 
 
 @dataclasses.dataclass
@@ -246,6 +256,12 @@ class _Slot:
     # (block index, entry) of the snapshot entries this row's prefill has
     # yet to write (models/paged.py, the snapshot budget)
     snap_pending: list[tuple] = dataclasses.field(default_factory=list)
+    # per full block of the prompt, the radix node this row reserved at its
+    # admission and flips when the chunk that fills it is dispatched (None:
+    # the path had one); the first n_indexed are done
+    nodes: "list[RadixNode | None]" = dataclasses.field(
+        default_factory=list)
+    n_indexed: int = 0
 
 
 class ServeEngine:
@@ -1133,7 +1149,9 @@ class ServeEngine:
                 f"  queued rid={e.rid} prompt={len(e.req.prompt)} "
                 f"prior={len(e.prior)} need={self._need_blocks(e.req)} "
                 f"retries={e.retries} wait={e.wait_steps} "
-                f"queued_steps={e.queued_steps}")
+                f"queued_steps={e.queued_steps} held_steps={e.held_steps}"
+                + ("" if e.held_on is None
+                   else f" held_on=block {e.held_on.block}"))
         for i, s in enumerate(self._slots):
             lines.append(
                 f"  slot {i}: {s.state}" + (
@@ -1349,6 +1367,25 @@ class ServeEngine:
                 still.append((i, entry))
         s.snap_pending = still
 
+    def _index_written(self, s: _Slot, length: int) -> None:
+        """The row's prefill is dispatched up to ``length``: the blocks it
+        fills join the radix index, hits from here on for whatever is
+        dispatched later (after ``_commit_snapshots``: a block's entry is
+        its own before the block can be matched)."""
+        upto = length // self.block_size
+        for i in range(s.n_indexed, upto):
+            if s.nodes[i] is not None:
+                self.prefix.written(s.nodes[i], s.blocks[i])
+        s.n_indexed = upto
+
+    def _hold(self, e: _QueueEntry) -> None:
+        """Pass a candidate over for this step: a live row is writing what
+        it would otherwise prefill again."""
+        if e.held_steps == 0:
+            self.metrics.counter("prefix.admissions_held").inc()
+        e.held_steps += 1
+        self.metrics.counter("prefix.held_steps").inc()
+
     def _admit_entry(self, e: _QueueEntry, slot: int,
                      hit: list[int] | None = None,
                      matched: list[int] | None = None) -> None:
@@ -1378,6 +1415,9 @@ class ServeEngine:
             snaps = self._grant_snapshots(s, blocks, len(hit),
                                           matched or hit, L)
         self._map_row(slot, row, base, snaps)
+        if self.prefix is not None:
+            s.nodes = self.prefix.reserve(prompt, blocks)
+            s.n_indexed = len(hit)
         rem = L - base                    # tokens still to prefill (>= 1)
         n_win = -(-rem // self.chunk)
         padded = np.zeros((1, n_win * self.chunk), np.int32)
@@ -1440,7 +1480,14 @@ class ServeEngine:
         candidate first longest-prefix-matches (``serve.cache`` faults
         quarantine to that request alone — shared blocks are untouched)
         and zero-ref cached blocks are evicted LRU-leaf-first to cover
-        any shortfall before the head counts as starved.  Returns
+        any shortfall before the head counts as starved.  A candidate
+        whose prompt goes on into blocks that a live row is admitted to
+        write and has not (or, under a snapshot budget, whose matched
+        block has an entry granted and not committed) is **held**: passed
+        over for this step as one in backoff is, with no slot, no block
+        and no part in the starvation count, and looked at again the next
+        — the hit is a few chunks away, or its writer is freed and the
+        node it waits on goes.  Returns
         ``(admitted, starved_need)`` — the NEW block count the stalled
         head needs (its cache hit already discounted), or None when
         nothing block-starved."""
@@ -1452,8 +1499,13 @@ class ServeEngine:
                 return admitted, None
             if e.wait_steps > 0:          # admit-retry backoff
                 continue
+            if e.held_on is not None:
+                if self.prefix.on_its_way(e.held_on):
+                    self._hold(e)
+                    continue
+                e.held_on = None
             need = self._need_blocks(e.req)
-            hit: list[int] = []
+            hit: list[int] | None = []
             if self.prefix is not None:
                 try:
                     self.faults.check("serve.cache", key=e.rid)
@@ -1473,6 +1525,10 @@ class ServeEngine:
                         e.wait_steps = 2 ** e.retries
                         self._bump_counter("retries")
                         self._event("retry", -1, e.rid)
+                    continue
+                if hit is None:           # a deeper hit is on its way
+                    e.held_on = self.prefix.awaited
+                    self._hold(e)
                     continue
                 short = (need - len(hit)) - self.pool.free_count()
                 if short > 0:             # cache evicts before rows do
@@ -1514,17 +1570,23 @@ class ServeEngine:
 
     def _release_row_blocks(self, s: _Slot, *, register: bool) -> None:
         """Drop a retiring row's block references.  With the prefix
-        cache on and ``register`` set (OK retirement or a requeue whose
-        KV is known-good), the row's fully written blocks first join
-        the radix index — release-to-cache — so zero-ref blocks park in
-        LRU order instead of freeing; otherwise (cache off, or a FAILED
-        / expired row whose frontier is not trusted) references drop
-        straight back toward the free list, in the classic order."""
+        cache on the blocks its dispatched chunks filled are indexed
+        already (``_index_written``); the nodes of those it has not
+        written leave the tree, and with ``register`` set (OK retirement
+        or a requeue whose KV is known-good) the rest of its fully written
+        blocks, the answer's, join the index first — release-to-cache — so
+        zero-ref blocks park in LRU order instead of freeing.  Without
+        ``register`` (a FAILED / expired row whose frontier is not
+        trusted) nothing more is indexed; what is not indexed (all of it
+        with the cache off) drops straight back toward the free list, in
+        the classic order."""
         for _, entry in s.snap_pending:   # writes that will not come
             self.snaps.cancel(entry)
-        if self.prefix is not None and register and s.req is not None:
-            toks = (list(s.req.prompt) + list(s.prior) + list(s.out))
-            self.prefix.insert(toks, s.blocks, s.true_len + len(s.out))
+        if self.prefix is not None:
+            self.prefix.forget(s.nodes[s.n_indexed:])
+            if register and s.req is not None:
+                toks = (list(s.req.prompt) + list(s.prior) + list(s.out))
+                self.prefix.insert(toks, s.blocks, self._row_length(s))
         for b in reversed(s.blocks):
             self.pool.decref(b)
 
@@ -1898,6 +1960,8 @@ class ServeEngine:
                                      h2d_bytes=toks[i].nbytes + 12)
             s.w_done += 1
             self._commit_snapshots(s, int(new_len[i]))
+            if self.prefix is not None:
+                self._index_written(s, int(new_len[i]))
             if tr is not None:
                 if tr.trace_id is not None:
                     self._emit_chunk_span(tr, t_chunk, t_done)

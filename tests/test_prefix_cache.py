@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from horovod_tpu import metrics as metrics_mod
 from horovod_tpu.faults import FaultRegistry, PermanentFault
 from horovod_tpu.models import llama
 from horovod_tpu.models.llama import BlockPool
@@ -172,6 +173,14 @@ def test_key_digest_summary_and_concurrent_walk_fallback(monkeypatch):
     assert not summary["truncated"]
     assert set(summary["paths"]) == set(chunk_path_digests(toks, 2))
 
+    # A second walk hashes nothing again: each node keeps its path's hash.
+    import horovod_tpu.prefix_cache as prefix_cache_mod
+    hashed = []
+    with monkeypatch.context() as m:
+        m.setattr(prefix_cache_mod, "_update_chunk",
+                  lambda h, chunk: hashed.append(chunk))
+        assert cache.key_digest() == summary and hashed == []
+
     # One mutation mid-walk: the retry succeeds transparently.
     real_walk = cache._key_digest_walk
     calls = {"n": 0}
@@ -198,6 +207,85 @@ def test_key_digest_summary_and_concurrent_walk_fallback(monkeypatch):
     empty = cold.key_digest()
     assert empty["n_paths"] == 0 and empty["paths"] == []
     assert not empty["truncated"]
+
+
+def _row(pool, n):
+    blocks = [pool.alloc() for _ in range(n)]
+    for b in blocks:
+        pool.incref(b)
+    return blocks
+
+
+def test_unwritten_nodes_are_waited_for_not_hit():
+    """A row admitted to write a path reserves it: ``acquire`` stops there
+    and gives nothing, the nodes are not advertised, and each becomes a hit
+    when it is flipped; a row that leaves first takes the rest with it."""
+    pool = BlockPool(12)
+    reg = metrics_mod.MetricsRegistry(event_log=None)
+    cache = RadixPrefixCache(pool, block_size=2, metrics=reg)
+    toks = [5, 6, 7, 8, 9, 10, 11]
+    writer = _row(pool, 4)
+    nodes = cache.reserve(toks, writer)
+    assert [n.block for n in nodes] == writer[:3]
+    assert cache.indexed_blocks() == 0 and cache.key_digest()["n_paths"] == 0
+    assert cache.path_blocks(toks) == []
+    cache.check_consistency()
+    # a second bearer of the first two blocks: nothing now, more later
+    assert cache.acquire([5, 6, 7, 8, 1]) is None
+    assert cache.awaited is nodes[0] and cache.on_its_way(nodes[0])
+    assert cache.stats["hits"] == 0 and cache.stats["misses"] == 0
+    # ... nor does it reserve anything below a node that is another row's
+    late = _row(pool, 3)
+    assert cache.reserve([5, 6, 7, 8, 1, 2], late) == [None] * 3
+    cache.release(late)
+    # the first block's chunk is dispatched: a hit, and the walk stops at
+    # the second
+    cache.written(nodes[0], writer[0])
+    assert not cache.on_its_way(nodes[0]) and writer[0] in cache
+    assert cache.key_digest()["paths"] == chunk_path_digests(toks, 2)[:1]
+    assert cache.acquire([5, 6, 7, 8, 1]) is None
+    assert cache.awaited is nodes[1]
+    # a prompt that leaves the path there is served what there is
+    assert cache.acquire([5, 6, 1, 2]) == writer[:1]
+    cache.release(writer[:1])
+    assert reg.counter("prefix.blocks_indexed_live").value == 1
+    assert cache.stats["inserted_blocks"] == 1
+    cache.check_consistency()
+    # the writer is freed with two blocks unwritten: they leave the tree,
+    # the written one parks, and the waiting prompt gets its one block
+    cache.forget(nodes[1:])
+    cache.release(reversed(writer))
+    assert not cache.on_its_way(nodes[1]) and nodes[2].parent is None
+    assert pool.cached_count() == 1 and pool.free_count() == 10
+    cache.check_consistency()
+    assert cache.acquire([5, 6, 7, 8, 1]) == writer[:1]
+    cache.release(writer[:1])
+    cache.check_consistency()
+
+
+def test_a_retiring_row_takes_an_unwritten_node_over():
+    """A prompt that ends on a block boundary is admitted beside the row
+    that is to write that block (its match is capped short of it) and writes
+    a copy of its own.  If it retires first, the node is its copy's, and the
+    reserved block is the duplicate when its chunk comes."""
+    pool = BlockPool(12)
+    cache = RadixPrefixCache(pool, block_size=2)
+    slow = _row(pool, 3)
+    nodes = cache.reserve([5, 6, 7, 8, 9], slow)
+    cache.written(nodes[0], slow[0])
+    assert cache.acquire([5, 6, 7, 8]) == slow[:1]      # capped: not held
+    quick = slow[:1] + _row(pool, 2)
+    assert cache.reserve([5, 6, 7, 8], quick) == [None, None]
+    assert cache.insert([5, 6, 7, 8, 1, 2], quick, frontier=6) == 2
+    assert cache.path_blocks([5, 6, 7, 8, 1, 2]) == quick
+    cache.release(reversed(quick))
+    cache.check_consistency()
+    cache.written(nodes[1], slow[1])        # the duplicate: nothing changes
+    assert cache.path_blocks([5, 6, 7, 8]) == quick[:2]
+    cache.forget(nodes)                     # nothing of it is unwritten
+    cache.release(reversed(slow))
+    assert pool.cached_count() == 3 and pool.free_count() == 8
+    cache.check_consistency()
 
 
 # -- engine integration ------------------------------------------------------
@@ -397,3 +485,129 @@ def test_timeline_prefix_counters(world, tmp_path):
         "hits", "blocks_reused", "tokens_skipped", "evictions"}
     assert prefix_events[-1]["args"] == eng.prefix_counters
     assert prefix_events[-1]["args"]["hits"] > 0
+
+
+# -- registered at dispatch, waited for at admission -------------------------
+
+SHARED = [5, 17, 42, 9, 3, 8, 11, 2]            # two blocks of four
+
+
+def _batch(n):
+    return [Request(prompt=SHARED + [20 + i, 40 + i, 60 + i][:1 + i % 3],
+                    max_new_tokens=4) for i in range(n)]
+
+
+def _engine(params, cfg, n_slots, **kw):
+    return ServeEngine(params, cfg, n_slots=n_slots, max_len=24, chunk=4,
+                       prefix_cache=True,
+                       metrics=metrics_mod.MetricsRegistry(event_log=None),
+                       **kw)
+
+
+def _assert_solo(params, cfg, reqs, results):
+    for req, res in zip(reqs, results):
+        assert res.status == OK
+        np.testing.assert_array_equal(
+            np.asarray(list(res), np.int64),
+            _solo(params, cfg, req.prompt, req.max_new_tokens, 24))
+
+
+def _prefix_counters(eng):
+    c = eng.metrics.snapshot()["counters"]
+    return {k[len("prefix."):]: v for k, v in c.items()
+            if k.startswith("prefix.")}
+
+
+def test_a_batch_over_one_prefix_prefills_it_once(world, monkeypatch):
+    """N requests over one prefix of two blocks handed over at once to N
+    slots: one prefills the prefix, the others are passed over until its
+    second chunk is dispatched and then hit both blocks while it still
+    runs; every request's tokens are its cache-off run's."""
+    cfg, params = world
+    monkeypatch.setenv("HVD_TPU_VERIFY_BLOCKS", "1")
+    n = 4
+    reqs = _batch(n)
+    eng = _engine(params, cfg, n)
+    ids = [eng.submit(r) for r in reqs]
+    eng.step()
+    assert [s.request_id for s in eng._slots] == [ids[0], -1, -1, -1]
+    eng.step()                              # the second shared block's chunk
+    assert eng._slots[0].state == "prefill" and len(eng.events) == 1
+    eng.step()
+    assert [s.n_hit for s in eng._slots] == [0, 2, 2, 2]
+    assert eng.pool.refcount(eng._slots[0].blocks[1]) == n
+    while eng.pending():
+        eng.step()
+    _assert_solo(params, cfg, reqs, [eng.results[i] for i in ids])
+    c = _prefix_counters(eng)
+    assert c["tokens_skipped"] == (n - 1) * len(SHARED)
+    assert c["admissions_held"] == n - 1 and c["held_steps"] == 2 * (n - 1)
+    assert c["blocks_indexed_live"] == 2 and c["hits"] == n - 1
+    _assert_drained_consistent(eng)
+
+
+@pytest.mark.parametrize("how", ["cancel", "fail", "preempt"])
+def test_the_bearer_leaves_before_it_has_written_the_prefix(world, how,
+                                                            monkeypatch):
+    """The row the others wait for goes after its first chunk, with the
+    second shared block unwritten: cancelled, failed by a ``serve.prefill``
+    fault, or taken off its slot for replay.  The node they waited on goes
+    with it, so the next step admits the first of them on the one block that
+    was written, the others wait for that one, and nothing leaks."""
+    cfg, params = world
+    monkeypatch.setenv("HVD_TPU_VERIFY_BLOCKS", "1")
+    reg = FaultRegistry()
+    reqs = _batch(3)
+    eng = _engine(params, cfg, 3, faults=reg)
+    ids = [eng.submit(r) for r in reqs]
+    if how == "fail":
+        reg.inject("serve.prefill", on_hit=2, permanent=True, key=ids[0])
+    eng.step()
+    assert eng._slots[0].request_id == ids[0]
+    if how == "cancel":
+        assert eng.cancel(ids[0])
+    elif how == "preempt":
+        eng._preempt_row(0)
+    else:
+        eng.step()                          # the fault takes its second chunk
+        assert eng.results[ids[0]].status == FAILED
+    assert all(e.held_on is not None and not eng.prefix.on_its_way(e.held_on)
+               for e in eng._queue if e.rid != ids[0])
+    eng.step()
+    bearer = next(s for s in eng._slots if s.request_id == ids[1])
+    assert bearer.n_hit == 1                # the block the first one wrote
+    assert ids[2] not in [s.request_id for s in eng._slots]
+    while eng.pending():
+        eng.step()
+    served = [i for i in range(3) if how == "preempt" or i > 0]
+    _assert_solo(params, cfg, [reqs[i] for i in served],
+                 [eng.results[ids[i]] for i in served])
+    c = _prefix_counters(eng)
+    # the replayed bearer waits in its turn; a candidate counts once
+    assert c["admissions_held"] == (3 if how == "preempt" else 2)
+    assert c["tokens_skipped"] == 4 + 8 + (8 if how == "preempt" else 0)
+    _assert_drained_consistent(eng)
+
+
+def test_a_failed_row_leaves_the_blocks_its_chunks_filled_as_hits(world):
+    """A row that fails in decode registers nothing at its retirement, but
+    its prompt's blocks were indexed as their chunks were dispatched, each
+    without a fault: they stay, and the next bearer of the prompt hits them
+    and serves its cache-off tokens."""
+    cfg, params = world
+    reg = FaultRegistry()
+    eng = _engine(params, cfg, 2, faults=reg)
+    doomed = Request(prompt=SHARED + [7, 13], max_new_tokens=8)
+    rid = eng.submit(doomed)
+    reg.inject("serve.tick", on_hit=2, permanent=True, key=rid)
+    while eng.pending():
+        eng.step()
+    assert eng.results[rid].status == FAILED
+    assert eng.prefix.path_blocks(doomed.prompt) != [] \
+        and eng.prefix.indexed_blocks() == 2    # none of the answer's
+    assert eng.cached_block_count() == 2
+    nxt = Request(prompt=SHARED + [7, 60], max_new_tokens=5)
+    out = eng.run([nxt])
+    _assert_solo(params, cfg, [nxt], out)
+    assert eng.prefix_counters["tokens_skipped"] == len(SHARED)
+    _assert_drained_consistent(eng)

@@ -395,6 +395,59 @@ def test_step_rows_tile_and_host_bound_is_bounded(world):
         <= counters["serve.steps"] == eng.step_index
 
 
+def _held_world(params, cfg, second_new=4, **kw):
+    """An engine of three slots and, handed over at once, two bearers of one
+    prefix of two blocks with a request of another prefix behind them."""
+    shared = [5, 17, 42, 9, 3, 8, 11, 2]
+    reqs = [Request(prompt=shared + [21], max_new_tokens=4),
+            Request(prompt=shared + [22, 23], max_new_tokens=second_new),
+            Request(prompt=[90, 91, 92, 93, 94, 95], max_new_tokens=4)]
+    eng = ServeEngine(params, cfg, n_slots=3, max_len=24, chunk=4,
+                      prefix_cache=True, **kw)
+    return eng, reqs, [eng.submit(r) for r in reqs]
+
+
+def test_a_held_candidate_is_skipped_not_a_head_of_line(world):
+    """The second bearer of a prefix the first is still prefilling is passed
+    over; the request behind it, of another prefix, is admitted in the same
+    step, and every request is served its solo tokens."""
+    cfg, params = world
+    eng, reqs, ids = _held_world(params, cfg)
+    eng.step()
+    assert [s.request_id for s in eng._slots] == [ids[0], ids[2], -1]
+    assert [e.rid for e in eng._queue] == [ids[1]]
+    assert eng._queue[0].held_steps == 1 and eng._queue[0].held_on is not None
+    assert "held_on=block" in eng.state_dump()
+    eng.step()
+    eng.step()
+    assert eng._slots[2].request_id == ids[1] and eng._slots[2].n_hit == 2
+    while eng.pending():
+        eng.step()
+    _assert_parity(params, cfg, reqs, [eng.results[i] for i in ids], 24)
+
+
+def test_a_held_candidate_is_not_block_starved(world):
+    """A pool that backs the first bearer and two blocks more, and a trigger
+    that preempts at the first starved step: while the second bearer is held
+    it is not starved (it asks for no block), so the first is left to write
+    the prefix.  Once that is a hit the second is one block short of the
+    three it still needs, and the trigger is its to pull."""
+    cfg, params = world
+    eng, reqs, ids = _held_world(params, cfg, second_new=8, n_blocks=7,
+                                 preempt_after=1)
+    eng.cancel(ids[2])
+    for _ in range(2):
+        eng.step()
+        assert eng._starve_steps == 0 and eng.counters["preemptions"] == 0
+    assert eng._queue[0].held_steps == 2
+    eng.step()
+    assert eng._starve_steps == 1 or eng.counters["preemptions"] == 1
+    while eng.pending():
+        eng.step()
+    _assert_parity(params, cfg, reqs[:2], [eng.results[i] for i in ids[:2]],
+                   24)
+
+
 @pytest.mark.slow
 def test_randomized_soak_parity(world):
     """Soak: random prompts/budgets/submission times over a small pool;

@@ -179,9 +179,30 @@ def test_cancel_mid_prefill_frees_every_block_and_the_slot_serves_on(served):
     while eng.pending():
         eng.step()
     assert eng.results[rid].status == "CANCELLED"
-    assert eng.free_block_count() == eng.pool.n_blocks - 1
+    # the block its one dispatched chunk filled stays indexed, the rest free
+    assert eng.cached_block_count() == 1
+    assert eng.free_block_count() == eng.pool.n_blocks - 2
     # the slot's stale state is not the next row's: mapped at 0 it is zeros
     assert [list(r) for r in eng.run(_requests(prompts))] == want
+
+
+def test_a_batch_over_one_template_prefills_it_once(served):
+    """The template's three bearers handed over at once to three slots: one
+    prefills the template's two blocks while the other two are held, and
+    these are admitted on a hit as soon as the second chunk is dispatched,
+    their convolution state restored from the snapshot that chunk wrote."""
+    mc, params, prompts, want = served
+    eng = _engine(mc, params, n_slots=3, prefix_cache=True)
+    out = eng.run(_requests([prompts[0], prompts[2], prompts[3]]))
+    assert [list(r) for r in out] == [want[0], want[2], want[3]]
+    c = _counters(eng)
+    assert c["prefix.tokens_skipped"] == 2 * 16
+    assert c["prefix.admissions_held"] == 2 and c["prefix.held_steps"] == 4
+    assert c["conv.state_restores"] == 2
+    # the first bearer's three full blocks, indexed as their chunks went
+    assert c["prefix.blocks_indexed_live"] == 2 + sum(
+        (len(p) - 16) // 8 for p in (prompts[0], prompts[2], prompts[3]))
+    eng._check_block_invariants()
 
 
 def test_tensor_parallel_serving_is_refused_clearly(served):

@@ -270,32 +270,66 @@ def test_a_match_evicted_at_its_own_admission_is_granted_no_entry():
 
 
 def test_at_insert_an_entry_follows_the_block_that_stays():
-    """Two rows with one prompt side by side and a budget of one: the first
-    holds the entry at its own copy of the prompt's last full block, the
-    second retires first and its copies are the ones indexed; when the first
-    retires its copies are duplicates, and the entry moves to the block that
-    stays.  A third request then hits there."""
-    _, mc, params = tiny(snapshots=1)
-    p = tokens(19, seed=31)
-    eng = _engine(mc, params, prefix_cache=True)
-    first = eng.submit(Request(prompt=p, max_new_tokens=N_NEW))
-    second = eng.submit(Request(prompt=p, max_new_tokens=2))
-    eng.step()
+    """One prompt of exactly two blocks, twice, under a budget of two.  The
+    second bearer's match is capped a token short, so it recomputes the
+    prompt's last block into a copy of its own and is given, for that copy,
+    the entry the first bearer's block held (nothing was ever restored from
+    it).  When it retires its copy is the duplicate, and the entry moves
+    back to the block that stays.  A third request then hits there."""
+    _, mc, params = tiny(snapshots=2)
+    p = tokens(16, seed=31)
+    eng = _engine(mc, params, n_slots=1, prefix_cache=True)
+    eng.run(_requests([p], n_new=2))
+    first, stays = eng.prefix.path_blocks(p + [0])
+    assert eng.snaps.entry(stays) is not None
+    eng.submit(Request(prompt=p, max_new_tokens=2))
+    eng.step()                              # its first chunk: one block
     own = eng._slots[0].blocks[1]
-    assert eng._slots[0].snap_pending and not eng._slots[1].snap_pending
-    while second not in eng.results:
-        eng.step()
-    stays = eng.prefix.path_blocks(p + [0])[1]
-    assert stays != own and eng.snaps.entry(own) is not None
-    assert eng.snaps.entry(stays) is None
+    assert own != stays and eng._slots[0].n_hit == 0
+    # the matched block's entry (on evidence) is written; the copy's is to be
+    assert eng.snaps.entry(first) is not None
+    assert [eng.snaps.pending_block(e) for _, e in
+            eng._slots[0].snap_pending] == [own]
+    eng.step()
+    assert eng.snaps.entry(own) is not None and eng.snaps.entry(stays) is None
     while eng.pending():
         eng.step()
+    assert eng.prefix.path_blocks(p + [0])[1] == stays
     assert eng.snaps.entry(stays) is not None and eng.snaps.entry(own) is None
     p3 = p + tokens(4, seed=32)
     out = eng.run(_requests([p3]))
     assert list(out[0]) == sm.generate(params, mc, p3, N_NEW, pad_to=48)
     assert _counters(eng)["ssm.state_restores"] == 1
     eng._check_block_invariants()
+
+
+def test_a_batch_over_one_prefix_computes_it_twice_under_a_budget(served):
+    """The three bearers of the system prompt handed over at once, under a
+    budget of two entries.  The first prefills alone while the others are
+    held (it is admitted to write what they would); it has no evidence that
+    the prefix is shared, so its one entry is at its own prompt's end.  The
+    second matches the two blocks, finds no snapshot, recomputes them and is
+    given the entry for the block that is indexed; the third is held for
+    that entry and restored from it once it is committed."""
+    _, mc, params = tiny(snapshots=2)
+    _, _, prompts, want = served
+    eng = _engine(mc, params, n_slots=3, prefix_cache=True)
+    rids = [eng.submit(r) for r in _requests(
+        [prompts[0], prompts[2], prompts[3]])]
+    admitted_at = {}
+    while eng.pending():
+        eng.step()
+        eng._check_block_invariants()
+        for s in eng._slots:
+            admitted_at.setdefault(s.request_id, (eng.step_index, s.n_hit))
+    # two chunks for the first bearer's two blocks, two for the second's
+    assert [admitted_at[r] for r in rids] == [(1, 0), (3, 0), (5, 2)]
+    assert [list(eng.results[r]) for r in rids] == [want[0], want[2], want[3]]
+    c = _counters(eng)
+    assert c["prefix.admissions_held"] == 2 and c["prefix.held_steps"] == 6
+    assert c["prefix.tokens_skipped"] == 16 and c["ssm.state_restores"] == 1
+    assert (c["prefix.blocks_matched"], c["prefix.blocks_restored"]) == (4, 2)
+    assert c["ssm.snapshots_evicted"] == 0
 
 
 def test_preemption_and_replay_serve_the_same_tokens(served):
@@ -396,7 +430,9 @@ def test_cancel_mid_prefill_frees_every_block_and_entry(served):
     while eng.pending():
         eng.step()
     assert eng.results[rid].status == "CANCELLED"
-    assert eng.free_block_count() == eng.pool.n_blocks - 1
+    # the block its one dispatched chunk filled stays indexed, the rest free
+    assert eng.cached_block_count() == 1
+    assert eng.free_block_count() == eng.pool.n_blocks - 2
     assert eng.snaps.pending_count() == 0 and eng.snaps.held_count() == 0
     # the slot's stale state is not the next row's: mapped at 0 it is zeros
     assert [list(r) for r in eng.run(_requests(prompts))] == want

@@ -507,7 +507,9 @@ def test_cancel_mid_prefill_frees_every_block_and_the_slot_serves_on(served):
     while eng.pending():
         eng.step()
     assert eng.results[rid].status == "CANCELLED"
-    assert eng.free_block_count() == eng.pool.n_blocks - 1
+    # the block its one dispatched chunk filled stays indexed, the rest free
+    assert eng.cached_block_count() == 1
+    assert eng.free_block_count() == eng.pool.n_blocks - 2
     # the slot's stale ring is not the next row's: mapped at 0 it is zeros
     assert [list(r) for r in eng.run(_requests(prompts))] == want
 
