@@ -36,6 +36,21 @@ import pytest  # noqa: E402
 
 import horovod_tpu as hvd  # noqa: E402
 from horovod_tpu.parallel.flash_attention import interpret_mode  # noqa: E402
+from horovod_tpu.utils.env import compile_cache_dir  # noqa: E402
+
+# One persistent compilation cache for the suite, where chip_smoke.py and
+# benchmark/run.py keep theirs: a case builds its own engine, and its
+# programs are the ones twenty cases before it lowered.  Small programs are
+# kept too (most of the suite's are).  The settings go into the environment
+# under jax's own names as well, so the processes that tests start
+# (tests/multiprocess_*_worker.py) read them as they import jax.
+for _name, _value in (
+        ("jax_compilation_cache_dir", compile_cache_dir(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))))),
+        ("jax_persistent_cache_min_compile_time_secs", 0.0),
+        ("jax_persistent_cache_min_entry_size_bytes", -1)):
+    jax.config.update(_name, _value)
+    os.environ[_name.upper()] = str(_value)
 
 
 @pytest.fixture(scope="session", autouse=True)
@@ -77,6 +92,20 @@ def _ensure_world(_hvd_world):
             hvd.shutdown()
         hvd.init()
     yield
+
+
+@pytest.fixture
+def no_compile_cache():
+    """The persistent compilation cache off around one test: for a case
+    whose outcome hangs on a compilation being made, or on the time one
+    takes."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
 
 
 class HostTrace:

@@ -646,13 +646,19 @@ def test_chaos_campaign_smoke(world):
     assert report["schedule"] == again.to_json()
 
 
-def test_chaos_campaign_alert_oracle(world):
+def test_chaos_campaign_alert_oracle(world, no_compile_cache):
     """The health-plane acceptance campaign: a consecutive-prefill
     fault rule exhausts retry budgets (FAILED requests -> goodput
     dip) on a single-replica fleet with one kill.  replica_death and
     goodput_burn_fast must FIRE during the storm and RESOLVE after
     heal + recovery traffic — by the alerts_covered oracle and by
-    name."""
+    name.
+
+    Without the compilation cache: ``replica_death`` is a counter's
+    delta, which needs a sample from before the kill, and the campaign
+    takes its first sample while its first wave waits for the fleet's
+    programs to compile.  Served from the cache the wave is over, kill
+    included, before the sampler's first poll (ROADMAP.md, D14)."""
     cfg, params = world
     report = run_campaign(
         params, cfg, seed=7, n_replicas=1, n_kills=1,

@@ -47,7 +47,7 @@ def _requests(prompts):
 def served():
     """The tiny model, four prompts of which three share their first two
     blocks (a template), and each prompt's solo tokens with no cache."""
-    import test_shortconv_moe as t
+    import toy_shortconv_moe as t
 
     _, mc, params = t.tiny()
     template = tokens(16, seed=3)

@@ -1,6 +1,6 @@
 """models/state_space_moe.py behind ``ServeEngine``, at a tiny size on the CPU
 with seeded weights (the helpers and the tiny configuration are
-``tests/test_state_space_moe.py``'s): the engine's logits against the
+``tests/toy_state_space_moe.py``'s): the engine's logits against the
 reference, cache-free generation, the snapshot budget's rules (a prefix learnt
 at its second bearer and hit from its third, a hit rounded down to the deepest
 block that holds a snapshot, entries and blocks evicted apart and an evicted
@@ -18,8 +18,8 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from test_state_space_moe import (ATOL, N_LAYERS, TINY,  # noqa: E402
-                                  reference_logits, tiny, tokens)
+from toy_state_space_moe import (ATOL, N_LAYERS, TINY,  # noqa: E402
+                                 reference_logits, tiny, tokens)
 
 from horovod_tpu import metrics as metrics_mod  # noqa: E402
 from horovod_tpu import supervisor  # noqa: E402
