@@ -203,6 +203,7 @@ METRIC_HELP: dict[str, str] = {
     "serve.phase.device_sync_compute_est_s": "Device-sync sub-phase: cost-model-predicted device compute share (estimate, host clock, not on the device trace)",
     "serve.phase.device_sync_host_stall_s": "Device-sync sub-phase: readback wait beyond predicted device time (estimate, host clock, not on the device trace)",
     "serve.phase.verify_s": "Tick phase: acceptance + token emission (spec engines)",
+    "serve.phase.unmask_s": "Tick phase: dispatch of the unmask program in front of a block tick (engines whose model decodes a block a row)",
     "serve.phase.sample_postprocess_s": "Tick phase: per-slot token handling and retirement",
     "serve.phase.bookkeeping_s": "Tick phase: counters, gauges, sentry, watchdog",
     "serve.phase.tick_s": "Whole engine step wall time as the profiler measures it",
@@ -265,6 +266,14 @@ METRIC_HELP: dict[str, str] = {
     # ring per slot and its snapshot per block, what the live rows hold,
     # and the device-side counters (as above: <name>.device beside each)
     "moe.load_max": "Token-choices of the busiest held expert since start",
+    # diffusion.* — a model that generates by diffusion over blocks (ServeEngine.block)
+    "diffusion.denoise_forwards": "Row-forwards of block ticks that denoised: a decoding row's block went through a tick that did not commit it",
+    "diffusion.commit_forwards": "Row-forwards of block ticks that committed, which is the blocks committed: the row's block had come clean (by the ids the host read) and the tick stored its keys and advanced its length",
+    "diffusion.tokens_unmasked": "Positions the unmask program unmasked (given positions of a prompt's tail apart)",
+    "diffusion.unmasked_by_threshold": "Positions unmasked because their confidence cleared the threshold (low_confidence_dynamic)",
+    "diffusion.unmasked_by_schedule": "Positions unmasked as the step's n_s most confident (the schedule's floor)",
+    "diffusion.blocks_redone": "Blocks in flight dropped when their row was preempted or replayed: denoised again after the replay",
+    "diffusion.blocks_committed.device": "The device's own count of rows whose length a block tick advanced, as last read (what diffusion.commit_forwards has to come to)",
     "kv.tokens_live": "Positions the slots hold, as the last decode tick left them",
     "kv.full_bytes_live": "Pool bytes (keys and values of the full layers) of the blocks the live rows' tables map",
     "kv.window_bytes_live": "Bytes the live rows hold for their sliding layers: a ring a row and a snapshot a mapped block, whatever their lengths",

@@ -980,14 +980,26 @@ class TileWalk(NamedTuple):
 
 
 def tile_walk(table: jax.Array, qpos: jax.Array, bs: int,
-              active: jax.Array | None = None) -> TileWalk:
+              active: jax.Array | None = None, *,
+              span: int = 1) -> TileWalk:
     """The walk of a program whose queries stand at ``qpos`` [B, T] under
     block tables ``table`` [B, per] of ``bs``-position blocks, computed once
     for all its layers.  ``active`` [B] marks the rows whose outputs are read
     (default: all).  A row that is not walks one tile whatever its length: a
     free slot, a row still prefilling, a row held in place.  Every query
     still sees key 0, so its softmax has a real maximum and a sum above
-    zero; its output is of a prefix of its keys, and nobody reads it."""
+    zero; its output is of a prefix of its keys, and nobody reads it.
+
+    ``span`` is the mask inside the program's own tokens: 1 is causal (a
+    query sees the keys up to its own position); ``span`` > 1 is
+    **block-causal** over spans of that many positions, ``key_pos // span <=
+    query_pos // span``: a query sees every earlier span and the whole of its
+    own, in both directions (a model that denoises a span at a time).  The
+    walk stores, in place of a query's position, the last position it sees,
+    which is all :func:`paged_attend_tiles` asks of it; ``span`` has to
+    divide a key tile, so the tiles a row walks are the same."""
+    if span > 1:        # a causal program is traced as it ever was
+        qpos = (qpos // span + 1) * span - 1
     b, per = table.shape
     g = _tile_blocks(bs, per)               # blocks a key tile spans
     n_tiles = -(-per // g)

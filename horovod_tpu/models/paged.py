@@ -4,8 +4,8 @@ ServeEngine` asks of a model, and which module answers for a config.
 The engine names no model.  A model is a module with these functions, which
 :mod:`horovod_tpu.models.llama` has as it stands and
 :mod:`horovod_tpu.models.latent_moe`, :mod:`horovod_tpu.models.shortconv_moe`,
-:mod:`horovod_tpu.models.window_moe` and
-:mod:`horovod_tpu.models.state_space_moe` implement:
+:mod:`horovod_tpu.models.window_moe`, :mod:`horovod_tpu.models.state_space_moe`
+and :mod:`horovod_tpu.models.block_diffusion_moe` implement:
 
 * ``init_paged_cache(cfg, n_slots, max_len, *, block_size, n_blocks)`` — the
   paged state: a NamedTuple of device arrays with ``block_table``
@@ -57,6 +57,36 @@ The engine names no model.  A model is a module with these functions, which
   programs)`` — the model's own gauges and counters into the engine's
   registry: at construction, and after every step that dispatched a program
   (``programs``: a :class:`Dispatched` each).
+
+**A step that yields a block a row.**  A model that generates by diffusion
+over blocks (``block_diffusion_moe``) decodes, in place of one token a row a
+tick, a *block* of ``B`` positions a row, some of which are still the mask id:
+a tick denoises the block, a small program unmasks the most confident
+positions, and only a block with no mask left is committed.  Such a model
+answers three more entries, and the engine then runs its block loop
+(``docs/inference.md``, "Serving a model that generates by diffusion over
+blocks") in place of ``decode_chunk_paged`` / ``spec_verify_paged``, which it
+need not have:
+
+* ``block_length(cfg)`` — ``B``, and the engine's sign that a row decodes a
+  block.  A row's length is then a whole number of blocks: a prompt's
+  trailing ``L mod B`` tokens are not prefilled but given as the first
+  positions of the first generated block;
+* ``decode_block_paged(params, block_tokens, cfg, pcache, *, active,
+  commit)`` — the block tick: ``block_tokens`` [n_slots, B] stand at
+  ``[length, length + B)`` of every row under the block-causal mask
+  (:func:`llama.tile_walk`'s ``span``), their keys are written *past the
+  length* and rewritten every tick, ``length += B`` where ``commit``
+  [n_slots]; returns the block's logits ``[n_slots, B, V]``;
+* ``unmask(cfg, logits, block_tokens, step)`` — the sampler's rule on the
+  device, the program in ``_sample``'s place: ``(block_tokens, left,
+  by_threshold)``, the new ids, how many are still masked and how many the
+  confidence threshold (and not the schedule) unmasked, so that the host reads
+  ``[n_slots, B]`` ids and two small vectors a step and never logits.
+
+Its config holds ``mask_token_id``, which the engine fills a fresh block
+with and tells a masked position by.  A prefill chunk takes the same mask, so
+the engine refuses a chunk or a page that is not a whole number of blocks.
 
 **State of a second kind.**  The pools hold state that is per position and
 immutable once written, which is why a block can be shared, cached and
@@ -124,8 +154,9 @@ import numpy as np
 
 def paged_model(cfg: Any) -> ModuleType:
     """The model module for a config object, by the config's type."""
-    from horovod_tpu.models import (latent_moe, llama, shortconv_moe,
-                                    state_space_moe, window_moe)
+    from horovod_tpu.models import (block_diffusion_moe, latent_moe, llama,
+                                    shortconv_moe, state_space_moe,
+                                    window_moe)
 
     if isinstance(cfg, llama.LlamaConfig):
         return llama
@@ -137,10 +168,12 @@ def paged_model(cfg: Any) -> ModuleType:
         return window_moe
     if isinstance(cfg, state_space_moe.StateSpaceMoEConfig):
         return state_space_moe
+    if isinstance(cfg, block_diffusion_moe.BlockDiffusionMoEConfig):
+        return block_diffusion_moe
     raise TypeError(
         f"ServeEngine serves a LlamaConfig, a LatentMoEConfig, a "
-        f"ShortConvMoEConfig, a WindowMoEConfig or a StateSpaceMoEConfig, "
-        f"not a {type(cfg).__name__}")
+        f"ShortConvMoEConfig, a WindowMoEConfig, a StateSpaceMoEConfig or a "
+        f"BlockDiffusionMoEConfig, not a {type(cfg).__name__}")
 
 
 def serving_tree(model: ModuleType, params: Any, cfg: Any, *,

@@ -41,8 +41,8 @@ whether anything is kept:
 
 Design rules (pinned by ``tests/test_profiler.py``):
 
-* **One vocabulary.**  :data:`PHASES`, :data:`SPEC_PHASES` and
-  :data:`SUB_PHASES` name the phases in the row, in ``/profile``, in the
+* **One vocabulary.**  :data:`TILING` (:data:`PHASES`, :data:`SPEC_PHASES`
+  and ``unmask``) and :data:`SUB_PHASES` name the phases in the row, in ``/profile``, in the
   ``serve.phase.*_s`` histograms and (behind ``serve.step.``) in the
   trace, letter for letter.
 * **Host code only.**  Nothing here touches a traced value or sits
@@ -96,11 +96,15 @@ SPEC_PHASES = ("draft", "verify")
 SUB_PHASES = ("admit.cache_acquire", "admit.prefill_dispatch",
               "device_sync.compute_est", "device_sync.host_stall")
 
-#: The tiling phases of both kinds in ``step()`` order: a row's
-#: durations of these sum to its ``ended - began``.
-TILING = ("expire", "admit", "draft", "decode_dispatch", "device_sync",
-          "verify", "sample_postprocess", "bookkeeping")
-assert set(TILING) == set(PHASES + SPEC_PHASES)
+#: The tiling phases of every kind in ``step()`` order: a row's
+#: durations of these sum to its ``ended - began``.  ``unmask`` is the
+#: extra phase of an engine whose model decodes a block a row (generation
+#: by diffusion over blocks): the dispatch of the unmask program in front
+#: of the block tick.  What is not of :data:`PHASES` joins ``report()``
+#: once observed.
+TILING = ("expire", "admit", "draft", "unmask", "decode_dispatch",
+          "device_sync", "verify", "sample_postprocess", "bookkeeping")
+assert set(TILING) == set(PHASES + SPEC_PHASES + ("unmask",))
 
 #: What ``_step`` counted (``counts()``): chunk programs dispatched, the
 #: rows they carried (a program prefills a window of each of its rows), the
@@ -137,7 +141,7 @@ _SPAN_NAMES = {p: f"{STEP_SPAN}.{p}" for p in TILING + SUB_PHASES}
 #: lead-in and probes included (``kexaone_mixedq`` in its 141 s drain,
 #: ``mistral7b_chat`` in its 60 s; PERF.md section 6, PR 35).  Three
 #: times that, 3 min of a chat engine's 22 ms steps:
-#: 8,192 rows x 23 fields x 8 B = 1.5 MB an engine, touched as written.
+#: 8,192 rows x 25 fields x 8 B = 1.6 MB an engine, touched as written.
 STEP_LOG_ROWS = 8_192
 
 #: How many engines' logs :func:`step_logs` keeps: the newest engine's
@@ -322,10 +326,11 @@ class PhaseSpans:
         tick_total = float(ticks.sum())
         phases: dict[str, dict] = {}
         tiled = 0.0
-        # Spec phases join the report only once a tick of the window
-        # had them — non-spec engines keep the fixed PHASES schema.
-        spec = tuple(p for p in SPEC_PHASES if rows[:, _COL[p]].any())
-        for phase in PHASES + spec + SUB_PHASES:
+        # The other tiling phases join the report only once a tick of
+        # the window had them — other engines keep the fixed PHASES schema.
+        seen = tuple(p for p in TILING
+                     if p not in PHASES and rows[:, _COL[p]].any())
+        for phase in PHASES + seen + SUB_PHASES:
             vals = rows[:, _COL[phase]]
             count = int(np.count_nonzero(vals))
             total = float(vals.sum())
@@ -375,6 +380,7 @@ class TickProfiler(PhaseSpans):
             "admit.prefill_dispatch":
                 metrics.histogram("serve.phase.admit_prefill_dispatch_s"),
             "draft": metrics.histogram("serve.phase.draft_s"),
+            "unmask": metrics.histogram("serve.phase.unmask_s"),
             "decode_dispatch":
                 metrics.histogram("serve.phase.decode_dispatch_s"),
             "device_sync": metrics.histogram("serve.phase.device_sync_s"),

@@ -134,6 +134,14 @@ class RequestResult(list):
     preemption / retry / prefix-reuse odometers.  The ServeEngine
     populates it for EVERY terminal state (a rejected request still has
     its enqueue and terminal stamps); simpler producers leave it None.
+
+    ``blocks`` and ``unmask_steps`` are ``None`` but for a model that
+    generates by diffusion over blocks: every block the request committed,
+    whole (``[n_blocks, B]`` ids: a prompt's trailing ``L mod B`` tokens lead
+    the first, and the last holds the positions ``max_new_tokens`` or an eos
+    cut), and the denoise step that unmasked each of their positions (-1
+    for a given one) — what a reference needs to rebuild every noisy block
+    the program saw.
     """
 
     def __init__(self, tokens=(), status: str = OK,
@@ -143,6 +151,7 @@ class RequestResult(list):
         self.status = status
         self.error = error
         self.trace = trace
+        self.blocks = self.unmask_steps = None
 
     @property
     def tokens(self) -> list[int]:

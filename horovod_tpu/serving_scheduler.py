@@ -5,7 +5,12 @@ the paged model interface (:mod:`horovod_tpu.models.paged`) — a
 ``LlamaConfig`` (described below), a ``LatentMoEConfig`` (three latent
 pools behind the same block table) or a ``ShortConvMoEConfig`` (attention
 pools, and beside them a recurrent state per slot with a snapshot per
-block: the interface's second kind of state, ``docs/inference.md``).
+block: the interface's second kind of state, ``docs/inference.md``); and a
+model that generates by diffusion over blocks (a ``BlockDiffusionMoEConfig``)
+through the interface's block entries: a step then denoises a block of
+positions a row and commits it when it is clean (``ServeEngine.block``,
+``docs/inference.md``, "Serving a model that generates by diffusion over
+blocks").
 
 :class:`~horovod_tpu.serving.ContinuousBatcher` admits into a fixed slot
 pool but each admission runs its whole prefill at once and the pool's
@@ -228,6 +233,11 @@ class _QueueEntry:
     slo_deadline: float | None = None    # enqueue + slo_s (EDF policy)
     held_on: "RadixNode | None" = None
     held_steps: int = 0
+    # of a model that decodes a block a row: the blocks committed before a
+    # preemption/replay re-queue, whole, and the step that unmasked each
+    # of their positions (``RequestResult.blocks`` / ``.unmask_steps``)
+    blocks: list = dataclasses.field(default_factory=list)
+    steps: list = dataclasses.field(default_factory=list)
 
 
 @dataclasses.dataclass
@@ -262,6 +272,21 @@ class _Slot:
     nodes: "list[RadixNode | None]" = dataclasses.field(
         default_factory=list)
     n_indexed: int = 0
+    # A row of a model that decodes a block a row (``ServeEngine.block``):
+    # the ids of its block in flight as the host last read them (mask ids
+    # where a position is still masked; the first ``given`` are the
+    # prompt's tail), the step that unmasked each position (-1: given),
+    # how many denoise steps the block has had, whether it has yet to go
+    # into its first tick, the blocks this stint committed, and every
+    # committed block whole with its steps (replays included).
+    block: list[int] = dataclasses.field(default_factory=list)
+    when: list[int] = dataclasses.field(default_factory=list)
+    given: int = 0
+    block_step: int = 0
+    fresh: bool = True
+    n_committed: int = 0
+    done_blocks: list = dataclasses.field(default_factory=list)
+    done_steps: list = dataclasses.field(default_factory=list)
 
 
 class ServeEngine:
@@ -417,6 +442,19 @@ class ServeEngine:
         # interface (horovod_tpu.models.paged), chosen by the config's
         # type.  Everything below reaches the model through it.
         self.model = model = paged_model(cfg)
+        # A model that generates by diffusion over blocks decodes a block
+        # of this many positions a row a tick (models/paged.py); 0: a
+        # token a row a tick.
+        self.block = (int(model.block_length(cfg))
+                      if hasattr(model, "block_length") else 0)
+        if self.block and (chunk % self.block or block_size % self.block):
+            raise ValueError(
+                f"a model that generates by diffusion over blocks of "
+                f"{self.block} positions needs chunk ({chunk}) and "
+                f"block_size ({block_size}) to be multiples of its "
+                f"block_length: a prefill chunk takes the block-causal "
+                f"mask and a prefix hit's frontier is a whole number of "
+                f"pages")
         if tp_size > 1:
             for dim_name, dim in model.tp_split_dims(cfg):
                 if dim % tp_size:
@@ -479,6 +517,12 @@ class ServeEngine:
             draft_k = int(raw) if raw else drafting_mod.DEFAULT_DRAFT_K
         if spec and draft_k < 1:
             raise ValueError(f"draft_k must be >= 1, got {draft_k}")
+        if spec and self.block:
+            raise ValueError(
+                "spec=True does not apply to a model that generates by "
+                "diffusion over blocks: a step denoises a block of "
+                f"{self.block} positions a row and commits it when it is "
+                "clean, there is no next-token draft to verify")
         self.spec = bool(spec)
         self.draft_k = int(draft_k)
         self.spec_counters = {"rounds": 0, "row_rounds": 0,
@@ -497,6 +541,20 @@ class ServeEngine:
         for h in ("serve.ttft_s", "serve.tpot_s", "serve.queue_wait_s",
                   "serve.e2e_s"):
             self.metrics.histogram(h)
+        if self.block:
+            # Row-forwards of either kind (a commit forward is a block
+            # committed), positions unmasked and by which rule, and blocks
+            # a preemption dropped in flight (registered up front, by
+            # literal name: HVD005).
+            self._c_block = {
+                "denoise": self.metrics.counter("diffusion.denoise_forwards"),
+                "commit": self.metrics.counter("diffusion.commit_forwards"),
+                "unmasked": self.metrics.counter("diffusion.tokens_unmasked"),
+                "by_threshold": self.metrics.counter(
+                    "diffusion.unmasked_by_threshold"),
+                "by_schedule": self.metrics.counter(
+                    "diffusion.unmasked_by_schedule"),
+                "redone": self.metrics.counter("diffusion.blocks_redone")}
         # Causal tracing plane (horovod_tpu.tracing): spans are emitted
         # post-hoc from Trace stamps at terminal time, so with sampling
         # off the hot path pays one None-check per request.
@@ -648,6 +706,11 @@ class ServeEngine:
         if self.tp_size > 1:
             self.last_logits = jax.device_put(self.last_logits,
                                               self._repl_sh)
+        # what a block tick leaves for the next step's unmask: the logits
+        # of every position of every row's block
+        self.block_logits = (jnp.zeros(
+            (n_slots, self.block, cfg.vocab_size), jnp.float32)
+            if self.block else None)
         self._slots = [_Slot() for _ in range(n_slots)]
         self._queue: list[_QueueEntry] = []
         self._next_id = 0
@@ -770,6 +833,44 @@ class ServeEngine:
                 # told the entry of each block of the row (`_map_row`)
                 return model.set_row(pcache, slot, row, length, snaps)
 
+        if self.block:
+            @jax.jit
+            def _unmask(block_logits, tokens, step, go, counters):
+                # the program in `_sample`'s place for a model that decodes
+                # a block a row: the sampler's rule over the logits the
+                # last tick left, for the rows `go` [B] whose block that
+                # tick ran over (a block that has been in no tick yet
+                # stands as the host gave it).  `tokens` [B, block] and
+                # `step` [B] are the host's mirror, whole since it read
+                # the step before; what it reads of this one is the new
+                # ids and two small vectors, never logits, and `commit`
+                # (the rows whose block came clean) goes to the tick
+                # behind without the host.  Donates nothing.
+                new, left, by_threshold = model.unmask(
+                    cfg, block_logits, tokens, step)
+                go = go > 0
+                tokens = jnp.where(go[:, None], new, tokens)
+                left = jnp.where(go, left, self.block)
+                commit = (go & (left == 0)).astype(jnp.int32)
+                return (tokens, left, jnp.where(go, by_threshold, 0), commit,
+                        jax.tree.map(jnp.copy, counters))
+
+            @partial(jax.jit, donate_argnums=(1, 2))
+            def _tick(params, pcache, block_logits, tokens,  # noqa: F811
+                      active, commit):
+                # the block tick: every decoding row's block under the
+                # block-causal mask against its pages, the block's keys
+                # written past the row's length; the length advances only
+                # where `commit`.  Left in flight as the one-token tick is.
+                return model.decode_block_paged(
+                    params, tokens, cfg, pcache, active=active,
+                    commit=commit)
+
+            self._unmask = _unmask
+            _sample = None
+        else:
+            self._unmask = None
+
         if self.spec:
             @partial(jax.jit, donate_argnums=(1, 2), **_spec_sh)
             def _spec_tick(params, pcache, last_logits, drafts, active):
@@ -819,9 +920,14 @@ class ServeEngine:
         it holds beyond one a width is a retrace of some width.
         A spec engine adds the ``spec_tick`` key (its always-wide verify
         program, which replaces ``sample`` and ``tick`` so that those
-        counts stay 0)."""
+        counts stay 0).  An engine whose model decodes a block a row has
+        ``unmask`` in ``sample``'s place."""
+        if self.block:
+            front = {"unmask": self._unmask._cache_size()}
+        else:
+            front = {"sample": self._sample._cache_size()}
         sizes = {
-            "sample": self._sample._cache_size(),
+            **front,
             "tick": self._tick._cache_size(),
             "chunk": max(self._chunk._cache_size()
                          - (len(self.chunk_widths) - 1), 0),
@@ -850,9 +956,20 @@ class ServeEngine:
         i32 = jax.ShapeDtypeStruct((), jnp.int32)
         active_av = jax.ShapeDtypeStruct((self.n_slots,), jnp.int32)
         row_av = jax.ShapeDtypeStruct((self.blocks_per_slot,), jnp.int32)
+        if self.block:
+            bl_av = aval(self.block_logits)
+            ids_av = jax.ShapeDtypeStruct((self.n_slots, self.block),
+                                          jnp.int32)
+            front = {
+                "unmask": (self._unmask, bl_av, ids_av, active_av, active_av,
+                           counters_av),
+                "tick": (self._tick, p_av, c_av, bl_av, ids_av, active_av,
+                         active_av)}
+        else:
+            front = {"sample": (self._sample, ll_av, counters_av),
+                     "tick": (self._tick, p_av, c_av, ll_av, active_av)}
         progs = {
-            "sample": (self._sample, ll_av, counters_av),
-            "tick": (self._tick, p_av, c_av, ll_av, active_av),
+            **front,
             "chunk": (self._chunk, p_av, c_av, ll_av,
                       *self._chunk_arg_avals(1)),
             "set_row": ((self._set_row, c_av, i32, row_av, i32)
@@ -1159,15 +1276,23 @@ class ServeEngine:
                     f" rid={s.request_id} w={s.w_done}/{s.n_win} "
                     f"out={len(s.out)} budget={s.budget} "
                     f"blocks={s.n_blocks} shared={s.n_hit} "
-                    f"retries={s.retries} wait={s.wait_steps}"))
+                    f"retries={s.retries} wait={s.wait_steps}"
+                    + (f" block={s.block} denoise_steps={s.block_step} "
+                       f"committed={s.n_committed}" if self.block else "")))
         return "\n".join(lines)
 
     # -- queue -------------------------------------------------------------
 
+    def _positions(self, n: int) -> int:
+        """The positions a row of ``n`` tokens writes: ``n``, in whole
+        blocks where the model decodes a block a row (its last block is
+        written whole though ``max_new_tokens`` cuts it)."""
+        return -(-n // self.block) * self.block if self.block else n
+
     def _need_blocks(self, req: Request) -> int:
         # constant across replays: replay prompt grows by exactly the
         # tokens the remaining budget shrinks by
-        return -(-(len(req.prompt) + req.max_new_tokens)
+        return -(-self._positions(len(req.prompt) + req.max_new_tokens)
                  // self.block_size)
 
     def _row_length(self, s: _Slot) -> int:
@@ -1176,6 +1301,8 @@ class ServeEngine:
         if s.state == PREFILL:
             return s.base + s.w_done * self.chunk
         if s.state == DECODE:
+            if self.block:      # what was prefilled and the blocks committed
+                return s.true_len + self.block * s.n_committed
             return s.true_len + len(s.out)
         return 0
 
@@ -1192,6 +1319,12 @@ class ServeEngine:
             # covers every "the fleet would not serve this" path.
             return self._reject_submit(req, L)
         if req.temperature not in (None, 0.0) or req.sample_key is not None:
+            if self.block:
+                raise ValueError(
+                    "a model that generates by diffusion over blocks is "
+                    "served greedily: the unmask rule keeps the argmax of "
+                    "the most confident positions, and a sampled request "
+                    "has no place in it")
             raise ValueError(
                 "ServeEngine is greedy-only; serve sampled requests "
                 "through ContinuousBatcher")
@@ -1201,7 +1334,7 @@ class ServeEngine:
                 "ContinuousBatcher for prefix requests")
         if req.slo_s is not None and req.slo_s <= 0:
             raise ValueError(f"slo_s must be positive, got {req.slo_s}")
-        if L + req.max_new_tokens > self.max_len:
+        if self._positions(L + req.max_new_tokens) > self.max_len:
             raise ValueError(
                 f"prompt {L} + max_new_tokens {req.max_new_tokens} "
                 f"exceeds max_len {self.max_len}")
@@ -1418,16 +1551,24 @@ class ServeEngine:
         if self.prefix is not None:
             s.nodes = self.prefix.reserve(prompt, blocks)
             s.n_indexed = len(hit)
-        rem = L - base                    # tokens still to prefill (>= 1)
-        n_win = -(-rem // self.chunk)
+        # A model that decodes a block a row prefills the prompt's whole
+        # blocks; the trailing `L mod block` tokens are the given positions
+        # of the first generated block, whose keys depend on what is
+        # generated (and no logits of the prefill seed anything).
+        tail = L % self.block if self.block else 0
+        rem = L - tail - base             # tokens still to prefill
+        n_win = -(-rem // self.chunk)     # >= 1 but for a row of blocks
         padded = np.zeros((1, n_win * self.chunk), np.int32)
-        padded[0, :rem] = prompt[base:]
-        s.state = PREFILL
+        padded[0, :rem] = prompt[base:L - tail]
+        s.state = PREFILL if n_win else DECODE
         s.request_id = e.rid
         s.padded = padded
         s.n_win = n_win
         s.w_done = 0
-        s.true_len = L
+        s.true_len = L - tail
+        if self.block:
+            s.done_blocks, s.done_steps = list(e.blocks), list(e.steps)
+            self._next_block(s, prompt[L - tail:])
         s.base = base
         s.n_hit = len(hit)
         s.budget = e.req.max_new_tokens - len(e.prior)
@@ -1604,7 +1745,11 @@ class ServeEngine:
             retries=s.retries + (1 if retried else 0),
             wait_steps=2 ** (s.retries + 1) if retried else 0,
             deadline=s.deadline,
-            slo_deadline=s.slo_deadline)
+            slo_deadline=s.slo_deadline,
+            blocks=s.done_blocks, steps=s.done_steps)
+        if self.block and s.state == DECODE and not s.fresh:
+            # the block in flight is dropped and denoised again
+            self._c_block["redone"].inc()
         self._release_row_blocks(s, register=True)
         self._map_row(slot, self._trash_row, 0)
         self._slots[slot] = _Slot()
@@ -1652,6 +1797,8 @@ class ServeEngine:
         write for every status — OK, TIMEOUT, CANCELLED, FAILED)."""
         s = self._slots[slot]
         res = RequestResult(list(s.prior) + list(s.out), status, error)
+        if self.block:
+            res.blocks, res.unmask_steps = s.done_blocks, s.done_steps
         self.results[s.request_id] = res
         self._finished[s.request_id] = res
         self._finalize_trace(s.request_id, res)
@@ -1669,6 +1816,8 @@ class ServeEngine:
         """Terminal result for a request that never (re)entered a slot:
         tokens-so-far is whatever a previous stint emitted."""
         res = RequestResult(list(e.prior), status, error)
+        if self.block:
+            res.blocks, res.unmask_steps = e.blocks, e.steps
         self.results[e.rid] = res
         self._finished[e.rid] = res
         self._finalize_trace(e.rid, res)
@@ -1910,6 +2059,66 @@ class ServeEngine:
                 f"cached={self.pool.cached_count()} "
                 f"referenced={len(self.pool._ref)} != {total}")
 
+    def _next_block(self, s: _Slot, given: list[int] = ()) -> None:
+        """Give a row its next block: the ``given`` tokens (a prompt's
+        tail, in a first block) and the mask id elsewhere, in no tick
+        yet."""
+        n = len(given)
+        s.block = list(given) + [self.cfg.mask_token_id] * (self.block - n)
+        s.when = [-1] * n + [None] * (self.block - n)
+        s.given, s.block_step, s.fresh = n, 0, True
+
+    def _block_front(self, decoding: list[int]) -> tuple:
+        """What the unmask program takes of the host: every decoding
+        row's block as the host last read it, the denoise steps it has
+        had, and ``go``, the rows whose block the tick in flight ran over
+        (the others' goes into its first tick as it stands)."""
+        tokens = np.zeros((self.n_slots, self.block), np.int32)
+        step = np.zeros((self.n_slots,), np.int32)
+        go = np.zeros((self.n_slots,), np.int32)
+        for slot in decoding:
+            s = self._slots[slot]
+            tokens[slot] = s.block
+            step[slot] = s.block_step
+            go[slot] = not s.fresh
+        return tokens, step, go
+
+    def _block_read(self, s: _Slot, ids: list[int], left: int,
+                    by_threshold: int) -> list[int]:
+        """A decoding row's part of a step's read-back: its block's ids
+        after the unmask program, how many are still masked and how many
+        the confidence threshold unmasked.  Books the step that unmasked
+        each position and the ``diffusion.*`` counters; a block that came
+        clean is committed by the tick this step dispatched, and its
+        generated tokens (the prompt's tail apart) are what the row
+        emits, in order; the row's next block goes in with the next
+        tick.  Any other step emits nothing."""
+        count = self._c_block
+        if s.fresh:         # in its first tick now: nothing was unmasked
+            s.fresh = False
+            count["denoise"].inc()
+            return []
+        mask = self.cfg.mask_token_id
+        took = [i for i, (was, now) in enumerate(zip(s.block, ids))
+                if was == mask and now != mask]
+        for i in took:
+            s.when[i] = s.block_step
+        s.block = ids
+        s.block_step += 1
+        count["unmasked"].inc(len(took))
+        count["by_threshold"].inc(by_threshold)
+        count["by_schedule"].inc(len(took) - by_threshold)
+        if left:
+            count["denoise"].inc()
+            return []
+        count["commit"].inc()
+        s.n_committed += 1
+        s.done_blocks.append(s.block)
+        s.done_steps.append(s.when)
+        emit = s.block[s.given:]
+        self._next_block(s)
+        return emit
+
     def _dispatch_chunk(self, group: list[int]) -> Dispatched | None:
         """One chunk program over the next prefill window of each slot of
         ``group`` (as many as one of ``chunk_widths``), and the rows'
@@ -2109,7 +2318,7 @@ class ServeEngine:
                 active[decoding] = 1
                 accept = accept_host = None
                 programs.append(Dispatched(
-                    self.draft_k + 1 if spec else 1,
+                    self.draft_k + 1 if spec else self.block or 1,
                     np.array([self._row_length(s) for s in self._slots]),
                     active))
                 if spec:
@@ -2122,6 +2331,27 @@ class ServeEngine:
                             jnp.asarray(drafts_host),
                             jnp.asarray(active))
                     stats = self.model.paged_counters(self.pcache)
+                elif self.block:
+                    # a block a row: the unmask program in front reads the
+                    # logits the last tick left and hands the host the
+                    # blocks' new ids; the tick behind it takes them, and
+                    # which rows commit, straight from the device, and is
+                    # left in flight as below
+                    prof.mark("unmask")
+                    tok, left, by_threshold, commit, stats = self._unmask(
+                        self.block_logits,
+                        *(jnp.asarray(a) for a in
+                          self._block_front(decoding)),
+                        self.model.paged_counters(self.pcache))
+                    prof.mark("decode_dispatch")
+                    self.block_logits, self.pcache = self._tick(
+                        self.params, self.pcache, self.block_logits, tok,
+                        jnp.asarray(active), commit)
+                    self._tick_rows = {
+                        slot: self._slots[slot].request_id
+                        for slot in decoding}
+                    left.copy_to_host_async()
+                    by_threshold.copy_to_host_async()
                 else:
                     # the step's tokens (and the model's device-side
                     # counters, None for a model that keeps none) come
@@ -2145,7 +2375,8 @@ class ServeEngine:
                     stats.copy_to_host_async()
                 if self.device is not None:
                     if not spec:
-                        self.device.dispatch("sample")
+                        self.device.dispatch(
+                            "unmask" if self.block else "sample")
                     self.device.dispatch(
                         "spec_tick" if spec else "tick",
                         h2d_bytes=active.nbytes + (
@@ -2178,6 +2409,9 @@ class ServeEngine:
                     tok_host = np.asarray(tok)
                     if spec:
                         accept_host = np.asarray(accept)
+                    if self.block:
+                        left_host = np.asarray(left)
+                        threshold_host = np.asarray(by_threshold)
                     stats_host = (None if stats is None
                                   else np.asarray(stats))
                     if self.device is not None:
@@ -2236,7 +2470,10 @@ class ServeEngine:
                     self._bump_spec("rounds")
                 for slot in decoding:
                     s = self._slots[slot]
-                    emit = [int(tok_host[slot])]
+                    # what the step read of the row: its token, or the ids
+                    # of its block
+                    emit = ([int(t) for t in tok_host[slot]] if self.block
+                            else [int(tok_host[slot])])
                     if accept_host is not None:
                         acc = int(accept_host[slot])
                         emit += [int(x) for x in
@@ -2254,7 +2491,13 @@ class ServeEngine:
                     except Exception as exc:
                         self._row_fault(slot, exc)
                         continue
-                    if not s.prior and not s.out:
+                    if self.block:
+                        # a row of blocks emits nothing but a block that
+                        # came clean: its generated tokens, in order
+                        emit = self._block_read(
+                            s, emit, int(left_host[slot]),
+                            int(threshold_host[slot]))
+                    if emit and not s.prior and not s.out:
                         n_first += 1
                         tr = self.traces.get(s.request_id)
                         if tr is not None and tr.first_token_ts is None:

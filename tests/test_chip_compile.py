@@ -726,3 +726,110 @@ def test_the_mistral_programs_hold_one_pool_and_no_more_scratch(
     assert mem.temp_size_in_bytes <= PARENT_LLAMA_TEMP_BYTES[program] + room
     # the weights once: 11.0 GB with the pool's 2.54, not 12.2
     assert mem.argument_size_in_bytes < 13.7e9
+
+
+def _blockgen_engine() -> dict:
+    """The engine of the one cell that serves the sixth model, from its
+    traffic file: the size is written down there alone."""
+    return _benchmark_json("traffic", "blockgen.json")["engine"]
+
+
+@pytest.fixture(scope="module")
+def block_diffusion_compiled(one_chip, no_compile_cache):
+    """``compiled(program)`` of the sixth model's three kinds of program at
+    the cell's own size (``sdar_blockgen``: 128 slots of 2,048 over 1,029
+    pages of 256): the unmask program in front, the block tick, and the chunk
+    at its wide width of 8 rows and at one, each compiled once."""
+    from horovod_tpu.models import block_diffusion_moe as bd
+
+    e = _blockgen_engine()
+    cfg = bd.BlockDiffusionMoEConfig(
+        denoising_steps=e["denoising_steps"], remasking=e["remasking"],
+        confidence_threshold=e["confidence_threshold"])
+    n_slots, chunk_len, b = e["n_slots"], e["chunk"], cfg.block_length
+    params = _avals(jax.eval_shape(
+        lambda: bd.init_params(cfg, jax.random.key(0))), one_chip)
+    cache = _avals(jax.eval_shape(lambda: bd.init_paged_cache(
+        cfg, n_slots, e["max_len"], block_size=chunk_len,
+        n_blocks=e["n_blocks"])), one_chip)
+    sds = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(   # noqa: E731
+        shape, dt, sharding=one_chip)
+    block_logits = sds((n_slots, b, cfg.vocab_size), jnp.float32)
+    last_logits = sds((n_slots, cfg.vocab_size), jnp.float32)
+
+    @jax.jit
+    def unmask(block_logits, tokens, step):
+        return bd.unmask(cfg, block_logits, tokens, step)
+
+    @partial(jax.jit, donate_argnums=(1, 2))
+    def tick(params, pcache, block_logits, tokens, active, commit):
+        return bd.decode_block_paged(params, tokens, cfg, pcache,
+                                     active=active, commit=commit)
+
+    @partial(jax.jit, donate_argnums=(1, 2))
+    def chunk(params, pcache, last_logits, toks, slots, new_len, sel):
+        out, pcache = bd.decode_chunk_paged_rows(
+            params, toks, cfg, pcache, slots, new_length=new_len, sel=sel)
+        return pcache, last_logits.at[slots].set(out, mode="drop")
+
+    def chunk_of(rows):
+        return lambda: chunk.lower(
+            params, cache, last_logits, sds((rows, chunk_len)), sds((rows,)),
+            sds((rows,)), sds((rows,)))
+
+    lowered = {
+        "unmask": lambda: unmask.lower(block_logits, sds((n_slots, b)),
+                                       sds((n_slots,))),
+        "tick": lambda: tick.lower(params, cache, block_logits,
+                                   sds((n_slots, b)), sds((n_slots,)),
+                                   sds((n_slots,))),
+        "chunk": chunk_of(1), "chunk_rows": chunk_of(8)}
+    done = {}
+
+    def compiled(program):
+        if program not in done:
+            done[program] = lowered[program]().compile()
+        return done[program]
+
+    return compiled, cache, cfg
+
+
+@pytest.mark.parametrize("program", ["tick", "chunk", "chunk_rows"])
+def test_block_diffusion_programs_fit_the_chip_and_hold_one_pool(
+        block_diffusion_compiled, program):
+    """8.72 GB of weights and the 3.24 GB pool are held once and written in
+    place (the block's keys land past the rows' lengths in the donated pool);
+    what a program allocates beside its arguments (its scratch, and the block
+    tick's logits of every position, 0.31 GB in float32) is within the room
+    the mix's ``engine_is`` states, and 1 GB of the chip is to spare beside
+    the two copies of the block's logits a step holds at once."""
+    compiled, cache, cfg = block_diffusion_compiled
+    mem = compiled(program).memory_analysis()
+    pool = cache.k.size * cache.k.dtype.itemsize
+    assert cache.k.shape == (6, 1029, 256, 4, 128) and 2 * pool == \
+        3_236_954_112
+    assert mem.alias_size_in_bytes >= 2 * pool
+    fresh = mem.temp_size_in_bytes + mem.output_size_in_bytes \
+        - mem.alias_size_in_bytes
+    block_logits = 128 * cfg.block_length * cfg.vocab_size * 4
+    assert fresh < {"tick": 0.4e9 + block_logits, "chunk": 0.1e9,
+                    "chunk_rows": 0.4e9}[program], fresh
+    assert mem.temp_size_in_bytes < pool // 4, mem.temp_size_in_bytes
+    spare = V5E_USABLE_BYTES - mem.argument_size_in_bytes - fresh \
+        - 2 * block_logits
+    assert spare > 1.0e9, spare
+    m = re.search(r"(\d\.\d+) GB to spare", _benchmark_json(
+        "traffic", "blockgen.json")["engine_is"])
+    assert m and spare > float(m.group(1)) * 1e9
+
+
+def test_the_unmask_program_reads_logits_and_allocates_next_to_nothing(
+        block_diffusion_compiled):
+    """The program in front of the block tick takes the 0.31 GB of logits the
+    last tick left and hands back ids and two small vectors: no copy of the
+    logits with the mask id's column set, no sorted copy."""
+    compiled, _, cfg = block_diffusion_compiled
+    mem = compiled("unmask").memory_analysis()
+    assert mem.argument_size_in_bytes < 128 * cfg.block_length \
+        * cfg.vocab_size * 4 + 1e5
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 1e6

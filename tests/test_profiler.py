@@ -518,7 +518,7 @@ def _as_dicts(log):
 
 def test_row_fields_are_the_vocabulary():
     assert ROW_FIELDS[:3] == ("step", "began", "ended")
-    assert set(TILING) == set(PHASES) | set(SPEC_PHASES)
+    assert set(TILING) == set(PHASES) | set(SPEC_PHASES) | {"unmask"}
     assert ROW_FIELDS == (("step", "began", "ended") + TILING + SUB_PHASES
                           + COUNTS + CARRIED)
     assert COUNTS == ("chunks", "chunk_rows", "tick_rows", "tokens",

@@ -23,7 +23,7 @@ def test_paged_model_answers_for_the_config():
     _, mc, _ = tiny()
     assert paged.paged_model(mc) is wm
     assert paged.paged_model(wm.window_moe_tiny()) is wm
-    with pytest.raises(TypeError, match="a WindowMoEConfig or a StateSpaceMoEConfig"):
+    with pytest.raises(TypeError, match="a WindowMoEConfig, a StateSpaceMoEConfig"):
         paged.paged_model(object())
     for fn in (lambda: wm.param_partition_specs(mc),
                lambda: wm.paged_cache_partition_specs(),
