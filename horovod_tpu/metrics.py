@@ -236,6 +236,7 @@ METRIC_HELP: dict[str, str] = {
     "kv.window_bytes_beyond_window": "Window-pool bytes live rows hold for positions older than the window (what a window-sized pool would free)",
     "moe.choices_total": "Token-choices routed (tokens x top_k x expert layers)",
     "moe.choices_in_place": "Choices of the dispatched programs whose expert layers computed over their rows in place (rows x tokens a row x top_k x expert layers, idle rows counted; the programs latent_moe.rows_in_place sends that way)",
+    "moe.choices_grouped": "Choices of the dispatched programs whose expert layers put their sorted tiles through the grouped product (counted as moe.choices_in_place counts its own; the programs latent_moe.rows_grouped sends that way)",
     "moe.layers_batched": "Expert layers in place that computed every held expert at once and not one touched expert a step (device-side count)",
     "moe.choices_held": "Token-choices that fell on an expert this chip holds",
     "moe.held_load": "Token-choices per held expert since start (moe.held_load.<expert>)",

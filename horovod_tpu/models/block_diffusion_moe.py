@@ -276,10 +276,10 @@ def publish_paged_metrics(metrics, cfg: BlockDiffusionMoEConfig,
     construction what a cached token holds; after a step the share of the
     tables attention walked (``attn.blocks_*``, as :mod:`llama` counts them
     from ``programs``: a block tick is a program of ``block_length`` tokens a
-    row) and ``moe.choices_in_place``; where a step's readback brought
-    ``stats_host``, the device's counters, the experts the last block tick
-    touched, each expert's load, the largest load, and the device's own count
-    of the blocks it committed (``diffusion.blocks_committed.device``: what
+    row), ``moe.choices_in_place`` and ``moe.choices_grouped``; where a
+    step's readback brought ``stats_host``, the device's counters, the
+    experts the last block tick touched, each expert's load, the largest
+    load, and the device's own count of the blocks it committed (``diffusion.blocks_committed.device``: what
     the engine's ``diffusion.commit_forwards`` has to come to)."""
     if stats_host is None and not programs:     # once, at construction
         per_block = paged_pool_bytes(pcache)
@@ -288,8 +288,7 @@ def publish_paged_metrics(metrics, cfg: BlockDiffusionMoEConfig,
         for _, device_total, _ in _counted(metrics):
             device_total.set(0)
     llama.publish_paged_metrics(metrics, cfg, pcache, programs=programs)
-    metrics.counter("moe.choices_in_place").inc(
-        latent_moe.choices_in_place(cfg, programs))
+    latent_moe.count_choices_by_form(metrics, cfg, programs)
     if stats_host is None:          # nothing was read back: no tick ran
         return
     c = read_counters(stats_host)

@@ -46,12 +46,16 @@ layer input:
   its rows where they are: every row through each computed expert, the row's
   weight on it selected onto the outcome, all held experts at once where
   most are touched and one touched expert a step where few are.  A longer
-  program sorts the held choices by expert into tile-aligned segments and a
-  loop over the tiles in use multiplies each by its expert's matrices.  Only
-  the form that takes all experts at once reads one that no row chose, and
-  it runs where at least three quarters are touched.  What the absent
-  experts would add is left out — on one chip the layer runs without its
-  exchange — and the partial result goes on to the next layer.
+  program sorts the held choices by expert into tile-aligned segments and
+  one grouped product (:mod:`horovod_tpu.models.grouped_experts`, a Pallas
+  kernel) puts every tile in use through its expert's matrices, the next
+  tile's weights streaming in while the present tile is multiplied; widths
+  that are not whole lanes (the CPU tests' toys) keep the plain form, a loop
+  over the tiles.  Only the form that takes all experts at once reads one
+  that no row chose, and it runs where at least three quarters are touched.
+  What the absent experts would add is left out — on one chip the layer
+  runs without its exchange — and the partial result goes on to the next
+  layer.
 
 **The absorbed form.**  Nothing per head is cached.  ``W_kvb``'s key half is
 folded into the query (``q_nope W_kvb_k -> [heads, kv_rank]``) and its value
@@ -85,7 +89,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from horovod_tpu.models import llama
+from horovod_tpu.models import grouped_experts, llama
 from horovod_tpu.models.llama import rmsnorm
 
 FULL, WINDOW = "full", "window"
@@ -100,8 +104,9 @@ _LO_BITS = 24                 # a running sum is hi * 2**24 + lo, both int32
 LANES = 128
 #: how much of a long computation one loop step takes: cached keys the
 #: indexer scores at once, queries that attend at once (their selected
-#: latents gathered, or a tile of keys scored for them), and the rows of one
-#: expert's tile in a program that sorts its choices into tiles
+#: latents gathered, or a tile of keys scored for them), and the most rows
+#: of one expert's tile in a program that sorts its choices into tiles
+#: (:func:`tile_rows`)
 INDEX_STEP_KEYS = 2048
 QUERY_BLOCK = 128
 TILE_ROWS = 128
@@ -110,9 +115,17 @@ TILE_ROWS = 128
 #: then goes through every expert that is computed: 6·d·f operations a row
 #: and expert against the expert's 6·d·f bytes, so up to 197 TFLOP/s / 819
 #: GB/s = 240 rows the products hide behind the weights' read whatever the
-#: widths.  Measured on one v5e at lfm2's widths (PERF.md, PR 32): a tick of
-#: 128 rows 23.2 -> 16.6 ms, a chunk of 256 rows (the balance point) 21.0 ->
+#: widths.  Measured on one v5e at lfm2's widths (PERF.md, PR 32) against
+#: the loop over sorted tiles that was the other side then: a tick of 128
+#: rows 23.2 -> 16.6 ms, a chunk of 256 rows (the balance point) 21.0 ->
 #: 16.1 ms; a chunk of 512 would spend twice the MXU's time of its read.
+#: Read again with the grouped product as the other side (PERF.md, PR 46; the
+#: layer alone, ``tools/expert_layer_sweep.py``): at 256 rows in place 1.23
+#: ms a layer and grouped 1.15 at lfm2's widths, 1.99 and 1.53 at sdar's with
+#: 101 of 128 experts touched (1.69 and 1.46 at 128 rows: in place reads the
+#: experts nobody chose, too); at 512 rows 4.24 and 2.04.  Left at 256: the
+#: ticks and the one-row chunks of five cells lower to the text they had, and
+#: what 6-23 % of their expert layers is worth there is ROADMAP S19 (d)'s.
 IN_PLACE_ROWS = 256
 #: the selection is kept as a mask over key tiles (and never made into a
 #: list) while a program's last query sees no more than this many times
@@ -395,8 +408,8 @@ def publish_paged_metrics(metrics, cfg: LatentMoEConfig,
     queries times the full layers and ``dsa.mask_queries`` those of the
     programs that kept the selection as a mask, by :func:`mask_reach`, the
     function the programs' own branch comes from; nothing is read back.
-    ``moe.choices_in_place`` is reckoned the same way
-    (:func:`choices_in_place`)."""
+    ``moe.choices_in_place`` and ``moe.choices_grouped`` are reckoned the
+    same way (:func:`choices_in_place`, :func:`choices_grouped`)."""
     if stats_host is None and not programs:     # once, at construction
         per_block = paged_pool_bytes(pcache)
         metrics.gauge("kv.latent_block_bytes").set(per_block["latent"])
@@ -404,7 +417,8 @@ def publish_paged_metrics(metrics, cfg: LatentMoEConfig,
         metrics.gauge("kv.window_block_bytes").set(per_block["window"])
         metrics.gauge("kv.window_bytes_beyond_window").set(0)
         for name in ("moe.choices_total", "moe.choices_held",
-                     "moe.choices_in_place", "moe.layers_batched",
+                     "moe.choices_in_place", "moe.choices_grouped",
+                     "moe.layers_batched",
                      "dsa.keys_visible", "dsa.keys_selected",
                      "dsa.queries", "dsa.mask_queries"):
             metrics.counter(name)
@@ -415,8 +429,7 @@ def publish_paged_metrics(metrics, cfg: LatentMoEConfig,
     metrics.counter("dsa.mask_queries").inc(cfg.n_of(FULL) * sum(
         p.rows * p.t for p in programs
         if p.longest + p.t <= mask_reach(p.t, m, k)))
-    metrics.counter("moe.choices_in_place").inc(
-        choices_in_place(cfg, programs))
+    count_choices_by_form(metrics, cfg, programs)
     if stats_host is None:          # nothing was read back: no tick ran
         return
     keep = -(-(cfg.window - 1) // pcache.block_size) + 1
@@ -838,7 +851,23 @@ def held_experts(cfg: LatentMoEConfig, lp: dict, h2, valid):
     ``valid`` [N] marks real tokens (pads and idle rows choose nothing).
     Returns the weighted sum per token and the per-held-expert load.  A
     program of few rows computes them in place (:func:`rows_in_place`), one
-    of many sorts its choices into tiles."""
+    of many sorts its choices into tiles and multiplies the tiles in one
+    grouped product (:func:`rows_grouped`)."""
+    n = h2.shape[0]
+    held, group, weights, load = held_choices(cfg, lp, h2, valid)
+    if rows_in_place(n):
+        return _experts_in_place(cfg, lp, h2, group, weights, load), load
+    tiles = _tiles_grouped if rows_grouped(
+        n, h2.shape[1], lp["e_gate"].shape[-1]) else _tiles_looped
+    return _experts_in_tiles(cfg, lp, h2, held, group, weights, load,
+                             tiles), load
+
+
+def held_choices(cfg, lp: dict, h2, valid):
+    """What :func:`held_experts` computes from: ``held`` [N, k] marks the
+    choices of real tokens that fell on a held expert, ``group`` [N * k] is
+    each choice's held expert (``held_count`` where it is not held),
+    ``weights`` [N, k] the router's, ``load`` [E] the choices an expert."""
     n = h2.shape[0]
     e = cfg.held_count
     with jax.named_scope("moe.route"):
@@ -848,15 +877,40 @@ def held_experts(cfg: LatentMoEConfig, lp: dict, h2, valid):
         group = jnp.where(held, local, e).reshape(n * cfg.top_k)
         load = jnp.sum(jax.nn.one_hot(group, e + 1, dtype=jnp.int32),
                        axis=0)[:e]                                   # [E]
-    if rows_in_place(n):
-        return _experts_in_place(cfg, lp, h2, group, weights, load), load
-    return _experts_in_tiles(cfg, lp, h2, held, group, weights, load), load
+    return held, group, weights, load
 
 
 def rows_in_place(n_rows: int) -> bool:
     """Whether a program of ``n_rows`` tokens computes its experts over the
     rows where they stand."""
     return n_rows <= IN_PLACE_ROWS
+
+
+def tile_rows(n_rows: int, cfg) -> int:
+    """The rows of one expert's tile in a program of ``n_rows`` tokens that
+    sorts its choices into tiles: twice the mean load an expert (``n_rows *
+    top_k / n_experts``) rounded up to a power of two, so that few experts
+    need a second tile, from 32 rows to :data:`TILE_ROWS`.  A tile costs the
+    MXU all its rows, padding too, and at 128 rows that is no longer hidden
+    behind the read of a small expert's weights.  Measured on one v5e
+    (PERF.md, PR 46; the expert layer alone, ``tools/expert_layer_sweep.py``):
+    at sdar's widths and 32 choices an expert, tiles of 128 / 64 / 32 rows
+    2.42 / 2.08 / 2.14 ms a layer; at dots3's and 16 an expert, 128 / 32 rows
+    3.01 / 2.59; at K-EXAONE's and 64 an expert (75.5 MB an expert in four
+    blocks: a second tile reads it again) 128 / 64 rows 3.02 / 3.53; at
+    granite's and 71 an expert 128 / 64 rows 1.77 / 1.60, where the rule's
+    128 is not the best (its expert is one block, a second tile reads
+    nothing again: ROADMAP S19)."""
+    twice = max(-(-2 * n_rows * cfg.top_k // cfg.n_experts), 1)
+    return min(TILE_ROWS, max(32, 1 << (twice - 1).bit_length()))
+
+
+def rows_grouped(n_rows: int, d: int, f: int) -> bool:
+    """Whether a program of ``n_rows`` tokens puts its sorted tiles through
+    the grouped product: one that does not compute in place, over experts
+    ``[d, f]`` wide in whole lanes (every published width; the toy widths of
+    the CPU tests keep the loop over the tiles, the plain form)."""
+    return not rows_in_place(n_rows) and grouped_experts.lane_aligned(d, f)
 
 
 def _most_experts_touched(n_touched, e: int):
@@ -878,16 +932,39 @@ def layers_batched(n_rows: int, load) -> jax.Array:
         jnp.sum(load > 0, dtype=jnp.int32), load.shape[0]).astype(jnp.int32)
 
 
+def _choices_where(cfg, programs: tuple, takes) -> int:
+    """``rows x tokens a row x top_k x expert layers`` of each dispatched
+    program (:class:`paged.Dispatched`) whose count of tokens ``takes``."""
+    return cfg.top_k * (cfg.n_layers - cfg.first_dense) * sum(
+        p.rows * p.t for p in programs if takes(p.rows * p.t))
+
+
 def choices_in_place(cfg, programs: tuple) -> int:
     """The choices of the dispatched programs whose expert layers computed in
-    place: ``rows x tokens a row x top_k x expert layers`` of each program
-    (:class:`paged.Dispatched`) within :func:`rows_in_place`, the
-    function the program's own form comes from.  Every row counts, idle and
-    padded ones too (the layer computes over them), where
-    ``moe.choices_total`` counts the real tokens' on the device: over
-    programs whose rows are all live the two are alike."""
-    return cfg.top_k * (cfg.n_layers - cfg.first_dense) * sum(
-        p.rows * p.t for p in programs if rows_in_place(p.rows * p.t))
+    place: those within :func:`rows_in_place`, the function the program's
+    own form comes from.  Every row counts, idle and padded ones too (the
+    layer computes over them), where ``moe.choices_total`` counts the real
+    tokens' on the device: over programs whose rows are all live the two are
+    alike."""
+    return _choices_where(cfg, programs, rows_in_place)
+
+
+def count_choices_by_form(metrics, cfg, programs: tuple) -> None:
+    """``moe.choices_in_place`` and ``moe.choices_grouped`` of a step's
+    dispatched programs, added to the engine's registry."""
+    metrics.counter("moe.choices_in_place").inc(
+        choices_in_place(cfg, programs))
+    metrics.counter("moe.choices_grouped").inc(
+        choices_grouped(cfg, programs))
+
+
+def choices_grouped(cfg, programs: tuple) -> int:
+    """The choices of the dispatched programs whose expert layers ran the
+    grouped product, counted as :func:`choices_in_place` counts its own and
+    by :func:`rows_grouped`, the predicate :func:`held_experts` goes by
+    (``cfg.dim`` and ``cfg.expert_dim`` are the widths its experts have)."""
+    return _choices_where(cfg, programs, lambda n: rows_grouped(
+        n, cfg.dim, cfg.expert_dim))
 
 
 def _experts_in_place(cfg, lp, h2, group, weights, load):
@@ -940,15 +1017,16 @@ def _experts_in_place(cfg, lp, h2, group, weights, load):
     return y.astype(dt)
 
 
-def _experts_in_tiles(cfg, lp, h2, held, group, weights, load):
-    """The choices sorted by expert into tile-aligned segments, a loop over
-    the tiles in use (one expert's weights a tile), and each token's
-    outcomes gathered back and summed by rank."""
+def _experts_in_tiles(cfg, lp, h2, held, group, weights, load, tiles):
+    """The choices sorted by expert into tile-aligned segments, every tile in
+    use through its expert (``tiles``: :func:`_tiles_grouped` or
+    :func:`_tiles_looped`), and each token's outcomes gathered back and
+    summed by rank."""
     dt = cfg.dtype
     n, d = h2.shape
     e, k = cfg.held_count, cfg.top_k
+    tile = tile_rows(n, cfg)
     with jax.named_scope("moe.route"):
-        tile = TILE_ROWS
         padded = -(-load // tile) * tile
         seg_end = jnp.cumsum(padded)
         seg_start = seg_end - padded
@@ -965,22 +1043,47 @@ def _experts_in_tiles(cfg, lp, h2, held, group, weights, load):
         src = jnp.full((rows,), n, jnp.int32).at[dest].set(token, mode="drop")
     with jax.named_scope("moe.experts"):
         x_rows = jnp.concatenate([h2, jnp.zeros((1, d), dt)])[src]  # [R, d]
-        n_tiles = seg_end[-1] // tile
-
-        def one_tile(i, y):
-            j = jnp.searchsorted(seg_end, i * tile, side="right")
-            x = lax.dynamic_slice_in_dim(x_rows, i * tile, tile)
-            out = _swiglu(x, lp["e_gate"][j], lp["e_up"][j], lp["e_down"][j],
-                          dt)
-            return lax.dynamic_update_slice_in_dim(y, out, i * tile, axis=0)
-
-        y_rows = lax.fori_loop(0, n_tiles, one_tile,
-                               jnp.zeros((rows, d), dt))
-        picked = jnp.concatenate([y_rows, jnp.zeros((1, d), dt)])[
-            dest.reshape(n, k)]                                  # [N, k, d]
-        y = jnp.sum(picked.astype(jnp.float32)
-                    * jnp.where(held, weights, 0.0)[..., None], axis=1)
+        y_rows = tiles(cfg, lp, x_rows, seg_end, tile)
+        # a choice that is not held points past the rows: it reads the last
+        # one and is selected away (a zero row appended to gather instead
+        # was a copy of all of y_rows, 84 MB a layer at sdar's widths)
+        picked = jnp.where(
+            held[..., None],
+            y_rows[jnp.minimum(dest, rows - 1).reshape(n, k)], 0)  # [N, k, d]
+        y = jnp.sum(picked.astype(jnp.float32) * weights[..., None], axis=1)
     return y.astype(dt)
+
+
+def _tiles_grouped(cfg, lp, x_rows, seg_end, tile: int):
+    """Every tile in use through its expert in one kernel call
+    (:func:`grouped_experts.grouped_swiglu`): the tile -> expert map is
+    searched once, here, for all tiles; the rows of the tiles past the last
+    in use are not written, and no choice points at them."""
+    e = lp["e_gate"].shape[0]
+    first_row = jnp.arange(x_rows.shape[0] // tile, dtype=jnp.int32) * tile
+    # every tile against every segment's end at once: a search by halving
+    # is a loop of its own on the device
+    tile_expert = jnp.minimum(jnp.searchsorted(
+        seg_end, first_row, side="right", method="compare_all"), e - 1)
+    return grouped_experts.grouped_swiglu(
+        x_rows, tile_expert.astype(jnp.int32), seg_end[-1] // tile,
+        lp["e_gate"], lp["e_up"], lp["e_down"], tile=tile, dtype=cfg.dtype)
+
+
+def _tiles_looped(cfg, lp, x_rows, seg_end, tile: int):
+    """The plain form, for widths that are not whole lanes (the toy
+    configurations of the CPU tests): a loop over the tiles in use, one
+    expert's matrices sliced out a trip."""
+
+    def one_tile(i, y):
+        j = jnp.searchsorted(seg_end, i * tile, side="right")
+        x = lax.dynamic_slice_in_dim(x_rows, i * tile, tile)
+        out = _swiglu(x, lp["e_gate"][j], lp["e_up"][j], lp["e_down"][j],
+                      cfg.dtype)
+        return lax.dynamic_update_slice_in_dim(y, out, i * tile, axis=0)
+
+    return lax.fori_loop(0, seg_end[-1] // tile, one_tile,
+                         jnp.zeros(x_rows.shape, cfg.dtype))
 
 
 def _expert_layer(cfg, lp, h, valid):

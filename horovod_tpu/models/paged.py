@@ -438,8 +438,9 @@ def publish_state_metrics(metrics, cfg: Any, pcache: Any, stats_host,
     counts from zero); after a step the share of the tables attention walked
     (``attn.blocks_*``, as :mod:`llama` counts them from ``programs`` and
     ``walk_split``) and
-    ``moe.choices_in_place`` (:func:`latent_moe.choices_in_place` of the
-    step's programs, not read back); where a tick's readback brought
+    ``moe.choices_in_place`` and ``moe.choices_grouped``
+    (:func:`latent_moe.count_choices_by_form` of the step's programs, not
+    read back); where a tick's readback brought
     ``stats_host``, the device's counters (``read(stats_host)``, the
     model's ``read_counters``) under ``counted``, the experts touched and
     each held expert's load.  Returns the counters read, ``None`` where no
@@ -455,8 +456,7 @@ def publish_state_metrics(metrics, cfg: Any, pcache: Any, stats_host,
             device_total.set(0)
     llama.publish_paged_metrics(metrics, cfg, pcache, programs=programs,
                                 walk_split=walk_split)
-    metrics.counter("moe.choices_in_place").inc(
-        latent_moe.choices_in_place(cfg, programs))
+    latent_moe.count_choices_by_form(metrics, cfg, programs)
     if stats_host is None:          # nothing was read back: no tick ran
         return None
     c = read(stats_host)

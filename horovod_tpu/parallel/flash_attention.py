@@ -28,6 +28,7 @@ import contextlib
 import functools
 import importlib
 import math
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -47,6 +48,13 @@ class _OnFirstUse:
 
     def __getattr__(self, attr: str):
         return getattr(importlib.import_module(self._name), attr)
+
+    def preload(self) -> None:
+        """Start the import on a thread of its own, for a caller that knows
+        a kernel will be asked for: the second of import then passes while
+        the process makes its weights, not inside the first trace."""
+        threading.Thread(target=importlib.import_module, args=(self._name,),
+                         name=f"import {self._name}", daemon=True).start()
 
 
 pl = _OnFirstUse("jax.experimental.pallas")
@@ -69,6 +77,12 @@ def interpret_mode(on: bool = True):
         yield
     finally:
         _interpret = prev
+
+
+def interpreted() -> bool:
+    """Whether a kernel traced now goes to the Pallas interpreter: the one
+    switch every Pallas kernel of the package follows."""
+    return _interpret
 
 
 def _out_vma(*arrays):

@@ -33,6 +33,17 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _mosaic_not_the_interpreter():
+    """The suite's session traces Pallas kernels for the interpreter
+    (``tests/conftest.py``); what is compiled here for the described chip
+    holds the Mosaic kernels a chip would run."""
+    from horovod_tpu.parallel.flash_attention import interpret_mode
+
+    with interpret_mode(False):
+        yield
+
+
 @pytest.fixture(scope="module")
 def no_compile_cache():
     """Such a compile is written to the persistent cache and cannot be read
@@ -172,15 +183,19 @@ def _computations(hlo: str) -> dict:
     return {k: "\n".join(v) for k, v in out.items()}
 
 
-def _reaches_a_while(comps: dict, name: str, seen: set) -> bool:
-    """Whether computation ``name``, or one it calls, holds a ``while``."""
+def _reaches(comps: dict, name: str, wanted, seen: set) -> bool:
+    """Whether computation ``name``, or one it calls, holds a line that
+    ``wanted`` matches."""
     if name in seen:
         return False
     seen.add(name)
     body = comps[name]
-    return " while(" in body or any(
-        _reaches_a_while(comps, ref, seen)
+    return bool(wanted.search(body)) or any(
+        _reaches(comps, ref, wanted, seen)
         for ref in re.findall(r"%([\w.\-]+)", body) if ref in comps)
+
+
+_A_WHILE = re.compile(r" while\(")
 
 
 def test_the_shortconv_tick_computes_its_experts_in_place(shortconv_compiled):
@@ -200,8 +215,8 @@ def test_the_shortconv_tick_computes_its_experts_in_place(shortconv_compiled):
     loops = re.findall(r"^.* while\(.*?body=%([\w.\-]+).*$", hlo, re.M)
     assert loops and all(b in comps for b in loops)
     nested = [line for line in hlo.splitlines() if " while(" in line
-              and _reaches_a_while(comps, re.search(
-                  r"body=%([\w.\-]+)", line).group(1), set())]
+              and _reaches(comps, re.search(
+                  r"body=%([\w.\-]+)", line).group(1), _A_WHILE, set())]
     assert len(nested) == sum(k == sm.ATTN for k in cfg.layer_kinds)
     assert all("attn.gqa" in line for line in nested), nested
     sorts = [line for line in hlo.splitlines() if " sort(" in line]
@@ -211,75 +226,78 @@ def test_the_shortconv_tick_computes_its_experts_in_place(shortconv_compiled):
         <= PARENT_TICK_TEMP_BYTES
 
 
-def _held_experts_in_tiles_only(cfg, lp, h2, valid):
-    """``latent_moe.held_experts`` as it was before any program computed in
-    place (PR 31), kept to compare lowerings with."""
-    dt = cfg.dtype
-    n, d = h2.shape
-    e, k = cfg.held_count, cfg.top_k
-    experts, weights = lm.route(cfg, lp, h2)
-    local = experts - cfg.held_first
-    held = (local >= 0) & (local < e) & valid[:, None]
-    group = jnp.where(held, local, e).reshape(n * k)
-    load = jnp.sum(jax.nn.one_hot(group, e + 1, dtype=jnp.int32),
-                   axis=0)[:e]
-    tile = lm.TILE_ROWS
-    padded = -(-load // tile) * tile
-    seg_end = jnp.cumsum(padded)
-    seg_start = seg_end - padded
-    order = jnp.argsort(group, stable=True)
-    place = jnp.zeros((n * k,), jnp.int32).at[order].set(
-        jnp.arange(n * k, dtype=jnp.int32))
-    before = jnp.cumsum(load) - load
-    g = jnp.minimum(group, e - 1)
-    rows = n * k + e * tile
-    dest = jnp.where(group < e, seg_start[g] + place - before[g], rows)
-    token = jnp.arange(n * k, dtype=jnp.int32) // k
-    src = jnp.full((rows,), n, jnp.int32).at[dest].set(token, mode="drop")
-    x_rows = jnp.concatenate([h2, jnp.zeros((1, d), dt)])[src]
-    n_tiles = seg_end[-1] // tile
-
-    def one_tile(i, y):
-        j = jnp.searchsorted(seg_end, i * tile, side="right")
-        x = lax.dynamic_slice_in_dim(x_rows, i * tile, tile)
-        out = lm._swiglu(x, lp["e_gate"][j], lp["e_up"][j], lp["e_down"][j],
-                         dt)
-        return lax.dynamic_update_slice_in_dim(y, out, i * tile, axis=0)
-
-    y_rows = lax.fori_loop(0, n_tiles, one_tile, jnp.zeros((rows, d), dt))
-    picked = jnp.concatenate([y_rows, jnp.zeros((1, d), dt)])[
-        dest.reshape(n, k)]
-    y = jnp.sum(picked.astype(jnp.float32)
-                * jnp.where(held, weights, 0.0)[..., None], axis=1)
-    return y.astype(dt), load
+def _grouped_products(hlo: str) -> list:
+    """The Mosaic calls of the expert layers' grouped product in an optimised
+    module's text."""
+    return [line for line in hlo.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            and "moe.experts/grouped_swiglu" in line]
 
 
-def test_a_chunk_of_many_rows_lowers_to_the_program_it_was(monkeypatch):
-    """The second model's chunk of 512 rows, at the published widths, is
-    over :data:`latent_moe.IN_PLACE_ROWS`: letter for letter the program it
-    was when every program sorted its choices into tiles.  (Lowered for the
-    host; nothing is compiled.)"""
+def _assert_the_experts_are_one_grouped_product(hlo, cfg, e_gate_slice):
+    """One kernel call an expert layer, compiled by Mosaic (the interpreter's
+    expansion is a loop of its own and no custom call), and no loop left that
+    looks an expert up or slices one out of ``e_gate``: what every trip of
+    the loop over the tiles did."""
+    assert len(_grouped_products(hlo)) == cfg.n_layers - cfg.first_dense
+    comps = _computations(hlo)
+    per_trip = re.compile(
+        r"searchsorted| dynamic-slice\(.*dynamic_slice_sizes="
+        + re.escape(e_gate_slice))
+    for line in hlo.splitlines():
+        if " while(" in line:
+            assert "moe.experts" not in line, line
+            body = re.search(r"body=%([\w.\-]+)", line).group(1)
+            assert not _reaches(comps, body, per_trip, set()), line
+
+
+#: scratch of dots3's chunk of 512 tokens and of sdar's block tick at their
+#: cells' sizes on the parent of PR 46 (the loop over the tiles), compiled
+#: the same way
+PARENT_CHUNK_TEMP_BYTES = {"dots3": 979_840_512, "sdar": 326_099_456}
+
+
+@pytest.fixture(scope="module")
+def latent_chunk_compiled(one_chip, no_compile_cache):
+    """The second model's chunk of 512 tokens at the published widths and
+    ``dots3_longdoc32k``'s size (8 slots of 32,768 over blocks of 512),
+    compiled once."""
     cfg = lm.LatentMoEConfig()
-    assert not lm.rows_in_place(512)
-    params = jax.eval_shape(lambda: lm.init_params(cfg, jax.random.key(0)))
-    cache = jax.eval_shape(lambda: lm.init_paged_cache(
-        cfg, 8, 32768, block_size=512))
-    logits = jax.ShapeDtypeStruct((8, cfg.vocab_size), jnp.float32)
-    i32 = jax.ShapeDtypeStruct((), jnp.int32)
-    args = (params, cache, logits,
-            jax.ShapeDtypeStruct((1, 512), jnp.int32), i32, i32, i32)
+    e = _benchmark_json("traffic", "longdoc32k.json")["engine"]
+    n_slots, chunk_len = e["n_slots"], e["chunk"]
+    params = _avals(jax.eval_shape(
+        lambda: lm.init_params(cfg, jax.random.key(0))), one_chip)
+    cache = _avals(jax.eval_shape(lambda: lm.init_paged_cache(
+        cfg, n_slots, e["max_len"], block_size=chunk_len)), one_chip)
+    logits = jax.ShapeDtypeStruct((n_slots, cfg.vocab_size), jnp.float32,
+                                  sharding=one_chip)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
 
-    def lowered():
-        @partial(jax.jit, donate_argnums=(1, 2))
-        def chunk(params, pcache, last_logits, toks, slot, new_len, sel):
-            out, pcache = lm.decode_chunk_paged_row(
-                params, toks, cfg, pcache, slot, new_length=new_len)
-            return pcache, last_logits.at[slot].set(out[0, sel])
-        return chunk.lower(*args).as_text()
+    @partial(jax.jit, donate_argnums=(1, 2))
+    def chunk(params, pcache, last_logits, toks, slot, new_len, sel):
+        out, pcache = lm.decode_chunk_paged_row(
+            params, toks, cfg, pcache, slot, new_length=new_len)
+        return pcache, last_logits.at[slot].set(out[0, sel])
 
-    now = lowered()
-    monkeypatch.setattr(lm, "held_experts", _held_experts_in_tiles_only)
-    assert now == lowered()
+    return chunk.lower(params, cache, logits, jax.ShapeDtypeStruct(
+        (1, chunk_len), jnp.int32, sharding=one_chip), i32, i32,
+        i32).compile(), cfg
+
+
+def test_a_chunk_of_many_rows_holds_one_grouped_product_an_expert_layer(
+        latent_chunk_compiled):
+    """The second model's chunk of 512 tokens is over
+    :data:`latent_moe.IN_PLACE_ROWS`: compiled for the chip, each of its four
+    expert layers sorts its choices into tiles and hands them to one Mosaic
+    call; the loop over the tiles, with its search for the tile's expert and
+    its slices of one expert's matrices a trip, is gone, and the scratch is
+    no larger than the loop's was."""
+    program, cfg = latent_chunk_compiled
+    assert lm.rows_grouped(512, cfg.dim, cfg.expert_dim)
+    _assert_the_experts_are_one_grouped_product(
+        program.as_text(), cfg, "{1,%d,%d}" % (cfg.dim, cfg.expert_dim))
+    assert program.memory_analysis().temp_size_in_bytes \
+        <= PARENT_CHUNK_TEMP_BYTES["dots3"]
 
 
 #: what one v5e chip leaves a program: 15.75 GiB less the runtime's 258 MiB
@@ -520,25 +538,11 @@ def _route_as_it_was(cfg, lp, h2):
     return experts, picked / total * cfg.routed_scale
 
 
-@pytest.mark.parametrize("model", ["shortconv", "window"])
-def test_the_snapshot_rules_two_users_lower_to_the_programs_they_were(
-        model, monkeypatch):
-    """lfm2's and K-EXAONE's tick, chunk and table write at their cells'
-    sizes are, letter for letter, the programs they were before the router
-    had a second rule; their ``set_row`` keeps its four arguments (the
-    engine jits the five-argument form only for a model that has a
-    ``snapshot_budget``).  (Lowered for the host; nothing is compiled.)"""
-    from horovod_tpu.models import window_moe as wm
-
-    if model == "shortconv":
-        mod, cfg = sm, sm.ShortConvMoEConfig()
-        n_slots, max_len, chunk_len, n_blocks = 128, 2048, 256, 1041
-    else:
-        mod, cfg = wm, wm.WindowMoEConfig()
-        e = _mixedq_engine()
-        n_slots, max_len, chunk_len, n_blocks = (
-            e["n_slots"], e["max_len"], e["chunk"], e["n_blocks"])
-    assert not hasattr(mod, "snapshot_budget")
+def _lowered(mod, cfg, n_slots, max_len, chunk_len, n_blocks) -> dict:
+    """The text of a served model's tick, one-row chunk and table write as
+    they are traced now, lowered for the TPU platform (a chunk over
+    :data:`latent_moe.IN_PLACE_ROWS` holds a Mosaic kernel, which has no
+    lowering for the host); nothing is compiled."""
     params = jax.eval_shape(lambda: mod.init_params(cfg, jax.random.key(0)))
     cache = jax.eval_shape(lambda: mod.init_paged_cache(
         cfg, n_slots, max_len, block_size=chunk_len, n_blocks=n_blocks))
@@ -546,32 +550,90 @@ def test_the_snapshot_rules_two_users_lower_to_the_programs_they_were(
     i32 = jax.ShapeDtypeStruct((), jnp.int32)
     row = jax.ShapeDtypeStruct((max_len // chunk_len,), jnp.int32)
 
-    def lowered() -> dict:
-        @partial(jax.jit, donate_argnums=(1, 2))
-        def tick(params, pcache, last_logits, active):
-            tok = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
-            out, pcache = mod.decode_chunk_paged(
-                params, tok[:, None], cfg, pcache, advance=active)
-            return out[:, 0], pcache
+    @partial(jax.jit, donate_argnums=(1, 2))
+    def tick(params, pcache, last_logits, active):
+        tok = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
+        out, pcache = mod.decode_chunk_paged(
+            params, tok[:, None], cfg, pcache, advance=active)
+        return out[:, 0], pcache
 
-        @partial(jax.jit, donate_argnums=(1, 2))
-        def chunk(params, pcache, last_logits, toks, slot, new_len, sel):
-            out, pcache = mod.decode_chunk_paged_row(
-                params, toks, cfg, pcache, slot, new_length=new_len)
-            return pcache, last_logits.at[slot].set(out[0, sel])
+    @partial(jax.jit, donate_argnums=(1, 2))
+    def chunk(params, pcache, last_logits, toks, slot, new_len, sel):
+        out, pcache = mod.decode_chunk_paged_row(
+            params, toks, cfg, pcache, slot, new_length=new_len)
+        return pcache, last_logits.at[slot].set(out[0, sel])
 
-        set_row = jax.jit(mod.set_row, donate_argnums=(0,))
-        return {
-            "tick": tick.lower(params, cache, logits, jax.ShapeDtypeStruct(
-                (n_slots,), jnp.int32)).as_text(),
-            "chunk": chunk.lower(
-                params, cache, logits, jax.ShapeDtypeStruct(
-                    (1, chunk_len), jnp.int32), i32, i32, i32).as_text(),
-            "set_row": set_row.lower(cache, i32, row, i32).as_text()}
+    set_row = jax.jit(mod.set_row, donate_argnums=(0,))
 
-    now = lowered()
+    def text(fn, *args):
+        return fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+
+    return {
+        "tick": text(tick, params, cache, logits, jax.ShapeDtypeStruct(
+            (n_slots,), jnp.int32)),
+        "chunk": text(chunk, params, cache, logits, jax.ShapeDtypeStruct(
+            (1, chunk_len), jnp.int32), i32, i32, i32),
+        "set_row": text(set_row, cache, i32, row, i32)}
+
+
+@pytest.mark.parametrize("model", ["shortconv", "window"])
+def test_the_snapshot_rules_two_users_lower_to_the_programs_they_were(
+        model, monkeypatch):
+    """lfm2's and K-EXAONE's tick, chunk and table write at their cells'
+    sizes are, letter for letter, the programs they were before the router
+    had a second rule; their ``set_row`` keeps its four arguments (the
+    engine jits the five-argument form only for a model that has a
+    ``snapshot_budget``).  (Lowered, not compiled.)"""
+    from horovod_tpu.models import window_moe as wm
+
+    if model == "shortconv":
+        mod, cfg = sm, sm.ShortConvMoEConfig()
+        size = (128, 2048, 256, 1041)
+    else:
+        mod, cfg = wm, wm.WindowMoEConfig()
+        e = _mixedq_engine()
+        size = (e["n_slots"], e["max_len"], e["chunk"], e["n_blocks"])
+    assert not hasattr(mod, "snapshot_budget")
+    now = _lowered(mod, cfg, *size)
     monkeypatch.setattr(lm, "route", _route_as_it_was)
-    assert now == lowered()
+    assert now == _lowered(mod, cfg, *size)
+
+
+def _held_experts_as_it_was(cfg, lp, h2, valid):
+    """``latent_moe.held_experts`` for a program within
+    :data:`latent_moe.IN_PLACE_ROWS` as it was before a longer program's
+    tiles went through one grouped product (PR 45), kept to compare
+    lowerings with."""
+    n = h2.shape[0]
+    e = cfg.held_count
+    assert n <= lm.IN_PLACE_ROWS
+    with jax.named_scope("moe.route"):
+        experts, weights = lm.route(cfg, lp, h2)
+        local = experts - cfg.held_first
+        held = (local >= 0) & (local < e) & valid[:, None]
+        group = jnp.where(held, local, e).reshape(n * cfg.top_k)
+        load = jnp.sum(jax.nn.one_hot(group, e + 1, dtype=jnp.int32),
+                       axis=0)[:e]
+    return lm._experts_in_place(cfg, lp, h2, group, weights, load), load
+
+
+@pytest.mark.parametrize("model", ["shortconv", "window"])
+def test_programs_within_in_place_rows_lower_to_the_programs_they_were(
+        model, monkeypatch):
+    """A tick of 128 rows and a one-row chunk of 256 tokens compute their
+    experts in place and hold no grouped product: letter for letter the
+    programs they were before the sorted tiles had one, in the third model
+    and in the fourth.  (Lowered, not compiled.)"""
+    from horovod_tpu.models import window_moe as wm
+
+    mod, cfg = ((sm, sm.ShortConvMoEConfig()) if model == "shortconv"
+                else (wm, wm.WindowMoEConfig()))
+    size = (128, 2048, 256, 1041)
+    assert lm.rows_in_place(128) and lm.rows_in_place(256)
+    now = _lowered(mod, cfg, *size)
+    assert not any("grouped_swiglu" in text for text in now.values())
+    monkeypatch.setattr(lm, "held_experts", _held_experts_as_it_was)
+    assert now == _lowered(mod, cfg, *size)
 
 
 # -- the Mistral programs read wq / wk / wv inside one product (PR 42) -------
@@ -833,3 +895,19 @@ def test_the_unmask_program_reads_logits_and_allocates_next_to_nothing(
     assert mem.argument_size_in_bytes < 128 * cfg.block_length \
         * cfg.vocab_size * 4 + 1e5
     assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 1e6
+
+
+def test_the_block_tick_holds_one_grouped_product_an_expert_layer(
+        block_diffusion_compiled):
+    """``sdar_blockgen``'s block tick is 128 rows of 4 positions, 512 tokens:
+    over :data:`latent_moe.IN_PLACE_ROWS`, so each of its six expert layers
+    is one Mosaic call over the sorted tiles and no loop of the program
+    looks an expert up or slices one out; the scratch is no larger than the
+    loop's was."""
+    compiled, _, cfg = block_diffusion_compiled
+    assert lm.rows_grouped(128 * cfg.block_length, cfg.dim, cfg.expert_dim)
+    program = compiled("tick")
+    _assert_the_experts_are_one_grouped_product(
+        program.as_text(), cfg, "{1,%d,%d}" % (cfg.dim, cfg.expert_dim))
+    assert program.memory_analysis().temp_size_in_bytes \
+        <= PARENT_CHUNK_TEMP_BYTES["sdar"]
