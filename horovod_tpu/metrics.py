@@ -154,6 +154,11 @@ METRIC_HELP: dict[str, str] = {
     "hvd.negotiate_s": "Seconds from eager-op enqueue to negotiated dispatch",
     "hvd.step_s": "Per-rank engine/training step wall time in seconds",
     "hvd.step_skew_s": "Slowest-minus-median rank step time over the straggler window",
+    # fusion.* — the plan of the last in-graph fused exchange traced (ops/fusion.py)
+    "fusion.buckets": "Collectives the exchange emits: one a bucket of the plan",
+    "fusion.bucket_bytes_max": "Bytes of the plan's largest bucket (at most the fusion threshold unless it is one leaf)",
+    "fusion.leaves_in_place": "Leaves that go into their bucket's collective as they lie (no concatenate, no slice)",
+    "fusion.leaves_packed": "Leaves that ride in a bucket's flat piece (flattened, concatenated, cut out again)",
     # serve.* — ServeEngine request latencies and occupancy
     "serve.queue_wait_s": "Seconds a request waited from submit to first admission",
     "serve.ttft_s": "Seconds from submit to first emitted token",

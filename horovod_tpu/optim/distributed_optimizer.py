@@ -13,9 +13,18 @@ gradient + background fusion" collapses into a gradient transformation:
 ``DistributedOptimizer(tx)`` returns an ``optax.GradientTransformation``
 whose ``update`` all-reduces the gradient pytree over the mesh axis (fused
 into ≤ threshold buckets, compression applied) before delegating to ``tx``.
-XLA then overlaps those psums with the backward pass the same way Horovod
-overlaps NCCL with autograd — but scheduled by the compiler, not a cycle
-thread.
+Nothing of it overlaps with the backward pass, and on a TPU v5e nothing
+should: left to itself XLA merges the psums of a step into one all-reduce
+after the last convolution; chained buckets are placed inside the backward
+pass, synchronous, and slow the convolutions around them; under the
+compiler's options for asynchronous all-reduce less than half of the
+exchange is hidden and the step is slower still (``PERF.md`` section 6,
+PR 47: the plans tried on four chips, with the schedule each compiled to).
+What the plan buys is the packing: :func:`allreduce_gradients` reduces every
+leaf where it lies, in buckets chained and held behind the backward pass
+(``ops/fusion.py``).  The proof is the schedule compiled for four described
+chips (``tests/test_chip_compile.py``, the cases over ``dp4_step``) and the
+ledger's ``resnet50_dp4``.
 
 Use inside ``shard_map``/``pjit`` over a mesh with the data axis, or via
 :func:`make_train_step`, which builds the canonical step function.
@@ -31,7 +40,7 @@ import optax
 
 from horovod_tpu import basics
 from horovod_tpu.basics import AXIS_NAME
-from horovod_tpu.ops import collective_ops
+from horovod_tpu.ops import collective_ops, fusion
 from horovod_tpu.ops.collective_ops import Average, Sum, _ReduceOp
 from horovod_tpu.ops.compression import Compression, TopKCompressor
 
@@ -54,6 +63,15 @@ def allreduce_gradients(
     (tensorflow/__init__.py:183-209), with Tensor Fusion applied
     structurally: leaves are bucketed (same dtype, ≤ threshold bytes) and
     each bucket is ONE psum (operations.cc:1916-1943's merge, compiled).
+
+    A plain or cast-compressed Sum / Average reduces every leaf where it
+    lies (:func:`fusion.reduce_in_place`: a bucket is one variadic psum,
+    nothing is concatenated or cut out again; 16 MiB a bucket where
+    ``fusion_threshold_bytes`` is None), the buckets chained behind what
+    yields the gradients.  Everything else keeps its packed wire
+    (:func:`collective_ops.grouped_allreduce`, 64 MiB a bucket): int8's
+    blocks want the flat buffer, Adasum is one collective a leaf, a
+    ``process_set`` has its groups; ``sparse`` has its all-gathers.
     """
     leaves, treedef = jax.tree.flatten(grads)
     if sparse and process_set is not None:
@@ -67,6 +85,28 @@ def allreduce_gradients(
             topk.sparse_allreduce(g, average=op is Average, axis_name=axis_name)
             for g in leaves
         ]
+    elif (
+        op in (Sum, Average)
+        and process_set is None
+        and not callable(getattr(compression, "quantized_allreduce", None))
+    ):
+
+        def reduce_bucket(leaves):
+            wire, ctxs = zip(*map(compression.compress, leaves))
+            return [
+                compression.decompress(r, ctx) for r, ctx in zip(
+                    collective_ops._reduce(list(wire), op, axis_name), ctxs)
+            ]
+
+        reduced = fusion.reduce_in_place(
+            leaves,
+            reduce_bucket,
+            threshold_bytes=fusion_threshold_bytes,
+            # one member has nothing to exchange: XLA drops its collectives,
+            # and a barrier would only keep the update out of the fusions
+            # the gradients end in
+            chained=collective_ops._axis_size(axis_name) > 1,
+        )
     else:
         reduced = collective_ops.grouped_allreduce(
             leaves,

@@ -1,8 +1,10 @@
-"""Programs of the serving path compiled at real widths for a described TPU
-v5e, with no chip: what interpret-mode and CPU tests cannot see.  The
-topology is described inside a fixture (never while a module is imported), in
-this one file, and the tests skip where it cannot be described."""
+"""Programs of the serving path, and the data-parallel train step, compiled
+at real widths for a described TPU v5e, with no chip: what interpret-mode and
+CPU tests cannot see.  The topology is described inside a fixture (never
+while a module is imported), in this one file, and the tests skip where it
+cannot be described."""
 
+import math
 import os
 import re
 from functools import partial
@@ -22,14 +24,18 @@ PARENT_TICK_TEMP_BYTES = 161_400_000
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -911,3 +917,140 @@ def test_the_block_tick_holds_one_grouped_product_an_expert_layer(
         program.as_text(), cfg, "{1,%d,%d}" % (cfg.dim, cfg.expert_dim))
     assert program.memory_analysis().temp_size_in_bytes \
         <= PARENT_CHUNK_TEMP_BYTES["sdar"]
+
+
+# --- the data-parallel train step: where the gradient exchange is placed ----
+
+
+def _resnet_step(devices):
+    """``resnet50_dp4``'s step (``benchmark/configs/resnet50.json`` under
+    ``traffic/synthetic_b128_dp.json``) over ``devices``, as the family
+    builds it, and its arguments as shapes on the mesh."""
+    import numpy as np
+    import optax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import horovod_tpu as hvd
+    from horovod_tpu.models.resnet import ResNet
+
+    cfg = _benchmark_json("configs", "resnet50.json")
+    per_chip = _benchmark_json("traffic",
+                               "synthetic_b128_dp.json")["per_chip_batch"]
+    mesh = Mesh(np.array(devices), ("hvd",))
+    model = ResNet(stage_sizes=tuple(cfg["stage_sizes"]),
+                   num_classes=cfg["num_classes"], width=cfg["width"],
+                   dtype=jnp.dtype(cfg["compute_dtype"]))
+    size = cfg["image_size"]
+    shapes = jax.eval_shape(partial(model.init, train=False),
+                            jax.random.key(0), jnp.zeros((1, size, size, 3)))
+    batch_stats = jax.tree.map(lambda s: np.zeros(s.shape, s.dtype),
+                               shapes["batch_stats"])
+
+    def loss_fn(p, batch):
+        x, y = batch
+        logits, _ = model.apply({"params": p, "batch_stats": batch_stats}, x,
+                                train=True, mutable=["batch_stats"])
+        return optax.softmax_cross_entropy_with_integer_labels(
+            logits, y).mean()
+
+    opt = cfg["optimizer"]
+    tx = hvd.DistributedOptimizer(optax.sgd(
+        opt["lr_per_chip"] * len(devices), momentum=opt["momentum"]))
+    replicated = NamedSharding(mesh, P())
+    rows = NamedSharding(mesh, P("hvd"))
+    n = per_chip * len(devices)
+    args = (_avals(shapes["params"], replicated),
+            _avals(jax.eval_shape(tx.init, shapes["params"]), replicated),
+            (jax.ShapeDtypeStruct((n, size, size, 3), jnp.float32,
+                                  sharding=rows),
+             jax.ShapeDtypeStruct((n,), jnp.int32, sharding=rows)))
+    return hvd.make_train_step(loss_fn, tx, mesh=mesh), args
+
+
+def _entry_ops(hlo: str) -> list:
+    """The entry computation of a scheduled module, an op a line, in the
+    order the device runs them."""
+    assert "is_scheduled=true" in hlo[:200]
+    return re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", hlo,
+                     re.S | re.M).group(1).splitlines()
+
+
+def _result_shapes(line: str, op: str) -> list:
+    """The shapes, as tuples, of what ``op`` on ``line`` yields."""
+    return [tuple(int(d) for d in filter(None, dims.split(",")))
+            for dims in re.findall(r"\b[a-z]+\d+\[([\d,]*)\]",
+                                   line.split(f" {op}(")[0])]
+
+
+@pytest.fixture(scope="module")
+def dp4_step(topo, no_compile_cache):
+    """The cell's step compiled once for the four described chips: the
+    entry computation's ops, the indices of its collectives and of its
+    convolution fusions, and the gradient leaves' shapes."""
+    step, args = _resnet_step(topo.devices)
+    ops = _entry_ops(step.lower(*args).compile().as_text())
+    return {"ops": ops,
+            "collectives": [i for i, op in enumerate(ops)
+                            if " all-reduce(" in op],
+            "convolutions": [i for i, op in enumerate(ops)
+                             if "kind=kOutput" in op],
+            "leaves": jax.tree.leaves(args[0])}
+
+
+def test_the_dp4_step_packs_no_bucket_of_gradients(dp4_step):
+    """102 MB of gradients, and no ``concatenate`` of the program yields as
+    much as a megabyte (the parent packed two buffers of 60 and 42 MB and
+    cut them up again)."""
+    packed = [math.prod(shape) for op in dp4_step["ops"]
+              if " concatenate(" in op
+              for shape in _result_shapes(op, "concatenate")]
+    assert sum(x.size for x in dp4_step["leaves"]) * 4 > 100e6
+    assert all(4 * n < 1 << 20 for n in packed), packed
+
+
+def test_the_dp4_step_keeps_its_buckets_apart(dp4_step):
+    """The chained buckets stay several collectives (independent ones the
+    compiler merges into one), none of them asynchronous (the chip's verdict
+    on those is in ``PERF.md``, PR 47)."""
+    from horovod_tpu.ops import fusion
+
+    buckets = fusion.plan_buckets(dp4_step["leaves"],
+                                  fusion.IN_PLACE_THRESHOLD_BYTES)
+    assert len(dp4_step["collectives"]) >= len(buckets) > 2
+    assert not [op for op in dp4_step["ops"]
+                if "all-reduce-start" in op or "async-collective" in op]
+
+
+def test_the_dp4_step_exchanges_after_its_last_convolution(dp4_step):
+    """Every collective is scheduled behind the last convolution fusion and
+    none between two of them: where the chain alone put them (the first
+    after 71 of the 162 convolution fusions) each cost the fusions around
+    it more than its bucket saved (four chips, ``PERF.md`` section 6,
+    PR 47: 48.87 ms a step inside the backward pass, 48.33 behind it)."""
+    assert len(dp4_step["convolutions"]) > 150
+    assert min(dp4_step["collectives"]) > max(dp4_step["convolutions"])
+
+
+def test_every_gradient_leaf_of_the_dp4_step_reaches_one_collective(dp4_step):
+    """What the collectives reduce is the gradient tree once over and the
+    loss: each leaf is an operand of its own, shape for shape."""
+    reduced = [shape for i in dp4_step["collectives"] for shape in
+               _result_shapes(dp4_step["ops"][i], "all-reduce")]
+    assert sorted(math.prod(s) for s in reduced if s) == \
+        sorted(x.size for x in dp4_step["leaves"])
+    assert [s for s in reduced if not s] == [()]             # the loss
+
+
+def test_one_chip_s_step_lowers_without_barrier_or_packed_bucket(topo):
+    """``resnet50_train``'s step, on its lowered text (no second compile):
+    an axis of one chains nothing, so no ``optimization_barrier`` stands
+    between what yields a gradient and the update that takes it, and no flat
+    buffer of gradients is concatenated (XLA drops the one-member
+    collectives; the compiled program is the parent's op for op, ``PERF.md``
+    PR 47)."""
+    step, args = _resnet_step(topo.devices[:1])
+    text = step.lower(*args).as_text()
+    assert "optimization_barrier" not in text
+    assert "stablehlo.all_reduce" in text
+    assert not re.findall(r"stablehlo\.concatenate.*-> tensor<(\d+)xf32>",
+                          text)
