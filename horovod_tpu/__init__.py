@@ -83,6 +83,7 @@ from horovod_tpu.ops.eager import (  # noqa: F401
 )
 from horovod_tpu.optim.distributed_optimizer import (  # noqa: F401
     DistributedOptimizer,
+    TrainStepAuxResult,
     TrainStepResult,
     allgather_object,
     allreduce_gradients,

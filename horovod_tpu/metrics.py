@@ -154,6 +154,9 @@ METRIC_HELP: dict[str, str] = {
     "hvd.negotiate_s": "Seconds from eager-op enqueue to negotiated dispatch",
     "hvd.step_s": "Per-rank engine/training step wall time in seconds",
     "hvd.step_skew_s": "Slowest-minus-median rank step time over the straggler window",
+    # train.* — what the last train step traced holds (models/moe_decoder.py)
+    "train.params_held": "Parameters the traced train step holds on a chip",
+    "train.state_bytes": "Bytes of weights, gradients and AdamW's two moments the traced train step holds",
     # fusion.* — the plan of the last in-graph fused exchange traced (ops/fusion.py)
     "fusion.buckets": "Collectives the exchange emits: one a bucket of the plan",
     "fusion.bucket_bytes_max": "Bytes of the plan's largest bucket (at most the fusion threshold unless it is one leaf)",

@@ -81,6 +81,7 @@ add to it on the device and the engine reads it with the tick's readback.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from functools import partial
 from typing import Any, NamedTuple
 
@@ -985,16 +986,37 @@ def _experts_in_place(cfg, lp, h2, group, weights, load):
         touched = load > 0
         n_touched = jnp.sum(touched, dtype=jnp.int32)
 
-    def weighed(out, c, wt):
-        return jnp.where(c[..., None], wt[..., None] * out.astype(jnp.float32),
-                         0.0)
+    with jax.named_scope("moe.experts"):
+        y = _in_place((jnp.dtype(dt), e), h2, lp["e_gate"], lp["e_up"],
+                      lp["e_down"], chosen, w, touched, n_touched)
+    return y.astype(dt)
+
+
+def _weighed(out, c, wt):
+    return jnp.where(c[..., None], wt[..., None] * out.astype(jnp.float32),
+                     0.0)
+
+
+def _in_place_batched(dt, h2, e_gate, e_up, e_down, chosen, w):
+    """Every held expert at once over the rows in place, ``[N, d]`` float32."""
+    gate = jnp.einsum("nd,edf->enf", h2, e_gate.astype(dt))
+    up = jnp.einsum("nd,edf->enf", h2, e_up.astype(dt))
+    out = jnp.einsum("enf,efd->end", jax.nn.silu(gate) * up,
+                     e_down.astype(dt))
+    return jnp.sum(_weighed(out, chosen, w), axis=0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _in_place(form, h2, e_gate, e_up, e_down, chosen, w, touched, n_touched):
+    """All held experts at once where most are touched, one touched expert a
+    step otherwise.  The loop's bound is a device value, which jax cannot
+    differentiate through; both forms compute the same sum, so the rule's
+    backward is the first form's (:func:`_in_place_bwd`)."""
+    dt, e = form
+    n, d = h2.shape
 
     def batched():
-        gate = jnp.einsum("nd,edf->enf", h2, lp["e_gate"].astype(dt))
-        up = jnp.einsum("nd,edf->enf", h2, lp["e_up"].astype(dt))
-        out = jnp.einsum("enf,efd->end", jax.nn.silu(gate) * up,
-                         lp["e_down"].astype(dt))
-        return jnp.sum(weighed(out, chosen, w), axis=0)
+        return _in_place_batched(dt, h2, e_gate, e_up, e_down, chosen, w)
 
     def looped():
         # the touched experts' ids, compacted once: nothing is searched for
@@ -1005,24 +1027,40 @@ def _experts_in_place(cfg, lp, h2, group, weights, load):
 
         def one_expert(i, y):
             j = ids[i]
-            out = _swiglu(h2, lp["e_gate"][j], lp["e_up"][j], lp["e_down"][j],
-                          dt)
-            return y + weighed(out, chosen[j], w[j])
+            out = _swiglu(h2, e_gate[j], e_up[j], e_down[j], dt)
+            return y + _weighed(out, chosen[j], w[j])
 
         return lax.fori_loop(0, n_touched, one_expert,
                              jnp.zeros((n, d), jnp.float32))
 
-    with jax.named_scope("moe.experts"):
-        y = lax.cond(_most_experts_touched(n_touched, e), batched, looped)
-    return y.astype(dt)
+    return lax.cond(_most_experts_touched(n_touched, e), batched, looped)
+
+
+def _in_place_fwd(form, h2, e_gate, e_up, e_down, chosen, w, touched,
+                  n_touched):
+    y = _in_place(form, h2, e_gate, e_up, e_down, chosen, w, touched,
+                  n_touched)
+    return y, (h2, e_gate, e_up, e_down, chosen, w)
+
+
+def _in_place_bwd(form, res, dy):
+    h2, e_gate, e_up, e_down, chosen, w = res
+    _, back = jax.vjp(
+        lambda h2, a, b, c, w: _in_place_batched(form[0], h2, a, b, c,
+                                                 chosen, w),
+        h2, e_gate, e_up, e_down, w)
+    d_h2, d_gate, d_up, d_down, d_w = back(dy)
+    return d_h2, d_gate, d_up, d_down, None, d_w, None, None
+
+
+_in_place.defvjp(_in_place_fwd, _in_place_bwd)
 
 
 def _experts_in_tiles(cfg, lp, h2, held, group, weights, load, tiles):
     """The choices sorted by expert into tile-aligned segments, every tile in
     use through its expert (``tiles``: :func:`_tiles_grouped` or
     :func:`_tiles_looped`), and each token's outcomes gathered back and
-    summed by rank."""
-    dt = cfg.dtype
+    summed by rank (:func:`_tiles_layer`, which has a derivative)."""
     n, d = h2.shape
     e, k = cfg.held_count, cfg.top_k
     tile = tile_rows(n, cfg)
@@ -1042,35 +1080,109 @@ def _experts_in_tiles(cfg, lp, h2, held, group, weights, load, tiles):
         token = jnp.arange(n * k, dtype=jnp.int32) // k
         src = jnp.full((rows,), n, jnp.int32).at[dest].set(token, mode="drop")
     with jax.named_scope("moe.experts"):
-        x_rows = jnp.concatenate([h2, jnp.zeros((1, d), dt)])[src]  # [R, d]
-        y_rows = tiles(cfg, lp, x_rows, seg_end, tile)
-        # a choice that is not held points past the rows: it reads the last
-        # one and is selected away (a zero row appended to gather instead
-        # was a copy of all of y_rows, 84 MB a layer at sdar's widths)
-        picked = jnp.where(
-            held[..., None],
-            y_rows[jnp.minimum(dest, rows - 1).reshape(n, k)], 0)  # [N, k, d]
-        y = jnp.sum(picked.astype(jnp.float32) * weights[..., None], axis=1)
+        return _tiles_layer((tiles, jnp.dtype(cfg.dtype), tile), h2, weights,
+                            lp["e_gate"], lp["e_up"], lp["e_down"], held,
+                            dest, src, seg_end)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _tiles_layer(form, h2, weights, e_gate, e_up, e_down, held, dest, src,
+                 seg_end):
+    """``[N, d]``: the rows gathered into their sorted places, the tiles in
+    use through their experts, and each token's outcomes gathered back and
+    summed by rank under the router's ``weights`` [N, k].  ``form`` is
+    ``(tiles, dtype, tile)``; ``dest`` [N * k] is each choice's row (past
+    the rows where it is not held), ``src`` [R] each row's token (``N`` for
+    padding).  The derivative (:func:`_tiles_layer_bwd`) walks the same
+    tiles and sorts nothing again; the indices carry none."""
+    tiles, dt, tile = form
+    n, d = h2.shape
+    rows = src.shape[0]
+    x_rows = jnp.concatenate([h2, jnp.zeros((1, d), dt)])[src]      # [R, d]
+    y_rows = tiles(dt, e_gate, e_up, e_down, x_rows, seg_end, tile)
+    # a choice that is not held points past the rows: it reads the last
+    # one and is selected away (a zero row appended to gather instead
+    # was a copy of all of y_rows, 84 MB a layer at sdar's widths)
+    picked = jnp.where(
+        held[..., None],
+        y_rows[jnp.minimum(dest, rows - 1).reshape(held.shape)], 0)  # [N,k,d]
+    y = jnp.sum(picked.astype(jnp.float32) * weights[..., None], axis=1)
     return y.astype(dt)
 
 
-def _tiles_grouped(cfg, lp, x_rows, seg_end, tile: int):
+def _tiles_layer_fwd(form, h2, weights, e_gate, e_up, e_down, held, dest, src,
+                     seg_end):
+    y = _tiles_layer(form, h2, weights, e_gate, e_up, e_down, held, dest, src,
+                     seg_end)
+    return y, (h2, weights, e_gate, e_up, e_down, held, dest, src, seg_end)
+
+
+def _tiles_layer_bwd(form, res, dy):
+    """Back over the sorted tiles.  A row's outcome is ``w * swiglu(x)``:
+    ``dy`` is gathered to the rows as ``x`` was (``src``), the tiles give the
+    rows' gradient, each row's ``<swiglu(x), dy>`` and every held expert's
+    weight gradient over its own tiles, and the tokens' gradient is gathered
+    from the rows as the outcome was (``dest``): no scatter of rows."""
+    tiles, dt, tile = form
+    h2, weights, e_gate, e_up, e_down, held, dest, src, seg_end = res
+    n, d = h2.shape
+    k = weights.shape[1]
+    rows = src.shape[0]
+    at = jnp.minimum(dest, rows - 1).reshape(n, k)
+    with jax.named_scope("moe.experts"):
+        pad = jnp.zeros((1, d), dt)
+        x_rows = jnp.concatenate([h2, pad])[src]
+        dy_rows = jnp.concatenate([dy.astype(dt), pad])[src]
+        w_rows = jnp.zeros((rows,), jnp.float32).at[dest].set(
+            weights.reshape(n * k).astype(jnp.float32), mode="drop")
+        dx_rows, s_rows, d_gate, d_up, d_down = _TILES_GRAD[tiles](
+            dt, e_gate, e_up, e_down, x_rows, dy_rows, w_rows[:, None],
+            seg_end, tile)
+        dh2 = jnp.sum(jnp.where(held[..., None], dx_rows[at], 0).astype(
+            jnp.float32), axis=1).astype(h2.dtype)
+        dw = jnp.where(held, s_rows[:, 0][at], 0.0).astype(weights.dtype)
+    return (dh2, dw, d_gate.astype(e_gate.dtype), d_up.astype(e_up.dtype),
+            d_down.astype(e_down.dtype), None, None, None, None)
+
+
+_tiles_layer.defvjp(_tiles_layer_fwd, _tiles_layer_bwd)
+
+
+def _tile_experts(seg_end, n_tiles: int, tile: int, e: int):
+    """The expert of every tile, searched once for all tiles: every tile
+    against every segment's end at once (a search by halving is a loop of
+    its own on the device)."""
+    first_row = jnp.arange(n_tiles, dtype=jnp.int32) * tile
+    return jnp.minimum(jnp.searchsorted(
+        seg_end, first_row, side="right", method="compare_all"),
+        e - 1).astype(jnp.int32)
+
+
+def _tiles_grouped(dt, e_gate, e_up, e_down, x_rows, seg_end, tile: int):
     """Every tile in use through its expert in one kernel call
     (:func:`grouped_experts.grouped_swiglu`): the tile -> expert map is
     searched once, here, for all tiles; the rows of the tiles past the last
     in use are not written, and no choice points at them."""
-    e = lp["e_gate"].shape[0]
-    first_row = jnp.arange(x_rows.shape[0] // tile, dtype=jnp.int32) * tile
-    # every tile against every segment's end at once: a search by halving
-    # is a loop of its own on the device
-    tile_expert = jnp.minimum(jnp.searchsorted(
-        seg_end, first_row, side="right", method="compare_all"), e - 1)
+    tile_expert = _tile_experts(seg_end, x_rows.shape[0] // tile, tile,
+                                e_gate.shape[0])
     return grouped_experts.grouped_swiglu(
-        x_rows, tile_expert.astype(jnp.int32), seg_end[-1] // tile,
-        lp["e_gate"], lp["e_up"], lp["e_down"], tile=tile, dtype=cfg.dtype)
+        x_rows, tile_expert, seg_end[-1] // tile, e_gate, e_up, e_down,
+        tile=tile, dtype=dt)
 
 
-def _tiles_looped(cfg, lp, x_rows, seg_end, tile: int):
+def _tiles_grouped_grad(dt, e_gate, e_up, e_down, x_rows, dy_rows, w_rows,
+                        seg_end, tile: int):
+    """:func:`_tiles_grouped`'s derivative, two kernel calls over the same
+    tiles (:func:`grouped_experts.grouped_swiglu_grad`)."""
+    tile_expert = _tile_experts(seg_end, x_rows.shape[0] // tile, tile,
+                                e_gate.shape[0])
+    tiles_of = jnp.diff(seg_end, prepend=0) // tile
+    return grouped_experts.grouped_swiglu_grad(
+        x_rows, dy_rows, w_rows, tile_expert, seg_end[-1] // tile,
+        tiles_of.astype(jnp.int32), e_gate, e_up, e_down, tile=tile, dtype=dt)
+
+
+def _tiles_looped(dt, e_gate, e_up, e_down, x_rows, seg_end, tile: int):
     """The plain form, for widths that are not whole lanes (the toy
     configurations of the CPU tests): a loop over the tiles in use, one
     expert's matrices sliced out a trip."""
@@ -1078,12 +1190,46 @@ def _tiles_looped(cfg, lp, x_rows, seg_end, tile: int):
     def one_tile(i, y):
         j = jnp.searchsorted(seg_end, i * tile, side="right")
         x = lax.dynamic_slice_in_dim(x_rows, i * tile, tile)
-        out = _swiglu(x, lp["e_gate"][j], lp["e_up"][j], lp["e_down"][j],
-                      cfg.dtype)
+        out = _swiglu(x, e_gate[j], e_up[j], e_down[j], dt)
         return lax.dynamic_update_slice_in_dim(y, out, i * tile, axis=0)
 
     return lax.fori_loop(0, seg_end[-1] // tile, one_tile,
-                         jnp.zeros(x_rows.shape, cfg.dtype))
+                         jnp.zeros(x_rows.shape, dt))
+
+
+def _tiles_looped_grad(dt, e_gate, e_up, e_down, x_rows, dy_rows, w_rows,
+                       seg_end, tile: int):
+    """:func:`_tiles_looped`'s derivative in the same plain form: a trip a
+    tile in use, ``_swiglu``'s own transpose, the expert's gradients added in
+    float32 where they lie."""
+    f32 = jnp.float32
+
+    def one_tile(i, carry):
+        dx, s, dg, du, dd = carry
+        j = jnp.searchsorted(seg_end, i * tile, side="right")
+        x = lax.dynamic_slice_in_dim(x_rows, i * tile, tile)
+        dy = lax.dynamic_slice_in_dim(dy_rows, i * tile, tile)
+        w = lax.dynamic_slice_in_dim(w_rows, i * tile, tile)
+        out, back = jax.vjp(lambda x, a, b, c: _swiglu(x, a, b, c, dt),
+                            x, e_gate[j], e_up[j], e_down[j])
+        gx, ga, gb, gc = back((dy.astype(f32) * w).astype(out.dtype))
+        put = functools.partial(lax.dynamic_update_slice_in_dim, axis=0)
+        return (put(dx, gx.astype(dt), i * tile),
+                put(s, jnp.sum(out.astype(f32) * dy.astype(f32), axis=1,
+                               keepdims=True), i * tile),
+                dg.at[j].add(ga.astype(f32)), du.at[j].add(gb.astype(f32)),
+                dd.at[j].add(gc.astype(f32)))
+
+    return lax.fori_loop(
+        0, seg_end[-1] // tile, one_tile,
+        (jnp.zeros(x_rows.shape, dt), jnp.zeros(w_rows.shape, f32),
+         jnp.zeros(e_gate.shape, f32), jnp.zeros(e_up.shape, f32),
+         jnp.zeros(e_down.shape, f32)))
+
+
+#: each form of the tiles' product with its derivative
+_TILES_GRAD = {_tiles_grouped: _tiles_grouped_grad,
+               _tiles_looped: _tiles_looped_grad}
 
 
 def _expert_layer(cfg, lp, h, valid):

@@ -25,6 +25,7 @@ capability-extension model the baseline tracks, built TPU-first:
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Callable, NamedTuple
 
@@ -227,12 +228,49 @@ def rmsnorm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     return (x32 * scale).astype(x.dtype) * w.astype(x.dtype)
 
 
+def rope_inv_freq(head_dim: int, theta: float) -> jax.Array:
+    """Plain rotary: ``theta ** (-2i / head_dim)`` for ``i`` in the half."""
+    half = head_dim // 2
+    return theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+
+
+def yarn_inv_freq(head_dim: int, theta: float, *, factor: float,
+                  original_max: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0) -> jax.Array:
+    """YaRN's frequencies as ``transformers`` computes them (``truncate``
+    true): pair ``i`` keeps its plain frequency below ``low``, takes it
+    divided by ``factor`` above ``high``, and a linear blend between, where
+    ``low`` / ``high`` are the pairs that turn ``beta_fast`` / ``beta_slow``
+    times over ``original_max`` positions."""
+    half = head_dim // 2
+
+    def pair_of(turns: float) -> float:
+        return head_dim * math.log(original_max / (turns * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = min(max(math.floor(pair_of(beta_fast)), 0), half - 1)
+    high = min(max(math.ceil(pair_of(beta_slow)), 0), half - 1)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    plain = rope_inv_freq(head_dim, theta)
+    return (1.0 - ramp) * plain + ramp * plain / factor
+
+
+def rope_tables_from(inv_freq: jax.Array, positions: jax.Array,
+                     attention_factor: float | None = None) -> tuple:
+    """cos/sin tables for ``positions`` [..., L] → [..., L, head_dim//2],
+    both times ``attention_factor`` where a scaling gives one (YaRN's)."""
+    angles = positions[..., None].astype(jnp.float32) * inv_freq
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if attention_factor is None:
+        return cos, sin
+    return cos * attention_factor, sin * attention_factor
+
+
 def rope_tables(cfg: LlamaConfig, positions: jax.Array) -> tuple[jax.Array, jax.Array]:
     """cos/sin tables for ``positions`` [..., L] → [..., L, head_dim//2]."""
-    half = cfg.head_dim // 2
-    freqs = cfg.rope_theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    angles = positions[..., None].astype(jnp.float32) * freqs
-    return jnp.cos(angles), jnp.sin(angles)
+    return rope_tables_from(rope_inv_freq(cfg.head_dim, cfg.rope_theta),
+                            positions)
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:

@@ -1,9 +1,13 @@
-"""Mixture-of-Experts layer with expert parallelism — the TRAINING-side
-layer: routing is over a fixed expert capacity and tokens past it are
-dropped (``capacity_factor``), which a training step tolerates and a served
-model cannot (its logits would not agree with a reference).  The served,
-dropless expert layer that is told which experts it holds is
-:func:`horovod_tpu.models.latent_moe.held_experts`.
+"""Mixture-of-Experts layer with expert parallelism in its capacity form:
+routing is over a fixed expert capacity and tokens past it are dropped
+(``capacity_factor``), dispatch and combine are one-hot einsums ``[T, E,
+C]``.  That is what it is: a static-shape layer whose exchange GSPMD can
+place, which cannot agree with a reference (a dropped token's logits
+differ).  The dropless layer that is told which experts it holds, for
+serving and, with a backward pass over its sorted tiles, for training that
+agrees with a reference, is
+:func:`horovod_tpu.models.latent_moe.held_experts`
+(:mod:`horovod_tpu.models.moe_decoder` trains through it).
 
 No reference equivalent (the reference is a data-parallel-only framework,
 SURVEY.md §2.3); this supplies the EP axis of the framework's parallelism

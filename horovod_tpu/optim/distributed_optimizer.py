@@ -250,6 +250,15 @@ class TrainStepResult(NamedTuple):
     loss: jax.Array
 
 
+class TrainStepAuxResult(NamedTuple):
+    """:class:`TrainStepResult` of a step built with ``has_aux``: the same
+    three fields and the loss function's counters, summed over the ranks."""
+    params: Any
+    opt_state: Any
+    loss: jax.Array
+    aux: Any
+
+
 def make_train_step(
     loss_fn: Callable[..., jax.Array],
     optimizer: optax.GradientTransformation,
@@ -257,6 +266,7 @@ def make_train_step(
     mesh: jax.sharding.Mesh | None = None,
     axis_name: str = AXIS_NAME,
     donate: bool = True,
+    has_aux: bool = False,
 ) -> Callable[..., TrainStepResult]:
     """Build the canonical data-parallel train step, compiled over the mesh.
 
@@ -268,6 +278,12 @@ def make_train_step(
     a bare ``optimizer.init(params)`` will do); it returns updated
     replicated params, opt_state, and the globally-averaged loss.
 
+    With ``has_aux`` the loss function returns ``(loss, aux)``, ``aux`` a
+    tree of counters (what a model counts while it computes: choices routed,
+    blocks visited); they leave the step summed over the ranks, as ``aux``
+    of a :class:`TrainStepAuxResult`, and the host reads them when it wants
+    to.  Without it the step is traced as it always was.
+
     This is the whole L5→L2 stack of the reference collapsed into one
     compiled program: examples/tensorflow_mnist.py:85's
     ``opt.minimize(loss)`` → stack §3.2 of SURVEY.md.
@@ -278,17 +294,25 @@ def make_train_step(
         mesh = basics.mesh()
 
     def step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+        loss, grads = jax.value_and_grad(loss_fn, has_aux=has_aux)(
+            params, batch)
+        if has_aux:
+            loss, aux = loss
         updates, opt_state = optimizer.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)
         mean_loss = collective_ops.allreduce(loss, op=Average, axis_name=axis_name)
+        if has_aux:
+            return TrainStepAuxResult(
+                params, opt_state, mean_loss,
+                jax.tree.map(lambda a: jax.lax.psum(a, axis_name), aux))
         return TrainStepResult(params, opt_state, mean_loss)
 
     smapped = jax.shard_map(
         step,
         mesh=mesh,
         in_specs=(P(), P(), P(axis_name)),
-        out_specs=TrainStepResult(P(), P(), P()),
+        out_specs=(TrainStepAuxResult(P(), P(), P(), P()) if has_aux
+                   else TrainStepResult(P(), P(), P())),
         check_vma=False,
     )
     jitted = jax.jit(smapped, donate_argnums=(0, 1) if donate else ())

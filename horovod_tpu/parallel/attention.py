@@ -46,11 +46,14 @@ def _repeat_kv(k: jax.Array, n_rep: int) -> jax.Array:
 def dense_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
     q_offset: int | jax.Array = 0, kv_offset: int | jax.Array = 0,
+    window: int | None = None,
 ) -> jax.Array:
     """Reference O(L²)-memory attention (the ground truth for tests).
 
     ``q_offset``/``kv_offset`` are the global positions of element 0 of the
     q/kv sequence axes — needed for causal masking on sequence shards.
+    ``window`` (with ``causal``): query ``t`` sees key ``j`` iff
+    ``0 <= t - j < window``.
     """
     b, lq, h, d = q.shape
     kvh = k.shape[2]
@@ -62,10 +65,17 @@ def dense_attention(
     if causal:
         qpos = q_offset + jnp.arange(lq)[:, None]
         kpos = kv_offset + jnp.arange(k.shape[1])[None, :]
-        s = jnp.where(qpos >= kpos, s, NEG_INF)
+        s = jnp.where(_visible(qpos, kpos, window), s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
     return out.astype(q.dtype)
+
+
+def _visible(qpos, kpos, window):
+    """The causal mask, narrowed to a band of ``window`` keys if given."""
+    if window is None:
+        return qpos >= kpos
+    return (qpos >= kpos) & (qpos - kpos < window)
 
 
 class _SoftmaxState(NamedTuple):
@@ -92,6 +102,7 @@ def _block_update(
     q_positions: jax.Array | None = None,
     kv_positions: jax.Array | None = None,
     kv_valid: jax.Array | None = None,
+    window: int | None = None,
 ) -> _SoftmaxState:
     """Fold one KV block into the running softmax state.
 
@@ -119,7 +130,7 @@ def _block_update(
                 else q_offset + jnp.arange(lq))[:, None]
         kpos = (kv_positions if kv_positions is not None
                 else kv_offset + jnp.arange(lk))[None, :]
-        s = jnp.where(qpos >= kpos, s, NEG_INF)
+        s = jnp.where(_visible(qpos, kpos, window), s, NEG_INF)
     if kv_valid is not None:
         s = jnp.where(kv_valid[None, None, None, :], s, NEG_INF)
     m_new = jnp.maximum(state.m, s.max(axis=-1))
@@ -144,8 +155,11 @@ def _finalize(state: _SoftmaxState, dtype) -> jax.Array:
 def blockwise_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
     block_size: int = 512, q_offset=0, kv_offset=0,
+    window: int | None = None,
 ) -> jax.Array:
     """O(L)-memory attention: scan over KV chunks with online softmax.
+    ``window`` narrows the causal mask to a band (every chunk is still
+    scanned: this is the oracle, not the fast path).
 
     Single-device analogue of ring attention (one ring step per local KV
     block); also the differentiable fallback the pallas flash kernel's
@@ -167,7 +181,7 @@ def blockwise_attention(
             state, q, kblk, vblk, causal=causal,
             q_offset=q_offset,
             kv_offset=kv_offset + i * block_size,
-            kv_valid=valid if pad else None,
+            kv_valid=valid if pad else None, window=window,
         )
         return new, None
 

@@ -29,13 +29,31 @@ ACCEPTED_DIGEST = \
     "93369594d82581a81e0de054f52bc44d62b7765c7c3e83b810910a1f2e9c3481"
 
 
+#: cells that later PRs appended to accepted metrics' lists of cells (the one
+#: edit of an accepted entry the contract allows): PR 48's, to the train
+#: step's and the device's four
+JOINED_LATER = ("mellum2_pretrain8k",)
+
+
+def _as_accepted(entry: dict) -> dict:
+    """An accepted entry without the cells that joined its list later, which
+    have to stand at the list's end."""
+    cells = entry.get("workloads", [])
+    kept = [c for c in cells if c not in JOINED_LATER]
+    assert cells[:len(kept)] == kept, entry["name"]
+    return dict(entry, workloads=kept) if "workloads" in entry else entry
+
+
 def test_every_new_entry_has_its_file_and_accepted_cells(monkeypatch):  # noqa: F811,E501
     spec = lib.benchmark_spec()
-    assert hashlib.sha256(json.dumps(
-        spec["per_layer"][:ACCEPTED_ENTRIES], sort_keys=True).encode()
-    ).hexdigest() == ACCEPTED_DIGEST
+    first = [_as_accepted(m) for m in spec["per_layer"][:ACCEPTED_ENTRIES]]
+    assert hashlib.sha256(json.dumps(first, sort_keys=True).encode()
+                          ).hexdigest() == ACCEPTED_DIGEST
+    assert [m["name"] for m, was in zip(spec["per_layer"], first)
+            if m != was] == ["step_ms", "mfu_pct", "device_idle_pct.train",
+                             "hbm_peak_gb.train"]
     monkeypatch.setattr(accepted.lib, "benchmark_spec", lambda: dict(
-        spec, per_layer=spec["per_layer"][:ACCEPTED_ENTRIES]))
+        spec, per_layer=first))
     accepted.test_every_new_entry_has_its_file_and_accepted_cells()
     cells = {w["name"] for w in spec["workloads"]}
     for m in spec["per_layer"][ACCEPTED_ENTRIES:]:

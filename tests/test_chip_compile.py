@@ -1054,3 +1054,75 @@ def test_one_chip_s_step_lowers_without_barrier_or_packed_bucket(topo):
     assert "stablehlo.all_reduce" in text
     assert not re.findall(r"stablehlo\.concatenate.*-> tensor<(\d+)xf32>",
                           text)
+
+
+def _decoder_step(devices):
+    """``mellum2_pretrain8k``'s step (``benchmark/configs/mellum2-12b-a2.5b
+    .json`` under ``traffic/packed8k_b4.json``) over ``devices``, as the
+    family builds it, and its arguments as shapes on the mesh."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import horovod_tpu as hvd
+    from benchmark import lib
+    from horovod_tpu.models import moe_decoder as md
+
+    family = lib.load_module("families", "mellum_train")
+    cfg = _benchmark_json("configs", "mellum2-12b-a2.5b.json")
+    mix = _benchmark_json("traffic", "packed8k_b4.json")
+    mc = family.model_config(cfg)
+    mesh = Mesh(np.array(devices), ("hvd",))
+    replicated = NamedSharding(mesh, P())
+    rows = NamedSharding(mesh, P("hvd"))
+    params = jax.eval_shape(lambda: md.init_params(mc, jax.random.key(0)))
+    tx = hvd.DistributedOptimizer(family.optimizer(cfg))
+    ids = jax.ShapeDtypeStruct(
+        (mix["per_chip_batch"] * len(devices), cfg["training"]["seq_len"]),
+        jnp.int32, sharding=rows)
+    args = (_avals(params, replicated),
+            _avals(jax.eval_shape(tx.init, params), replicated), (ids, ids))
+    return hvd.make_train_step(partial(md.loss_and_counters, cfg=mc), tx,
+                               mesh=mesh, has_aux=True), args, mc
+
+
+@pytest.fixture(scope="module")
+def decoder_step(topo, no_compile_cache):
+    """The cell's train step compiled once for one described chip at the
+    cell's own size: its text and the compiler's account of its memory."""
+    step, args, mc = _decoder_step(topo.devices[:1])
+    compiled = step.lower(*args).compile()
+    return {"text": compiled.as_text(), "mem": compiled.memory_analysis(),
+            "mc": mc}
+
+
+def test_the_decoder_step_fits_the_chip_beside_its_state(decoder_step):
+    """Weights and AdamW's two moments are the step's arguments (updated in
+    place), the gradients and what the backward keeps are its scratch: all
+    of it under the chip's 15.75 GiB with half a GB to spare."""
+    from horovod_tpu.models import moe_decoder as md
+
+    mem = decoder_step["mem"]
+    n = md.param_count(decoder_step["mc"])
+    assert n == 595_153_152
+    assert mem.argument_size_in_bytes == pytest.approx(12 * n, rel=1e-3)
+    assert mem.alias_size_in_bytes >= 12 * n         # updated where it lies
+    held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert held < 15.75 * 2**30 - 0.5e9, (held, mem)
+
+
+@pytest.mark.parametrize("kernel,calls", [
+    # 3 sliding layers and 1 full one; a layer's flash forward runs once
+    # more in the backward's recomputation (its outcome and row sums are
+    # what dQ and dK/dV read), the grouped forward does not (the expert
+    # layer's backward reads the layer's inputs alone)
+    ("flash_band_fwd", 6), ("flash_band_dq", 3), ("flash_band_dkv", 3),
+    ("flash_fwd", 2), ("flash_dq", 1), ("flash_dkv", 1),
+    ("grouped_swiglu", 4), ("grouped_swiglu_dx", 4),
+    ("grouped_swiglu_dw", 4)])
+def test_the_decoder_step_holds_its_kernels_lowered_by_mosaic(decoder_step,
+                                                              kernel, calls):
+    found = [line for line in decoder_step["text"].splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and re.search(rf"/{kernel}/pallas_call", line)]
+    assert len(found) == calls, (kernel, len(found))
